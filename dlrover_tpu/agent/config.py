@@ -11,6 +11,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ..common.compile_cache import CACHE_DIR_ENV, resolve_cache_dir
 from ..common.constants import Accelerators, DefaultValues, NodeEnv
 
 
@@ -63,11 +64,6 @@ class ElasticLaunchConfig:
     # boundary (trainer/remesh.py) before falling back to a restart.
     soft_remesh: bool = True
     soft_remesh_timeout_s: float = 15.0
-    # Persistent XLA compile cache shared by every worker incarnation
-    # of this job (warm-restart fast path, docs/recovery.md). Empty =
-    # inherit DLROVER_COMPILE_CACHE_DIR from the environment (possibly
-    # unset → disabled).
-    compile_cache_dir: str = ""
     # Double-buffered input pipeline in ElasticTrainLoop (default on;
     # tpurun --sync-input turns it off for sources that must not see a
     # draw ahead of the step that consumes it).
@@ -121,8 +117,21 @@ class ElasticLaunchConfig:
         env[NodeEnv.NODE_UNIT] = str(self.node_unit)
         if self.auto_tunning:
             env[NodeEnv.AUTO_TUNNING] = "1"
-        if self.compile_cache_dir:
-            env["DLROVER_COMPILE_CACHE_DIR"] = self.compile_cache_dir
+        # One compile cache for every incarnation and every later run:
+        # the caller's JAX_COMPILATION_CACHE_DIR, else the fixed path in
+        # the checkout (common/compile_cache.py). JAX reads the variable
+        # itself, so the worker script need not call anything.
+        env.setdefault(CACHE_DIR_ENV, resolve_cache_dir())
+        # No hidden CPU: a TPU job whose caller pinned no platform pins
+        # "tpu", so a failed TPU initialization raises in the worker
+        # (and the agent sees a failed worker) instead of JAX warning
+        # and training on the host. A caller's own JAX_PLATFORMS (tests,
+        # virtual-CPU drills) is inherited untouched.
+        if (
+            self.accelerator == Accelerators.TPU
+            and not os.environ.get("JAX_PLATFORMS")
+        ):
+            env.setdefault("JAX_PLATFORMS", "tpu")
         if not self.input_prefetch:
             env["DLROVER_INPUT_PREFETCH"] = "0"
         return env

@@ -36,13 +36,10 @@ def main() -> int:
             pass
     # Pre-apply the shared compile cache (safe: config stays mutable
     # until backend init, which the spare never triggers) so even this
-    # knob's setup cost is paid before the handoff.
-    try:
-        from dlrover_tpu.common.compile_cache import enable_compile_cache
+    # setup cost is paid before the handoff.
+    from dlrover_tpu.common.compile_cache import enable_compile_cache
 
-        enable_compile_cache()
-    except Exception as e:  # noqa: BLE001 — an optimization only
-        print(f"warm spare: compile cache unavailable: {e!r}", file=sys.stderr)
+    enable_compile_cache()
     # Tell the agent we are ready (it may wait to avoid racing a
     # half-imported spare into a rendezvous round). The marker is a
     # file because stdout is usually redirected into the worker log.

@@ -23,6 +23,7 @@ import sys
 import time
 from typing import Dict, Optional
 
+from ..common.compile_cache import CACHE_ENABLE_ENV
 from ..common.log import logger
 
 _TRAINER_TEMPLATE = r'''
@@ -30,11 +31,10 @@ import os, time
 from dlrover_tpu.common.platform import force_virtual_cpu
 force_virtual_cpu(1)
 import jax
-# Same-host persistent compile cache through the SHARED runtime knob
-# (common/compile_cache.py, DLROVER_COMPILE_CACHE_DIR in the storm
-# env): replacements of THIS run must not pay the jit compile again —
-# production, storm, and tests now ride one code path, and importing
-# any module has no config side effects.
+# Persistent compile cache through the one shared placement rule
+# (common/compile_cache.py): replacements of THIS run — and later runs —
+# must not pay the jit compile again. Production, storm and tests ride
+# one code path, and importing any module has no config side effects.
 from dlrover_tpu.common.compile_cache import enable_compile_cache
 enable_compile_cache()
 import jax.numpy as jnp
@@ -145,7 +145,7 @@ def run_goodput_storm(
     slice_kills: int = 0,
     extra_env: Optional[Dict[str, str]] = None,
     prewarm: bool = True,
-    cache_dir: Optional[str] = None,
+    compile_cache: bool = True,
     max_relaunch: Optional[int] = None,
 ) -> Optional[Dict[str, float]]:
     """Run the storm; returns the measured outcome or None on timeout.
@@ -161,12 +161,14 @@ def run_goodput_storm(
     ``slice_relaunches`` (how many times the master's slice-aligned
     group relaunch actually ran).
 
-    ``cache_dir`` controls the persistent compile cache: None (default)
-    uses a per-run directory under ``workdir`` — every replacement of
-    this run reuses its first boot's compiles; ``""`` DISABLES the
-    cache entirely (the cold leg of :func:`run_recovery_ab` — every
-    incarnation, replacements included, pays the full XLA compile
-    inside the measured window).
+    ``compile_cache`` controls the persistent compile cache: True
+    (default) follows the shared placement rule
+    (``common/compile_cache.py``: the caller's
+    ``JAX_COMPILATION_CACHE_DIR``, else the fixed path in the checkout),
+    so every replacement of this run and every later run reuses the
+    first boot's compiles; False DISABLES the cache entirely (the cold
+    leg of :func:`run_recovery_ab` — every incarnation, replacements
+    included, pays the full XLA compile inside the measured window).
 
     ``max_relaunch`` overrides both the agent worker-restart budget and
     the master's node-relaunch budget for this run (None keeps the
@@ -175,13 +177,9 @@ def run_goodput_storm(
     plan) crash-loops workers; the kills stay identical either way.
     """
     os.makedirs(workdir, exist_ok=True)
-    if cache_dir is None:
-        cache_dir = os.path.join(workdir, "xla_cache")
     ckpt_dir = os.path.join(workdir, "ckpt")
     recovery_dir = os.path.join(workdir, "recovery")
     trace_dir = os.path.join(workdir, "trace")
-    if cache_dir:
-        os.makedirs(cache_dir, exist_ok=True)
     os.makedirs(ckpt_dir, exist_ok=True)
     os.makedirs(recovery_dir, exist_ok=True)
     os.makedirs(trace_dir, exist_ok=True)
@@ -203,14 +201,13 @@ def run_goodput_storm(
     with open(script, "w") as f:
         f.write(_TRAINER_TEMPLATE)
 
-    if prewarm and cache_dir:
+    if prewarm and compile_cache:
         # Prewarm the shared compile cache outside the measured window.
         import subprocess
 
         prewarm_env = dict(
             os.environ,
             STORM_PREWARM="1",
-            DLROVER_COMPILE_CACHE_DIR=cache_dir,
             PYTHONPATH=os.pathsep.join(sys.path),
         )
         subprocess.run(
@@ -242,11 +239,12 @@ def run_goodput_storm(
         "DLROVER_EVENT_DIR": trace_dir,
         "DLROVER_TRACE_DIR": trace_dir,
     }
-    # The shared runtime knob (common/compile_cache.py): agents inherit
-    # it and export it to every trainer incarnation. Explicitly "" when
-    # disabled, so a cache dir in the CALLER's environment (bench) can
-    # never leak into a cold leg.
-    env["DLROVER_COMPILE_CACHE_DIR"] = cache_dir or ""
+    # Placement is the shared rule's (agents hand the directory to
+    # every trainer incarnation). The cold leg turns the cache OFF with
+    # JAX's own switch, so a directory in the caller's environment can
+    # never leak into it.
+    if not compile_cache:
+        env[CACHE_ENABLE_ENV] = "0"
     env.update(extra_env or {})
     master, scaler, watcher = make_process_master(
         job_name,
@@ -539,7 +537,7 @@ def run_recovery_ab(
     cold = run_goodput_storm(
         os.path.join(workdir, "cold"),
         prewarm=False,
-        cache_dir="",  # disabled: recoveries recompile from scratch
+        compile_cache=False,  # recoveries recompile from scratch
         job_name=f"{job}_cold",
         **params,
     )
@@ -548,7 +546,6 @@ def run_recovery_ab(
     warm = run_goodput_storm(
         os.path.join(workdir, "warm"),
         prewarm=True,
-        cache_dir=os.path.join(workdir, "warm_xla_cache"),
         job_name=f"{job}_warm",
         **params,
     )

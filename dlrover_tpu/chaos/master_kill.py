@@ -364,8 +364,7 @@ def run_master_kill_storm(
     state_dir = os.path.join(workdir, "state")
     recovery_dir = os.path.join(workdir, "recovery")
     ckpt_dir = os.path.join(workdir, "ckpt")
-    cache_dir = os.path.join(workdir, "xla_cache")
-    for d in (recovery_dir, ckpt_dir, cache_dir):
+    for d in (recovery_dir, ckpt_dir):
         os.makedirs(d, exist_ok=True)
     script = os.path.join(workdir, "storm_trainer.py")
     with open(script, "w") as f:
@@ -374,7 +373,6 @@ def run_master_kill_storm(
         prewarm_env = dict(
             os.environ,
             STORM_PREWARM="1",
-            DLROVER_COMPILE_CACHE_DIR=cache_dir,
             PYTHONPATH=os.pathsep.join(sys.path),
         )
         subprocess.run(
@@ -413,7 +411,6 @@ def run_master_kill_storm(
                 os.environ,
                 PYTHONPATH=os.pathsep.join(sys.path),
                 DLROVER_RECOVERY_DIR=recovery_dir,
-                DLROVER_COMPILE_CACHE_DIR=cache_dir,
                 DLROVER_MASTER_SERVICE_TYPE=_HTTP,
                 DLROVER_IPC_NAMESPACE=namespaces[rank],
                 DLROVER_LOCAL_DEVICES="1",

@@ -71,6 +71,11 @@ def _restore_into_template(template: Any, arrays: Dict[str, np.ndarray]) -> Any:
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
+def _device_memory_stats(device) -> Optional[Dict[str, int]]:
+    """What the backend reports for ``device`` (None on the CPU)."""
+    return device.memory_stats()
+
+
 def _process_count() -> int:
     """World size WITHOUT initializing a jax backend: the engine also
     runs inside non-JAX workers (torch family), where jax.process_count()
@@ -78,13 +83,10 @@ def _process_count() -> int:
     accelerator is unreachable. jax.distributed.initialize records the
     world in the distributed global state; absent that, we are single
     process by definition."""
-    try:
-        from jax._src import distributed
+    # private module, checked against the installed jax 0.9.0
+    from jax._src import distributed
 
-        return int(getattr(distributed.global_state, "num_processes", None) or 1)
-    except Exception as e:  # noqa: BLE001 — private-module drift
-        logger.debug("jax distributed state unreadable: %r", e)
-        return 1
+    return int(distributed.global_state.num_processes or 1)
 
 
 class CheckpointEngine:
@@ -187,6 +189,7 @@ class CheckpointEngine:
         # attempt fails RESOURCE_EXHAUSTED and all later block=False
         # saves transparently degrade to the blocking path.
         self._async_disabled = False
+        self._headroom_logged = False
         # Overlapped restore (warm-restart fast path, docs/recovery.md):
         # the host-side half of the restore — shm attach + copy-out, or
         # the peer replica fetch when this host's shm is empty
@@ -437,8 +440,10 @@ class CheckpointEngine:
                 "skip save_to_memory step %s: a persister is busy", step
             )
             return False
-        if not block and self._async_disabled:
-            block = True  # degraded: no HBM headroom for snapshots
+        if not block and (
+            self._async_disabled or not self._snapshot_fits(pytree)
+        ):
+            block = True  # no HBM headroom for a device-side snapshot
         if not block:
             try:
                 snapshot = self._snapshot(pytree)
@@ -488,6 +493,47 @@ class CheckpointEngine:
             # Mirror to the backup peer — handled by the agent saver so
             # the trainer never blocks on a DCN transfer.
             self._event_q.put({"type": CheckpointEvent.REPLICATE, "step": step})
+        return True
+
+    def _snapshot_fits(self, pytree: Any) -> bool:
+        """Whether every device has room for a second copy of its share
+        of ``pytree`` BESIDE the largest program it has run. The
+        snapshot outlives the dispatch of the next step (staging streams
+        it out meanwhile), and XLA reserves a step's temporaries as one
+        block when it loads the program: a snapshot that fit between two
+        steps makes the NEXT step fail to load (seen on the v5e with
+        GPT-2-small at b32: "Attempting to reserve 13.25G ... 12.97G
+        free"). The reservation is not part of ``bytes_in_use``; its
+        high-water mark is ``peak_bytes_reserved``. A backend that
+        reports no memory stats (CPU) is taken to have room."""
+        need: Dict[Any, int] = {}
+        for leaf in jax.tree_util.tree_leaves(pytree):
+            if isinstance(leaf, jax.Array):
+                for shard in leaf.addressable_shards:
+                    need[shard.device] = (
+                        need.get(shard.device, 0) + shard.data.nbytes
+                    )
+        for device, nbytes in need.items():
+            stats = _device_memory_stats(device)
+            if not stats or "bytes_limit" not in stats:
+                continue
+            headroom = (
+                stats["bytes_limit"]
+                - stats.get("peak_bytes_reserved", 0)
+                - stats.get("bytes_in_use", 0)
+            )
+            if nbytes > headroom:
+                if not self._headroom_logged:
+                    self._headroom_logged = True
+                    logger.warning(
+                        "async staging off: %s needs %.2f GiB for a "
+                        "snapshot, %.2f GiB free beside its largest "
+                        "program; saves block on D2H instead",
+                        device,
+                        nbytes / 2**30,
+                        headroom / 2**30,
+                    )
+                return False
         return True
 
     def _snapshot(self, pytree: Any) -> Any:

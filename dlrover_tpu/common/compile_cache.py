@@ -1,80 +1,67 @@
-"""Persistent XLA compilation-cache: the shared runtime knob.
+"""Persistent XLA compilation cache: one placement rule for every process.
 
 Recovery is compile-dominated once restore is overlapped: a restarted
 (or re-meshed) worker re-traces and re-compiles the train step before
-its first step runs, and on real models that is tens of seconds of
-pure MTTR. XLA's persistent compilation cache turns that into a disk
-read — but only if every process of the job points at the SAME cache
-directory with the SAME thresholds. Before this module each consumer
-wired its own (``goodput_storm`` set a private ``STORM_CACHE_DIR`` at
-trainer-template import time); now there is one Context/env-driven
-knob that the agent exports to every worker, the warm spare pre-applies
-during its idle imports, and the chaos storm shares with production.
+its first step runs. XLA's persistent compilation cache turns that into
+a disk read — but only if every process of the job, and every later
+run, points at the SAME directory: the directory is part of the cache
+key, so a cache that moves never hits.
 
-Knobs (Context fields, ``DLROVER_*`` env overridable):
+Placement (the whole rule):
 
-- ``compile_cache_dir`` — cache directory; empty disables the cache.
-- ``compile_cache_min_compile_s`` — only compilations at least this
-  expensive are persisted (kernel-sized entries would bloat the cache
-  for no MTTR win).
+- ``JAX_COMPILATION_CACHE_DIR`` set by the caller: JAX reads it itself,
+  this code sets no other directory, and the agent hands the same value
+  to its workers (:func:`resolve_cache_dir` in ``worker_env``).
+- unset: one fixed path inside the checkout, :data:`DEFAULT_CACHE_DIR`
+  (git-ignored). Never a path made from a temporary name, a pid or the
+  time.
 
-Same-machine/same-topology reuse is the sound case (one directory per
-job; the fingerprint covers the computation + compile options, so a
-stale entry can mislead only across incompatible XLA versions, which
-the cache itself guards). Calling :func:`enable_compile_cache` is
-idempotent and must happen before the first compilation it should
-serve — jax config stays mutable until then.
+Turning the cache off is JAX's own switch too
+(``JAX_ENABLE_COMPILATION_CACHE=0``; the chaos harnesses' cold arm sets
+it). ``DLROVER_COMPILE_CACHE_MIN_COMPILE_S`` keeps kernel-sized entries
+out. :func:`enable_compile_cache` is idempotent and must run before the
+first compilation it should serve; a directory that cannot be made
+raises — a cache silently off is an unexplained slow restart.
 """
 
 import os
-import threading
 from typing import Optional
 
 from .log import logger
 
-_lock = threading.Lock()
-_applied_dir: Optional[str] = None
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+CACHE_ENABLE_ENV = "JAX_ENABLE_COMPILATION_CACHE"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_compile_cache",
+)
 
 
-def enable_compile_cache(
-    cache_dir: Optional[str] = None,
-    min_compile_s: Optional[float] = None,
-) -> Optional[str]:
-    """Point jax's persistent compilation cache at the job's shared
-    directory. Resolution order: explicit arg → Context (env-applied
-    ``DLROVER_COMPILE_CACHE_DIR``). Returns the directory in effect, or
-    None when the knob is unset (cache disabled). Idempotent; never
-    raises — a broken cache dir must not take training down with it.
-    """
-    global _applied_dir
+def resolve_cache_dir() -> str:
+    """The directory the placement rule yields in this environment.
+    Imports no JAX — safe in the agent and in harness parents."""
+    return os.environ.get(CACHE_DIR_ENV) or DEFAULT_CACHE_DIR
+
+
+def enable_compile_cache(min_compile_s: Optional[float] = None) -> str:
+    """Apply the placement rule to this process's jax config and return
+    the directory in effect."""
+    import jax
+
     from .config import get_context
 
-    ctx = get_context()
-    cache_dir = cache_dir if cache_dir is not None else ctx.compile_cache_dir
-    if not cache_dir:
-        return None
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != path:
+            jax.config.update("jax_compilation_cache_dir", path)
+            logger.info("persistent compile cache: %s", path)
     if min_compile_s is None:
-        min_compile_s = ctx.compile_cache_min_compile_s
-    with _lock:
-        if _applied_dir == cache_dir:
-            return cache_dir
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs",
-                float(min_compile_s),
-            )
-            _applied_dir = cache_dir
-            logger.info("persistent compile cache: %s", cache_dir)
-            return cache_dir
-        except Exception as e:  # noqa: BLE001 — an optimization only
-            logger.warning("compile cache unavailable (%s): %s", cache_dir, e)
-            return None
-
-
-def active_cache_dir() -> Optional[str]:
-    """The directory :func:`enable_compile_cache` applied, or None."""
-    return _applied_dir
+        min_compile_s = get_context().compile_cache_min_compile_s
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", float(min_compile_s)
+    )
+    return path

@@ -94,10 +94,9 @@ class Context:
     # Rank 0's wait for every host's shard-done signal before commit.
     durable_commit_timeout_s: float = 120.0
 
-    # Persistent XLA compilation cache shared by every process of the
-    # job (common/compile_cache.py); empty disables it. Recompiles
-    # after a worker restart / re-mesh become cache reads.
-    compile_cache_dir: str = ""
+    # Persistent XLA compilation cache (common/compile_cache.py holds
+    # the placement rule): only compilations at least this expensive
+    # are persisted.
     compile_cache_min_compile_s: float = 1.0
 
     # Input pipeline: the train loop keeps one batch in flight on a

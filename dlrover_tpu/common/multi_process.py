@@ -435,6 +435,12 @@ class SharedQueue:
                 raise _queue.Empty
             if deadline is not None and time.time() >= deadline:
                 raise _queue.Empty
+            # Yield between polls: the client lock is released and taken
+            # again within a few bytecodes, and threading.Lock is not
+            # fair — a put() from another thread of this process (the
+            # saver's shutdown handing the runner its exit message)
+            # otherwise starves behind a blocked get() for minutes.
+            time.sleep(0.001)
 
     def qsize(self) -> int:
         return self._client.call("qsize")
@@ -622,7 +628,7 @@ class SharedMemorySegment:
         if self._shm is not None:
             self.unlink()
         try:
-            self._shm = shared_memory.SharedMemory(name=self.name, create=True, size=size)
+            self._shm = self._create_reserved(size)
         except FileExistsError:
             existing = shared_memory.SharedMemory(name=self.name)
             self._untrack(existing)
@@ -631,11 +637,25 @@ class SharedMemorySegment:
             else:
                 existing.close()
                 self._posix_unlink(existing)
-                self._shm = shared_memory.SharedMemory(
-                    name=self.name, create=True, size=size
-                )
+                self._shm = self._create_reserved(size)
         self._untrack(self._shm)
         self._record_ino()
+
+    def _create_reserved(self, size: int) -> shared_memory.SharedMemory:
+        """Create the segment with its pages RESERVED. ``ftruncate`` on
+        tmpfs reserves nothing, so a segment larger than what /dev/shm
+        has left would be created fine and then kill the writer with
+        SIGBUS mid-copy; ``posix_fallocate`` makes the shortage an
+        ``OSError`` (ENOSPC) here, where the caller can see it."""
+        shm = shared_memory.SharedMemory(name=self.name, create=True, size=size)
+        try:
+            os.posix_fallocate(shm._fd, 0, size)
+        except OSError:
+            self._untrack(shm)
+            shm.close()
+            self._posix_unlink(shm)
+            raise
+        return shm
 
     def attach(self) -> bool:
         if self._shm is not None:

@@ -145,14 +145,6 @@ def parse_args(args: Optional[List[str]] = None) -> argparse.Namespace:
         help="base port for the jax.distributed coordinator (0 = free port)",
     )
     parser.add_argument(
-        "--compile-cache-dir",
-        default="",
-        dest="compile_cache_dir",
-        help="persistent XLA compile cache shared by every worker "
-        "incarnation (warm-restart fast path; also settable via "
-        "DLROVER_COMPILE_CACHE_DIR). Empty disables it.",
-    )
-    parser.add_argument(
         "--sync-input",
         action="store_true",
         dest="sync_input",
@@ -225,8 +217,6 @@ def config_from_args(ns: argparse.Namespace) -> ElasticLaunchConfig:
         numa_affinity=ns.numa_affinity,
         profile=ns.profile,
         monitor_interval=ns.monitor_interval,
-        compile_cache_dir=ns.compile_cache_dir
-        or os.environ.get("DLROVER_COMPILE_CACHE_DIR", ""),
         input_prefetch=not ns.sync_input,
     )
     config.auto_configure_params()

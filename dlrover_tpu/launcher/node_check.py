@@ -16,13 +16,21 @@ TPU-native check per host:
      assigned by the master (DCN control-plane reachability + latency).
 
 Each round reports (normal, elapsed) to the master; the launcher then
-reads fault/straggler verdicts. Runs inline in the agent process — JAX
-is initialized local-only (no global mesh yet), which is exactly the
-pre-rendezvous state tpurun is in.
+reads fault/straggler verdicts. The device half (1 and 2) runs in a
+CHILD process (``python -m dlrover_tpu.launcher.node_check``) that
+exits before the round reports: a chip belongs to one process at a
+time, so an agent that initialized JAX itself would hold the chip its
+worker needs. The agent stays off JAX; the child gets the worker's env
+contract, platform pin included, so a probe cannot pass on the CPU when
+the job asked for the TPU.
 """
 
+import json
+import os
+import subprocess
+import sys
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from ..common.constants import NodeCheckConstants, RendezvousName
 from ..common.log import logger
@@ -32,6 +40,7 @@ from ..agent.rendezvous import MasterRendezvousHandler
 
 CHECK_ROUNDS = NodeCheckConstants.CHECK_ROUNDS
 _MATMUL_DIM = 1024
+_PROBE_TIMEOUT_S = 300.0
 
 
 def _device_matmul_seconds() -> Tuple[bool, float]:
@@ -54,6 +63,19 @@ def _device_matmul_seconds() -> Tuple[bool, float]:
         return False, 0.0
 
 
+def _local_psum(devices):
+    """Jitted all-reduce over ``devices`` (a one-axis local mesh)."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(devices), ("d",))
+    # tpulint: ignore[mesh-axes] "d" is the health check's single-host probe axis, not a training mesh axis
+    psum = lambda x: jax.lax.psum(x, "d")  # noqa: E731
+    # tpulint: ignore[mesh-axes] same probe axis
+    return jax.jit(jax.shard_map(psum, mesh=mesh, in_specs=P("d"), out_specs=P()))
+
+
 def _local_collective_seconds() -> Tuple[bool, float]:
     """Time a psum across the local devices (single-host mesh)."""
     import jax
@@ -65,14 +87,47 @@ def _local_collective_seconds() -> Tuple[bool, float]:
             return True, 0.0
         n = len(devices)
         started = time.monotonic()
-        # tpulint: ignore[mesh-axes] "d" is the health check's single-host pmap probe axis, not a training mesh axis
-        psum_d = jax.pmap(lambda x: jax.lax.psum(x, "d"), axis_name="d", devices=devices)
-        out = psum_d(jnp.ones((n, 128)))
+        out = _local_psum(devices)(jnp.ones((n, 128)))
         out.block_until_ready()
         return True, time.monotonic() - started
     except Exception as e:
         logger.error("local collective check failed: %s", e)
         return False, 0.0
+
+
+def _probe_devices_in_child(
+    config: ElasticLaunchConfig, comm_perf: bool = False
+) -> Dict[str, Tuple[bool, float]]:
+    """Run the device checks in a child that owns the chip only for the
+    probe's lifetime. A child that dies, hangs or prints no verdict is a
+    failed check — never a pass."""
+    env = dict(os.environ)
+    env.update(config.worker_env())
+    cmd = [sys.executable, "-m", "dlrover_tpu.launcher.node_check"]
+    if comm_perf:
+        cmd.append("--comm-perf")
+    failed = {"matmul": (False, 0.0), "collective": (False, 0.0)}
+    try:
+        proc = subprocess.run(
+            cmd,
+            env=env,
+            capture_output=True,
+            text=True,
+            timeout=_PROBE_TIMEOUT_S,
+        )
+    except subprocess.TimeoutExpired:
+        logger.error("device probe timed out after %.0fs", _PROBE_TIMEOUT_S)
+        return failed
+    lines = proc.stdout.strip().splitlines()
+    if proc.returncode != 0 or not lines:
+        logger.error(
+            "device probe failed rc=%s: %s",
+            proc.returncode,
+            proc.stderr[-2000:],
+        )
+        return failed
+    verdict = json.loads(lines[-1])
+    return {k: (bool(v[0]), float(v[1])) for k, v in verdict.items()}
 
 
 def _pair_exchange_seconds(
@@ -129,8 +184,6 @@ def run_node_check(
     chaos-test hook for injecting a faulty host without a faulty host.
     """
     client = client or MasterClient.singleton()
-    matmul_fn = matmul_fn or _device_matmul_seconds
-    collective_fn = collective_fn or _local_collective_seconds
     for round_idx in range(CHECK_ROUNDS):
         handler = MasterRendezvousHandler(
             RendezvousName.NETWORK_CHECK,
@@ -149,13 +202,16 @@ def run_node_check(
                 if member_ranks[0] == config.node_rank
                 else member_ranks[0]
             )
-        ok_m, t_m = matmul_fn()
-        ok_c, t_c = collective_fn()
+        probe: Dict[str, Tuple[bool, float]] = {}
+        if matmul_fn is None or collective_fn is None:
+            probe = _probe_devices_in_child(
+                config, comm_perf=config.comm_perf_test and round_idx == 0
+            )
+        ok_m, t_m = matmul_fn() if matmul_fn else probe["matmul"]
+        ok_c, t_c = collective_fn() if collective_fn else probe["collective"]
         ok_p, t_p = _pair_exchange_seconds(
             client, config.node_rank, peer, world.round
         )
-        if config.comm_perf_test and round_idx == 0:
-            _comm_perf_report(config)
         normal = ok_m and ok_c and ok_p
         elapsed = t_m + t_c + t_p
         # Echo the wave number back: the master owns the wave→check-round
@@ -200,7 +256,7 @@ def _wait_round_results(
     logger.warning("node check round results incomplete after %.0fs", timeout)
 
 
-def _comm_perf_report(config: ElasticLaunchConfig) -> None:
+def _comm_perf_report() -> None:
     """--comm-perf-test: measure local-mesh allreduce bus bandwidth once.
 
     Reference: comm-perf subprocess in trainer/torch/node_check. On a
@@ -217,8 +273,7 @@ def _comm_perf_report(config: ElasticLaunchConfig) -> None:
             return
         mb = 8
         x = jnp.ones((n, mb * 1024 * 1024 // 4), jnp.float32)
-        # tpulint: ignore[mesh-axes] "d" is the health check's single-host pmap probe axis, not a training mesh axis
-        psum = jax.pmap(lambda v: jax.lax.psum(v, "d"), axis_name="d")
+        psum = _local_psum(devices)
         psum(x).block_until_ready()  # compile
         started = time.monotonic()
         psum(x).block_until_ready()
@@ -236,3 +291,21 @@ def _comm_perf_report(config: ElasticLaunchConfig) -> None:
         )
     except Exception as e:
         logger.warning("comm perf test failed: %s", e)
+
+
+def main(argv=None) -> int:
+    """Child entry: run the device checks in THIS process and print the
+    verdict as one JSON line. The parent agent never touches JAX."""
+    argv = sys.argv[1:] if argv is None else argv
+    verdict = {
+        "matmul": _device_matmul_seconds(),
+        "collective": _local_collective_seconds(),
+    }
+    if "--comm-perf" in argv:
+        _comm_perf_report()
+    print(json.dumps(verdict), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

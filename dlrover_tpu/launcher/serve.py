@@ -439,10 +439,13 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
 
         def do_GET(self):
             if self.path == "/healthz":
+                from ..common.platform import device_summary
+
                 stats = daemon.eng.stats()
                 self._send(
                     200,
                     {
+                        "device": device_summary(),
                         # which fleet member answered (None outside a
                         # fleet) — the supervisor asserts identity on
                         # relaunch and operators read it in curl output
@@ -857,10 +860,15 @@ def main(argv=None) -> int:
     )
     ns = ap.parse_args(argv)
 
-    if ns.cpu:
-        from ..common.platform import force_virtual_cpu
+    from ..common.platform import force_virtual_cpu, pin_accelerator
 
+    if ns.cpu:
         force_virtual_cpu(1)
+    else:
+        # no hidden CPU: without --cpu (or a caller's own JAX_PLATFORMS)
+        # a failed TPU initialization raises here instead of serving
+        # from the host
+        pin_accelerator()
 
     import jax
 

@@ -240,9 +240,12 @@ class LlamaAttention(nn.Module):
                 raise ValueError("attention_impl='ring' needs current_mesh")
             out = ring_attention_sharded(q, k, v, mesh, causal=True)
         elif impl == "flash":
-            from ..ops.flash_attention import flash_attention
+            from ..ops.flash_attention import flash_attention_sharded
+            from ..parallel.mesh import get_current_mesh
 
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention_sharded(
+                q, k, v, get_current_mesh(), causal=True
+            )
         elif impl == "dense":
             scale = 1.0 / jnp.sqrt(Hd).astype(cfg.dtype)
             logits = jnp.einsum("bqhk,bshk->bhqs", q, k) * scale

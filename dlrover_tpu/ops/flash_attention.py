@@ -15,8 +15,11 @@ Layout: inputs are ``[batch, seq, heads, head_dim]`` (the model's
 ``bqhk``); kernels operate on ``[batch*heads, seq, head_dim]``. Blocks
 default to 128×128 (MXU tile), fp32 softmax, inputs in bf16 on TPU.
 
-On non-TPU backends the same kernels run in Pallas interpret mode, so
-CPU tests cover the kernel logic bit-for-bit.
+On the CPU backend (tests, rehearsals) the same kernels run in Pallas
+interpret mode, so CPU tests cover the kernel logic bit-for-bit. A
+worker that asked for the TPU cannot reach that branch: its platform is
+pinned (``ElasticLaunchConfig.worker_env``), so a failed TPU
+initialization raises instead of falling to the CPU.
 """
 
 import functools
@@ -26,14 +29,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 # Tuned on v5e silicon (in-device scan timing, B=32/H=12/T=1024/D=64 and
 # B=4/T=4096): 1024×1024 beats 512×1024 by ~27% fwd-only and ~10%
@@ -52,15 +48,7 @@ def _use_interpret() -> bool:
 
 
 def _vmem_spec(block_shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(block_shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(block_shape, index_map)
-
-
-def _scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY  # pragma: no cover
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -213,9 +201,9 @@ def _flash_fwd(
             jax.ShapeDtypeStruct((bh, tq_pad, _LSE_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q, d), jnp.float32),
-            _scratch((block_q, 128), jnp.float32),
-            _scratch((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_use_interpret(),
     )(qp, kp, vp)
@@ -417,8 +405,8 @@ def _flash_bwd(
             jax.ShapeDtypeStruct((bh, tk_pad, d), v.dtype),
         ],
         scratch_shapes=[
-            _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_use_interpret(),
     )(qp, kp, vp, dop, lsep, deltap)
@@ -436,7 +424,7 @@ def _flash_bwd(
         ],
         out_specs=[_vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype)],
-        scratch_shapes=[_scratch((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_use_interpret(),
     )(qp, kp, vp, dop, lsep, deltap)[0]
     return dq[:, :t_q], dk[:, :t_kv], dv[:, :t_kv]
@@ -502,6 +490,58 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, residuals, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
+    """:func:`flash_attention` under the model's layout on ``mesh``.
+
+    A Mosaic kernel cannot be partitioned by GSPMD (lowering a sharded
+    step around it raises "wrap the call in a shard_map"), so on a mesh
+    of more than one device the kernel runs per shard: batch and heads
+    split as the active logical rules say, each device attending over
+    its own rows — attention never mixes batch rows or heads, so no
+    collective is needed. The sequence must be whole on every device;
+    a rule table that shards "seq" wants ``attention_impl="ring"``.
+    With no mesh (or one device) this is the plain kernel call.
+    """
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal)
+    from flax.linen import partitioning as nn_partitioning
+    from flax.linen import spmd as flax_spmd
+    from jax.sharding import PartitionSpec
+
+    from ..parallel.sharding import DEFAULT_RULES
+
+    rules = list(nn_partitioning.get_axis_rules()) or DEFAULT_RULES
+    logical = flax_spmd.logical_to_mesh_axes(
+        ("batch", "seq", "heads", "kv"), rules
+    )
+
+    def extent(axis):
+        axes = axis if isinstance(axis, tuple) else (axis,)
+        return math.prod(mesh.shape[a] for a in axes if a is not None)
+
+    # an axis that does not split the dim evenly (or has extent 1)
+    # stays whole on every device, as state_shardings does for params
+    spec = PartitionSpec(
+        *(
+            axis if extent(axis) > 1 and dim % extent(axis) == 0 else None
+            for dim, axis in zip(q.shape, logical)
+        )
+    )
+    if spec[1] is not None:
+        raise ValueError(
+            f"flash attention needs the whole sequence on each device, "
+            f"but 'seq' is sharded over {spec[1]!r}; use "
+            f"attention_impl='ring'"
+        )
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def reference_attention(q, k, v, causal: bool = True, sm_scale=None):

@@ -147,10 +147,7 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True, rules=None):
     """
     from flax.linen import spmd as flax_spmd
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ..parallel.sharding import DEFAULT_RULES
 

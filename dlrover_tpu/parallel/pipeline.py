@@ -23,7 +23,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def stack_stage_params(per_stage_params) -> Any:
@@ -131,7 +131,7 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(P(axis), data_spec),
         out_specs=data_spec,
-        check_rep=False,
+        check_vma=False,
     )(stage_params, microbatches)
 
 

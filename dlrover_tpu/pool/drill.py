@@ -36,7 +36,6 @@ import os
 import tempfile
 import threading
 import time
-from contextlib import contextmanager
 from http.server import ThreadingHTTPServer
 from typing import Dict, Optional
 
@@ -48,34 +47,6 @@ from .config import PoolConfig
 from .tenants import LoopTrainingController, ServingTenant, TrainingTenant
 
 __all__ = ["run_traffic_spike_drill", "ScriptedReplica"]
-
-
-@contextmanager
-def _no_persistent_compile_cache():
-    """Disable the persistent XLA compile cache for the drill's scope.
-
-    This container's jaxlib dies in C++ when an in-process
-    ElasticTrainLoop runs with the persistent cache ACTIVE under a
-    thread mix that includes engine modules (the PR 7 root-cause note:
-    keep such code cache-off or subprocessed). The drill needs no
-    persistent cache anyway — its compile-ahead warms an in-memory
-    program table — so cache-off here costs nothing and keeps the
-    drill runnable inside any process."""
-    try:
-        import jax
-        from jax._src import compilation_cache as cc
-    except Exception as e:  # noqa: BLE001 — no jax (synthetic mode)
-        logger.debug("compile cache scope skipped (no jax): %r", e)
-        yield
-        return
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    cc.reset_cache()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        cc.reset_cache()
 
 
 class ScriptedReplica:
@@ -326,299 +297,298 @@ def run_traffic_spike_drill(
     def remaining() -> float:
         return max(0.0, deadline - time.monotonic())
 
-    with _no_persistent_compile_cache():
-        # -- training side ------------------------------------------------
-        if real_engines:
-            engine, build_step_fn, state, data_fn = _real_training(
-                workdir, train_start, per_unit_batch
+    # -- training side ------------------------------------------------
+    if real_engines:
+        engine, build_step_fn, state, data_fn = _real_training(
+            workdir, train_start, per_unit_batch
+        )
+    else:
+        engine, build_step_fn, state, data_fn = _synthetic_training(
+            workdir, train_start
+        )
+    controller = LoopTrainingController(
+        engine,
+        build_step_fn,
+        state,
+        data_fn,
+        max_units=train_start,
+        start_world=train_start,
+        storage_every=10_000,  # shm staging is the handoff path
+    )
+
+    # -- serving side -------------------------------------------------
+    script: Dict = {}
+    if real_engines:
+        import jax
+        import jax.numpy as jnp
+
+        from ..fleet import InProcessReplica
+        from ..models.generation import SamplingConfig
+        from ..models.gpt import GPT, GPTConfig
+        from ..models.serving import ContinuousBatchingEngine
+
+        smodel = GPT(
+            GPTConfig(
+                vocab_size=64, max_seq_len=128, num_layers=2,
+                num_heads=2, head_dim=8, embed_dim=16,
+                use_remat=False,
             )
-        else:
-            engine, build_step_fn, state, data_fn = _synthetic_training(
-                workdir, train_start
-            )
-        controller = LoopTrainingController(
-            engine,
-            build_step_fn,
-            state,
-            data_fn,
-            max_units=train_start,
-            start_world=train_start,
-            storage_every=10_000,  # shm staging is the handoff path
+        )
+        sparams = smodel.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        sampling = SamplingConfig(
+            max_new_tokens=6, temperature=0.0
         )
 
-        # -- serving side -------------------------------------------------
-        script: Dict = {}
-        if real_engines:
-            import jax
-            import jax.numpy as jnp
-
-            from ..fleet import InProcessReplica
-            from ..models.generation import SamplingConfig
-            from ..models.gpt import GPT, GPTConfig
-            from ..models.serving import ContinuousBatchingEngine
-
-            smodel = GPT(
-                GPTConfig(
-                    vocab_size=64, max_seq_len=128, num_layers=2,
-                    num_heads=2, head_dim=8, embed_dim=16,
-                    use_remat=False,
-                )
-            )
-            sparams = smodel.init(
-                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-            )["params"]
-            sampling = SamplingConfig(
-                max_new_tokens=6, temperature=0.0
+        def engine_factory():
+            return ContinuousBatchingEngine(
+                smodel, sparams, sampling, batch_size=2,
+                prompt_width=16, decode_chunk=4,
             )
 
-            def engine_factory():
-                return ContinuousBatchingEngine(
-                    smodel, sparams, sampling, batch_size=2,
-                    prompt_width=16, decode_chunk=4,
+        def replica_factory(rid, port):
+            return InProcessReplica(
+                rid, port, engine_factory=engine_factory
+            )
+    else:
+
+        def replica_factory(rid, port):
+            return ScriptedReplica(rid, port, script=script)
+
+    # lenient poll thresholds (the replica_loss rationale: jit
+    # tracing holds the GIL; a merely-compiling replica must not
+    # read as dead), fleet bounds wide open to the pool ceiling
+    fleet_cfg = FleetConfig(
+        replicas=serve_start,
+        min_replicas=1,
+        max_replicas=total_units,
+        health_interval_s=0.1,
+        health_fails=100,
+        health_timeout_s=15.0,
+        start_timeout_s=120.0,
+        relaunch_budget=2,
+        queue_limit=256,
+        drain_timeout_s=30.0,
+    )
+    supervisor = ReplicaSupervisor(replica_factory, fleet_cfg)
+    gateway = Gateway(supervisor, fleet_cfg)
+
+    pool_cfg = config or PoolConfig(
+        total_units=total_units,
+        train_floor=1,
+        train_ceiling=train_start,
+        serve_floor=serve_start,
+        serve_ceiling=total_units - 1,
+        queue_high=queue_high,
+        handback_evals=handback_evals,
+        revoke_deadline_s=revoke_deadline_s,
+        spike_units=1,
+        journal_path=os.path.join(workdir, "pool_journal.jsonl"),
+    )
+
+    results = {"ok": 0, "failed": 0}
+    res_mu = threading.Lock()
+    spike_on = threading.Event()
+    pump_stop = threading.Event()
+
+    def client_loop(i: int):
+        while spike_on.is_set() and not pump_stop.is_set():
+            try:
+                got = gateway.complete(
+                    {"prompt": [5, 9, (i % 50) + 1]}
                 )
+                assert got["tokens"]
+                with res_mu:
+                    results["ok"] += 1
+            except Exception:  # noqa: BLE001 — counted, judged below
+                with res_mu:
+                    results["failed"] += 1
 
-            def replica_factory(rid, port):
-                return InProcessReplica(
-                    rid, port, engine_factory=engine_factory
-                )
-        else:
+    arbiter = None
+    try:
+        supervisor.start()
+        controller.start()
+        if not supervisor.wait_ready(serve_start, timeout=remaining()):
+            out["error"] = "serving fleet never came READY"
+            return out
 
-            def replica_factory(rid, port):
-                return ScriptedReplica(rid, port, script=script)
-
-        # lenient poll thresholds (the replica_loss rationale: jit
-        # tracing holds the GIL; a merely-compiling replica must not
-        # read as dead), fleet bounds wide open to the pool ceiling
-        fleet_cfg = FleetConfig(
-            replicas=serve_start,
-            min_replicas=1,
-            max_replicas=total_units,
-            health_interval_s=0.1,
-            health_fails=100,
-            health_timeout_s=15.0,
-            start_timeout_s=120.0,
-            relaunch_budget=2,
-            queue_limit=256,
-            drain_timeout_s=30.0,
+        serving = ServingTenant(supervisor)
+        training = TrainingTenant(
+            controller, floor_units=pool_cfg.train_floor
         )
-        supervisor = ReplicaSupervisor(replica_factory, fleet_cfg)
-        gateway = Gateway(supervisor, fleet_cfg)
-
-        pool_cfg = config or PoolConfig(
-            total_units=total_units,
-            train_floor=1,
-            train_ceiling=train_start,
-            serve_floor=serve_start,
-            serve_ceiling=total_units - 1,
-            queue_high=queue_high,
-            handback_evals=handback_evals,
-            revoke_deadline_s=revoke_deadline_s,
-            spike_units=1,
-            journal_path=os.path.join(workdir, "pool_journal.jsonl"),
+        arbiter = ChipPoolArbiter(
+            serving, training, config=pool_cfg
         )
 
-        results = {"ok": 0, "failed": 0}
-        res_mu = threading.Lock()
-        spike_on = threading.Event()
-        pump_stop = threading.Event()
-
-        def client_loop(i: int):
-            while spike_on.is_set() and not pump_stop.is_set():
-                try:
-                    got = gateway.complete(
-                        {"prompt": [5, 9, (i % 50) + 1]}
-                    )
-                    assert got["tokens"]
-                    with res_mu:
-                        results["ok"] += 1
-                except Exception:  # noqa: BLE001 — counted, judged below
-                    with res_mu:
-                        results["failed"] += 1
-
-        arbiter = None
-        try:
-            supervisor.start()
-            controller.start()
-            if not supervisor.wait_ready(serve_start, timeout=remaining()):
-                out["error"] = "serving fleet never came READY"
+        # -- calibrate ------------------------------------------------
+        while controller.steps_total < calibration_steps:
+            if controller.wait_finished(0):
+                # fail FAST on a dead loop (a crashed train step
+                # would otherwise burn the whole drill timeout)
+                out["error"] = "training loop died during calibration"
                 return out
-
-            serving = ServingTenant(supervisor)
-            training = TrainingTenant(
-                controller, floor_units=pool_cfg.train_floor
-            )
-            arbiter = ChipPoolArbiter(
-                serving, training, config=pool_cfg
-            )
-
-            # -- calibrate ------------------------------------------------
-            while controller.steps_total < calibration_steps:
-                if controller.wait_finished(0):
-                    # fail FAST on a dead loop (a crashed train step
-                    # would otherwise burn the whole drill timeout)
-                    out["error"] = "training loop died during calibration"
-                    return out
-                if remaining() <= 0:
-                    out["error"] = "training never calibrated"
-                    return out
-                time.sleep(0.05)
-            # warm every serving replica's program (first completion
-            # pays the jit trace)
-            for _ in range(2):
-                try:
-                    gateway.complete({"prompt": [3, 7, 11]})
-                except Exception as e:  # noqa: BLE001
-                    out["error"] = f"warm request failed: {e!r}"
-                    return out
-            svc = controller.compile_ahead_service
-            if svc is not None:
-                # the shrink ladder must be warm BEFORE the spike —
-                # that is the compile-ahead contract under arbitration
-                svc.wait(min(compile_ahead_wait_s, remaining()))
-                out["compile_ahead"] = svc.stats()
-            mb0 = controller.microbatches
-            t0 = time.monotonic()
-            time.sleep(calibration_window_s)
-            baseline_rate = (controller.microbatches - mb0) / (
-                time.monotonic() - t0
-            )
-            out["baseline_microbatches_per_s"] = round(baseline_rate, 3)
-            if baseline_rate <= 0:
-                out["error"] = "no baseline training progress"
+            if remaining() <= 0:
+                out["error"] = "training never calibrated"
                 return out
+            time.sleep(0.05)
+        # warm every serving replica's program (first completion
+        # pays the jit trace)
+        for _ in range(2):
+            try:
+                gateway.complete({"prompt": [3, 7, 11]})
+            except Exception as e:  # noqa: BLE001
+                out["error"] = f"warm request failed: {e!r}"
+                return out
+        svc = controller.compile_ahead_service
+        if svc is not None:
+            # the shrink ladder must be warm BEFORE the spike —
+            # that is the compile-ahead contract under arbitration
+            svc.wait(min(compile_ahead_wait_s, remaining()))
+            out["compile_ahead"] = svc.stats()
+        mb0 = controller.microbatches
+        t0 = time.monotonic()
+        time.sleep(calibration_window_s)
+        baseline_rate = (controller.microbatches - mb0) / (
+            time.monotonic() - t0
+        )
+        out["baseline_microbatches_per_s"] = round(baseline_rate, 3)
+        if baseline_rate <= 0:
+            out["error"] = "no baseline training progress"
+            return out
 
-            # -- spike ----------------------------------------------------
-            window_mb0 = controller.microbatches
-            t_window0 = time.monotonic()
-            spike_on.set()
-            script["queue_depth"] = 8  # synthetic signal; real engines
-            # breach through genuine queue depth from the flood
-            pumps = [
-                threading.Thread(target=client_loop, args=(i,))
-                for i in range(spike_clients)
-            ]
-            for p in pumps:
-                p.start()
+        # -- spike ----------------------------------------------------
+        window_mb0 = controller.microbatches
+        t_window0 = time.monotonic()
+        spike_on.set()
+        script["queue_depth"] = 8  # synthetic signal; real engines
+        # breach through genuine queue depth from the flood
+        pumps = [
+            threading.Thread(target=client_loop, args=(i,))
+            for i in range(spike_clients)
+        ]
+        for p in pumps:
+            p.start()
 
-            t_breach = None
-            t_ready = None
-            want_ready = serve_start + 1
-            while remaining() > 0:
-                if controller.wait_finished(0):
-                    out["error"] = "training loop died during spike"
-                    out["journal"] = arbiter.journal()
-                    return out
-                arbiter.step()
-                if t_breach is None and any(
-                    e["event"] == "revoke"
-                    for e in arbiter.journal()
-                ):
-                    t_breach = time.monotonic()
-                if (
-                    t_breach is not None
-                    and len(supervisor.ready_replicas()) >= want_ready
-                ):
-                    t_ready = time.monotonic()
-                    break
-                time.sleep(eval_interval_s)
-            if t_ready is None:
-                out["error"] = "preempted capacity never came READY"
+        t_breach = None
+        t_ready = None
+        want_ready = serve_start + 1
+        while remaining() > 0:
+            if controller.wait_finished(0):
+                out["error"] = "training loop died during spike"
                 out["journal"] = arbiter.journal()
                 return out
-            out["preempt_to_ready_s"] = round(t_ready - t_breach, 3)
-            out["world_during_spike"] = controller.world()
-
-            # hold the spike briefly with the grown fleet serving it
-            time.sleep(spike_hold_s)
-            script["queue_depth"] = 0
-            spike_on.clear()
-            for p in pumps:
-                p.join(timeout=max(1.0, remaining()))
-
-            # -- calm / handback ------------------------------------------
-            handback = False
-            while remaining() > 0:
-                if controller.wait_finished(0):
-                    out["error"] = "training loop died during handback"
-                    out["journal"] = arbiter.journal()
-                    return out
-                arbiter.step()
-                if (
-                    arbiter.allocations().get(TRAINING, 0)
-                    == train_start
-                    and controller.world() == train_start
-                    and len(supervisor.replicas()) == serve_start
-                    and not arbiter.pending_leases()
-                ):
-                    handback = True
-                    break
-                time.sleep(eval_interval_s)
-            out["handback"] = handback
-            t_window = time.monotonic() - t_window0
-            window_rate = (
-                controller.microbatches - window_mb0
-            ) / t_window
-            out["train_goodput"] = round(
-                window_rate / baseline_rate, 3
-            )
-            out["window_s"] = round(t_window, 2)
-
-            # post-handback steady state: with the unit returned, the
-            # full-world rate must come back (the half of "training
-            # reclaims" that goodput-over-the-window can't show — on a
-            # shared-CPU container the spike window itself is dominated
-            # by serving/training core contention, see docs/pool.md)
-            if handback:
-                mb1 = controller.microbatches
-                t1 = time.monotonic()
-                time.sleep(min(calibration_window_s, remaining()))
-                recovered = (controller.microbatches - mb1) / max(
-                    1e-9, time.monotonic() - t1
-                )
-                out["recovered_microbatches_per_s"] = round(
-                    recovered, 3
-                )
-                out["recovered_vs_baseline"] = round(
-                    recovered / baseline_rate, 3
-                )
-
-            with res_mu:
-                ok_n, failed_n = results["ok"], results["failed"]
-            total_req = ok_n + failed_n
-            out["requests_ok"] = ok_n
-            out["requests_failed"] = failed_n
-            out["availability"] = (
-                round(ok_n / total_req, 4) if total_req else None
-            )
-            out["escalations"] = arbiter.escalations
-            out["revokes"] = arbiter.revokes
-            out["grants"] = arbiter.grants
-            out["allocations"] = arbiter.allocations()
-            out["phase_split"] = arbiter.phases.split().summary()
+            arbiter.step()
+            if t_breach is None and any(
+                e["event"] == "revoke"
+                for e in arbiter.journal()
+            ):
+                t_breach = time.monotonic()
+            if (
+                t_breach is not None
+                and len(supervisor.ready_replicas()) >= want_ready
+            ):
+                t_ready = time.monotonic()
+                break
+            time.sleep(eval_interval_s)
+        if t_ready is None:
+            out["error"] = "preempted capacity never came READY"
             out["journal"] = arbiter.journal()
-            out["train_report"] = controller.report()
-            out["elapsed_s"] = round(time.monotonic() - t_drill0, 2)
-            out["ok"] = (
-                handback
-                and failed_n == 0
-                and total_req > 0
-                and out["preempt_to_ready_s"] >= 0
-                and arbiter.escalations == 0
-            )
             return out
-        finally:
-            pump_stop.set()
-            spike_on.clear()
-            try:
-                controller.stop(timeout=30.0)
-            except Exception as e:  # noqa: BLE001 — teardown
-                logger.warning("drill: controller stop: %r", e)
-            supervisor.stop()
-            try:
-                engine.shm.unlink()
-                engine.close()
-            except Exception as e:  # noqa: BLE001 — teardown
-                logger.warning("drill: engine close: %r", e)
+        out["preempt_to_ready_s"] = round(t_ready - t_breach, 3)
+        out["world_during_spike"] = controller.world()
+
+        # hold the spike briefly with the grown fleet serving it
+        time.sleep(spike_hold_s)
+        script["queue_depth"] = 0
+        spike_on.clear()
+        for p in pumps:
+            p.join(timeout=max(1.0, remaining()))
+
+        # -- calm / handback ------------------------------------------
+        handback = False
+        while remaining() > 0:
+            if controller.wait_finished(0):
+                out["error"] = "training loop died during handback"
+                out["journal"] = arbiter.journal()
+                return out
+            arbiter.step()
+            if (
+                arbiter.allocations().get(TRAINING, 0)
+                == train_start
+                and controller.world() == train_start
+                and len(supervisor.replicas()) == serve_start
+                and not arbiter.pending_leases()
+            ):
+                handback = True
+                break
+            time.sleep(eval_interval_s)
+        out["handback"] = handback
+        t_window = time.monotonic() - t_window0
+        window_rate = (
+            controller.microbatches - window_mb0
+        ) / t_window
+        out["train_goodput"] = round(
+            window_rate / baseline_rate, 3
+        )
+        out["window_s"] = round(t_window, 2)
+
+        # post-handback steady state: with the unit returned, the
+        # full-world rate must come back (the half of "training
+        # reclaims" that goodput-over-the-window can't show — on a
+        # shared-CPU container the spike window itself is dominated
+        # by serving/training core contention, see docs/pool.md)
+        if handback:
+            mb1 = controller.microbatches
+            t1 = time.monotonic()
+            time.sleep(min(calibration_window_s, remaining()))
+            recovered = (controller.microbatches - mb1) / max(
+                1e-9, time.monotonic() - t1
+            )
+            out["recovered_microbatches_per_s"] = round(
+                recovered, 3
+            )
+            out["recovered_vs_baseline"] = round(
+                recovered / baseline_rate, 3
+            )
+
+        with res_mu:
+            ok_n, failed_n = results["ok"], results["failed"]
+        total_req = ok_n + failed_n
+        out["requests_ok"] = ok_n
+        out["requests_failed"] = failed_n
+        out["availability"] = (
+            round(ok_n / total_req, 4) if total_req else None
+        )
+        out["escalations"] = arbiter.escalations
+        out["revokes"] = arbiter.revokes
+        out["grants"] = arbiter.grants
+        out["allocations"] = arbiter.allocations()
+        out["phase_split"] = arbiter.phases.split().summary()
+        out["journal"] = arbiter.journal()
+        out["train_report"] = controller.report()
+        out["elapsed_s"] = round(time.monotonic() - t_drill0, 2)
+        out["ok"] = (
+            handback
+            and failed_n == 0
+            and total_req > 0
+            and out["preempt_to_ready_s"] >= 0
+            and arbiter.escalations == 0
+        )
+        return out
+    finally:
+        pump_stop.set()
+        spike_on.clear()
+        try:
+            controller.stop(timeout=30.0)
+        except Exception as e:  # noqa: BLE001 — teardown
+            logger.warning("drill: controller stop: %r", e)
+        supervisor.stop()
+        try:
+            engine.shm.unlink()
+            engine.close()
+        except Exception as e:  # noqa: BLE001 — teardown
+            logger.warning("drill: engine close: %r", e)
 
 
 def main(argv=None) -> int:

@@ -42,13 +42,10 @@ REMESH_DIR_ENV = "DLROVER_REMESH_DIR"
 
 
 def _jax_distributed_initialized() -> bool:
-    try:
-        from jax._src import distributed
+    # private module, checked against the installed jax 0.9.0
+    from jax._src import distributed
 
-        return getattr(distributed.global_state, "client", None) is not None
-    except Exception as e:  # noqa: BLE001 — private-module drift
-        logger.debug("jax distributed state unreadable: %r", e)
-        return False
+    return distributed.global_state.client is not None
 
 
 class SoftRemesh:

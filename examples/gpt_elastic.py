@@ -33,6 +33,10 @@ from dlrover_tpu.trainer.elastic import elastic_context
 
 TOTAL_STEPS = int(os.environ.get("TOTAL_STEPS", "200"))
 CKPT_DIR = os.environ.get("CKPT_DIR", "/tmp/gpt_elastic_ckpt")
+# The model is chosen HERE, not by how many devices happen to be
+# attached: "gpt2_small" (124M, the default) or "tiny" (a 2-layer toy
+# for CPU walkthroughs). The device count decides the mesh only.
+MODEL = os.environ.get("MODEL", "gpt2_small")
 BATCH_PER_DEVICE = 2
 
 
@@ -41,7 +45,7 @@ def main():
 
     n = len(jax.devices())
     mesh = build_mesh(choose_mesh_shape(n, tp=1))
-    cfg = GPTConfig.tiny() if n <= 8 else GPTConfig.gpt2_small()
+    cfg = {"gpt2_small": GPTConfig.gpt2_small, "tiny": GPTConfig.tiny}[MODEL]()
     model = GPT(cfg)
     tx = default_optimizer()
     batch = BATCH_PER_DEVICE * n

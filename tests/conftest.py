@@ -9,11 +9,12 @@ import os
 
 # Persistent XLA compile cache: the suite is compile-heavy (pipeline /
 # MoE / sharded train steps) and repeated runs drop ~3x in wall time.
-# Per-uid path: a world-shared /tmp dir would be unwritable for the
-# second user on a shared machine.
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR", f"/tmp/dlrover_tpu_jax_cache_{os.getuid()}"
-)
+# Placement follows the program's own rule (common/compile_cache.py): a
+# caller's JAX_COMPILATION_CACHE_DIR, else the fixed path in the
+# checkout — exported here so every subprocess a test starts agrees.
+from dlrover_tpu.common.compile_cache import CACHE_DIR_ENV, resolve_cache_dir
+
+os.environ.setdefault(CACHE_DIR_ENV, resolve_cache_dir())
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 from dlrover_tpu.common.platform import force_virtual_cpu
@@ -30,26 +31,3 @@ def tmp_ipc_dir(tmp_path, monkeypatch):
 
     monkeypatch.setattr(mp, "SOCKET_TMP_DIR", str(tmp_path / "sockets"))
     return tmp_path
-
-
-def pytest_collection_modifyitems(session, config, items):
-    """Hoist test_train_loop to the FRONT of the session.
-
-    This container's jaxlib segfaults the whole pytest process (C++
-    stack, no repo frames — pre-existing at seed HEAD, stash-verified)
-    when an in-process ElasticTrainLoop test runs AFTER any
-    engine-heavy module (test_generation/test_serving/...) in the same
-    process with the persistent compile cache warm; at its alphabetical
-    slot the crash killed every test sorting after test_train_loop.
-    Run FIRST — paired with the module's own cache-off fixture — the
-    same tests pass 100%. Ordering is otherwise preserved."""
-    front = [
-        it for it in items if it.fspath.basename == "test_train_loop.py"
-    ]
-    if front:
-        rest = [
-            it
-            for it in items
-            if it.fspath.basename != "test_train_loop.py"
-        ]
-        items[:] = front + rest

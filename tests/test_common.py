@@ -293,3 +293,39 @@ class TestErrorHandler:
             p.read_text() for p in tmp_path.glob("events*")
         )
         assert '"crash"' in contents and "the crash reason" in contents
+
+
+class TestPinAccelerator:
+    """No hidden CPU: a process started for the TPU pins it, so a failed
+    TPU initialization raises; a caller's own pin is honoured."""
+
+    def _run(self, env_value):
+        import subprocess
+        import sys
+
+        env = dict(os.environ, TPU_LOG_DIR="disabled")
+        env.pop("JAX_PLATFORMS", None)
+        if env_value is not None:
+            env["JAX_PLATFORMS"] = env_value
+        code = (
+            "import os, jax\n"
+            "from dlrover_tpu.common.platform import pin_accelerator\n"
+            "print('PIN', pin_accelerator(), os.environ['JAX_PLATFORMS'],"
+            " jax.config.jax_platforms)\n"
+            "print('DEV', jax.devices()[0].platform)\n"
+        )
+        return subprocess.run(
+            [sys.executable, "-c", code],
+            env=env, capture_output=True, text=True, timeout=120,
+        )
+
+    def test_nothing_pinned_pins_the_chip_and_fails_without_one(self):
+        proc = self._run(None)
+        assert "PIN tpu tpu tpu" in proc.stdout
+        # no chip here: the first backend touch raises, no CPU answer
+        assert proc.returncode != 0 and "DEV" not in proc.stdout
+
+    def test_the_callers_pin_is_honoured(self):
+        proc = self._run("cpu")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "PIN cpu cpu cpu" in proc.stdout and "DEV cpu" in proc.stdout

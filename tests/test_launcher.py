@@ -192,3 +192,52 @@ def test_standalone_worker_failure_relaunch_path(tmp_path, monkeypatch):
         ["--standalone", "--nnodes", "1", "--max_restarts", "0", str(script)]
     )
     assert rc != 0
+
+
+def test_node_check_leaves_the_agent_off_the_chip():
+    """One process per chip: the device probes run in a CHILD that has
+    exited by the time the check returns; the process that ran the check
+    (the agent) has no initialized JAX backend, so its worker can still
+    take the chip."""
+    import subprocess
+
+    code = (
+        "import sys\n"
+        "from dlrover_tpu.agent.config import ElasticLaunchConfig\n"
+        "from dlrover_tpu.launcher import node_check\n"
+        "from dlrover_tpu.master.local_master import LocalJobMaster\n"
+        "from dlrover_tpu.rpc.client import MasterClient\n"
+        "master = LocalJobMaster(num_workers=1, fresh_context=True)\n"
+        "master.prepare()\n"
+        "client = MasterClient(master_addr=master.addr, node_id=0)\n"
+        "config = ElasticLaunchConfig(min_nodes=1, max_nodes=1)\n"
+        "ok = node_check.run_node_check(config, client)\n"
+        "master.stop()\n"
+        "jax = sys.modules.get('jax')\n"
+        "backends = dict(jax._src.xla_bridge._backends) if jax else {}\n"
+        "print('VERDICT', ok, 'jax' in sys.modules, len(backends))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    (verdict,) = [
+        l for l in proc.stdout.splitlines() if l.startswith("VERDICT")
+    ]
+    _, ok, _jax_imported, n_backends = verdict.split()
+    assert ok == "True" and n_backends == "0"
+
+
+def test_node_check_child_failure_is_a_failed_check(monkeypatch):
+    """A probe child that dies (the chip is held, the plugin is broken)
+    is a FAILED device check — never a pass, never an exception that
+    skips the report to the master."""
+    from dlrover_tpu.agent.config import ElasticLaunchConfig
+
+    monkeypatch.setattr(
+        node_check.sys, "executable", "/bin/false", raising=False
+    )
+    verdict = node_check._probe_devices_in_child(ElasticLaunchConfig())
+    assert verdict == {"matmul": (False, 0.0), "collective": (False, 0.0)}

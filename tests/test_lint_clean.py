@@ -198,10 +198,13 @@ def test_lock_witness_is_jax_free():
     assert proc.returncode == 0, proc.stderr
 
 
-def test_no_new_timestamped_artifacts_tracked():
-    """Repo hygiene: generated probe/diagnosis artifacts are gitignored
-    from PR 9 on — only the ``*_LATEST`` pointers and the numbered
-    ``BENCH_r0*.json`` trajectory files the bench reads stay tracked."""
+def test_no_run_products_tracked():
+    """Repo hygiene: what a run makes is git-ignored, never tracked —
+    bench.py's over-budget sidecar, chip_smoke.py's work dir, the
+    persistent compile cache, the native build products and what the
+    chip tool brings back. The chip check copies only what git would
+    commit, so a tracked product would also ride to the chip in place
+    of a build from the committed sources."""
     import re
     import subprocess
 
@@ -216,13 +219,13 @@ def test_no_new_timestamped_artifacts_tracked():
         import pytest
 
         pytest.skip("not a git checkout")
-    timestamped = re.compile(
-        r"^(BENCH_probe_sidecar_\d|SILICON_r\d+_\d|HANG_DIAGNOSIS_r\d+_\d)"
+    product = re.compile(
+        r"^(BENCH_extra_|\.smoke_work/|\.jax_compile_cache/|chiprun_out/"
+        r"|native/.*\.so$|native/.*/test_(driver|tpu_timer|tsan)"
+        r"(_tsan)?$)"
     )
-    offenders = [
-        f for f in proc.stdout.splitlines() if timestamped.match(f)
-    ]
+    offenders = [f for f in proc.stdout.splitlines() if product.match(f)]
     assert not offenders, (
-        "timestamped artifacts tracked (add to .gitignore, git rm "
-        f"--cached): {offenders}"
+        "run products tracked (add to .gitignore, git rm --cached): "
+        f"{offenders}"
     )

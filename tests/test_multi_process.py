@@ -162,6 +162,33 @@ class TestSharedMemorySegment:
         seg = SharedMemorySegment(uniq + "_nope")
         assert not seg.attach()
 
+    def test_a_segment_that_does_not_fit_raises_and_leaves_nothing(
+        self, uniq, monkeypatch
+    ):
+        """ftruncate on tmpfs reserves nothing: without the reservation a
+        too-large segment is created fine and the writer dies of SIGBUS
+        mid-copy. The shortage must be an OSError at creation."""
+        import errno
+
+        def full(fd, offset, size):
+            raise OSError(errno.ENOSPC, "No space left on device")
+
+        monkeypatch.setattr(os, "posix_fallocate", full)
+        seg = SharedMemorySegment(uniq)
+        with pytest.raises(OSError) as err:
+            seg.ensure(1 << 20)
+        assert err.value.errno == errno.ENOSPC
+        assert not seg.exists() and seg.buf is None
+
+    def test_pages_are_reserved_at_creation(self, uniq):
+        seg = SharedMemorySegment(uniq)
+        try:
+            seg.ensure(1 << 20)
+            # allocated blocks, not a sparse file
+            assert os.stat(seg._path()).st_blocks * 512 >= 1 << 20
+        finally:
+            seg.unlink()
+
 
 class TestCrashSafety:
     def test_lock_released_when_holder_connection_drops(self, uniq):

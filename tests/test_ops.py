@@ -19,12 +19,7 @@ from dlrover_tpu.ops.flash_attention import (
 )
 from dlrover_tpu.ops.ring_attention import ring_attention
 
-try:
-    from jax import shard_map as _shard_map_mod  # jax >= 0.7 style
-
-    shard_map = _shard_map_mod
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _qkv(b=2, t=32, h=2, d=16, dtype=jnp.float32, seed=0):
@@ -204,3 +199,61 @@ class TestCrossLengthCausal:
         out = flash_attention(q, k, v, True, None, 8, 8)
         ref = reference_attention(q, k, v, True)
         np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+class TestFlashAttentionSharded:
+    """A Mosaic kernel cannot be partitioned by GSPMD; on a mesh the
+    kernel runs per shard under shard_map (found compiling the 4-device
+    step for a described v5e, tests/test_tpu_compile.py)."""
+
+    def test_matches_reference_on_a_four_device_mesh(self):
+        from dlrover_tpu.ops.flash_attention import (
+            flash_attention_sharded,
+            reference_attention,
+        )
+        from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+        from dlrover_tpu.parallel.sharding import apply_rules
+
+        mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2), jax.devices()[:4])
+        q, k, v = _qkv(b=4, t=32, h=4, d=16)
+
+        @jax.jit
+        def fwd_bwd(q, k, v):
+            def loss(q, k, v):
+                return flash_attention_sharded(q, k, v, mesh).sum()
+
+            return loss(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        with mesh, apply_rules():
+            out = flash_attention_sharded(q, k, v, mesh)
+            _, grads = fwd_bwd(q, k, v)
+        want = reference_attention(q, k, v)
+        want_grads = jax.grad(
+            lambda *a: reference_attention(*a).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        for got, ref in zip(grads, want_grads):
+            np.testing.assert_allclose(got, ref, atol=2e-4)
+        # batch over fsdp, heads over tp: really split, not gathered
+        assert len(out.sharding.device_set) == 4
+
+    def test_no_mesh_is_the_plain_kernel(self):
+        from dlrover_tpu.ops.flash_attention import (
+            flash_attention,
+            flash_attention_sharded,
+        )
+
+        q, k, v = _qkv()
+        np.testing.assert_array_equal(
+            flash_attention_sharded(q, k, v, None), flash_attention(q, k, v)
+        )
+
+    def test_sharded_sequence_is_refused(self):
+        from dlrover_tpu.ops.flash_attention import flash_attention_sharded
+        from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+        from dlrover_tpu.parallel.sharding import apply_rules
+
+        mesh = build_mesh(MeshConfig(dp=2, sp=2), jax.devices()[:4])
+        q, k, v = _qkv(b=4, t=32)
+        with mesh, apply_rules(), pytest.raises(ValueError, match="ring"):
+            flash_attention_sharded(q, k, v, mesh)

@@ -399,64 +399,155 @@ class TestPlannerRungLadder:
             build(3)
 
 
-class TestCompileCacheKnob:
-    def test_enable_disable_and_idempotence(self, tmp_path, monkeypatch):
-        import dlrover_tpu.common.compile_cache as cc
-        from dlrover_tpu.common.config import get_context
+class TestCompileCachePlacement:
+    """The whole placement rule (common/compile_cache.py): the caller's
+    JAX_COMPILATION_CACHE_DIR untouched, else ONE fixed path in the
+    checkout — never a temporary, pid or time-made path."""
 
-        prev = jax.config.jax_compilation_cache_dir
-        monkeypatch.setattr(cc, "_applied_dir", None)
-        monkeypatch.setattr(get_context(), "compile_cache_dir", "")
-        try:
-            # knob unset -> disabled, no config touch
-            assert cc.enable_compile_cache() is None
-            target = str(tmp_path / "xla_cache")
-            assert cc.enable_compile_cache(target) == target
-            assert jax.config.jax_compilation_cache_dir == target
-            assert os.path.isdir(target)
-            assert cc.active_cache_dir() == target
-            # idempotent re-apply
-            assert cc.enable_compile_cache(target) == target
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+    @pytest.fixture()
+    def restore_jax_config(self):
+        prev = (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+        )
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prev[1]
+        )
+
+    def test_env_set_is_left_untouched(
+        self, tmp_path, monkeypatch, restore_jax_config
+    ):
+        import dlrover_tpu.common.compile_cache as cc
+
+        theirs = str(tmp_path / "callers_cache")
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, theirs)
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert cc.resolve_cache_dir() == theirs
+        assert cc.enable_compile_cache() == theirs
+        # JAX read the variable itself; this code set no directory (and
+        # made none)
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        assert not os.path.exists(theirs)
+
+    def test_env_unset_goes_to_the_fixed_checkout_path(
+        self, monkeypatch, restore_jax_config
+    ):
+        import dlrover_tpu
+        import dlrover_tpu.common.compile_cache as cc
+
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        checkout = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+        fixed = os.path.join(checkout, ".jax_compile_cache")
+        assert cc.resolve_cache_dir() == cc.DEFAULT_CACHE_DIR == fixed
+        assert cc.enable_compile_cache(min_compile_s=2.5) == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 2.5
+        assert os.path.isdir(fixed)
+        # idempotent
+        assert cc.enable_compile_cache() == fixed
+
+    def test_never_a_temporary_or_pid_path(self, monkeypatch):
+        import tempfile
+
+        import dlrover_tpu.common.compile_cache as cc
+
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        seen = set()
+        for tmp in ("/tmp/a", "/tmp/b"):
+            monkeypatch.setenv("TMPDIR", tmp)
+            tempfile.tempdir = None
+            monkeypatch.setattr(os, "getpid", lambda t=tmp: hash(t) % 9999)
+            seen.add(cc.resolve_cache_dir())
+        tempfile.tempdir = None
+        (path,) = seen  # the same path whatever TMPDIR and pid say
+        assert "/tmp" not in path and not any(c.isdigit() for c in path)
+
+    def test_the_chaos_harnesses_make_no_cache_dir_of_their_own(self):
+        import inspect
+
+        from dlrover_tpu.chaos import goodput_storm, master_kill
+
+        for mod in (goodput_storm, master_kill):
+            src = inspect.getsource(mod)
+            assert "xla_cache" not in src, mod
+            assert "jax_compilation_cache_dir" not in src, mod
 
     def test_context_env_wiring(self, monkeypatch):
         from dlrover_tpu.common.config import Context
 
-        monkeypatch.setenv("DLROVER_COMPILE_CACHE_DIR", "/tmp/cc_env")
         monkeypatch.setenv("DLROVER_COMPILE_CACHE_MIN_COMPILE_S", "2.5")
         monkeypatch.setenv("DLROVER_INPUT_PREFETCH", "0")
         monkeypatch.setenv("DLROVER_CKPT_PREFETCH_RESTORE", "false")
         ctx = Context()
         ctx.apply_env()
-        assert ctx.compile_cache_dir == "/tmp/cc_env"
         assert ctx.compile_cache_min_compile_s == 2.5
         assert ctx.input_prefetch is False
         assert ctx.ckpt_prefetch_restore is False
 
-    def test_launcher_flags(self):
+    def test_agent_hands_the_same_directory_to_its_workers(
+        self, tmp_path, monkeypatch
+    ):
+        import dlrover_tpu.common.compile_cache as cc
         from dlrover_tpu.launcher.elastic_run import (
             config_from_args,
             parse_args,
         )
 
-        ns = parse_args(
-            [
-                "--nnodes", "1",
-                "--compile-cache-dir", "/tmp/job_cache",
-                "--sync-input",
-                "train.py",
-            ]
-        )
-        cfg = config_from_args(ns)
-        env = cfg.worker_env()
-        assert env["DLROVER_COMPILE_CACHE_DIR"] == "/tmp/job_cache"
+        ns = parse_args(["--nnodes", "1", "--sync-input", "train.py"])
+        theirs = str(tmp_path / "callers_cache")
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, theirs)
+        env = config_from_args(ns).worker_env()
+        assert env[cc.CACHE_DIR_ENV] == theirs
         assert env["DLROVER_INPUT_PREFETCH"] == "0"
-        # default: prefetch on -> no override exported
+        monkeypatch.delenv(cc.CACHE_DIR_ENV)
         ns2 = parse_args(["--nnodes", "1", "train.py"])
-        assert "DLROVER_INPUT_PREFETCH" not in config_from_args(
-            ns2
+        env2 = config_from_args(ns2).worker_env()
+        assert env2[cc.CACHE_DIR_ENV] == cc.DEFAULT_CACHE_DIR
+        # default: prefetch on -> no override exported
+        assert "DLROVER_INPUT_PREFETCH" not in env2
+
+
+class TestWorkerPlatformPin:
+    """No hidden CPU: the worker env contract pins ``tpu`` only when the
+    job is a TPU job AND nothing else pinned a platform."""
+
+    @pytest.mark.parametrize(
+        "accelerator,caller,extra,want",
+        [
+            ("tpu", None, {}, "tpu"),  # nothing pinned -> pin the chip
+            ("tpu", "cpu", {}, None),  # caller's pin is inherited as is
+            ("tpu", None, {"JAX_PLATFORMS": "cpu"}, "cpu"),  # extra_env
+            ("cpu", None, {}, None),  # a CPU job pins nothing
+        ],
+    )
+    def test_worker_env_pin(self, monkeypatch, accelerator, caller, extra, want):
+        from dlrover_tpu.agent.config import ElasticLaunchConfig
+
+        if caller is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", caller)
+        env = ElasticLaunchConfig(
+            accelerator=accelerator, extra_env=dict(extra)
         ).worker_env()
+        assert env.get("JAX_PLATFORMS") == want
+
+    def test_pinned_worker_fails_instead_of_training_on_the_host(self):
+        """With the pin, a process that cannot initialize the TPU raises
+        at its first backend touch — here, where there is no chip."""
+        import subprocess
+        import sys
+
+        env = dict(os.environ, JAX_PLATFORMS="tpu", TPU_LOG_DIR="disabled")
+        env.pop("ALLOW_MULTIPLE_LIBTPU_LOAD", None)
+        proc = subprocess.run(
+            [sys.executable, "-c", "import jax; print(jax.devices())"],
+            env=env, capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode != 0
+        assert "CpuDevice" not in proc.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -1467,92 +1467,6 @@ def _bench_elastic(extra):
         AsyncCheckpointSaver.shutdown()
 
 
-def _bench_attribution(extra, cfg, params, on_tpu, interposed,
-                       serving_split=None):
-    """Performance-attribution rung (r6): the serving host/device
-    split from the engine's phase accounting, plus the op-bucket table
-    from the interposer's trace ring when this worker runs interposed.
-    The FULL Report goes to a run-unique artifact; the line carries the
-    POINTER (``attr_report``) + ≤5 headline floats — the instrument the
-    next perf rounds aim with (VERDICT r5 #4/#5).
-
-    ``serving_split`` is the per-row engine's steady-state split handed
-    over by ``_bench_serving`` (same timed stream as the per-row rate);
-    the rung only builds its own small engine when the serving section
-    failed to produce one — recompiles are the scarce resource on a
-    budgeted chip window."""
-    import numpy as np
-
-    from dlrover_tpu.attribution import build_report
-    from dlrover_tpu.models.generation import SamplingConfig
-    from dlrover_tpu.models.gpt import GPT
-
-    split = serving_split
-    if split is None:
-        model = GPT(cfg)
-        if on_tpu:
-            B, Pw, N, n_req = 8, 64, 16, 16
-        else:
-            B, Pw, N, n_req = 2, 16, 6, 4
-        sampling = SamplingConfig(max_new_tokens=N, temperature=0.0)
-        r = np.random.default_rng(17)
-        prompts = [
-            [int(x) for x in r.integers(
-                1, cfg.vocab_size, r.integers(4, Pw)
-            )]
-            for _ in range(n_req)
-        ]
-        _, eng = _timed_stream(
-            model, params, sampling, B, Pw, prompts, layout="per_row",
-        )
-        split = eng.phases.split()
-
-    op_table = None
-    if interposed:
-        try:
-            from dlrover_tpu.attribution.ops import account_events
-            from dlrover_tpu.profiler import pjrt
-
-            ring_path = os.path.join(
-                _REPO_DIR,
-                f"BENCH_attr_ring_{int(time.time())}_{os.getpid()}"
-                ".timeline",
-            )
-            events, names = pjrt.drain_trace_events(keep_path=ring_path)
-            if events:
-                # record the pointer the moment the kept files exist:
-                # an accounting failure below must not strand an
-                # unreferenced (hence never-committed) ring artifact
-                extra["attr_ring"] = os.path.basename(ring_path)
-                op_table = account_events(events, names)
-        except Exception as e:  # noqa: BLE001 — keep the serving split
-            extra["attr_ring_error"] = repr(e)[:160]
-
-    report = build_report(
-        op_table=op_table, serving=split,
-        meta={"device": extra.get("device", ""),
-              "source": "serving_rung" if serving_split else "own_engine"},
-    )
-    path = os.path.join(
-        _REPO_DIR, f"BENCH_attr_{int(time.time())}_{os.getpid()}.json"
-    )
-    try:
-        report.save(path)
-        extra["attr_report"] = os.path.basename(path)
-    except OSError as e:
-        extra["attr_report_error"] = repr(e)[:120]
-    # the ≤5-float headline contract is owned by Report.headline()
-    head = report.headline()
-    if "serving_host_frac" in head:
-        extra["serving_host_frac"] = head["serving_host_frac"]
-    if "matmul_frac" in head:
-        extra["attr_matmul_frac"] = head["matmul_frac"]
-    res = report.top_residual()
-    if res.get("bucket"):
-        extra["attr_top_residual"] = res["bucket"]
-        extra["attr_top_residual_frac"] = res["frac"]
-
-
 def _section_gc(extra, name):
     """Between-section HBM hygiene + accounting: drop dead executables
     (jit caches pin their handles), collect cycles, and record the live
@@ -1945,15 +1859,6 @@ def main() -> int:
                 )
             except Exception as e:  # noqa: BLE001
                 extra["serving_error"] = repr(e)[:200]
-
-        if want("attr"):
-            try:
-                _bench_attribution(
-                    extra, cfg, params, on_tpu, interposed,
-                    serving_split,
-                )
-            except Exception as e:  # noqa: BLE001
-                extra["attr_error"] = repr(e)[:200]
 
         if want("fleet"):
             try:

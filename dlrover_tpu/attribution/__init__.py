@@ -1,36 +1,24 @@
-"""Performance attribution — turn raw profiling signals into answers.
+"""Performance attribution — what the program's own accounting reduces to.
 
-The runtime's headline numbers (MFU ~0.50, serving per-slot throughput
-~4.6x under raw decode) were unattributed for five rounds: the
-interposer's per-op trace ring and the serving engine's per-request
-timestamps existed, but nothing reduced them to "where does the time
-go, and what is the next lever". This subsystem is that reduction,
-in three pillars:
+Two reductions live here; both are read on every run:
 
-- :mod:`~dlrover_tpu.attribution.ops` — drain the PJRT interposer's
-  trace ring, classify device ops into buckets (matmul, attention,
-  VPU, optimizer/HBM, collective, gap/dispatch) via a fingerprint
-  table, and produce a per-step device-time table with a
-  ``top_residual`` recommendation.
 - :mod:`~dlrover_tpu.attribution.phases` — the serving host/device
-  split: the continuous-batching engine stamps its scheduler round
-  boundaries (admission, prefill, decode dispatch, host sync,
-  retirement) into a :class:`PhaseAccumulator`, which reduces them to
-  ``serving_host_frac`` plus a per-phase histogram.
-- :mod:`~dlrover_tpu.attribution.report` — the machine-readable
-  :class:`Report` (serialized to bench extras as POINTERS + a handful
-  of headline floats, never payloads) and its human table.
+  split: the continuous-batching engine's round opens ``serve.*`` spans
+  (:mod:`dlrover_tpu.observability.spans`) that book under admission,
+  prefill, decode dispatch, host sync, retirement and overlap-hidden in a
+  :class:`PhaseAccumulator`, which reduces them to ``serving_host_frac``
+  plus a per-phase histogram (``/healthz``). The fleet gateway, the pool
+  arbiter and the cluster scheduler keep accumulators of their own.
+- :mod:`~dlrover_tpu.attribution.recovery` — the MTTR phase spool: where
+  a recovery's time goes (rendezvous, restore, compile, first step).
 
-CLI: ``tpurun-attr RING.timeline`` dumps the op table from a saved
-trace ring (see :mod:`~dlrover_tpu.attribution.cli`).
+Device time by operation is not accounted here any more: the interposer's
+ring holds whole-executable envelopes, so its op buckets read ``other``.
+Open the ``.xplane.pb`` of any ``jax.profiler`` session instead — the
+program's spans sit there by name beside the device's operations
+(``docs/observability.md``); ``benchmark/reduce_trace.py`` reduces it.
 """
 
-from .ops import (  # noqa: F401
-    BUCKETS,
-    OpTable,
-    account_events,
-    classify_op,
-)
 from .phases import (  # noqa: F401
     DEVICE_PHASES,
     HOST_PHASES,
@@ -44,22 +32,15 @@ from .recovery import (  # noqa: F401
     record_phase_file,
 )
 from .recovery import PHASES as RECOVERY_PHASES  # noqa: F401
-from .report import Report, build_report  # noqa: F401
 
 __all__ = [
     "RECOVERY_DIR_ENV",
     "RECOVERY_PHASES",
     "aggregate_recovery",
     "record_phase_file",
-    "BUCKETS",
-    "OpTable",
-    "account_events",
-    "classify_op",
     "PHASES",
     "HOST_PHASES",
     "DEVICE_PHASES",
     "PhaseAccumulator",
     "PhaseSplit",
-    "Report",
-    "build_report",
 ]

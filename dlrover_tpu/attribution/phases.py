@@ -27,9 +27,10 @@ arithmetic over (phase, seconds) samples, so the split math is
 unit-testable on synthetic timestamps without an engine.
 """
 
-import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
+
+from ..observability.spans import SpanAccumulator
 
 PHASES = (
     "admission",
@@ -77,19 +78,6 @@ HOST_PHASES = frozenset(
 DEVICE_PHASES = frozenset({"prefill", "host_sync", "proxy", "drain"})
 OVERLAP_PHASES = frozenset({"overlap_hidden"})
 
-# log2(µs) histogram: bucket i covers [2^i, 2^(i+1)) µs; 20 buckets
-# reach ~10 min — far past any sane phase span.
-HIST_BUCKETS = 20
-
-
-@dataclass
-class PhaseStat:
-    total_s: float = 0.0
-    count: int = 0
-    max_s: float = 0.0
-    hist: List[int] = field(default_factory=lambda: [0] * HIST_BUCKETS)
-
-
 @dataclass
 class PhaseSplit:
     """One reduction of an accumulator: totals, fractions, histogram."""
@@ -103,10 +91,15 @@ class PhaseSplit:
     # host time hidden behind in-flight device chunks (the pipelined
     # scheduler's round): in total_s, in neither host_s nor device_s
     overlap_s: float = 0.0
+    # the accumulator's counters (requests admitted, seconds waited, ...)
+    counters: Dict[str, float] = field(default_factory=dict)
 
     def summary(self) -> Dict:
         """Compact dict for /healthz and bench extras (floats only,
-        bounded key count — the 1,800-byte line budget applies)."""
+        bounded key count — the 1,800-byte line budget applies). Only a
+        phase's total ends in ``_ms``: readers sum every such key into the
+        round's total. A counter rides as ``<name>_sum`` where it holds
+        seconds (``*_s``) and as ``<name>_n`` where it counts."""
         out = {
             "serving_host_frac": round(self.serving_host_frac, 4),
             "rounds": self.rounds,
@@ -115,33 +108,24 @@ class PhaseSplit:
             out["overlap_hidden_s"] = round(self.overlap_s, 4)
         for name, stat in self.phases.items():
             out[f"{name}_ms"] = round(stat["total_s"] * 1e3, 2)
+        for name, value in self.counters.items():
+            if name.endswith("_s"):
+                out[f"{name}_sum"] = round(value, 6)
+            else:
+                out[f"{name}_n"] = value
         return out
 
 
-def _hist_bucket(dur_s: float) -> int:
-    us = dur_s * 1e6
-    if us < 1.0:
-        return 0
-    return min(int(math.log2(us)), HIST_BUCKETS - 1)
-
-
-class PhaseAccumulator:
-    """Running per-phase totals + log2-µs histograms. ``add`` is a few
-    dict ops — cheap enough to leave always-on in the serving engine
-    (one call per phase per scheduler round, not per token)."""
+class PhaseAccumulator(SpanAccumulator):
+    """A :class:`SpanAccumulator` whose names are phases of a round, with
+    the host/device/hidden reduction over them. Fed by ``span(name,
+    book=<phase>)`` in the serving engine and by ``add`` elsewhere. A
+    phase's seconds are its spans' *self* time, so phases that nest (the
+    prefill inside an admission) still partition the round."""
 
     def __init__(self):
-        self._stats: Dict[str, PhaseStat] = {}
+        super().__init__()
         self.rounds = 0
-
-    def add(self, phase: str, dur_s: float) -> None:
-        if dur_s < 0:
-            dur_s = 0.0
-        stat = self._stats.setdefault(phase, PhaseStat())
-        stat.total_s += dur_s
-        stat.count += 1
-        stat.max_s = max(stat.max_s, dur_s)
-        stat.hist[_hist_bucket(dur_s)] += 1
 
     def add_round(
         self, spans: List[Tuple[str, float]]
@@ -153,7 +137,7 @@ class PhaseAccumulator:
         self.rounds += 1
 
     def reset(self) -> None:
-        self._stats.clear()
+        super().reset()
         self.rounds = 0
 
     def split(self) -> PhaseSplit:
@@ -161,15 +145,15 @@ class PhaseAccumulator:
         # handler) while the driver's step() inserts phase keys —
         # dict(d) is a single C-level copy under the GIL, so the
         # iteration below never sees a resize
-        stats = dict(self._stats)
+        stats = self.stats()
         host_s = sum(
-            s.total_s for p, s in stats.items() if p in HOST_PHASES
+            s.self_s for p, s in stats.items() if p in HOST_PHASES
         )
         overlap_s = sum(
-            s.total_s for p, s in stats.items() if p in OVERLAP_PHASES
+            s.self_s for p, s in stats.items() if p in OVERLAP_PHASES
         )
         device_s = sum(
-            s.total_s for p, s in stats.items()
+            s.self_s for p, s in stats.items()
             if p not in HOST_PHASES and p not in OVERLAP_PHASES
         )
         total_s = host_s + device_s + overlap_s
@@ -180,12 +164,13 @@ class PhaseAccumulator:
             overlap_s=overlap_s,
             serving_host_frac=(host_s / total_s) if total_s > 0 else 0.0,
             rounds=self.rounds,
+            counters=self.counters(),
             phases={
                 name: {
-                    "total_s": round(stat.total_s, 6),
+                    "total_s": round(stat.self_s, 6),
                     "count": stat.count,
                     "mean_ms": round(
-                        stat.total_s / stat.count * 1e3, 3
+                        stat.self_s / stat.count * 1e3, 3
                     )
                     if stat.count
                     else 0.0,

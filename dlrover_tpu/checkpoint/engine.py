@@ -22,6 +22,7 @@ from ..common.constants import NodeEnv
 from ..common.log import logger
 from ..common.multi_process import LocalSocketClient, SharedLock, SharedQueue
 from ..common.events import TrainerEvents
+from ..observability.spans import process_accumulator, span
 from .saver import (
     EVENT_QUEUE,
     FACTORY_QUEUE,
@@ -69,6 +70,24 @@ def _restore_into_template(template: Any, arrays: Dict[str, np.ndarray]) -> Any:
         for i, p in zip(positions, placed):
             leaves[i] = p
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+_SAVE_PARTS = ("plan", "ensure", "d2h", "memcpy")
+
+
+def _save_parts_since(before: Dict[str, float]) -> Dict[str, float]:
+    """Seconds each part of ``shm.save_pytree`` booked since ``before``
+    (``process_accumulator().totals()``): what the ``ckpt_save`` event
+    carries at its end, so that every save of every run, traced or not,
+    says where its time went (a slow one rarely falls into a traced window)."""
+    now = process_accumulator().totals()
+    return {
+        f"{part}_s": round(
+            now.get(f"ckpt.save.{part}", 0.0)
+            - before.get(f"ckpt.save.{part}", 0.0), 6
+        )
+        for part in _SAVE_PARTS
+    }
 
 
 def _device_memory_stats(device) -> Optional[Dict[str, int]]:
@@ -404,6 +423,107 @@ class CheckpointEngine:
         # window; an error must surface to the loop (which re-saves
         # blocking or skips the step), never wedge the shard lock.
         faults.inject("ckpt.engine.save", step=step)
+        # The save names its own time on the profiler's clock. A root
+        # this rare carries the wall clock too: the trace's timestamps
+        # count from the session's start, events carry wall-clock ``ts``,
+        # and ``unix_ns`` is what puts the two on one axis.
+        with span("ckpt.save", step=step, unix_ns=time.time_ns()) as root:
+            return self._save_to_memory(
+                step, pytree, extra, block, for_storage, root
+            )
+
+    def _save_to_memory(
+        self, step, pytree, extra, block, for_storage, root
+    ) -> bool:
+        with span("ckpt.save.ready"):
+            ready, acquired = self._ready_to_save(step)
+        if not ready:
+            if acquired:
+                self._shard_lock.release()
+            logger.warning(
+                "skip save_to_memory step %s: a persister is busy", step
+            )
+            return False
+        if not block and self._async_disabled:
+            block = True
+        if not block:
+            with span("ckpt.save.snapshot") as snap:
+                snapshot = None
+                if self._snapshot_fits(pytree):
+                    try:
+                        snapshot = self._snapshot(pytree)
+                    except Exception as e:
+                        msg = repr(e).lower()
+                        if not (
+                            "resource_exhausted" in msg
+                            or "out of memory" in msg
+                        ):
+                            self._shard_lock.release()
+                            raise
+                        # No HBM headroom for the snapshot: degrade THIS
+                        # and all later saves to the blocking path (we
+                        # still hold the shard lock — fall through).
+                        self._async_disabled = True
+                        logger.error(
+                            "snapshot OOM at step %s; degrading to "
+                            "blocking saves", step
+                        )
+                snap.set(fits=int(snapshot is not None))
+            # no snapshot: no HBM headroom for a device-side copy
+            block = snapshot is None
+        root.set(blocking=int(block))
+        if not block:
+            try:
+                t = threading.Thread(
+                    target=self._stage_async,
+                    args=(step, snapshot, extra, for_storage),
+                    name=f"ckpt-stage-{step}",
+                    daemon=True,
+                )
+                t.start()
+                # Assigned only AFTER start(): join() on a never-started
+                # thread raises, which would break every later
+                # wait_staged/close if start() itself failed.
+                self._stage_thread = t
+                return True
+            except Exception:
+                self._shard_lock.release()
+                raise
+        try:
+            self._stage_into_shm(step, pytree, extra, root)
+            # A successful blocking save supersedes any stale async
+            # failure: without this, a degraded (async-disabled) engine
+            # would keep failing wait_staged_all and force redundant
+            # re-saves of steps that already landed.
+            self._stage_error = None
+        finally:
+            self._shard_lock.release()
+        if self._replicate:
+            # Mirror to the backup peer — handled by the agent saver so
+            # the trainer never blocks on a DCN transfer.
+            self._event_q.put({"type": CheckpointEvent.REPLICATE, "step": step})
+        return True
+
+    def _stage_into_shm(self, step: int, tree: Any, extra, root) -> None:
+        """``shm.save_pytree`` under the ``ckpt_save`` event, whose end
+        carries the split; ``root`` (the open ``ckpt.save`` or
+        ``ckpt.stage`` span) gets what was staged."""
+        with self._events.ckpt_save(step, storage="memory") as event:
+            before = process_accumulator().totals()
+            meta = self.shm.save_pytree(
+                step,
+                tree,
+                num_hosts=self.num_hosts,
+                mesh=self.mesh,
+                extra=extra,
+            )
+            event.content.update(_save_parts_since(before))
+        root.set(bytes=meta.total_bytes, leaves=len(meta.records))
+
+    def _ready_to_save(self, step: int) -> Tuple[bool, bool]:
+        """Everything a save waits for before it touches the state:
+        the restore prefetch, the shard lock, the other hosts. Returns
+        (all hosts ready, this host holds the shard lock)."""
         # Any save supersedes the restore prefetch: a later consume of
         # the pre-save image would silently restore an older step.
         # Invalid FIRST — it doubles as the cancel signal, so a thread
@@ -433,67 +553,7 @@ class CheckpointEngine:
             if acquired:
                 self._shard_lock.release()
             raise
-        if not ready:
-            if acquired:
-                self._shard_lock.release()
-            logger.warning(
-                "skip save_to_memory step %s: a persister is busy", step
-            )
-            return False
-        if not block and (
-            self._async_disabled or not self._snapshot_fits(pytree)
-        ):
-            block = True  # no HBM headroom for a device-side snapshot
-        if not block:
-            try:
-                snapshot = self._snapshot(pytree)
-                t = threading.Thread(
-                    target=self._stage_async,
-                    args=(step, snapshot, extra, for_storage),
-                    name=f"ckpt-stage-{step}",
-                    daemon=True,
-                )
-                t.start()
-                # Assigned only AFTER start(): join() on a never-started
-                # thread raises, which would break every later
-                # wait_staged/close if start() itself failed.
-                self._stage_thread = t
-                return True
-            except Exception as e:
-                msg = repr(e).lower()
-                if "resource_exhausted" in msg or "out of memory" in msg:
-                    # No HBM headroom for the snapshot: degrade THIS and
-                    # all later saves to the blocking path (we still
-                    # hold the shard lock — fall through below).
-                    self._async_disabled = True
-                    logger.error(
-                        "snapshot OOM at step %s; degrading to blocking "
-                        "saves", step
-                    )
-                else:
-                    self._shard_lock.release()
-                    raise
-        try:
-            with self._events.ckpt_save(step, storage="memory"):
-                self.shm.save_pytree(
-                    step,
-                    pytree,
-                    num_hosts=self.num_hosts,
-                    mesh=self.mesh,
-                    extra=extra,
-                )
-            # A successful blocking save supersedes any stale async
-            # failure: without this, a degraded (async-disabled) engine
-            # would keep failing wait_staged_all and force redundant
-            # re-saves of steps that already landed.
-            self._stage_error = None
-        finally:
-            self._shard_lock.release()
-        if self._replicate:
-            # Mirror to the backup peer — handled by the agent saver so
-            # the trainer never blocks on a DCN transfer.
-            self._event_q.put({"type": CheckpointEvent.REPLICATE, "step": step})
-        return True
+        return ready, acquired
 
     def _snapshot_fits(self, pytree: Any) -> bool:
         """Whether every device has room for a second copy of its share
@@ -570,14 +630,12 @@ class CheckpointEngine:
         re-save, where the silent alternative loses the step."""
         ok = False
         try:
-            with self._events.ckpt_save(step, storage="memory"):
-                self.shm.save_pytree(
-                    step,
-                    snapshot,
-                    num_hosts=self.num_hosts,
-                    mesh=self.mesh,
-                    extra=extra,
-                )
+            # the staging thread's root: the save's own root (ckpt.save)
+            # closed on the trainer's thread when this one started
+            with span(
+                "ckpt.stage", step=step, unix_ns=time.time_ns()
+            ) as root:
+                self._stage_into_shm(step, snapshot, extra, root)
             ok = True
             self._stage_error = None
         except BaseException as e:  # noqa: BLE001 — recorded, surfaced by wait_staged

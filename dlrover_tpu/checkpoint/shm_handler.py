@@ -17,6 +17,7 @@ import numpy as np
 
 from ..common.log import logger
 from ..common.multi_process import SharedMemorySegment
+from ..observability.spans import span
 from .meta import (
     HEADER_LEN_BYTES,
     CheckpointMeta,
@@ -161,6 +162,48 @@ class SharedMemoryHandler:
         mesh=None,
         extra: Optional[Dict[str, Any]] = None,
     ) -> CheckpointMeta:
+        """Stage ``pytree`` into the segment. Names its own time on the
+        profiler's clock, under whichever root the caller opened
+        (``ckpt.save``, or ``ckpt.stage`` on the staging thread):
+        ``ckpt.save.plan``, ``ckpt.save.ensure`` and, per leaf,
+        ``ckpt.save.d2h`` (the wait on the device-to-host copy) and
+        ``ckpt.save.memcpy`` (the copy into ``/dev/shm``)."""
+        with span("ckpt.save.plan"):
+            meta, plan, meta_bytes = self._plan(
+                step, pytree, num_hosts, mesh, extra
+            )
+        total = HEADER_LEN_BYTES + len(meta_bytes) + meta.total_bytes
+        with span("ckpt.save.ensure", bytes=total):
+            self._segment.ensure(total)
+        buf = self._segment.buf
+        # Header lands LAST: a trainer killed mid-stage must leave an
+        # image that parses as absent, not a fresh meta over a torn
+        # payload (the agent's breakpoint save would persist it).
+        buf[:HEADER_LEN_BYTES] = b"\x00" * HEADER_LEN_BYTES
+        payload_base = HEADER_LEN_BYTES + len(meta_bytes)
+        buf[HEADER_LEN_BYTES:payload_base] = meta_bytes
+        for rec, shard in plan:
+            if isinstance(shard, np.ndarray):
+                data = shard
+            else:
+                data = getattr(shard, "data", shard)
+            with span("ckpt.save.d2h"):
+                host = np.asarray(data)
+            with span("ckpt.save.memcpy"):
+                flat = np.ascontiguousarray(host).reshape(-1)
+                start = payload_base + rec.offset
+                view = np.frombuffer(buf, dtype=np.uint8, count=rec.nbytes, offset=start)
+                view[:] = flat.view(np.uint8)
+                del view  # release the exported buffer pointer promptly
+        buf[:HEADER_LEN_BYTES] = len(meta_bytes).to_bytes(
+            HEADER_LEN_BYTES, "little"
+        )
+        return meta
+
+    def _plan(self, step, pytree, num_hosts, mesh, extra):
+        """Flatten, plan one record per unique addressable shard, kick
+        every device-to-host copy, lay the records out and encode the
+        meta: everything before the first byte moves."""
         flat, _ = jax.tree_util.tree_flatten_with_path(pytree)
         plan: List[Tuple[ShardRecord, Any]] = []
         for path, leaf in flat:
@@ -194,31 +237,7 @@ class SharedMemoryHandler:
             offset += rec.nbytes
             meta.records.append(rec)
         meta.total_bytes = offset
-
-        meta_bytes = meta.to_json().encode()
-        total = HEADER_LEN_BYTES + len(meta_bytes) + offset
-        self._segment.ensure(total)
-        buf = self._segment.buf
-        # Header lands LAST: a trainer killed mid-stage must leave an
-        # image that parses as absent, not a fresh meta over a torn
-        # payload (the agent's breakpoint save would persist it).
-        buf[:HEADER_LEN_BYTES] = b"\x00" * HEADER_LEN_BYTES
-        payload_base = HEADER_LEN_BYTES + len(meta_bytes)
-        buf[HEADER_LEN_BYTES:payload_base] = meta_bytes
-        for rec, shard in plan:
-            if isinstance(shard, np.ndarray):
-                data = shard
-            else:
-                data = getattr(shard, "data", shard)
-            flat = np.ascontiguousarray(np.asarray(data)).reshape(-1)
-            start = payload_base + rec.offset
-            view = np.frombuffer(buf, dtype=np.uint8, count=rec.nbytes, offset=start)
-            view[:] = flat.view(np.uint8)
-            del view  # release the exported buffer pointer promptly
-        buf[:HEADER_LEN_BYTES] = len(meta_bytes).to_bytes(
-            HEADER_LEN_BYTES, "little"
-        )
-        return meta
+        return meta, plan, meta.to_json().encode()
 
     # -- agent / loader side ----------------------------------------------
 

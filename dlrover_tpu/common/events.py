@@ -18,6 +18,7 @@ import uuid
 from typing import Any, Dict, Optional
 
 from ..observability import flight_recorder, trace
+from ..observability.spans import annotation
 from .log import logger
 
 
@@ -179,7 +180,11 @@ class AsyncExporter(Exporter):
 
 
 class DurationSpan:
-    """Context manager emitting paired begin/end events."""
+    """Context manager emitting paired begin/end events. Used as a
+    ``with`` block (one thread), it also opens a profiler annotation of
+    its own name, so the incident stream's intervals show on a
+    ``jax.profiler`` trace under the names ``tpurun-trace`` prints; a
+    ``begin()``/``end()`` pair may cross threads and opens none."""
 
     def __init__(self, emitter: "EventEmitter", name: str, content: Dict[str, Any]):
         self._emitter = emitter
@@ -189,6 +194,7 @@ class DurationSpan:
         self._ended = False
         self._trace_token = None
         self._span_ctx = None
+        self._annotation = None
 
     def begin(self) -> "DurationSpan":
         self._begin_time = time.time()
@@ -224,6 +230,9 @@ class DurationSpan:
         self.end({"error": error, "success": False})
 
     def __enter__(self) -> "DurationSpan":
+        self._annotation = annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self.begin()
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -231,6 +240,9 @@ class DurationSpan:
             self.fail(repr(exc))
         else:
             self.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
 
 
 class EventEmitter:

@@ -50,6 +50,7 @@ from typing import Optional
 
 from ..common.constants import ENV_KNOBS
 from ..common.log import logger
+from ..observability.spans import span
 
 __all__ = ["ServingDaemon", "main"]
 
@@ -102,12 +103,17 @@ class ServingDaemon:
             # the loop is gone; an enqueued future would never resolve
             raise RuntimeError("serving daemon stopped")
         fut: Future = Future()
-        self._inbox.put((kind, payload, fut))
+        # stamped HERE, on the caller's thread: the driver picks the
+        # item up only between two engine steps, and that wait is in no
+        # later stamp (the engine's submit_t starts after it)
+        self._inbox.put((kind, payload, fut, time.perf_counter()))
         try:
             return fut.result(timeout)
         except FutureTimeout:
             if cancel_on_timeout:
-                self._inbox.put(("cancel_fut", fut, None))
+                self._inbox.put(
+                    ("cancel_fut", fut, None, time.perf_counter())
+                )
             raise
 
     def complete(
@@ -230,9 +236,20 @@ class ServingDaemon:
             item = self._inbox.get(timeout=0.1 if block else 0.0)
         except queue.Empty:
             return
+        # the span covers the handling, not the idle wait above
+        with span("serve.inbox"):
+            self._handle_inbox(item)
+
+    def _handle_inbox(self, item):
         while item is not None:
-            kind, payload, fut = item
+            kind, payload, fut, t_put = item
             try:
+                if kind in ("req", "req_stream", "req_prefilled"):
+                    # the request reaches the engine now: what it
+                    # waited in the inbox behind a running step
+                    self.eng.phases.count(
+                        "inbox_wait_s", time.perf_counter() - t_put
+                    )
                 if kind == "req":
                     prompt, cap, prefix_id, allowed = payload
                     uid = self.eng.submit(
@@ -306,6 +323,21 @@ class ServingDaemon:
             except queue.Empty:
                 item = None
 
+    def _resolve(self, completions) -> None:
+        """Hand finished requests to their futures or stream records."""
+        for c in completions:
+            with self._mu:
+                fut = self._waiters.pop(c.uid, None)
+                streaming = c.uid in self._stream_uids
+                if streaming:
+                    self._stream_uids.discard(c.uid)
+                    self._stream_done[c.uid] = c
+            if fut is not None:
+                fut.set_result(c)
+                self.served += 1
+            elif streaming:
+                self.served += 1
+
     def _fail_all(self, exc: Exception) -> None:
         """Resolve every in-flight and queued future with ``exc`` — a
         dead driver must fail fast, not leave clients blocking out
@@ -322,7 +354,7 @@ class ServingDaemon:
                 fut.set_exception(exc)
         while True:
             try:
-                _, _, fut = self._inbox.get_nowait()
+                _, _, fut, _ = self._inbox.get_nowait()
             except queue.Empty:
                 break
             if fut is not None and not fut.done():
@@ -345,18 +377,10 @@ class ServingDaemon:
                     # reload on an idle server would leave
                     # swap_pending=true forever without this poll
                     self.eng.poll_pending_swap()
-                for c in self.eng.drain_completions():
-                    with self._mu:
-                        fut = self._waiters.pop(c.uid, None)
-                        streaming = c.uid in self._stream_uids
-                        if streaming:
-                            self._stream_uids.discard(c.uid)
-                            self._stream_done[c.uid] = c
-                    if fut is not None:
-                        fut.set_result(c)
-                        self.served += 1
-                    elif streaming:
-                        self.served += 1
+                done = self.eng.drain_completions()
+                if done:
+                    with span("serve.complete", n=len(done)):
+                        self._resolve(done)
             except Exception as e:  # noqa: BLE001 — driver must not die silently
                 logger.exception("serving driver error: %s", e)
                 self._fail_all(RuntimeError(f"serving driver error: {e!r}"))

@@ -63,7 +63,11 @@ TPU shape — every device program is static-shape and compiled once:
   Weight swaps adopt only at a drained pipeline (no chunk in flight),
   so a push can never split a round between parameter versions.
   Host time hidden behind in-flight chunks is stamped as the
-  ``overlap_hidden`` phase (attribution.phases).
+  ``overlap_hidden`` phase (attribution.phases). Every round names
+  its own time through ``self.phases.span``: one call writes the span
+  onto the profiler's clock (``serve.round`` > ``serve.admission``,
+  ``serve.prefill``, ``serve.decode_dispatch``, ``serve.host_sync``,
+  ``serve.retirement``) and books it under its phase for ``/healthz``.
 - **decode_chunk auto-tuning** (``auto_chunk=True``): the measured
   ``serving_host_frac`` drives the chunk length between dispatches —
   host-bound streams grow the chunk (amortize per-round host cost over
@@ -368,10 +372,12 @@ class ContinuousBatchingEngine:
         self._prefixes: Dict[int, List[int]] = {}
         self._prefix_states: Dict[int, tuple] = {}
         self._next_prefix_id = 0
-        # host/device phase accounting: every scheduler round stamps
-        # admission / prefill / decode_dispatch / host_sync /
-        # retirement spans; attribution.phases reduces them to
-        # serving_host_frac (the VERDICT r5 #4 unmeasured gap)
+        # host/device phase accounting: every scheduler round opens
+        # serve.* spans (observability.spans: the profiler's clock) that
+        # book under admission / prefill / decode_dispatch / host_sync /
+        # retirement / overlap_hidden; attribution.phases reduces them
+        # to serving_host_frac. The same accumulator counts requests,
+        # their waits, chunks and row-steps where they happen.
         self.phases = PhaseAccumulator()
         # rolling completion-latency window: (retire_t, total_s,
         # emitted tokens) per finished request. Sized to smooth over
@@ -1147,10 +1153,30 @@ class ContinuousBatchingEngine:
                 )
         # full prefix+suffix history: compaction (frontier layout)
         # rebuilds rows from these tokens
+        self._seat(slot, uid, full_prompt, submit_t, cap)
+
+    def _seat(self, slot, uid, prompt, submit_t, cap, now=None):
+        """The host half of an admission: the slot's record, and the
+        queue wait it ended (submit → admission), counted here."""
+        now = time.perf_counter() if now is None else now
         self._slots[slot] = _Slot(
-            uid=uid, prompt=full_prompt, submit_t=submit_t, cap=cap,
-            admit_t=time.perf_counter(),
+            uid=uid, prompt=prompt, submit_t=submit_t, cap=cap,
+            admit_t=now,
         )
+        self.phases.count("requests_admitted")
+        self.phases.count("queue_wait_s", max(now - submit_t, 0.0))
+
+    def _first_token(self, st: _Slot, now: float) -> None:
+        st.first_tok_t = now
+        self.phases.count(
+            "admit_to_first_token_s", max(now - st.admit_t, 0.0)
+        )
+
+    def _count_chunk(self, row_steps: int) -> None:
+        """One dispatched chunk: ``row_steps`` = slots x positions it
+        decodes, whether or not a live row fills them."""
+        self.phases.count("chunks")
+        self.phases.count("row_steps", row_steps)
 
     # -- paged block planning (host side of admission) ------------------
 
@@ -1315,12 +1341,12 @@ class ContinuousBatchingEngine:
     _burst_admit = True
 
     # tpulint: hotpath — admission runs under the in-flight chunk
-    def _admit_free_slots(self) -> float:
-        """Fill empty slots from the queue while the budget allows;
-        returns the seconds spent in the admission device path
-        (prefill + admit programs). The caller stamps phases — in the
-        overlapped round this whole span runs while a chunk is in
-        flight and is accounted as hidden.
+    def _admit_free_slots(self, hidden: bool = False) -> None:
+        """Fill empty slots from the queue while the budget allows.
+        The admission device path (prefill + admit programs) runs in
+        ``serve.prefill`` spans inside the caller's ``serve.admission``;
+        in the overlapped round the whole of it runs while a chunk is
+        in flight (``hidden``) and books as ``overlap_hidden``.
 
         The overlapped round admits a whole burst through ONE
         ``admit_many`` dispatch: a wave of equal-cap slots retires
@@ -1335,7 +1361,7 @@ class ContinuousBatchingEngine:
         frontier_layout = self.layout == "frontier"
         paged = self.layout == "paged"
         burst = self.overlap and self._burst_admit
-        prefill_s = 0.0
+        prefill_phase = "overlap_hidden" if hidden else "prefill"
         batch = []
         for slot, st in enumerate(self._slots):
             if st.uid >= 0 or not self._queue:
@@ -1365,32 +1391,35 @@ class ContinuousBatchingEngine:
             (uid, prompt, submit_t, cap, prefix_id, allowed) = (
                 self._queue.pop(0)
             )
-            ta = time.perf_counter()
-            if not burst:
-                # table_ids kwarg only when paged: subclasses override
-                # _admit_one without it (they force dense layouts)
-                if paged:
-                    self._admit_one(
-                        slot, uid, prompt, submit_t, cap, prefix_id,
-                        allowed, table_ids=table_ids,
-                    )
+            with self.phases.span(
+                "serve.prefill", book=prefill_phase, uid=uid
+            ):
+                if not burst:
+                    # table_ids kwarg only when paged: subclasses
+                    # override _admit_one without it (they force dense
+                    # layouts)
+                    if paged:
+                        self._admit_one(
+                            slot, uid, prompt, submit_t, cap, prefix_id,
+                            allowed, table_ids=table_ids,
+                        )
+                    else:
+                        self._admit_one(
+                            slot, uid, prompt, submit_t, cap, prefix_id,
+                            allowed,
+                        )
                 else:
-                    self._admit_one(
-                        slot, uid, prompt, submit_t, cap, prefix_id,
-                        allowed,
+                    row, width, full_prompt = self._build_row(
+                        uid, prompt, prefix_id, allowed
                     )
-            else:
-                row, width, full_prompt = self._build_row(
-                    uid, prompt, prefix_id, allowed
-                )
-                batch.append(
-                    (slot, row, width, cap, uid, full_prompt, submit_t,
-                     table_ids)
-                )
-            prefill_s += time.perf_counter() - ta
+                    batch.append(
+                        (slot, row, width, cap, uid, full_prompt,
+                         submit_t, table_ids)
+                    )
         if batch:
-            ta = time.perf_counter()
-            with self._ctx():
+            with self.phases.span(
+                "serve.prefill", book=prefill_phase, rows=len(batch)
+            ), self._ctx():
                 if paged:
                     self._state = self._admit_many_fn(
                         self._state,
@@ -1418,12 +1447,7 @@ class ContinuousBatchingEngine:
                  table_ids) in batch:
                 if paged:
                     self._row_blocks[slot] = list(table_ids)
-                self._slots[slot] = _Slot(
-                    uid=uid, prompt=full_prompt, submit_t=submit_t,
-                    cap=cap, admit_t=now,
-                )
-            prefill_s += now - ta
-        return prefill_s
+                self._seat(slot, uid, full_prompt, submit_t, cap, now)
 
     # tpulint: hotpath — drains happen via _drain_inflight, never inline
     def _frontier_housekeeping(self) -> int:
@@ -1449,9 +1473,11 @@ class ContinuousBatchingEngine:
             self._reset_device_state()
         if self._frontier + self.d > self.L:
             emitted += self._drain_inflight()
-            tc = time.perf_counter()
-            self._compact()  # a batched re-prefill: device work
-            self.phases.add("prefill", time.perf_counter() - tc)
+            # a batched re-prefill: device work
+            with self.phases.span(
+                "serve.prefill", book="prefill", compaction=1
+            ):
+                self._compact()
         return emitted
 
     # tpulint: hotpath — dispatch must never read the device back
@@ -1474,6 +1500,7 @@ class ContinuousBatchingEngine:
                 self._state, (toks, emits, logps) = chunk_fn(
                     self.params, self._state, self._i32(0), rng
                 )
+        self._count_chunk(self.B * self.d)
         return (
             toks, emits, logps, self._state[-2],  # -2: the done flags
             [st.uid for st in self._slots],
@@ -1502,7 +1529,7 @@ class ContinuousBatchingEngine:
                     new = new[:max(room, 0)]
                 if new:
                     if not st.emitted:
-                        st.first_tok_t = now
+                        self._first_token(st, now)
                     st.emitted.extend(int(t) for t in new)
                     st.logprobs.extend(
                         float(x)
@@ -1521,15 +1548,13 @@ class ContinuousBatchingEngine:
         newer chunk is still in flight behind it, the host work here
         is hidden by device execution — stamped ``overlap_hidden``."""
         entry = self._inflight.pop(0)
-        ts = time.perf_counter()
-        fetched = jax.device_get(entry[:-1])
-        t_sync = time.perf_counter()
-        self.phases.add("host_sync", t_sync - ts)
-        emitted = self._emit_outputs(fetched, entry[-1])
-        self.phases.add(
-            "overlap_hidden" if self._inflight else "retirement",
-            time.perf_counter() - t_sync,
-        )
+        with self.phases.span("serve.host_sync", book="host_sync"):
+            fetched = jax.device_get(entry[:-1])
+        with self.phases.span(
+            "serve.retirement",
+            book="overlap_hidden" if self._inflight else "retirement",
+        ):
+            emitted = self._emit_outputs(fetched, entry[-1])
         return emitted
 
     def _drain_inflight(self) -> int:
@@ -1579,7 +1604,8 @@ class ContinuousBatchingEngine:
         """One scheduler iteration. Returns the number of tokens
         emitted this call. Phase boundaries are stamped into
         ``self.phases`` so ``stats()`` (and the bench's attribution
-        rung) can report the host/device/hidden split.
+        rung) can report the host/device/hidden split, through spans
+        that also land on a running profiler's trace.
 
         Synchronous round (``overlap=False``): compact if out of
         headroom (frontier layout only), admit into free slots, decode
@@ -1597,12 +1623,21 @@ class ContinuousBatchingEngine:
         sampling; with temperature > 0 the admission lag shifts which
         rng a refilled slot consumes (either stream is a valid
         sample)."""
-        emitted = (
-            self._step_overlapped(rng) if self.overlap
-            else self._step_sync(rng)
-        )
+        # the round frames its children on the trace and books nothing:
+        # the phases below it partition its time
+        with self.phases.span(
+            "serve.round", book="",
+            live=sum(1 for st in self._slots if st.uid >= 0),
+            queued=len(self._queue), inflight=len(self._inflight),
+        ):
+            emitted = (
+                self._step_overlapped(rng) if self.overlap
+                else self._step_sync(rng)
+            )
         emitted += self._drained_uncounted
         self._drained_uncounted = 0
+        if emitted:
+            self.phases.count("tokens_emitted", emitted)
         if self._tuner is not None:
             self._tuner.maybe_retune()
         return emitted
@@ -1611,34 +1646,27 @@ class ContinuousBatchingEngine:
     def _step_sync(self, rng):
         """The host-serial round (pre-pipeline behavior, kept as the
         measured A/B baseline): dispatch, block, emit, retire."""
-        t0 = time.perf_counter()
+        span = self.phases.span
         # a completed async weight swap lands here, between chunks —
         # the non-blocking check costs ~nothing when none is pending
-        self._maybe_adopt_pending()
-        t_adopt = time.perf_counter()
-        # housekeeping stamps its own compaction span as "prefill" —
-        # exclude it from the admission bucket (double-counting it
+        with span("serve.admission", book="admission"):
+            self._maybe_adopt_pending()
+        # housekeeping books its own compaction span as "prefill" —
+        # it stays outside the admission spans (double-counting it
         # would inflate serving_host_frac, the metric under test)
         self._frontier_housekeeping()
-        t_hk = time.perf_counter()
-        prefill_s = self._admit_free_slots()
-        t_admit = time.perf_counter()
-        self.phases.add("prefill", prefill_s)
-        self.phases.add(
-            "admission",
-            (t_adopt - t0) + (t_admit - t_hk - prefill_s),
-        )
-
-        entry = self._dispatch_round(rng)
-        t_disp = time.perf_counter()
-        self.phases.add("decode_dispatch", t_disp - t_admit)
-        # tpulint: ignore[host-sync] the sync round IS the measured
-        # A/B baseline the overlapped pipeline is compared against
-        fetched = jax.device_get(entry[:-1])
-        t_sync = time.perf_counter()
-        self.phases.add("host_sync", t_sync - t_disp)
-        emitted = self._emit_outputs_sync(fetched, entry[-1])
-        self.phases.add("retirement", time.perf_counter() - t_sync)
+        # admission books its self time: the serve.prefill spans
+        # inside it (the device path) book as "prefill"
+        with span("serve.admission", book="admission"):
+            self._admit_free_slots()
+        with span("serve.decode_dispatch", book="decode_dispatch"):
+            entry = self._dispatch_round(rng)
+        with span("serve.host_sync", book="host_sync"):
+            # tpulint: ignore[host-sync] the sync round IS the measured
+            # A/B baseline the overlapped pipeline is compared against
+            fetched = jax.device_get(entry[:-1])
+        with span("serve.retirement", book="retirement"):
+            emitted = self._emit_outputs_sync(fetched, entry[-1])
         self.phases.rounds += 1
         return emitted
 
@@ -1657,7 +1685,7 @@ class ContinuousBatchingEngine:
                     break
                 if emits[t, slot]:
                     if not st.emitted:
-                        st.first_tok_t = time.perf_counter()
+                        self._first_token(st, time.perf_counter())
                     st.emitted.append(int(toks[t, slot]))
                     st.logprobs.append(float(logps[t, slot]))
                     emitted += 1
@@ -1688,30 +1716,23 @@ class ContinuousBatchingEngine:
         # admission overlaps the in-flight chunk: the prefill + admit
         # programs enqueue behind it and the host-side cost is hidden
         hidden = bool(self._inflight)
-        ta = time.perf_counter()
-        prefill_s = self._admit_free_slots()
-        t_admit = time.perf_counter()
-        if hidden:
-            self.phases.add("overlap_hidden", t_admit - ta)
-        else:
-            self.phases.add("prefill", prefill_s)
-            self.phases.add("admission", t_admit - ta - prefill_s)
+        span = self.phases.span
+        with span(
+            "serve.admission",
+            book="overlap_hidden" if hidden else "admission",
+        ):
+            self._admit_free_slots(hidden)
 
         dispatched = False
         if any(st.uid >= 0 for st in self._slots):
-            self._inflight.append(self._dispatch_round(rng))
-            self.phases.add(
-                "decode_dispatch", time.perf_counter() - t_admit
-            )
+            with span("serve.decode_dispatch", book="decode_dispatch"):
+                self._inflight.append(self._dispatch_round(rng))
             dispatched = True
             # queued requests' prompt rows prefill NOW, behind the
             # chunk just dispatched — their admission later is only
             # the insert
-            tp = time.perf_counter()
-            self._eager_prefill()
-            self.phases.add(
-                "overlap_hidden", time.perf_counter() - tp
-            )
+            with span("serve.prefill", book="overlap_hidden", eager=1):
+                self._eager_prefill()
         # keep pipeline depth at one: process the previous chunk while
         # the new one runs; with nothing dispatched, drain the tail
         if len(self._inflight) > (1 if dispatched else 0):
@@ -2303,10 +2324,7 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
                 self._state, t_row, d_row, row_logits, row_pos, row_kv,
                 self._i32(slot), self._i32(width), self._i32(cap),
             )
-        self._slots[slot] = _Slot(
-            uid=uid, prompt=prompt, submit_t=submit_t, cap=cap,
-            admit_t=time.perf_counter(),
-        )
+        self._seat(slot, uid, prompt, submit_t, cap)
 
     # tpulint: hotpath — dispatch must never read the device back
     def _dispatch_round(self, rng) -> tuple:
@@ -2322,6 +2340,7 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
             self._state, (win, accept, logps) = self._round_fn(
                 self.params, self.draft_params, self._state
             )
+        self._count_chunk(self.B * (self.k + 1))
         return (
             win, accept, logps, self._state[-2],  # -2: the done flags
             [st.uid for st in self._slots],
@@ -2353,7 +2372,7 @@ class SpeculativeBatchingEngine(ContinuousBatchingEngine):
                     break
                 tok = int(win[slot, t])
                 if not st.emitted:
-                    st.first_tok_t = time.perf_counter()
+                    self._first_token(st, time.perf_counter())
                 st.emitted.append(tok)
                 st.logprobs.append(float(logps[slot, t]))
                 emitted += 1

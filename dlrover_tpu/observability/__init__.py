@@ -1,5 +1,6 @@
 """Distributed observability plane: trace context, unified metrics,
-and the fault-triggered flight recorder.
+the fault-triggered flight recorder, and the hot paths' spans on the
+profiler's clock (``spans.span``).
 
 Layering note: :mod:`dlrover_tpu.common.events` imports this package on
 every process start, so nothing here may import back into
@@ -7,7 +8,7 @@ every process start, so nothing here may import back into
 ``tpurun-trace`` CLI) is deliberately NOT re-exported — it is an
 offline tool and only loaded by its entry point."""
 
-from . import flight_recorder, metrics, trace
+from . import flight_recorder, metrics, spans, trace
 from .flight_recorder import FlightRecorder, get_recorder
 from .metrics import (
     MetricsRegistry,
@@ -29,5 +30,6 @@ __all__ = [
     "maybe_start_metrics_server",
     "metrics",
     "reset_registry",
+    "spans",
     "trace",
 ]

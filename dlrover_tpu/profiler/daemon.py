@@ -24,6 +24,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ..common.events import EventEmitter
 from ..common.log import logger
 from ..rpc.client import MasterClient
 
@@ -59,6 +60,7 @@ class ProfilerDaemon:
         self._bind = bind
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
+        self._evt = EventEmitter("agent")
 
     @property
     def port(self) -> int:
@@ -85,10 +87,13 @@ class ProfilerDaemon:
                 # health probers / browser prefetchers issue GETs freely.
                 try:
                     if self.path.startswith("/metrics"):
-                        resp = daemon._client.get_cluster_metrics()
-                        self._send(
-                            200, render_cluster_metrics(resp.node_gauges)
-                        )
+                        # an incident-side span (see tpurun-trace): a
+                        # scrape runs in the rank-0 agent, beside the
+                        # worker whose saves it may disturb
+                        with daemon._evt.duration("agent_profiler_scrape"):
+                            resp = daemon._client.get_cluster_metrics()
+                            body = render_cluster_metrics(resp.node_gauges)
+                        self._send(200, body)
                     elif self.path.startswith("/job"):
                         status = daemon._client.get_job_status()
                         self._send(

@@ -203,52 +203,6 @@ def dump_timeline(path: str) -> int:
     return n
 
 
-def drain_trace_events(keep_path: Optional[str] = None):
-    """Drain the live trace ring into parsed events — the API the
-    attribution subsystem (``dlrover_tpu.attribution.ops``) consumes.
-
-    Dumps the ring (+ names sidecar) to ``keep_path`` when given (the
-    files persist as artifacts), otherwise to a throwaway temp pair.
-    Returns ``(events, names)``: ``timeline.TimelineEvent`` records and
-    the ``{name_id: op_name}`` intern table; ``([], {})`` when the ring
-    is empty (uninterposed process).
-    """
-    import tempfile
-
-    from . import timeline
-
-    if keep_path is not None:
-        path, cleanup = keep_path, False
-    else:
-        fd, path = tempfile.mkstemp(prefix="tt_ring_", suffix=".timeline")
-        os.close(fd)
-        cleanup = True
-    ok = False
-    try:
-        n = dump_timeline(path)
-        if n <= 0:
-            return [], {}
-        events = timeline.read_timeline(path)
-        # a valid ring is a keeper from here on — a corrupt NAMES
-        # sidecar must not destroy the timeline the caller asked for
-        ok = bool(events)
-        try:
-            names = timeline.read_names(path + ".names")
-        except (OSError, ValueError):  # torn/garbled sidecar line
-            names = {}
-        return events, names
-    finally:
-        # keep the files only for a successful non-empty parse of a
-        # keep_path drain — an empty or corrupt dump would otherwise
-        # strand a never-referenced artifact at the caller's path
-        if cleanup or not ok:
-            for p in (path, path + ".names"):
-                try:
-                    os.unlink(p)
-                except OSError:
-                    pass
-
-
 def step_begin(step: int) -> None:
     """Mark a train-step boundary in the live interposer (feeds
     tpu_timer_last_step / step_open_seconds — the hang watchdog's

@@ -24,6 +24,7 @@ from ..common.constants import NodeEnv
 from ..common.events import EventEmitter
 from ..common.log import logger
 from ..observability.metrics import get_registry
+from ..observability.spans import span
 
 # Process-wide GC tracer installed by the first loop run (gc.callbacks
 # hooks must not stack when run() is called repeatedly).
@@ -479,6 +480,39 @@ class ElasticTrainLoop:
         if record_phase_file("worker", payload):
             logger.info("recovery breakdown: %s", payload)
 
+    # tpulint: hotpath — scalar fetch at log cadence only
+    def _report(self, step, loss) -> None:
+        """What the loop tells the outside after a step: the agent's
+        progress report, the caller's ``on_step``, the log line."""
+        if self.ctx is not None:
+            self.ctx.report_step(step)
+        if self.on_step is not None:
+            self.on_step(step, loss)
+        if step % self.log_every == 0:
+            # scalar fetch only when logging: a per-step float()
+            # would serialize host and device
+            # tpulint: ignore[host-sync] log-cadence scalar fetch,
+            # amortized over log_every steps by design
+            logger.info("step %s: loss %.4f", step, float(loss))
+            # registry gauges at log cadence only — the hot path
+            # stays free of lock traffic between log points
+            get_registry().gauge("dlrover_trainer_last_step").set(step)
+            if self._replanner is not None:
+                # the float(loss) above already synced, so the wall
+                # clock here brackets fully-executed steps — feed
+                # the measured per-step time into the cost model
+                now = time.monotonic()
+                if (
+                    self._last_log_t is not None
+                    and step > self._last_log_step
+                ):
+                    self._replanner.observe_step_time(
+                        (now - self._last_log_t)
+                        / (step - self._last_log_step)
+                    )
+                self._last_log_t = now
+                self._last_log_step = step
+
     # tpulint: hotpath — the per-step path; scalar fetches only at
     # designed points (log cadence, boot timing), each with its reason
     def _run_inner(self, state, data_iter, start):
@@ -554,7 +588,8 @@ class ElasticTrainLoop:
                             self._anticipation_current()
                         )
             try:
-                batch = next(it)
+                with span("train.data_wait"):
+                    batch = next(it)
             except StopIteration:
                 break
             if self.ctx is not None:
@@ -563,7 +598,8 @@ class ElasticTrainLoop:
                 tt_begin(step)
             timed = step - start < 2  # first step = compile + step
             t_step0 = time.monotonic() if timed else 0.0
-            state, loss = self.step_fn(state, *batch)
+            with span("train.step_dispatch", step=step):
+                state, loss = self.step_fn(state, *batch)
             if timed:
                 self._record_boot_step(step - start, loss, t_step0)
             if tt_end is not None:
@@ -585,34 +621,8 @@ class ElasticTrainLoop:
                 )
             else:
                 last_save_ok = False
-            if self.ctx is not None:
-                self.ctx.report_step(step)
-            if self.on_step is not None:
-                self.on_step(step, loss)
-            if step % self.log_every == 0:
-                # scalar fetch only when logging: a per-step float()
-                # would serialize host and device
-                # tpulint: ignore[host-sync] log-cadence scalar fetch,
-                # amortized over log_every steps by design
-                logger.info("step %s: loss %.4f", step, float(loss))
-                # registry gauges at log cadence only — the hot path
-                # stays free of lock traffic between log points
-                get_registry().gauge("dlrover_trainer_last_step").set(step)
-                if self._replanner is not None:
-                    # the float(loss) above already synced, so the wall
-                    # clock here brackets fully-executed steps — feed
-                    # the measured per-step time into the cost model
-                    now = time.monotonic()
-                    if (
-                        self._last_log_t is not None
-                        and step > self._last_log_step
-                    ):
-                        self._replanner.observe_step_time(
-                            (now - self._last_log_t)
-                            / (step - self._last_log_step)
-                        )
-                    self._last_log_t = now
-                    self._last_log_step = step
+            with span("train.report"):
+                self._report(step, loss)
             step += 1
         if step > start and not self._recovery_written:
             # one-step runs never saw a steady step: record without the

@@ -1,170 +1,20 @@
-"""Performance attribution subsystem (dlrover_tpu/attribution/).
+"""Performance attribution (dlrover_tpu/attribution/phases.py).
 
-Pins the three pillars without a device: op-bucket classification +
-per-step accounting on synthetic ring events, the serving host/device
-phase-split math on synthetic timestamps, and Report serialization
-(the bench-line contract: pointers + ≤5 floats, payload in the
-artifact). The CLI is driven against a hand-written TPUTL001 ring.
+Pins the serving host/device phase-split math on synthetic timestamps,
+and that the engine's rounds (fed by ``observability.spans``) fill it.
 """
 
 import json
-import struct
-from dataclasses import dataclass
 
 import pytest
 
-from dlrover_tpu.attribution import (
-    BUCKETS,
-    PhaseAccumulator,
-    Report,
-    account_events,
-    build_report,
-    classify_op,
-)
-from dlrover_tpu.attribution import ops as attr_ops
+from dlrover_tpu.attribution import PhaseAccumulator
 from dlrover_tpu.attribution.phases import (
     DEVICE_PHASES,
     HOST_PHASES,
     OVERLAP_PHASES,
     PHASES,
 )
-
-
-@dataclass
-class _Ev:  # TimelineEvent-shaped
-    name_id: int
-    kind: int
-    start_us: int
-    dur_us: int
-    step: int
-
-
-class TestClassification:
-    def test_native_kind_wins_over_name(self):
-        # a collective whose fused name mentions "add" stays collective
-        assert classify_op("add.fusion", attr_ops.KIND_COLLECTIVE) == (
-            "collective"
-        )
-        assert classify_op("whatever", attr_ops.KIND_MATMUL) == "matmul"
-        assert classify_op("x", attr_ops.KIND_H2D) == "transfer"
-        assert classify_op("x", attr_ops.KIND_D2H) == "transfer"
-
-    @pytest.mark.parametrize(
-        "name,bucket",
-        [
-            ("fusion.123.dot_general.1", "matmul"),
-            ("jit_matmul", "matmul"),
-            ("custom-call.flash_attention_fwd", "attention"),
-            ("fusion.softmax.add", "attention"),
-            ("layer_norm.fusion", "vpu"),
-            ("rms_norm_bwd", "vpu"),
-            ("fusion.add.multiply.reduce", "vpu"),
-            ("adamw_update.fusion", "optimizer_hbm"),
-            ("convert_element_type.42", "optimizer_hbm"),
-            ("all-reduce.7", "collective"),
-            ("reduce-scatter.1", "collective"),
-            ("jit__psum", "collective"),
-            ("opaque_program_xyz", "other"),
-        ],
-    )
-    def test_fingerprints(self, name, bucket):
-        assert classify_op(name, attr_ops.KIND_EXECUTE) == bucket
-
-    def test_ordering_collective_beats_vpu_tokens(self):
-        # fused all-reduce-of-gradients contains "add": must stay
-        # collective (the table is ordered most-specific-first)
-        assert (
-            classify_op("all_reduce.add.fusion", attr_ops.KIND_EXECUTE)
-            == "collective"
-        )
-
-
-class TestAccounting:
-    def test_per_step_table_with_step_markers_and_gap(self):
-        names = {1: "dot_general.0", 2: "layer_norm.0", 3: "all-reduce.0"}
-        events = [
-            # step 0: span 1000us via step marker; ops cover 700us
-            _Ev(0, attr_ops.KIND_STEP, 0, 1000, 0),
-            _Ev(1, attr_ops.KIND_EXECUTE, 0, 400, 0),
-            _Ev(2, attr_ops.KIND_EXECUTE, 400, 200, 0),
-            _Ev(3, attr_ops.KIND_COLLECTIVE, 600, 100, 0),
-            # step 1: no marker → envelope span 500us, ops 500us, gap 0
-            _Ev(1, attr_ops.KIND_EXECUTE, 2000, 500, 1),
-        ]
-        table = account_events(events, names)
-        assert len(table.steps) == 2
-        s0 = table.steps[0]
-        assert s0.span_us == 1000 and s0.busy_us == 700
-        assert s0.buckets["matmul"] == 400
-        assert s0.buckets["vpu"] == 200
-        assert s0.buckets["collective"] == 100
-        assert s0.buckets["gap_dispatch"] == 300
-        s1 = table.steps[1]
-        assert s1.span_us == 500 and "gap_dispatch" not in s1.buckets
-        # aggregate fractions are over the summed spans (1500us)
-        assert table.total_span_us == 1500
-        assert table.buckets["matmul"].time_us == 900
-        assert table.buckets["matmul"].frac == pytest.approx(0.6)
-        assert table.buckets["gap_dispatch"].frac == pytest.approx(0.2)
-
-    def test_top_residual_excludes_matmul_and_recommends(self):
-        names = {1: "dot_general", 2: "adam_update"}
-        events = [
-            _Ev(0, attr_ops.KIND_STEP, 0, 1000, 0),
-            _Ev(1, attr_ops.KIND_EXECUTE, 0, 600, 0),
-            _Ev(2, attr_ops.KIND_EXECUTE, 600, 400, 0),
-        ]
-        res = account_events(events, names).top_residual()
-        # matmul is the biggest bucket but never the residual
-        assert res["bucket"] == "optimizer_hbm"
-        assert res["frac"] == pytest.approx(0.4)
-        assert "optimizer" in res["recommendation"] or "donate" in (
-            res["recommendation"]
-        )
-
-    def test_empty_ring(self):
-        table = account_events([], {})
-        assert table.total_span_us == 0 and table.events == 0
-        assert table.top_residual()["bucket"] is None
-
-    def test_marker_only_step_is_pure_gap(self):
-        """A step marker whose device ops were lost (ring overflow) or
-        that genuinely stalled in dispatch must still be accounted —
-        its whole span is gap_dispatch, not silently dropped."""
-        events = [
-            _Ev(0, attr_ops.KIND_STEP, 0, 50000, 7),
-            # a normal step alongside proves fractions stay honest
-            _Ev(0, attr_ops.KIND_STEP, 60000, 1000, 8),
-            _Ev(1, attr_ops.KIND_EXECUTE, 60000, 1000, 8),
-        ]
-        table = account_events(events, {1: "dot_general"})
-        assert [r.step for r in table.steps] == [7, 8]
-        assert table.steps[0].buckets == {"gap_dispatch": 50000}
-        assert table.total_span_us == 51000
-        assert table.buckets["gap_dispatch"].frac == pytest.approx(
-            50000 / 51000
-        )
-        assert table.top_residual()["bucket"] == "gap_dispatch"
-
-    def test_busy_exceeding_span_clamps_gap(self):
-        # concurrent streams: summed op time > marker span — gap must
-        # clamp at zero, not go negative
-        events = [
-            _Ev(0, attr_ops.KIND_STEP, 0, 100, 0),
-            _Ev(1, attr_ops.KIND_EXECUTE, 0, 90, 0),
-            _Ev(1, attr_ops.KIND_EXECUTE, 10, 90, 0),
-        ]
-        table = account_events(events, {1: "dot_general"})
-        assert "gap_dispatch" not in table.steps[0].buckets
-        assert table.steps[0].span_us == 180  # busy floor
-
-    def test_to_dict_bounded(self):
-        events = [
-            _Ev(i, attr_ops.KIND_EXECUTE, i * 10, 5, i) for i in range(100)
-        ]
-        d = account_events(events, {}).to_dict(max_steps=8, max_top_ops=3)
-        assert len(d["steps"]) == 8 and len(d["top_ops"]) <= 3
-        assert set(d["buckets"]) <= set(BUCKETS)
 
 
 class TestPhaseSplit:
@@ -260,120 +110,6 @@ class TestPhaseSplit:
             assert isinstance(s[f"{p}_ms"], float)
         # bounded: the 1,800-byte bench line must fit this whole
         assert len(json.dumps(s)) < 350
-
-
-class TestReport:
-    def _report(self):
-        acc = PhaseAccumulator()
-        acc.add_round(
-            [("admission", 0.01), ("host_sync", 0.03),
-             ("decode_dispatch", 0.01)]
-        )
-        events = [
-            _Ev(0, attr_ops.KIND_STEP, 0, 100, 0),
-            _Ev(1, attr_ops.KIND_EXECUTE, 0, 60, 0),
-            _Ev(2, attr_ops.KIND_EXECUTE, 60, 30, 0),
-        ]
-        table = account_events(
-            events, {1: "dot_general", 2: "layer_norm"}
-        )
-        return build_report(
-            op_table=table, serving=acc.split(), meta={"device": "test"}
-        )
-
-    def test_round_trip(self, tmp_path):
-        rep = self._report()
-        path = str(tmp_path / "report.json")
-        rep.save(path)
-        back = Report.load(path)
-        assert back.meta["device"] == "test"
-        assert back.op_table["buckets"]["matmul"]["time_us"] == 60
-        assert back.serving["serving_host_frac"] == pytest.approx(0.4)
-        # the file is plain JSON with the schema tag
-        raw = json.load(open(path))
-        assert raw["schema"].startswith("dlrover_tpu.attribution")
-
-    def test_rejects_foreign_json(self):
-        with pytest.raises(ValueError, match="not an attribution"):
-            Report.from_json(json.dumps({"schema": "nope"}))
-
-    def test_headline_is_at_most_five_floats(self):
-        head = self._report().headline()
-        assert 0 < len(head) <= 5
-        assert head["serving_host_frac"] == pytest.approx(0.4)
-        assert head["matmul_frac"] == pytest.approx(0.6)
-        for v in head.values():
-            assert isinstance(v, (int, float))
-        assert len(json.dumps(head)) < 200
-
-    def test_top_residual_falls_back_to_serving(self):
-        acc = PhaseAccumulator()
-        acc.add_round([("admission", 0.03), ("host_sync", 0.01)])
-        rep = build_report(serving=acc.split())
-        res = rep.top_residual()
-        assert res["bucket"] == "serving_host"
-        assert res["frac"] == pytest.approx(0.75)
-
-    def test_format_renders_both_pillars(self):
-        text = self._report().format()
-        assert "top residual" in text
-        assert "serving_host_frac" in text
-
-
-def _write_ring(path, events, names):
-    """Hand-write a TPUTL001 ring + names sidecar (the native dump
-    format timeline.py reads)."""
-    rec = struct.Struct("<IIqII")
-    with open(path, "wb") as f:
-        f.write(b"TPUTL001")
-        for ev in events:
-            f.write(
-                rec.pack(ev.name_id, ev.kind, ev.start_us, ev.dur_us,
-                         ev.step)
-            )
-    with open(str(path) + ".names", "w") as f:
-        for ident, name in names.items():
-            f.write(f"{ident}\t{name}\n")
-
-
-class TestCli:
-    def _ring(self, tmp_path):
-        ring = tmp_path / "run.timeline"
-        _write_ring(
-            ring,
-            [
-                _Ev(0, attr_ops.KIND_STEP, 0, 1000, 0),
-                _Ev(1, attr_ops.KIND_EXECUTE, 0, 700, 0),
-                _Ev(2, attr_ops.KIND_EXECUTE, 700, 200, 0),
-            ],
-            {1: "dot_general.3", 2: "adam_update"},
-        )
-        return str(ring)
-
-    def test_json_table_from_saved_ring(self, tmp_path, capsys):
-        from dlrover_tpu.attribution.cli import main
-
-        assert main([self._ring(tmp_path), "--json"]) == 0
-        out = json.loads(capsys.readouterr().out)
-        assert out["buckets"]["matmul"]["time_us"] == 700
-        assert out["buckets"]["optimizer_hbm"]["time_us"] == 200
-        assert out["top_residual"]["bucket"] == "optimizer_hbm"
-
-    def test_human_table_and_report_artifact(self, tmp_path, capsys):
-        from dlrover_tpu.attribution.cli import main
-
-        out_path = str(tmp_path / "rep.json")
-        assert main([self._ring(tmp_path), "--out", out_path]) == 0
-        text = capsys.readouterr().out
-        assert "matmul" in text and "top residual" in text
-        rep = Report.load(out_path)
-        assert rep.op_table["buckets"]["matmul"]["count"] == 1
-
-    def test_missing_ring_fails_cleanly(self, tmp_path, capsys):
-        from dlrover_tpu.attribution.cli import main
-
-        assert main([str(tmp_path / "absent.timeline")]) == 2
-        assert "tpurun-attr" in capsys.readouterr().err
 
 
 class TestEngineIntegration:

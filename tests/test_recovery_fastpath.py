@@ -7,7 +7,6 @@ proof (warm-vs-cold A/B at equal fault plans) lives in the bench's
 ``recovery_ab`` section and the storm harness.
 """
 
-import json
 import os
 import threading
 import time
@@ -634,19 +633,6 @@ class TestRecoverySpool:
         assert rec["resumed"] is False  # first boot
         assert rec["first_step_s"] > 0
         assert "compile_s" in rec  # steady step observed -> split done
-
-    def test_report_carries_recovery_section(self):
-        from dlrover_tpu.attribution.report import Report, build_report
-
-        rc = {"rdzv_s": 2.0, "restore_s": 0.4, "compile_s": 6.0,
-              "first_step_s": 7.0, "recovery_samples": 3}
-        rep = build_report(recovery=rc, meta={"job": "t"})
-        again = Report.from_dict(json.loads(rep.to_json()))
-        assert again.recovery == rc
-        text = again.format()
-        for key in recovery.PHASES:
-            assert key in text
-        assert "3 per-host recovery records" in text
 
 
 # ---------------------------------------------------------------------------

@@ -506,8 +506,9 @@ class CheckpointEngine:
 
     def _stage_into_shm(self, step: int, tree: Any, extra, root) -> None:
         """``shm.save_pytree`` under the ``ckpt_save`` event, whose end
-        carries the split; ``root`` (the open ``ckpt.save`` or
-        ``ckpt.stage`` span) gets what was staged."""
+        carries the split and on how many threads the payload was
+        copied; ``root`` (the open ``ckpt.save`` or ``ckpt.stage`` span)
+        gets what was staged."""
         with self._events.ckpt_save(step, storage="memory") as event:
             before = process_accumulator().totals()
             meta = self.shm.save_pytree(
@@ -517,7 +518,9 @@ class CheckpointEngine:
                 mesh=self.mesh,
                 extra=extra,
             )
-            event.content.update(_save_parts_since(before))
+            event.content.update(
+                _save_parts_since(before), copy_threads=self.shm.copy_threads
+            )
         root.set(bytes=meta.total_bytes, leaves=len(meta.records))
 
     def _ready_to_save(self, step: int) -> Tuple[bool, bool]:

@@ -9,7 +9,9 @@ the trainer blocks only for the D2H + memcpy, never for storage IO.
 Layout of the segment: [u64 meta_len][meta JSON][payload bytes...].
 """
 
+import os
 import time
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -29,6 +31,91 @@ from .meta import (
 
 
 _IMAGE_CHUNK = 8 << 20
+
+# How ``save_pytree`` moves the payload (PERF.md section 5 has the tables
+# these were chosen from; scripts/shm_copy_scaling.py makes the first):
+# the payload goes in pieces of about _PIECE_BYTES whatever the leaves'
+# sizes (a 154 MB embedding is ten pieces, fifty biases are one), and an
+# image of _POOLED_MIN_BYTES or more is copied by up to _COPY_THREADS_MAX
+# threads. On the v5e's host 8 threads reach 42 GB/s against one thread's
+# 12 and 16 add nothing; 4 to 32 MiB a piece read alike, 2 MiB lower.
+_PIECE_BYTES = 16 << 20
+_COPY_THREADS_MAX = 8
+_POOLED_MIN_BYTES = 32 << 20
+
+
+def _copy_chunk(buf, offset: int, src: np.ndarray) -> None:
+    """The copy primitive: ``src`` to ``offset`` of the segment, in C
+    order. numpy drops the interpreter lock for the assignment, so
+    chunks on several threads copy at once, and it converts the layout
+    of a source that is not C-contiguous on the way (on a TPU host the
+    ``wqkv`` leaves arrive with their first axis minor): no contiguous
+    temporary is made of it first."""
+    dst = np.ndarray(src.shape, dtype=src.dtype, buffer=buf, offset=offset)
+    dst[...] = src
+
+
+def _copy_piece(buf, piece: List[Tuple[int, np.ndarray]]) -> None:
+    with span("ckpt.save.copy", bytes=sum(src.nbytes for _, src in piece)):
+        for offset, src in piece:
+            _copy_chunk(buf, offset, src)
+
+
+class _PayloadCopy:
+    """One save's copy of its payload into ``buf``, piece by piece: on
+    ``pool``'s threads, or on the calling thread where ``pool`` is None."""
+
+    def __init__(self, buf, pool: Optional[ThreadPoolExecutor]):
+        self._buf = buf
+        self._pool = pool
+        self._pending: List[Tuple[int, np.ndarray]] = []
+        self._pending_bytes = 0
+        self._futures: List[Future] = []
+        self.pieces = 0
+
+    def add(self, offset: int, host: np.ndarray) -> None:
+        """Queue ``host`` for ``offset`` of the segment; every piece
+        that fills goes off at once. A C-contiguous array is cut at any
+        byte, another one between its rows."""
+        src = host.reshape(-1).view(np.uint8) if host.flags.c_contiguous else host
+        if not src.nbytes:
+            return
+        row_bytes = src.nbytes // len(src)
+        lo = 0
+        while lo < len(src):
+            room = _PIECE_BYTES - self._pending_bytes
+            chunk = src[lo : lo + max(1, room // row_bytes)]
+            self._pending.append((offset + lo * row_bytes, chunk))
+            self._pending_bytes += chunk.nbytes
+            lo += len(chunk)
+            if self._pending_bytes >= _PIECE_BYTES:
+                self._flush()
+
+    def _flush(self) -> None:
+        if not self._pending:
+            return
+        piece, self._pending, self._pending_bytes = self._pending, [], 0
+        self.pieces += 1
+        if self._pool is None:
+            _copy_piece(self._buf, piece)
+        else:
+            self._futures.append(
+                self._pool.submit(_copy_piece, self._buf, piece)
+            )
+
+    def finish(self) -> None:
+        """Send the last piece and wait for every piece; raises what the
+        first failed piece raised."""
+        self._flush()
+        for f in self._futures:
+            f.result()
+
+    def settle(self) -> None:
+        """Leave no piece running or waiting to run, whatever happened
+        (nothing to do after a ``finish`` that returned)."""
+        for f in self._futures:
+            f.cancel()
+        wait(self._futures)
 
 
 def segment_image_size(segment: SharedMemorySegment) -> int:
@@ -151,6 +238,9 @@ class SharedMemoryHandler:
     def __init__(self, host_rank: int = 0, name: str = ""):
         self.host_rank = host_rank
         self._segment = SharedMemorySegment(name or f"ckpt_shard_{host_rank}")
+        self._pool: Optional[ThreadPoolExecutor] = None
+        # on how many threads the last ``save_pytree`` copied its payload
+        self.copy_threads = 1
 
     # -- trainer side ------------------------------------------------------
 
@@ -162,12 +252,31 @@ class SharedMemoryHandler:
         mesh=None,
         extra: Optional[Dict[str, Any]] = None,
     ) -> CheckpointMeta:
-        """Stage ``pytree`` into the segment. Names its own time on the
-        profiler's clock, under whichever root the caller opened
-        (``ckpt.save``, or ``ckpt.stage`` on the staging thread):
-        ``ckpt.save.plan``, ``ckpt.save.ensure`` and, per leaf,
+        """Stage ``pytree`` into the segment; returns once the whole
+        image is there.
+
+        The header is zeroed first and written LAST, after every byte of
+        the payload: a trainer killed mid-stage, or a copy that raises,
+        leaves an image that parses as absent, not a fresh meta over a
+        torn payload (the agent's breakpoint save would persist it).
+        The payload moves in pieces of ``_PIECE_BYTES``; an image of
+        ``_POOLED_MIN_BYTES`` or more is copied by this handler's pool of
+        ``min(CPUs of the process, _COPY_THREADS_MAX)`` threads while the
+        calling thread waits for the next leaf's host copy, a smaller
+        one (or a process with one CPU) on the calling thread, through
+        the same code.
+
+        Names its own time on the profiler's clock, under whichever root
+        the caller opened (``ckpt.save``, or ``ckpt.stage`` on the
+        staging thread). On the calling thread, never overlapping:
+        ``ckpt.save.plan``, ``ckpt.save.ensure``, per leaf
         ``ckpt.save.d2h`` (the wait on the device-to-host copy) and
-        ``ckpt.save.memcpy`` (the copy into ``/dev/shm``)."""
+        ``ckpt.save.memcpy`` (handing the leaf to the copy, and copying
+        where that is inline), and one last ``ckpt.save.memcpy`` (stats
+        ``threads``, ``pieces``) that waits for the pieces: the
+        ``memcpy`` spans sum to the wall time the caller gave to the
+        copy. Each piece is a ``ckpt.save.copy`` (stat ``bytes``) on the
+        thread that copied it."""
         with span("ckpt.save.plan"):
             meta, plan, meta_bytes = self._plan(
                 step, pytree, num_hosts, mesh, extra
@@ -176,29 +285,57 @@ class SharedMemoryHandler:
         with span("ckpt.save.ensure", bytes=total):
             self._segment.ensure(total)
         buf = self._segment.buf
-        # Header lands LAST: a trainer killed mid-stage must leave an
-        # image that parses as absent, not a fresh meta over a torn
-        # payload (the agent's breakpoint save would persist it).
         buf[:HEADER_LEN_BYTES] = b"\x00" * HEADER_LEN_BYTES
         payload_base = HEADER_LEN_BYTES + len(meta_bytes)
         buf[HEADER_LEN_BYTES:payload_base] = meta_bytes
-        for rec, shard in plan:
-            if isinstance(shard, np.ndarray):
-                data = shard
-            else:
-                data = getattr(shard, "data", shard)
-            with span("ckpt.save.d2h"):
-                host = np.asarray(data)
-            with span("ckpt.save.memcpy"):
-                flat = np.ascontiguousarray(host).reshape(-1)
-                start = payload_base + rec.offset
-                view = np.frombuffer(buf, dtype=np.uint8, count=rec.nbytes, offset=start)
-                view[:] = flat.view(np.uint8)
-                del view  # release the exported buffer pointer promptly
+        pool = self._copy_pool(meta.total_bytes)
+        copy = _PayloadCopy(buf, pool)
+        try:
+            for rec, shard in plan:
+                if isinstance(shard, np.ndarray):
+                    data = shard
+                else:
+                    data = getattr(shard, "data", shard)
+                with span("ckpt.save.d2h"):
+                    host = np.asarray(data)
+                with span("ckpt.save.memcpy"):
+                    if host.nbytes != rec.nbytes:
+                        raise ValueError(
+                            f"{rec.path}: {host.nbytes} bytes on the host, "
+                            f"{rec.nbytes} planned"
+                        )
+                    copy.add(payload_base + rec.offset, host)
+            with span(
+                "ckpt.save.memcpy", threads=self.copy_threads
+            ) as join:
+                copy.finish()
+                join.set(pieces=copy.pieces)
+        finally:
+            copy.settle()
         buf[:HEADER_LEN_BYTES] = len(meta_bytes).to_bytes(
             HEADER_LEN_BYTES, "little"
         )
         return meta
+
+    def _copy_pool(self, nbytes: int) -> Optional[ThreadPoolExecutor]:
+        """The pool a payload of ``nbytes`` is copied on, made on first
+        use, or None where the calling thread copies; ``copy_threads``
+        says which it was."""
+        width = min(len(os.sched_getaffinity(0)), _COPY_THREADS_MAX)
+        if nbytes < _POOLED_MIN_BYTES or width < 2:
+            self.copy_threads = 1
+            return None
+        self.copy_threads = width
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(
+                max_workers=width, thread_name_prefix="ckpt-copy"
+            )
+        return self._pool
+
+    def _close_pool(self) -> None:
+        pool, self._pool = self._pool, None
+        if pool is not None:
+            pool.shutdown(wait=True)
 
     def _plan(self, step, pytree, num_hosts, mesh, extra):
         """Flatten, plan one record per unique addressable shard, kick
@@ -334,7 +471,9 @@ class SharedMemoryHandler:
         return self._segment.exists()
 
     def close(self) -> None:
+        self._close_pool()
         self._segment.close()
 
     def unlink(self) -> None:
+        self._close_pool()
         self._segment.unlink()

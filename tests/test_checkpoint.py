@@ -84,6 +84,162 @@ class TestShmHandler:
             shm.unlink()
 
 
+def _payload(shm: SharedMemoryHandler) -> bytes:
+    meta = shm.read_meta()
+    return bytes(shm.payload_reader(copy=False)(0, meta.total_bytes))
+
+
+# leaf -> what it is there for; together 38 MiB, over the 32 MiB from which
+# an image is copied by the pool
+POOLED_LEAVES = {
+    "larger_than_a_piece": lambda: jnp.arange(9 << 20, dtype=jnp.float32),
+    "smaller_than_a_piece": lambda: jnp.arange(1000, dtype=jnp.int32),
+    "ends_inside_a_piece": lambda: np.arange((2 << 20) + 3, dtype=np.uint8),
+    "zero_sized": lambda: np.zeros((0, 7), np.float32),
+    "bf16": lambda: jnp.arange(4096, dtype=jnp.float32).astype(jnp.bfloat16),
+    "host_ndarray": lambda: np.linspace(0.0, 1.0, 12345),
+    "scalar": lambda: np.float64(3.5),
+    # as a TPU host hands over a wqkv leaf: the first axis minor
+    "not_contiguous": lambda: np.arange(64 * 3 * 4 * 16, dtype=np.float32)
+    .reshape(3, 4, 16, 64).transpose(3, 0, 1, 2),
+    "not_contiguous_bf16": lambda: np.asarray(
+        jnp.arange(96 * 50, dtype=jnp.float32).astype(jnp.bfloat16)
+    ).reshape(50, 96).T,
+}
+
+
+class TestPooledCopy:
+    """``save_pytree`` copies an image of 32 MiB or more on its pool's
+    threads and a smaller one, or any in a process with one CPU, on the
+    calling thread: the same bytes either way, the header last."""
+
+    @pytest.fixture(scope="class")
+    def images(self):
+        from dlrover_tpu.checkpoint import shm_handler
+
+        tree = {k: make() for k, make in POOLED_LEAVES.items()}
+        out = {"tree": tree}
+        patch = pytest.MonkeyPatch()
+        try:
+            for how, cpus in (("pooled", 4), ("inline", 1)):
+                patch.setattr(
+                    shm_handler.os, "sched_getaffinity",
+                    lambda pid, n=cpus: set(range(n)),
+                )
+                shm = SharedMemoryHandler(0, name=f"pooled_{how}_{os.getpid()}")
+                try:
+                    meta = shm.save_pytree(step=3, pytree=tree)
+                    out[how] = dict(
+                        threads=shm.copy_threads, meta=meta,
+                        arrays=shm.load_pytree_host()[1],
+                        payload=_payload(shm),
+                    )
+                finally:
+                    shm.unlink()
+        finally:
+            patch.undo()
+        return out
+
+    def test_the_two_branches_were_taken(self, images):
+        assert images["pooled"]["meta"].total_bytes >= 32 << 20
+        assert images["pooled"]["threads"] == 4
+        assert images["inline"]["threads"] == 1
+
+    def test_the_image_is_the_one_thread_image(self, images):
+        assert images["pooled"]["payload"] == images["inline"]["payload"]
+
+    @pytest.mark.parametrize("leaf", sorted(POOLED_LEAVES))
+    def test_leaf_restores_bit_for_bit(self, images, leaf):
+        want = np.asarray(images["tree"][leaf])
+        for how in ("pooled", "inline"):
+            got = images[how]["arrays"][leaf]
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.tobytes() == want.tobytes(), how
+
+    @pytest.fixture(params=["pooled", "inline"])
+    def big_or_small(self, request, monkeypatch):
+        """(handler, tree): a 40 MiB tree on four CPUs, or a small one."""
+        from dlrover_tpu.checkpoint import shm_handler
+
+        monkeypatch.setattr(
+            shm_handler.os, "sched_getaffinity", lambda pid: set(range(4))
+        )
+        n = (10 << 20) if request.param == "pooled" else 1000
+        tree = {"a": np.arange(n, dtype=np.float32), "b": np.ones(5)}
+        shm = SharedMemoryHandler(0, name=f"copy_{request.param}")
+        yield shm, tree
+        shm.unlink()
+
+    def test_a_piece_that_raises_leaves_no_image(self, big_or_small, monkeypatch):
+        from dlrover_tpu.checkpoint import shm_handler
+
+        shm, tree = big_or_small
+        shm.save_pytree(step=1, pytree=tree)
+        assert shm.read_meta().step == 1
+        real, calls = shm_handler._copy_chunk, []
+
+        def second_chunk_fails(buf, offset, src):
+            calls.append(offset)
+            if len(calls) == 2:
+                raise OSError("copy failed")
+            real(buf, offset, src)
+
+        monkeypatch.setattr(shm_handler, "_copy_chunk", second_chunk_fails)
+        with pytest.raises(OSError, match="copy failed"):
+            shm.save_pytree(step=2, pytree=tree)
+        assert len(calls) >= 2
+        assert shm.read_meta() is None  # not step 1's meta over step 2's bytes
+        monkeypatch.setattr(shm_handler, "_copy_chunk", real)
+        shm.save_pytree(step=3, pytree=tree)  # and the handler is not wedged
+        assert shm.read_meta().step == 3
+
+    def test_the_header_is_written_after_the_last_piece(self, big_or_small, monkeypatch):
+        from dlrover_tpu.checkpoint import shm_handler
+
+        shm, tree = big_or_small
+        shm.save_pytree(step=1, pytree=tree)  # a valid header to begin with
+        real, headers = shm_handler._copy_chunk, []
+
+        def watch(buf, offset, src):
+            headers.append(bytes(buf[:8]))
+            real(buf, offset, src)
+            headers.append(bytes(buf[:8]))
+
+        monkeypatch.setattr(shm_handler, "_copy_chunk", watch)
+        meta = shm.save_pytree(step=2, pytree=tree)
+        assert headers and set(headers) == {bytes(8)}
+        assert shm.read_meta().step == 2
+        assert sum(r.nbytes for r in meta.records) == meta.total_bytes
+
+    @pytest.mark.parametrize("end", ["close", "unlink"])
+    def test_closing_the_handler_ends_the_pools_threads(self, monkeypatch, end):
+        import threading
+
+        from dlrover_tpu.checkpoint import shm_handler
+
+        monkeypatch.setattr(
+            shm_handler.os, "sched_getaffinity", lambda pid: set(range(4))
+        )
+
+        def copiers():
+            return [t for t in threading.enumerate()
+                    if t.name.startswith("ckpt-copy")]
+
+        before = set(copiers())
+        shm = SharedMemoryHandler(0, name=f"pool_{end}")
+        try:
+            shm.save_pytree(1, {"a": np.zeros(10 << 20, np.float32)})
+            mine = set(copiers()) - before
+            assert 1 <= len(mine) <= 4
+            getattr(shm, end)()
+            assert not any(t.is_alive() for t in mine)
+            shm.save_pytree(2, {"a": np.zeros(10 << 20, np.float32)})
+            assert shm.read_meta().step == 2  # a pool is made again on use
+        finally:
+            shm.unlink()
+        assert set(copiers()) <= before
+
+
 class TestStorage:
     def test_done_protocol_and_tracker(self, tmp_path):
         storage = PosixCheckpointStorage(str(tmp_path))

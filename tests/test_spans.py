@@ -158,6 +158,48 @@ def ckpt_session(job):
 
 
 @pytest.fixture(scope="module")
+def pooled_session(job):
+    """Two blocking saves of a 40 MiB tree, on four CPUs: the copy pool's."""
+    from dlrover_tpu.checkpoint import shm_handler
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+    from dlrover_tpu.common import events
+
+    seen = []
+
+    class Sink(events.Exporter):
+        def export(self, event):
+            seen.append(event.to_dict())
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(shm_handler.os, "sched_getaffinity",
+                  lambda pid: set(range(4)))
+    engine = CheckpointEngine(
+        str(job / "ckpt_pooled"), standalone=True, replicate=False
+    )
+    engine._events._em = events.EventEmitter("trainer", Sink())
+    tree = {
+        "w": jnp.arange(9 << 20, dtype=jnp.float32),
+        "b": jnp.ones((3,), jnp.bfloat16),
+        "n": np.arange(1 << 20),
+    }
+    before = booked(spans.process_accumulator())
+
+    def body():
+        assert engine.save_to_memory(1, tree)
+        assert engine.save_to_memory(2, tree)
+        return engine.shm.read_meta().total_bytes
+
+    try:
+        total, found = record(job / "trace_pooled", body)
+    finally:
+        engine.shm.unlink()
+        engine.close()
+        patch.undo()
+    return dict(spans=found, total_bytes=total, before=before, events=seen,
+                after=booked(spans.process_accumulator()))
+
+
+@pytest.fixture(scope="module")
 def train_session(job):
     """Four steps of ``ElasticTrainLoop`` over a toy step."""
     from dlrover_tpu.checkpoint.engine import CheckpointEngine
@@ -235,6 +277,12 @@ CONTRACT = [
     ("ckpt_session", "ckpt.save.d2h", "ckpt.save"),
     ("ckpt_session", "ckpt.save.memcpy", "ckpt.save"),
     ("ckpt_session", "ckpt_save", "ckpt.save"),  # the DurationSpan's own
+    ("ckpt_session", "ckpt.save.copy", "ckpt.save.memcpy"),  # inline: the caller's
+    ("pooled_session", "ckpt.save.plan", "ckpt.save"),
+    ("pooled_session", "ckpt.save.ensure", "ckpt.save"),
+    ("pooled_session", "ckpt.save.d2h", "ckpt.save"),
+    ("pooled_session", "ckpt.save.memcpy", "ckpt.save"),
+    ("pooled_session", "ckpt.save.copy", None),  # on the pool's lines
     ("ckpt_session", "ckpt.stage", None),
     ("ckpt_session", "ckpt.save.plan", "ckpt.stage"),
     ("ckpt_session", "ckpt.save.d2h", "ckpt.stage"),
@@ -323,10 +371,73 @@ def test_every_saves_event_says_where_its_time_went(ckpt_session):
         assert sum(parts) <= c["duration_s"] + 1e-3
 
 
+def saves_of(found):
+    """[(line, start, end)] of the session's ``ckpt.save`` roots, in order."""
+    return sorted((line, s, e) for line, s, e, _ in found["ckpt.save"])
+
+
+def test_the_callers_waits_and_copies_never_overlap(pooled_session):
+    """``save_d2h_s + save_memcpy_s + save_host_other_s`` is the whole save
+    only if the two lie on the caller's line, one after another."""
+    found = pooled_session["spans"]
+    for line, lo, hi in saves_of(found):
+        parts = sorted(
+            (s, e) for name in ("ckpt.save.d2h", "ckpt.save.memcpy")
+            for pl, s, e, _ in found[name] if lo <= s and e <= hi
+            and pl == line
+        )
+        assert len(parts) == 2 * 3 + 1  # a wait and a hand-over a leaf, the join
+        for (_, e0), (s1, _) in zip(parts, parts[1:]):
+            assert e0 <= s1
+        assert sum(e - s for s, e in parts) <= hi - lo
+    strays = [pl for name in ("ckpt.save.d2h", "ckpt.save.memcpy")
+              for pl, _, _, _ in found[name]
+              if pl not in {line for line, _, _ in saves_of(found)}]
+    assert not strays
+
+
+def test_every_piece_is_a_copy_span_and_the_join_counts_them(pooled_session):
+    found = pooled_session["spans"]
+    for line, lo, hi in saves_of(found):
+        copies = [(pl, st) for pl, s, e, st in found["ckpt.save.copy"]
+                  if lo <= s and e <= hi]
+        assert sum(st["bytes"] for _, st in copies) == pooled_session["total_bytes"]
+        assert all(pl != line for pl, _ in copies)  # none on the caller's line
+        joins = [st for _, s, e, st in found["ckpt.save.memcpy"]
+                 if lo <= s and e <= hi and "threads" in st]
+        assert len(joins) == 1  # the last one: it waits for the pieces
+        assert joins[0]["threads"] == 4
+        assert joins[0]["pieces"] == len(copies) == 3  # 40 MiB in 16 MiB pieces
+
+
+@pytest.mark.parametrize("session,threads", [
+    ("ckpt_session", 1), ("pooled_session", 4),
+])
+def test_every_saves_event_says_on_how_many_threads_it_copied(
+    request, session, threads
+):
+    ends = [e["content"] for e in request.getfixturevalue(session)["events"]
+            if e["name"] == "ckpt_save" and e["type"] == "end"]
+    assert len(ends) == 2
+    assert [c["copy_threads"] for c in ends] == [threads, threads]
+
+
+def test_the_inline_join_says_one_thread(ckpt_session):
+    joins = [st for _, _, _, st in ckpt_session["spans"]["ckpt.save.memcpy"]
+             if "threads" in st]
+    assert len(joins) == 2  # the blocking save's and the staging thread's
+    assert all(st["threads"] == 1 and st["pieces"] == 1 for st in joins)
+    copies = ckpt_session["spans"]["ckpt.save.copy"]
+    assert [st["bytes"] for _, _, _, st in copies] == [32 * 4 + 3 * 2 + 4 * 8] * 2
+
+
 @pytest.mark.parametrize("session,names", [
     ("ckpt_session", ["ckpt.save", "ckpt.stage", "ckpt.save.ready",
                       "ckpt.save.plan", "ckpt.save.ensure", "ckpt.save.d2h",
-                      "ckpt.save.memcpy"]),
+                      "ckpt.save.memcpy", "ckpt.save.copy"]),
+    ("pooled_session", ["ckpt.save", "ckpt.save.plan", "ckpt.save.ensure",
+                        "ckpt.save.d2h", "ckpt.save.memcpy",
+                        "ckpt.save.copy"]),
     ("train_session", ["train.data_wait", "train.step_dispatch",
                        "train.report"]),
     ("serve_sync", ["serve.inbox", "serve.complete"]),

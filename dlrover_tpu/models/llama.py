@@ -299,7 +299,13 @@ class SwiGluMlp(nn.Module):
 
 
 class MoeMlp(nn.Module):
-    """Top-2 expert-parallel SwiGLU layer (GShard einsum formulation).
+    """Top-2 expert-parallel SwiGLU layer (GShard einsum formulation):
+    the *capacity-factor* layer. Each expert takes at most
+    ``capacity_factor * 2 * S / E`` tokens of a batch row and the rest
+    are dropped; the ``[B,S,E,C]`` dispatch mask grows with tokens x
+    experts, which rules it out at hundreds of experts. The dropless
+    layer (sigmoid scores, top-k of hundreds, sorted grouped products
+    over the experts a chip holds) is ``models/mla_moe.py: MoeLayer``.
 
     Static shapes throughout: gating produces a [B,S,E,C] dispatch mask
     via one-hot position-in-expert bookkeeping; dispatch and combine are

@@ -13,7 +13,13 @@ recomputing probabilities from the saved logsumexp.
 
 Layout: inputs are ``[batch, seq, heads, head_dim]`` (the model's
 ``bqhk``); kernels operate on ``[batch*heads, seq, head_dim]``. Blocks
-default to 128×128 (MXU tile), fp32 softmax, inputs in bf16 on TPU.
+default to 1024×1024, fp32 softmax, inputs in bf16 on TPU.
+
+Two head sizes: q and k share ``d`` (the score's contraction), v and the
+output share ``d_v``, and the two may differ (latent attention:
+``d = 192`` for nope + rope, ``d_v = 128``). dq and dk come back ``d``
+wide, dv ``d_v`` wide. With ``d_v == d`` the kernels are the same
+programs as before the split. The default scale is ``1 / sqrt(d)``.
 
 On the CPU backend (tests, rehearsals) the same kernels run in Pallas
 interpret mode, so CPU tests cover the kernel logic bit-for-bit. A
@@ -164,9 +170,9 @@ def _pad_to(x, size, axis):
 def _flash_fwd(
     q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int
 ) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: (BH, T, D) → (out (BH,T,D), lse (BH,T))."""
+    """q,k: (BH, T, D), v: (BH, T, Dv) → (out (BH,T,Dv), lse (BH,T))."""
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
     tq_pad = _round_up(t_q, block_q)
     tk_pad = _round_up(t_kv, block_k)
@@ -190,18 +196,18 @@ def _flash_fwd(
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq_pad, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, tq_pad, _LSE_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -360,7 +366,7 @@ def _flash_bwd(
     q, k, v, out, lse, do, sm_scale, causal, block_q, block_k
 ):
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
     tq_pad = _round_up(t_q, block_q)
     tk_pad = _round_up(t_kv, block_k)
@@ -391,22 +397,22 @@ def _flash_bwd(
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),
             _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),
             _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
             _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
             _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk_pad, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         interpret=_use_interpret(),
     )(qp, kp, vp, dop, lsep, deltap)
@@ -417,8 +423,8 @@ def _flash_bwd(
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+            _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
         ],
@@ -455,7 +461,8 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
 ):
-    """Flash attention over ``[batch, seq, heads, head_dim]`` tensors."""
+    """Flash attention over ``[batch, seq, heads, head_dim]`` tensors;
+    v's (and the output's) head size may differ from q's and k's."""
     out, _ = _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k)
     return out
 
@@ -545,7 +552,8 @@ def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
 
 
 def reference_attention(q, k, v, causal: bool = True, sm_scale=None):
-    """Naive einsum attention — the correctness oracle for kernel tests."""
+    """Naive einsum attention — the correctness oracle for kernel tests
+    (v may be narrower or wider than q and k)."""
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale

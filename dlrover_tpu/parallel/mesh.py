@@ -60,6 +60,8 @@ MESH_AXIS_REGISTRY: Dict[str, Tuple[str, str]] = {
     "heads": ("logical", "attention query heads"),
     "kv": ("logical", "per-head projection dim (kept local)"),
     "kv_heads": ("logical", "GQA kv-head groups (few; kept local)"),
+    "q_lora": ("logical", "latent attention's query rank (kept local)"),
+    "kv_lora": ("logical", "latent attention's key/value rank, with its rope vector (kept local)"),
     "mlp": ("logical", "feed-forward hidden dim"),
     "vocab": ("logical", "embedding/logits vocabulary dim"),
     "expert": ("logical", "MoE expert index"),

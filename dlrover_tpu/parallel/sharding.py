@@ -28,6 +28,8 @@ DEFAULT_RULES: LogicalRules = [
     ("heads", "tp"),
     ("kv", None),
     ("kv_heads", None),  # GQA kv-head groups: few of them; keep local
+    ("q_lora", None),  # latent attention's two ranks: a few hundred wide,
+    ("kv_lora", None),  # contracted right after they are made; keep local
     ("mlp", "tp"),
     ("vocab", "tp"),
     ("expert", "ep"),  # MoE experts distributed over the ep axis

@@ -206,8 +206,21 @@ def build_train_step(
     example_data: Optional[Tuple[Any, Any]] = None,
     grad_accum_steps: int = 1,
     aux_loss_weight: float = 0.01,
-) -> Callable[[TrainState, jax.Array, jax.Array], Tuple[TrainState, jax.Array]]:
-    """Jitted (state, inputs, targets) -> (state', metrics) over the mesh.
+    return_metrics: bool = False,
+) -> Callable[[TrainState, jax.Array, jax.Array], Tuple[TrainState, Any]]:
+    """Jitted (state, inputs, targets) -> (state', loss) over the mesh.
+
+    With ``return_metrics`` the second result is ``(loss, metrics)``:
+    ``metrics`` holds what the model sowed under ``"metrics"`` (per-step
+    counters, summed over accumulation slices) and ``grad_norm``, the
+    global norm of the gradient the optimizer was handed. They are device
+    arrays of the same dispatch as the loss, so a caller reads them
+    where it syncs anyway and never inside a segment of steps.
+    It is an option and not every caller's second result because
+    ``ElasticTrainLoop``, the benchmark's GPT-2 worker and every training
+    script unpack ``(state', loss)``: a caller that wants the metrics wraps
+    the step and keeps them aside
+    (``benchmark/workers/model_train_worker.py``).
 
     ``example_data`` (inputs, targets) fixes the data sharding ranks; by
     default both are assumed [batch, seq].
@@ -226,7 +239,15 @@ def build_train_step(
     uniformly before relying on accumulation equivalence.
 
     ``aux_loss_weight`` scales any ``("losses", ...)`` terms the model
-    sows (MoE load-balance); 0 disables them.
+    sows (MoE load-balance); 0 disables them. Terms sown under
+    ``("objective", ...)`` are part of the objective and are added as they
+    are: a second loss the model computes from the targets it was handed
+    (``models/mla_moe.py``'s multi-token-prediction term, weight included).
+
+    Leaves named in ``model.config.frozen_leaves`` take neither gradient
+    nor weight decay: their gradient is zeroed before the optimizer (so
+    it stays out of the clipping norm) and their update after it, which
+    leaves them bit for bit what they were.
     """
     rules = rules or DEFAULT_RULES
     if example_data is not None:
@@ -243,22 +264,38 @@ def build_train_step(
     # computes per-token losses internally when handed targets — the
     # full logits never materialize. loss_fn then receives [B, T] token
     # losses (pair with token_loss_mean), not [B, T, V] logits.
-    fused_ce = getattr(model.config, "ce_chunk", 0) > 0
+    # A model whose objective has a term of its own over the targets
+    # (``takes_targets``) is handed them the same way.
+    fused_ce = getattr(model.config, "ce_chunk", 0) > 0 or getattr(
+        model.config, "takes_targets", False
+    )
+    frozen = tuple(getattr(model.config, "frozen_leaves", ()))
+    collections = ("losses", "objective", "metrics")
+
+    def zero_frozen(tree):
+        if not frozen:
+            return tree
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: jnp.zeros_like(leaf)
+            if getattr(path[-1], "key", None) in frozen else leaf,
+            tree,
+        )
 
     def grads_of(params, inputs, targets):
         def compute_loss(p):
-            # mutable=("losses",) collects ``self.sow("losses", ...)``
+            # mutable collects what the model sows: ``("losses", ...)``
             # auxiliary terms (MoE load-balance, GShard eq.4 — see
-            # models/llama.py MoeMlp); without it flax silently drops
-            # them and top-k routing trains with no balance pressure.
+            # models/llama.py MoeMlp; without it flax silently drops
+            # them and top-k routing trains with no balance pressure),
+            # ``("objective", ...)`` terms and ``"metrics"`` counters.
             if fused_ce:
                 logits, mutated = model.apply(
                     {"params": p}, inputs, targets=targets,
-                    mutable=("losses",),
+                    mutable=collections,
                 )
             else:
                 logits, mutated = model.apply(
-                    {"params": p}, inputs, mutable=("losses",)
+                    {"params": p}, inputs, mutable=collections
                 )
             loss = loss_fn(logits, targets)
             aux_leaves = jax.tree.leaves(mutated.get("losses", {}))
@@ -266,13 +303,15 @@ def build_train_step(
                 loss = loss + aux_loss_weight * sum(
                     jnp.sum(a) for a in aux_leaves
                 )
-            return loss
+            for term in jax.tree.leaves(mutated.get("objective", {})):
+                loss = loss + jnp.sum(term)
+            return loss, unfreeze(mutated.get("metrics", {}))
 
-        return jax.value_and_grad(compute_loss)(params)
+        return jax.value_and_grad(compute_loss, has_aux=True)(params)
 
     def step_fn(state: TrainState, inputs, targets):
         if accum == 1:
-            loss, grads = grads_of(state.params, inputs, targets)
+            (loss, metrics), grads = grads_of(state.params, inputs, targets)
         else:
             def slice_micro(x):
                 if x.shape[0] % accum:
@@ -288,35 +327,41 @@ def build_train_step(
             def one(carry, xs):
                 loss_acc, grads_acc = carry
                 mi, mt = xs
-                loss, grads = grads_of(state.params, mi, mt)
+                (loss, metrics), grads = grads_of(state.params, mi, mt)
                 grads = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
                 )
-                return (loss_acc + loss, grads), None
+                return (loss_acc + loss, grads), metrics
 
             zero_grads = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
-            (loss, grads), _ = jax.lax.scan(
+            (loss, grads), per_slice = jax.lax.scan(
                 one, (jnp.zeros((), jnp.float32), zero_grads),
                 (micro_in, micro_tgt),
             )
+            metrics = jax.tree.map(lambda m: jnp.sum(m, axis=0), per_slice)
             loss = loss / accum
             grads = jax.tree.map(
                 lambda g, p: (g / accum).astype(p.dtype),
                 grads,
                 state.params,
             )
+        grads = zero_frozen(grads)
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        new_params = optax.apply_updates(state.params, zero_frozen(updates))
         new_state = TrainState(
             step=state.step + 1, params=new_params, opt_state=new_opt
         )
-        return new_state, loss
+        if not return_metrics:
+            return new_state, loss
+        metrics["grad_norm"] = optax.global_norm(grads)
+        return new_state, (loss, metrics)
 
     jitted = jax.jit(
         step_fn,
         in_shardings=(sharding_tree, in_sharding, tgt_sharding),
+        # one replicated sharding stands for the whole (loss, metrics) tree
         out_shardings=(sharding_tree, replicated),
         donate_argnums=(0,) if donate else (),
     )
@@ -351,9 +396,12 @@ def build_eval_step(
         in_sharding = tgt_sharding = data_sharding_for(jnp.zeros((1, 1)), mesh, rules)
     replicated = NamedSharding(mesh, PartitionSpec())
 
-    # same fused-CE contract as build_train_step: a ce_chunk model
-    # hands targets in and returns token losses, never whole logits
-    fused_ce = getattr(model.config, "ce_chunk", 0) > 0
+    # same fused-CE contract as build_train_step: a ce_chunk (or
+    # takes_targets) model is handed the targets and returns token
+    # losses, never whole logits
+    fused_ce = getattr(model.config, "ce_chunk", 0) > 0 or getattr(
+        model.config, "takes_targets", False
+    )
 
     def eval_fn(params, inputs, targets):
         if fused_ce:

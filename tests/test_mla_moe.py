@@ -1,0 +1,307 @@
+"""``models/mla_moe.py`` against the plain reference of
+``benchmark/reference/mla_moe.py``, at tiny sizes on the CPU with seeded
+weights: both losses and every leaf's gradient, the chip's share of the
+experts, droplessness, interleaved RoPE, the frozen selection bias, the
+step's counters and a flash-checkpoint round trip of the expert state.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import mla_moe as ref
+from dlrover_tpu.models import mla_moe
+from dlrover_tpu.models.build import build_model
+from dlrover_tpu.models.gpt import token_loss_mean
+from dlrover_tpu.models.mla_moe import MlaMoeConfig, MlaMoeLM, MoeLayer
+from dlrover_tpu.ops.grouped_matmul import collect_rows, grouped_matmul, spread_rows
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+from dlrover_tpu.parallel.train_step import (
+    build_train_step,
+    default_optimizer,
+    init_train_state,
+)
+
+B, T = 2, 16
+
+
+def hp_of(cfg: MlaMoeConfig) -> dict:
+    """The reference's hyperparameters: the config's published keys."""
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+
+
+def batch(cfg, seed=0, b=B, t=T):
+    x = np.random.default_rng(seed).integers(0, cfg.vocab_size, (b, t)).astype(np.int32)
+    return jnp.asarray(x), jnp.asarray(np.roll(x, -1, axis=1))
+
+
+def init(cfg, seed=1):
+    model = MlaMoeLM(cfg)
+    params = model.init(jax.random.PRNGKey(seed), jnp.zeros((B, T), jnp.int32))["params"]
+    return model, jax.tree.map(np.asarray, params)
+
+
+def model_losses_and_grads(model, params, x, y):
+    """(total, trunk, mtp, landed-by-layer), grads: the objective exactly
+    as ``build_train_step`` assembles it."""
+    def total(p):
+        tl, mut = model.apply({"params": p}, x, targets=y, mutable=("objective", "metrics"))
+        loss = token_loss_mean(tl, y) + sum(jnp.sum(v) for v in jax.tree.leaves(mut["objective"]))
+        return loss, mut["metrics"]
+
+    (loss, metrics), grads = jax.value_and_grad(total, has_aux=True)(params)
+    c = mla_moe.step_counters(metrics)
+    return (float(loss), c["train.trunk_loss"], c["train.mtp_loss"],
+            c["moe.assignments_here_by_layer"]), grads
+
+
+# float32 compute: the two programs differ only in summation order (a
+# sorted grouped product against a loop over experts, a flash kernel
+# against a dense softmax), so they agree to float32 rounding through a
+# few layers. bf16 compute: 8 bits of mantissa through 3 blocks and two
+# heads; the losses sit near ln(128) and move in the third digit, single
+# gradient entries by a few percent of the leaf's largest. A score
+# computed from bf16 activations can also flip a near-tie in the top k,
+# which would move one expert's whole gradient: the bf16 case draws its
+# selection bias wide (1.0 against the scores' spread of ~0.1), so that
+# near-ties are rare, and checks that none flipped.
+TOLERANCES = {
+    "float32": dict(loss=2e-5, grad=2e-5, bias_init_std=0.01),
+    "bfloat16": dict(loss=3e-2, grad=6e-2, bias_init_std=1.0),
+}
+
+
+@pytest.mark.parametrize("use_remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_losses_and_every_gradient_match_the_reference(compute, use_remat):
+    cfg = MlaMoeConfig.tiny(
+        dtype=jnp.dtype(compute).type, experts_held=4, expert_offset=2,
+        num_hidden_layers=3, use_remat=use_remat, ce_chunk=8,
+        bias_init_std=TOLERANCES[compute]["bias_init_std"])
+    model, params = init(cfg)
+    x, y = batch(cfg)
+    (loss, trunk, mtp, landed), grads = model_losses_and_grads(model, params, x, y)
+    (r_loss, r_trunk, r_mtp, r_landed), r_grads = ref.loss_and_grads(params, x, y, hp_of(cfg))
+    tol = TOLERANCES[compute]
+    assert abs(trunk - float(r_trunk)) < tol["loss"]
+    assert abs(mtp - float(r_mtp)) < tol["loss"]
+    assert abs(loss - float(r_loss)) < 2 * tol["loss"]
+    assert landed == [int(n) for n in r_landed]
+    flat, r_flat = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (grads, r_grads))
+    assert len(flat) == len(r_flat) == len(jax.tree.leaves(params))
+    for (path, g), (_, r) in zip(flat, r_flat):
+        scale = max(float(jnp.max(jnp.abs(r))), 1e-6)
+        err = float(jnp.max(jnp.abs(g - r))) / scale
+        assert err < tol["grad"], f"{jax.tree_util.keystr(path)}: {err}"
+    # the selection bias enters the selection only
+    assert float(jnp.max(jnp.abs(grads["block_1"]["moe"]["e_score_correction_bias"]))) == 0.0
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """8 experts over 4 shares of 2: the routed parts that the shares
+    compute, plus the shared expert counted once, are the uncut layer."""
+    whole = MlaMoeConfig.tiny(dtype=jnp.float32)
+    h = jax.random.normal(jax.random.PRNGKey(3), (B, T, whole.hidden_size))
+    params = MoeLayer(whole).init(jax.random.PRNGKey(4), h)["params"]
+    want, _ = ref._expert_layer(h, params, hp_of(whole), jnp.float32)
+    total, landed = 0.0, 0
+    for share in range(4):
+        cfg = dataclasses.replace(whole, experts_held=2, expert_offset=2 * share)
+        mine = dict(params)
+        for name in ("w_gate", "w_up", "w_down"):
+            mine[name] = params[name][2 * share:2 * share + 2]
+        out, metrics = MoeLayer(cfg).apply({"params": mine}, h, mutable=("metrics",))
+        s = params["shared"]
+        shared = ref._swiglu(h, s["w_gate"], s["w_up"], s["w_down"])
+        total = total + (out - shared)
+        landed += int(metrics["metrics"]["assignments_here"][0])
+        r_out, r_landed = ref._expert_layer(h, mine, hp_of(cfg), jnp.float32)
+        np.testing.assert_allclose(out, r_out, atol=2e-6)
+        assert int(metrics["metrics"]["assignments_here"][0]) == int(r_landed)
+    np.testing.assert_allclose(total + shared, want, atol=5e-6)
+    assert landed == B * T * whole.num_experts_per_tok  # every assignment has one home
+
+
+@pytest.mark.parametrize("experts,held,extra", [(8, 1, 0), (8, 2, 0), (16, 2, 1), (32, 2, 3)])
+def test_no_token_is_dropped_when_all_choose_the_held_experts(experts, held, extra):
+    """A bias that sends every token to the experts held here: the load is
+    4, 8 or 16 times the mean, and the layer still equals the reference:
+    in one pass over the row buffer where the load fits it (4x the mean),
+    in as many more as it needs where not."""
+    cfg = MlaMoeConfig.tiny(
+        dtype=jnp.float32, n_routed_experts=experts, experts_held=held, expert_offset=4)
+    h = jax.random.normal(jax.random.PRNGKey(5), (B, T, cfg.hidden_size))
+    params = MoeLayer(cfg).init(jax.random.PRNGKey(6), h)["params"]
+    bias = np.zeros(experts, np.float32)
+    bias[4:4 + held] = 10.0  # k = 2: a token's choices fall on the held experts first
+    params = {**params, "e_score_correction_bias": jnp.asarray(bias)}
+
+    def run(p, h):
+        out, m = MoeLayer(cfg).apply({"params": p}, h, mutable=("metrics",))
+        return out, m["metrics"]
+
+    out, m = run(params, h)
+    want, landed = ref._expert_layer(h, params, hp_of(cfg), jnp.float32)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    here = B * T * min(held, cfg.num_experts_per_tok)
+    assert int(m["assignments_here"][0]) == int(landed) == here
+    assert int(m["dropped"][0]) == 0
+    assert int(m["extra_passes"][0]) == extra
+    # and the gradients, through every pass taken
+    w = jax.random.normal(jax.random.PRNGKey(7), out.shape)
+    g = jax.grad(lambda p, h: jnp.sum(run(p, h)[0] * w), argnums=(0, 1))(params, h)
+    r = jax.grad(lambda p, h: jnp.sum(ref._expert_layer(h, p, hp_of(cfg), jnp.float32)[0] * w),
+                 argnums=(0, 1))(params, h)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(r)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape", [(2, 16, 8), (2, 16, 3, 8), (1, 5, 2, 4)])
+def test_interleaved_rope_matches_the_reference(shape):
+    x = jax.random.normal(jax.random.PRNGKey(8), shape)
+    got = mla_moe.rope_interleaved(x, 32000000.0)
+    np.testing.assert_allclose(got, ref.rope_interleaved(x, 32000000.0), atol=1e-6)
+    # position 0 is not turned; a turn keeps each pair's length
+    np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-7)
+    pairs = lambda a: np.asarray(a).reshape(a.shape[:-1] + (-1, 2))
+    np.testing.assert_allclose(
+        np.linalg.norm(pairs(got), axis=-1), np.linalg.norm(pairs(x), axis=-1), atol=1e-5)
+
+
+def test_grouped_matmul_and_the_row_movements():
+    key = jax.random.PRNGKey(9)
+    rows, k, n, groups = 24, 8, 6, 3
+    lhs = jax.random.normal(key, (rows, k))
+    rhs = jax.random.normal(jax.random.fold_in(key, 1), (groups, k, n))
+    sizes = jnp.asarray([5, 0, 9], jnp.int32)  # 14 of 24 rows belong to a group
+    got = grouped_matmul(lhs, rhs, sizes)
+    np.testing.assert_allclose(got[:5], lhs[:5] @ rhs[0], atol=1e-5)
+    np.testing.assert_allclose(got[5:14], lhs[5:14] @ rhs[2], atol=1e-5)
+
+    # 6 tokens x 2 choices; 5 assignments have a row, in this order
+    # 6 tokens; 5 of the buffer's 8 rows belong to a token, in this order
+    token_of, n_valid = jnp.asarray([3, 0, 5, 3, 1, 0, 0, 0]), jnp.asarray(5)
+    x = jax.random.normal(key, (6, 4))
+    r = jax.random.normal(jax.random.fold_in(key, 2), (8, 4))
+    spread = spread_rows(x, token_of, n_valid)
+    np.testing.assert_allclose(spread[:5], x[token_of[:5]])
+    assert not np.any(np.asarray(spread[5:]))
+    collected = collect_rows(r, token_of, n_valid, 6)
+    np.testing.assert_allclose(collected[3], r[0] + r[3], atol=1e-6)
+    assert not np.any(np.asarray(collected[jnp.asarray([2, 4])]))
+    # each is the other's transpose: <spread(x), r> == <x, collect(r)>
+    np.testing.assert_allclose(jnp.sum(spread * r), jnp.sum(x * collected), rtol=1e-5)
+    gx = jax.grad(lambda x: jnp.sum(spread_rows(x, token_of, n_valid) * r))(x)
+    np.testing.assert_allclose(gx, collected, atol=1e-6)
+    gr = jax.grad(lambda r: jnp.sum(collect_rows(r, token_of, n_valid, 6) * x))(r)
+    np.testing.assert_allclose(gr, spread, atol=1e-6)
+
+
+@pytest.fixture()
+def tiny_step():
+    entry = {"family": "mla_moe", "config": dict(
+        vocab_size=128, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+        num_hidden_layers=2, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, rope_theta=10000.0,
+        n_routed_experts=8, num_experts_per_tok=2, experts_held=2, expert_offset=2,
+        use_remat=True, ce_chunk=8, dtype="float32")}
+    model, loss_fn = build_model(entry)
+    mesh = build_mesh(MeshConfig(dp=-1), jax.devices()[:1])
+    tx = default_optimizer(learning_rate=1e-2, weight_decay=0.1, warmup_steps=1)
+    state, shardings = init_train_state(
+        model, jnp.zeros((B, T), jnp.int32), mesh, tx, rng=jax.random.PRNGKey(2))
+    return model, loss_fn, mesh, tx, state, shardings
+
+
+def test_build_model_refuses_a_key_the_config_lacks():
+    with pytest.raises(ValueError, match="no field"):
+        build_model({"family": "mla_moe", "config": {"hiden_size": 32}})
+    with pytest.raises(ValueError, match="unknown model family"):
+        build_model({"family": "joy", "config": {}})
+    model, loss_fn = build_model({"family": "mla_moe", "config": {"num_hidden_layers": 1}})
+    assert type(model).__name__ == "MlaMoeLM" and loss_fn.__name__ == "token_loss_mean"
+    assert model.config.num_hidden_layers == 1 and model.config.hidden_size == 2048
+
+
+def test_selection_bias_is_bit_for_bit_unchanged_by_the_optimizer(tiny_step):
+    model, loss_fn, mesh, tx, state, shardings = tiny_step
+    before = jax.tree.map(np.asarray, state.params)
+    step = build_train_step(model, tx, loss_fn, mesh, shardings)
+    x, y = batch(model.config, seed=3)
+    for _ in range(4):
+        state, loss = step(state, x, y)
+    assert np.isfinite(float(loss))
+    flat = jax.tree_util.tree_flatten_with_path(state.params)[0]
+    old = dict(jax.tree_util.tree_flatten_with_path(before)[0])
+    biases = [(p, l) for p, l in flat if p[-1].key == "e_score_correction_bias"]
+    assert len(biases) == 2  # block_1's and the MTP block's
+    for path, leaf in biases:
+        assert np.asarray(leaf).tobytes() == old[path].tobytes()
+    # weight decay alone moves every other leaf
+    moved = [p for p, l in flat if p[-1].key != "e_score_correction_bias"
+             and not np.array_equal(np.asarray(l), old[p])]
+    assert len(moved) == len(flat) - 2
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_step_returns_its_counters_with_the_loss(tiny_step, accum):
+    model, loss_fn, mesh, tx, state, shardings = tiny_step
+    step = build_train_step(model, tx, loss_fn, mesh, shardings,
+                            return_metrics=True, grad_accum_steps=accum)
+    x, y = batch(model.config, seed=4)
+    state, (loss, metrics) = step(state, x, y)
+    c = mla_moe.step_counters(metrics)
+    assignments = B * T * model.config.num_experts_per_tok
+    assert c["moe.layer_steps"] == 2
+    assert c["moe.assignments_here"] + c["moe.assignments_absent"] == 2 * assignments
+    assert c["moe.assignments_here"] == sum(c["moe.assignments_here_by_layer"])
+    assert c["moe.dropped"] == 0
+    assert c["moe.load_max_over_mean"] >= 2.0 * accum  # a max over a mean is >= 1, per layer (and slice)
+    lam = model.config.mtp_loss_weight
+    total = (c["train.trunk_loss"] + lam * c["train.mtp_loss"]) / accum
+    assert abs(float(loss) - total) < 1e-5
+    assert float(metrics["grad_norm"]) > 0
+    from dlrover_tpu.observability.spans import process_accumulator
+
+    acc = process_accumulator()
+    before = acc.counters().get("moe.assignments_here", 0)
+    model.book_step_counters(metrics)  # the hook a worker that names no model finds
+    assert acc.counters()["moe.assignments_here"] - before == c["moe.assignments_here"]
+
+
+def test_flash_checkpoint_round_trip_of_the_expert_state(tiny_step, tmp_ipc_dir, monkeypatch):
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+    from dlrover_tpu.checkpoint.saver import AsyncCheckpointSaver
+    from dlrover_tpu.checkpoint.shm_handler import SharedMemoryHandler
+
+    model, loss_fn, mesh, tx, state, shardings = tiny_step
+    job = f"mla_{os.getpid()}_{id(tmp_ipc_dir)}"
+    monkeypatch.setenv("DLROVER_JOB_NAME", job)
+    AsyncCheckpointSaver.reset()
+    step = build_train_step(model, tx, loss_fn, mesh, shardings, donate=False)
+    state, _ = step(state, *batch(model.config, seed=5))
+    engine = CheckpointEngine(str(tmp_ipc_dir / "ckpt"), mesh=mesh)
+    try:
+        assert engine.save_to_memory(7, state)
+        template = jax.tree.map(jnp.zeros_like, state)
+        loaded, restored = engine.load_consistent(template)
+        assert loaded == 7
+        want = jax.tree_util.tree_flatten_with_path(state)[0]
+        got = jax.tree_util.tree_flatten_with_path(restored)[0]
+        assert [p for p, _ in want] == [p for p, _ in got]
+        for (path, a), (_, b) in zip(want, got):
+            assert a.shape == b.shape and a.dtype == b.dtype, jax.tree_util.keystr(path)
+            assert np.array_equal(np.asarray(a), np.asarray(b)), jax.tree_util.keystr(path)
+        expert = restored.params["block_1"]["moe"]["w_gate"]
+        assert expert.shape == (2, 32, 16)
+    finally:
+        engine.shm.unlink()
+        engine.close()
+        AsyncCheckpointSaver.reset()
+        for name in os.listdir("/dev/shm"):
+            if name.startswith(f"dlrover_{job}_"):
+                SharedMemoryHandler(0, name=name.split(f"dlrover_{job}_", 1)[1]).unlink()

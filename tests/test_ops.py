@@ -64,6 +64,37 @@ class TestFlashAttention:
             out.astype(jnp.float32), ref.astype(jnp.float32), atol=3e-2
         )
 
+    # Latent attention's shapes: q and k carry nope + rope, v is narrower.
+    @pytest.mark.parametrize("d_qk,d_v,t", [(24, 16, 32), (24, 16, 40), (16, 24, 32)])
+    def test_two_head_sizes_match_reference(self, d_qk, d_v, t):
+        q, k, _ = _qkv(t=t, d=d_qk)
+        v = _qkv(t=t, d=d_v, seed=1)[2]
+        out = flash_attention(q, k, v, True, None, 16, 16)
+        assert out.shape == q.shape[:3] + (d_v,)
+        np.testing.assert_allclose(
+            out, reference_attention(q, k, v, True), atol=2e-5
+        )
+
+    @pytest.mark.parametrize("d_qk,d_v", [(24, 16), (16, 24)])
+    def test_two_head_sizes_gradients_match_reference(self, d_qk, d_v):
+        q, k, _ = _qkv(t=40, d=d_qk)
+        v = _qkv(t=40, d=d_v, seed=1)[2]
+        w = jax.random.normal(jax.random.PRNGKey(2), q.shape[:3] + (d_v,))
+
+        def loss(attend, q, k, v):
+            return (attend(q, k, v) * w).sum()
+
+        fa = functools.partial(
+            flash_attention, causal=True, sm_scale=None, block_q=16, block_k=16
+        )
+        g_fa = jax.grad(functools.partial(loss, fa), argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(
+            functools.partial(loss, reference_attention), argnums=(0, 1, 2)
+        )(q, k, v)
+        for a, b in zip(g_fa, g_ref):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=5e-5)
+
 
 class TestRingAttention:
     def _mesh(self, sp):

@@ -115,6 +115,68 @@ def test_flash_attention_compiles_for_v5e(
     assert text.count("tpu_custom_call") >= (3 if backward else 1)
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_two_head_sizes_compiles_for_v5e(
+    backward, one_chip, on_chip_kernels
+):
+    """Latent attention's call at its published sizes: b4 x 4096, 32 heads,
+    q and k 192 wide (128 nope + 64 rope), v and the output 128."""
+    qk = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    compiled = jax.jit(fwd_bwd if backward else fwd).lower(qk, qk, v).compile()
+    assert _kernel_text(compiled).count("tpu_custom_call") >= (3 if backward else 1)
+    out = compiled.output_shardings  # shapes: out is v wide; dq, dk 192, dv 128
+    assert len(jax.tree.leaves(out)) == (3 if backward else 1)
+
+
+def test_grouped_expert_products_compile_for_v5e(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """The held experts' three grouped products (megablox.gmm) with the row
+    movements around them (a gather out, megablox.tgmm over token tiles
+    back), forward and backward, at the benchmark's sizes: 16,384 tokens,
+    a 32,768-row buffer, 16 experts of 2048 x 768."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    tokens, rows, d, f, held = 16384, 32768, 2048, 768, 16
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def experts(x, w_gate, w_up, w_down, token_of, sizes):
+        n_valid = jnp.sum(sizes)
+        xs = gm.spread_rows(x, token_of, n_valid)
+        h = jax.nn.silu(gm.grouped_matmul(xs, w_gate, sizes)) * (
+            gm.grouped_matmul(xs, w_up, sizes))
+        ys = gm.grouped_matmul(h, w_down, sizes)
+        return gm.collect_rows(ys, token_of, n_valid, tokens)
+
+    def loss_grads(*args):
+        return jax.grad(
+            lambda *a: experts(*a, *args[4:]).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3),
+        )(*args[:4])
+
+    compiled = jax.jit(loss_grads).lower(
+        shape((tokens, d)), shape((held, d, f)), shape((held, d, f)),
+        shape((held, f, d)), shape((rows,), jnp.int32), shape((held,), jnp.int32),
+    ).compile()
+    # two products forward (the third's value is dead under a summed loss),
+    # for each of the three a product back and a weight gradient, and the
+    # collecting kernel behind the spread's backward
+    assert _kernel_text(compiled).count("tpu_custom_call") >= 9
+
+
 def _gpt2_small_step(devices, mesh_config, batch=32):
     """(lowered-step factory) the GPT-2-small train step exactly as
     ``chip_smoke.py``'s worker builds it, over described devices."""

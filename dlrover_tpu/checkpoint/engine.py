@@ -10,6 +10,7 @@ full/fsdp/megatron engines; SURVEY.md §2.4).
 """
 
 import os
+import resource
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -88,6 +89,11 @@ def _save_parts_since(before: Dict[str, float]) -> Dict[str, float]:
         )
         for part in _SAVE_PARTS
     }
+
+
+def _minor_faults() -> int:
+    """Pages this process has had to be given so far, all threads."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
 
 
 def _device_memory_stats(device) -> Optional[Dict[str, int]]:
@@ -506,11 +512,15 @@ class CheckpointEngine:
 
     def _stage_into_shm(self, step: int, tree: Any, extra, root) -> None:
         """``shm.save_pytree`` under the ``ckpt_save`` event, whose end
-        carries the split and on how many threads the payload was
-        copied; ``root`` (the open ``ckpt.save`` or ``ckpt.stage`` span)
-        gets what was staged."""
+        carries the split, on how many threads the payload was copied
+        and ``minor_faults``: the pages the process was given meanwhile,
+        which are few where the host copies land in memory the allocator
+        kept from the last save (``ElasticLaunchConfig.worker_env``) and
+        one a page of the image where they do not. ``root`` (the open
+        ``ckpt.save`` or ``ckpt.stage`` span) gets what was staged."""
         with self._events.ckpt_save(step, storage="memory") as event:
             before = process_accumulator().totals()
+            faults_before = _minor_faults()
             meta = self.shm.save_pytree(
                 step,
                 tree,
@@ -518,10 +528,17 @@ class CheckpointEngine:
                 mesh=self.mesh,
                 extra=extra,
             )
+            minor_faults = _minor_faults() - faults_before
             event.content.update(
-                _save_parts_since(before), copy_threads=self.shm.copy_threads
+                _save_parts_since(before),
+                copy_threads=self.shm.copy_threads,
+                minor_faults=minor_faults,
             )
-        root.set(bytes=meta.total_bytes, leaves=len(meta.records))
+        root.set(
+            bytes=meta.total_bytes,
+            leaves=len(meta.records),
+            minor_faults=minor_faults,
+        )
 
     def _ready_to_save(self, step: int) -> Tuple[bool, bool]:
         """Everything a save waits for before it touches the state:

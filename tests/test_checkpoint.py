@@ -282,6 +282,31 @@ class TestEngineEndToEnd:
         _tree_equal(tree, restored)
         engine.close()
 
+    @pytest.mark.parametrize("block", [True, False])
+    def test_a_saves_event_counts_the_pages_it_was_given(self, tmp_path, block):
+        """The ``ckpt_save`` event of a ``save_to_memory`` ends with the
+        split and ``minor_faults``, on either path."""
+        from dlrover_tpu.common import events
+
+        seen = []
+
+        class Sink(events.Exporter):
+            def export(self, event):
+                seen.append(event.to_dict())
+
+        engine = CheckpointEngine(str(tmp_path / "ckpt"), standalone=True)
+        engine._events._em = events.EventEmitter("trainer", Sink())
+        tree = {"w": jnp.arange(1 << 20, dtype=jnp.float32)}
+        assert engine.save_to_memory(7, tree, block=block)
+        assert engine.wait_staged(timeout=30)
+        engine.close()
+        (end,) = [e["content"] for e in seen
+                  if e["name"] == "ckpt_save" and e["type"] == "end"]
+        assert end["step"] == 7 and end["copy_threads"] == 1
+        for part in ("plan_s", "ensure_s", "d2h_s", "memcpy_s"):
+            assert end[part] >= 0.0
+        assert isinstance(end["minor_faults"], int) and end["minor_faults"] >= 0
+
     def test_async_stage_save_and_load(self, tmp_path):
         """save_to_memory(block=False): staging completes in the
         background and the loader (behind the shard lock) sees it."""

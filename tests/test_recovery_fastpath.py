@@ -549,6 +549,98 @@ class TestWorkerPlatformPin:
         assert "CpuDevice" not in proc.stdout
 
 
+# What TestWorkerAllocatorPolicy's child does: two rounds of allocate,
+# write, free over 256 MB in pieces the size of a train state's leaves
+# (one above the 32 MiB that glibc's mmap threshold cannot pass), and the
+# pages it was given in each round.
+_ALLOCATE_TWICE = """
+import gc, resource
+import numpy as np
+
+def faults():
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+for _ in range(2):
+    before = faults()
+    held = [np.empty(mb << 20, np.uint8) for mb in [48] + [8] * 26]
+    for a in held:
+        a.fill(1)
+    print(faults() - before)
+    del held, a
+    gc.collect()
+"""
+
+
+class TestWorkerAllocatorPolicy:
+    """A worker is born keeping what it frees (``GLIBC_TUNABLES``), unless
+    the caller said how memory is to be allocated."""
+
+    @staticmethod
+    def clear(monkeypatch):
+        for key in list(os.environ):
+            if key.startswith("MALLOC_") or key in ("GLIBC_TUNABLES", "LD_PRELOAD"):
+                monkeypatch.delenv(key)
+
+    @pytest.mark.parametrize(
+        "caller,extra,want",
+        [
+            ({}, {}, "policy"),  # nothing chosen -> the agent's policy
+            ({"GLIBC_TUNABLES": "glibc.malloc.arena_max=2"}, {}, None),
+            ({"MALLOC_TOP_PAD_": "1800000000"}, {}, None),
+            ({"MALLOC_TRIM_THRESHOLD_": "2000000000"}, {}, None),
+            ({"MALLOC_MMAP_THRESHOLD_": "33554432"}, {}, None),
+            ({"MALLOC_ARENA_MAX": "1"}, {}, None),
+            ({"LD_PRELOAD": "/usr/lib/libjemalloc.so.2"}, {}, None),
+            # extra_env wins, whatever it says
+            ({}, {"GLIBC_TUNABLES": "glibc.malloc.top_pad=1"}, "glibc.malloc.top_pad=1"),
+            ({}, {"GLIBC_TUNABLES": "glibc.pthread.rseq=0"}, "glibc.pthread.rseq=0"),
+            ({}, {"MALLOC_ARENA_MAX": "4"}, None),
+            # a caller's other tunables are kept beside the policy
+            ({"GLIBC_TUNABLES": "glibc.pthread.rseq=0"}, {}, "glibc.pthread.rseq=0:policy"),
+        ],
+    )
+    def test_worker_env_policy(self, monkeypatch, caller, extra, want):
+        from dlrover_tpu.agent.config import (
+            WORKER_MALLOC_TUNABLES,
+            ElasticLaunchConfig,
+        )
+
+        self.clear(monkeypatch)
+        for key, value in caller.items():
+            monkeypatch.setenv(key, value)
+        env = ElasticLaunchConfig(extra_env=dict(extra)).worker_env()
+        if want is not None:
+            want = want.replace("policy", WORKER_MALLOC_TUNABLES)
+        assert env.get("GLIBC_TUNABLES") == want
+
+    @pytest.mark.parametrize("with_policy", [True, False])
+    def test_a_second_round_lands_in_kept_pages(self, monkeypatch, with_policy):
+        """Started with exactly the environment the agent writes, a
+        process that frees 256 MB and allocates them again is given
+        almost no new page; started without it, as many as at first."""
+        import platform
+        import subprocess
+        import sys
+
+        from dlrover_tpu.agent.config import ElasticLaunchConfig
+
+        if platform.libc_ver()[0] != "glibc":
+            pytest.skip(f"GLIBC_TUNABLES is glibc's: libc is {platform.libc_ver()}")
+        self.clear(monkeypatch)
+        env = dict(os.environ)
+        policy = ElasticLaunchConfig().worker_env()["GLIBC_TUNABLES"]
+        if with_policy:
+            env["GLIBC_TUNABLES"] = policy
+        proc = subprocess.run(
+            [sys.executable, "-c", _ALLOCATE_TWICE],
+            env=env, capture_output=True, text=True, timeout=60,
+        )
+        assert proc.returncode == 0, proc.stderr
+        first, second = (int(n) for n in proc.stdout.split())
+        assert first >= 100  # 256 MB in pages of 2 MiB at the largest
+        assert (second < first / 10) == with_policy, (first, second)
+
+
 # ---------------------------------------------------------------------------
 # MTTR phase attribution (attribution/recovery.py)
 # ---------------------------------------------------------------------------

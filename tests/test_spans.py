@@ -360,6 +360,18 @@ def test_the_save_root_says_what_it_saved(ckpt_session):
     assert stage["bytes"] == by_step[1]["bytes"] and stage["leaves"] == 3
 
 
+def test_the_roots_and_the_event_count_the_same_pages(ckpt_session):
+    """``minor_faults``: the pages the process was given during the
+    staging, a stat of either root and a field of the event's end."""
+    ends = {e["content"]["step"]: e["content"] for e in ckpt_session["events"]
+            if e["name"] == "ckpt_save" and e["type"] == "end"}
+    roots = {1: [st for _, _, _, st in ckpt_session["spans"]["ckpt.save"]
+                 if st["step"] == 1][0],
+             2: ckpt_session["spans"]["ckpt.stage"][0][3]}
+    for step, root in roots.items():
+        assert root["minor_faults"] == ends[step]["minor_faults"]
+
+
 def test_every_saves_event_says_where_its_time_went(ckpt_session):
     """Traced or not: the ``ckpt_save`` end event carries the split."""
     ends = [e["content"] for e in ckpt_session["events"]
@@ -369,6 +381,7 @@ def test_every_saves_event_says_where_its_time_went(ckpt_session):
         parts = [c["plan_s"], c["ensure_s"], c["d2h_s"], c["memcpy_s"]]
         assert all(p >= 0.0 for p in parts) and c["plan_s"] > 0.0
         assert sum(parts) <= c["duration_s"] + 1e-3
+        assert isinstance(c["minor_faults"], int) and c["minor_faults"] >= 0
 
 
 def saves_of(found):

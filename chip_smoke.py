@@ -537,12 +537,6 @@ def main(argv=None) -> int:
                 os.environ.get("XLA_FLAGS", "") + " " + flag
             ).strip()
 
-    if not ns.tiny:  # the rehearsal loads no plugin into a chip, and
-        # the tests beside it share this checkout's native build
-        for rel in NATIVE_PRODUCTS:
-            path = os.path.join(ROOT, rel)
-            if os.path.exists(path):
-                os.unlink(path)
     shutil.rmtree(WORK, ignore_errors=True)
     os.makedirs(WORK)
 
@@ -553,6 +547,13 @@ def main(argv=None) -> int:
             f"{ns.chips} x {required}. No result.\n"
         )
         return EXIT_NO_ACCELERATOR
+    if not ns.tiny:  # the rehearsal loads no plugin into a chip, and
+        # the tests beside it share this checkout's native build; a run
+        # that finds no chip has returned above and left that build alone
+        for rel in NATIVE_PRODUCTS:
+            path = os.path.join(ROOT, rel)
+            if os.path.exists(path):
+                os.unlink(path)
     say(f"probe: {found['count']} x {found['platform']} ({found['kind']})")
 
     t0 = time.monotonic()

@@ -5,8 +5,7 @@ per scheduler round (the VERDICT r5 #4 gap — a ~4.6x per-slot
 throughput loss vs raw decode that nothing measured):
 
 - ``admission``   host: queue pop, slot bookkeeping, swap adoption
-- ``prefill``     device: prompt prefill + admit program (and
-                  compaction re-prefills in the frontier layout)
+- ``prefill``     device: prompt prefill + admit program
 - ``decode_dispatch``  host: tracing/dispatching the decode chunk
 - ``host_sync``   device: blocking fetch of the chunk's tokens — the
                   wait measures device execution on a sync backend

@@ -64,7 +64,7 @@ def transformer_profile(
 ) -> JobProfile:
     """Profile for a dense-transformer LM job from first principles:
     tokens = batch*seq, step FLOPs ≈ 6*N*tokens (fwd 2N + bwd 4N per
-    token) — the same accounting bench.py's MFU uses."""
+    token) — the same accounting benchmark/flops.py's MFU uses."""
     tokens = float(global_batch) * float(seq_len)
     return JobProfile(
         job_uuid=job_uuid,

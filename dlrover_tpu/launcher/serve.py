@@ -825,15 +825,6 @@ def main(argv=None) -> int:
     ap.add_argument("--eos-id", type=int, default=-1)
     ap.add_argument("--decode-chunk", type=int, default=8)
     ap.add_argument(
-        "--speculative-draft", type=int, default=0, metavar="K",
-        help="serve through the speculative scheduler: self-draft K "
-        "tokens per round, target verifies in one forward (greedy "
-        "only; trained weights accept near 1.0 per draft). Measured "
-        "status: no silicon capture has yet shown spec_vs_plain > 1.0 "
-        "on this chip (r5: serving_spec_vs_per_row 0.727 self-draft) — "
-        "the win needs a draft meaningfully cheaper than the target",
-    )
-    ap.add_argument(
         "--sync-round", action="store_true",
         help="serve with the host-serialized scheduler round (the "
         "pre-pipeline behavior; A/B and debugging). Default is the "
@@ -853,12 +844,12 @@ def main(argv=None) -> int:
         "docs/generation.md)",
     )
     ap.add_argument(
-        "--cache-layout", choices=["frontier", "per_row", "paged"],
+        "--cache-layout", choices=["per_row", "paged"],
         default="per_row",
-        help="per_row: each request advances its own cache frontier — "
-        "no compaction re-prefills (default). frontier: shared write "
-        "slot + compaction (the pre-r5 layout). paged: block-pool KV "
-        "with copy-on-write prefix sharing (docs/generation.md).",
+        help="per_row: a dense [slots, length] cache in which each "
+        "request writes at its own next position (default). paged: "
+        "block-pool KV with copy-on-write prefix sharing "
+        "(docs/generation.md).",
     )
     ap.add_argument(
         "--kv-block-size", type=int,
@@ -927,40 +918,17 @@ def main(argv=None) -> int:
         top_p=ns.top_p,
         eos_id=ns.eos_id,
     )
-    if ns.speculative_draft > 0:
-        from ..models.serving import SpeculativeBatchingEngine
-
-        if ns.temperature != 0.0:
-            ap.error(
-                "--speculative-draft is greedy-only: pass "
-                "--temperature 0.0 (sampled speculation lives in the "
-                "one-shot engine, models/speculative.py)"
-            )
-        if ns.cache_layout != "per_row" or ns.decode_chunk != 8:
-            logger.warning(
-                "--speculative-draft forces per_row layout with one "
-                "round per dispatch; --cache-layout/--decode-chunk "
-                "are ignored"
-            )
-        engine = SpeculativeBatchingEngine(
-            model, params, sampling,
-            batch_size=ns.batch_size,
-            prompt_width=ns.prompt_width,
-            num_draft=ns.speculative_draft,
-            overlap=not ns.sync_round,
-        )
-    else:
-        engine = ContinuousBatchingEngine(
-            model, params, sampling,
-            batch_size=ns.batch_size,
-            prompt_width=ns.prompt_width,
-            decode_chunk=ns.decode_chunk,
-            cache_layout=ns.cache_layout,
-            overlap=not ns.sync_round,
-            auto_chunk=ns.auto_chunk,
-            kv_block_size=ns.kv_block_size,
-            kv_pool_blocks=ns.kv_pool_blocks,
-        )
+    engine = ContinuousBatchingEngine(
+        model, params, sampling,
+        batch_size=ns.batch_size,
+        prompt_width=ns.prompt_width,
+        decode_chunk=ns.decode_chunk,
+        cache_layout=ns.cache_layout,
+        overlap=not ns.sync_round,
+        auto_chunk=ns.auto_chunk,
+        kv_block_size=ns.kv_block_size,
+        kv_pool_blocks=ns.kv_pool_blocks,
+    )
     daemon = ServingDaemon(engine).start()
     httpd = serve(daemon, ns.port, reload_fn, replica_id=ns.replica_id,
                   role=ns.role)

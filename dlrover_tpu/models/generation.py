@@ -106,9 +106,7 @@ def filter_logits(logits, top_k: int = 0, top_p: float = 1.0):
     top-k keeps the k largest; top-p keeps the smallest prefix of the
     sorted distribution whose mass reaches p (always at least the
     argmax). Filters compose: k first, then p, the common serving
-    convention. Shared by direct sampling and the speculative path
-    (whose acceptance math must target the SAME filtered
-    distribution).
+    convention.
     """
     if top_k > 0 and top_k < logits.shape[-1]:
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
@@ -157,8 +155,8 @@ def decode_apply(
     applying the model identically. ``cache_slots`` selects the
     per-row write-slot mode: [B] for single-token decode (continuous
     batching's per-row cache layout) or [B, T] for a T-token window
-    written at per-row slots (the in-scheduler speculative verify);
-    see gpt._update_decode_cache.
+    written at per-row slots (no caller at present: ROADMAP D13); see
+    gpt._update_decode_cache.
     """
     logits, mut = model.apply(
         {"params": params, "cache": cache},

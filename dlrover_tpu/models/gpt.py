@@ -132,10 +132,10 @@ def _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots=None):
 
     ``cache_slots`` int32 switches to PER-ROW write slots: ``[B]`` for
     single-token decode (the continuous-batching engine's per-row
-    cache layout: every request advances its own frontier, so
-    admissions never leave frontier-wide holes and the stream never
-    compacts) or ``[B, T]`` for a T-token window written at per-row
-    slots (the in-scheduler speculative verify). The write is a
+    cache layout: every request advances its own write position, so
+    admissions leave no holes past a prompt's bucket) or ``[B, T]``
+    for a T-token window written at per-row slots (no caller at
+    present: ROADMAP D13). The write is a
     B(×T)-row scatter — tiny next to the attention pass that reads the
     whole cache anyway — and the causal mask keys on each query's own
     slot (returned mask is [B, T, max_len]). Requires an explicit
@@ -189,7 +189,7 @@ def _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots=None):
         if kv_valid is None:
             raise ValueError("cache_slots mode needs explicit kv_valid")
         # [B] (single-token decode) or [B, T] (a T-token window written
-        # at per-row slots — the in-engine speculative verify)
+        # at per-row slots)
         slots_bt = (
             cache_slots[:, None] if cache_slots.ndim == 1 else cache_slots
         )

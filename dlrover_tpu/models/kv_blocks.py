@@ -1,7 +1,7 @@
 """Paged KV-cache building blocks (vLLM's serving-memory idea).
 
-The slot-dense engine layouts (``frontier`` / ``per_row``) reserve a
-full ``[max_seq_len]`` cache row per batch slot, so HBM pays worst-case
+The slot-dense engine layout (``per_row``) reserves a full
+``[max_seq_len]`` cache row per batch slot, so HBM pays worst-case
 padding on every admission and a shared prompt prefix is stored once
 per row. The ``paged`` layout breaks the cache into fixed-size token
 BLOCKS drawn from one pool:

@@ -14,31 +14,22 @@ TPU shape — every device program is static-shape and compiled once:
   single-row cache at slots ``[0, W)`` (W = the smallest width bucket
   that fits it, at most Pw) and the whole row is inserted into the
   batch cache; positions count only valid slots, so RoPE/posembs never
-  see pad holes — the same contract speculative decoding proves
-  token-exact.
+  see pad holes.
 - **Decode runs in chunks**: a ``lax.scan`` of ``decode_chunk`` steps
   per scheduler iteration, so the host pays one dispatch + one result
   fetch per chunk, not per token.
-- **Two cache layouts** (``cache_layout=``):
+- **One write rule, two cache layouts** (``cache_layout=``): every row
+  writes at its OWN next slot (a B-row scatter —
+  gpt._update_decode_cache ``cache_slots`` mode), so there are no holes
+  past a prompt's bucket and a freed slot is reused in place. Liveness
+  is per-request: ``prompt_width + max_new_tokens <= max_seq_len``.
 
-  - ``"frontier"``: every row writes at one shared slot per step (a
-    single ``dynamic_update_slice``). Admissions leave kv_valid holes
-    up to the frontier; slots are a stream-wide budget, and when
-    headroom runs out the scheduler re-prefills every live row's full
-    history into a fresh cache (compaction — one batched MXU-friendly
-    forward), width-bucketed to bound recompiles. Liveness:
-    ``aligned(prompt_width + max_new_tokens) + max(max_new_tokens,
-    decode_chunk) <= max_seq_len``.
-  - ``"per_row"``: every row writes at its OWN next slot (a B-row
-    scatter — gpt._update_decode_cache ``cache_slots`` mode). No
-    shared frontier, no holes past a prompt's bucket, no compaction
-    ever: the paged-KV property, recovered in a static ``[B, L]``
-    cache by per-request slot reuse. Liveness is per-request:
-    ``prompt_width + max_new_tokens <= max_seq_len``.
+  - ``"per_row"`` (the default, and ``tpurun-serve``'s): a static
+    dense ``[B, L]`` cache.
   - ``"paged"``: the full vLLM-style serving memory (models/
     kv_blocks.py). The cache is a pool of fixed-size token blocks;
     each slot carries a block TABLE, the decode chunk gathers the
-    dense view by table, runs the SAME per-row step body (bit-exact
+    dense view by table, runs the SAME step body (bit-exact
     by construction), and scatters back. Admission is bounded by free
     BLOCKS (a short request reserves its bucket + cap, not a whole
     [L] row), a registered prefix's fully-covered blocks are
@@ -59,7 +50,7 @@ TPU shape — every device program is static-shape and compiled once:
   itself, so the one-chunk lag between device progress and host
   bookkeeping can neither over-emit nor corrupt KV. The host sees a
   one-chunk emission latency; greedy streams are bit-identical with
-  the synchronous round (``overlap=False``, kept as the A/B baseline).
+  the synchronous round (``overlap=False``, the tests' reference).
   Weight swaps adopt only at a drained pipeline (no chunk in flight),
   so a push can never split a round between parameter versions.
   Host time hidden behind in-flight chunks is stamped as the
@@ -101,7 +92,6 @@ from .generation import (
 __all__ = [
     "Completion",
     "ContinuousBatchingEngine",
-    "SpeculativeBatchingEngine",
 ]
 
 
@@ -132,7 +122,7 @@ def _device_put_like(tree, like):
     placement: a WeightBus push delivers HOST arrays, and a bare
     ``device_put`` would commit them to one device — collapsing
     tp/fsdp-sharded serving onto a single chip and forcing a
-    recompile. Shared by the target and draft swap paths."""
+    recompile."""
     try:
         spec = jax.tree_util.tree_map(lambda x: x.sharding, like)
     except AttributeError:  # engine was built with host arrays
@@ -160,9 +150,9 @@ class _ChunkAutoTuner:
     grow the chunk so one dispatch/readback amortizes over more
     tokens; when it runs low, shrink back — small chunks waste fewer
     tail steps on finished rows and admit queued requests sooner.
-    Candidates are fixed at construction (one compiled program each)
-    and every one satisfies the engine's liveness bound, so a retune
-    can never strand the stream."""
+    Candidates are fixed at construction (one compiled program each);
+    liveness is per request and does not depend on the chunk length, so
+    a retune can never strand the stream."""
 
     WINDOW = 8  # rounds per decision — enough samples to smooth noise
     HIGH = 0.35
@@ -175,13 +165,6 @@ class _ChunkAutoTuner:
             c for c in cands
             if c == engine.d or 1 <= c <= s.max_new_tokens
         }
-        if engine.layout == "frontier":
-            worst = engine._align(engine.Pw + s.max_new_tokens)
-            cands = {
-                c for c in cands
-                if c == engine.d
-                or worst + max(s.max_new_tokens, c) <= engine.L
-            }
         self.candidates = sorted(cands)
         self.engine = engine
         self.retunes = 0
@@ -198,7 +181,7 @@ class _ChunkAutoTuner:
         on decision rounds."""
         h0, t0, r0 = self._mark
         rounds = self.engine.phases.rounds
-        if rounds < r0:  # accumulator was reset (bench warm/reset)
+        if rounds < r0:  # accumulator was reset
             self._mark = self._snapshot()
             return None
         if rounds - r0 < self.WINDOW:
@@ -227,8 +210,7 @@ class ContinuousBatchingEngine:
     ``run()`` drives the scheduler until queue and slots drain,
     returning ``Completion``s. Greedy output is token-exact with
     :func:`generation.build_generate_fn` on the same prompt — the
-    keystone test (admission holes and compaction are invisible to the
-    math).
+    keystone test (admission holes are invisible to the math).
     """
 
     def __init__(
@@ -241,7 +223,7 @@ class ContinuousBatchingEngine:
         decode_chunk: int = 8,
         mesh=None,
         rules=None,
-        cache_layout: str = "frontier",
+        cache_layout: str = "per_row",
         overlap: bool = True,
         auto_chunk: bool = False,
         kv_block_size: int = 16,
@@ -258,18 +240,13 @@ class ContinuousBatchingEngine:
 
         ``cache_layout``:
 
-        - ``"frontier"`` (default): all rows write at one shared slot
-          (single ``dynamic_update_slice`` per step). Admissions leave
-          kv_valid holes up to the frontier, and the stream compacts
-          (a batched re-prefill) when the frontier nears the cache end.
-        - ``"per_row"``: every row writes at its OWN next slot via a
-          B-row scatter (``gpt._update_decode_cache`` ``cache_slots``
-          mode). No frontier, no holes past a request's prompt bucket,
-          and NO compaction ever — the paged-KV property that matters
-          on this engine (slots are reused in place; a request's
-          lifetime is bounded by its own prompt+budget, not by the
-          stream's). Liveness is simply prompt_width + max_new_tokens
-          <= max_seq_len. Preferred for long mixed streams.
+        - ``"per_row"`` (default): every row writes at its OWN next
+          slot of a dense ``[B, L]`` cache via a B-row scatter
+          (``gpt._update_decode_cache`` ``cache_slots`` mode). No holes
+          past a request's prompt bucket; slots are reused in place, so
+          a request's lifetime is bounded by its own prompt+budget, not
+          by the stream's. Liveness is simply prompt_width +
+          max_new_tokens <= max_seq_len.
         - ``"paged"``: per_row's write discipline over a BLOCK POOL
           (models/kv_blocks.py): ``kv_block_size`` tokens per block,
           ``kv_pool_blocks`` blocks total (0 = the dense equivalent,
@@ -277,7 +254,7 @@ class ContinuousBatchingEngine:
           batch in less HBM). Each slot holds a block table; the chunk
           program gathers the dense view, runs the per_row step body
           unchanged, and scatters back — greedy streams are bit-exact
-          with both dense layouts. Admission allocates ``ceil((bucket
+          with the dense layout. Admission allocates ``ceil((bucket
           + cap)/bs)`` blocks (bounded by free blocks, NOT free
           slots); a registered prefix's fully-covered blocks are
           shared refcounted across rows (copy-on-write: decode writes
@@ -289,43 +266,26 @@ class ContinuousBatchingEngine:
         default): chunk N+1 is dispatched before chunk N's results are
         synced, and the host's emission/retirement/admission runs while
         the device executes. ``overlap=False`` keeps the host-serial
-        round (the pre-pipeline behavior; the bench's A/B baseline).
+        round (the reference the bit-identity tests hold the overlapped
+        round to).
         ``auto_chunk`` lets the engine retune ``decode_chunk`` between
         dispatches from the measured host fraction.
         """
         cfg = model.config
         L = cfg.max_seq_len
-        if cache_layout not in ("frontier", "per_row", "paged"):
+        if cache_layout not in ("per_row", "paged"):
             raise ValueError(
-                f"cache_layout {cache_layout!r}: frontier | per_row | "
-                f"paged"
+                f"cache_layout {cache_layout!r}: per_row | paged"
             )
         self.layout = cache_layout
-        if cache_layout in ("per_row", "paged"):
-            # per-row liveness: each request lives in its own slots
-            if prompt_width + sampling.max_new_tokens > L:
-                raise ValueError(
-                    f"{cache_layout} liveness: prompt_width + "
-                    f"max_new_tokens = "
-                    f"{prompt_width + sampling.max_new_tokens} > "
-                    f"max_seq_len {L}"
-                )
-        else:
-            # Liveness: the worst compacted frontier is the aligned
-            # longest possible history (prompt + full budget); after it
-            # there must still be room for a whole request's decode AND
-            # for the next chunk's writes — otherwise compaction can
-            # strand the stream (or the chunk would write past the
-            # cache end, which dynamic_update_slice silently CLAMPS
-            # into valid slots).
-            worst = self._align(prompt_width + sampling.max_new_tokens)
-            need = worst + max(sampling.max_new_tokens, decode_chunk)
-            if need > L:
-                raise ValueError(
-                    f"continuous batching liveness: aligned(prompt_width"
-                    f" + max_new_tokens) + max(max_new_tokens, "
-                    f"decode_chunk) = {need} > max_seq_len {L}"
-                )
+        # liveness: each request lives in its own slots
+        if prompt_width + sampling.max_new_tokens > L:
+            raise ValueError(
+                f"{cache_layout} liveness: prompt_width + "
+                f"max_new_tokens = "
+                f"{prompt_width + sampling.max_new_tokens} > "
+                f"max_seq_len {L}"
+            )
         self.model = model
         self.params = params
         self.s = sampling
@@ -360,7 +320,6 @@ class ContinuousBatchingEngine:
         self._queue: List[tuple] = []
         self._slots = [_Slot() for _ in range(batch_size)]
         self._completions: List[Completion] = []
-        self._compact_fns: Dict[int, Callable] = {}
         # eager admission prefill (overlapped round): queued requests'
         # prompt rows computed WHILE a decode chunk is in flight, so
         # admission later pays only the cheap insert. Keyed by uid;
@@ -463,14 +422,11 @@ class ContinuousBatchingEngine:
         def admit(state, row_cache, row_logits, row_pos, row_kv,
                   row_allow, slot, next_slot, cap):
             """Insert a prefilled row at ``slot`` (traced — one compile
-            covers every slot). The batch cache's shared frontier scalar
-            is kept; the row's KV live at low slots, the gap up to the
-            frontier is kv_valid=False holes (frontier layout) or
-            nothing (per-row layout: the row's own write slot restarts
-            at ``next_slot`` = its prompt bucket width). ``cap`` arms
-            the row's DEVICE-side emission budget: the chunk fn
-            decrements it per emitted token and done-masks the row at
-            zero, so cap enforcement cannot lag the device (the
+            covers every slot). The row's KV live at low slots and its
+            own write slot restarts at ``next_slot`` = its prompt bucket
+            width. ``cap`` arms the row's DEVICE-side emission budget:
+            the chunk fn decrements it per emitted token and done-masks
+            the row at zero, so cap enforcement cannot lag the device (the
             overlapped round's one-chunk window)."""
             (cache, kv_valid, last_logits, cur_pos, allow, budget, done,
              row_f) = state
@@ -488,29 +444,23 @@ class ContinuousBatchingEngine:
                 row_f.at[slot].set(next_slot),
             )
 
-        def make_decode_chunk(layout: str, d: int):
-            """Build the d-step decode program for one layout; returns
-            stacked (toks, emits, logps) [d, B] and the advanced state.
-            ONE step body serves every layout (the sampling contract,
-            kv_valid handling, and logits dtype must never diverge
-            between them — token-exactness in each layout is proven
-            against the same one-shot engine): ``layout`` only selects
-            the write-slot source and, for ``paged``, wraps the body in
-            a block-table gather/scatter. Frontier layout: all rows
-            write at the stream-wide ``frontier + t`` (the per-row
-            frontier in the state rides along untouched). Per-row
-            layout: each row writes at its own frontier
-            (``cache_slots`` scatter); done/empty rows keep stepping on
-            pad (static shapes) with their write slot parked clamped at
-            L-1 — their kv bit and cache row are fully replaced at the
-            next admission, so the parked writes are invisible. Paged
-            layout: the state's cache element is ``(pool, tables)``;
-            the chunk gathers the dense [B, L] view by block table,
-            runs the per_row body on it unchanged (bit-exactness is
-            structural, not re-proven), and scatters the advanced view
-            back — one dispatch per chunk, same as the dense layouts.
-            A retired slot's table is parked on the trash block, so
-            its clamped writes can never touch a re-allocated block.
+        paged = self.layout == "paged"
+
+        def make_decode_chunk(d: int):
+            """Build the d-step decode program; returns stacked (toks,
+            emits, logps) [d, B] and the advanced state. Each row
+            writes at its own next slot (``cache_slots`` scatter);
+            done/empty rows keep stepping on pad (static shapes) with
+            their write slot parked clamped at L-1 — their kv bit and
+            cache row are fully replaced at the next admission, so the
+            parked writes are invisible. ``paged`` shares the step body
+            with the dense layout (bit-exactness is structural, not
+            re-proven): its state's cache element is ``(pool,
+            tables)``, and the chunk gathers the dense [B, L] view by
+            block table, steps it, and scatters the advanced view back
+            — one dispatch per chunk either way. A retired slot's table
+            is parked on the trash block, so its clamped writes can
+            never touch a re-allocated block.
 
             Per-row stop enforcement is ON THE DEVICE: each row carries
             a remaining-emission budget (its request cap), decremented
@@ -520,10 +470,8 @@ class ContinuousBatchingEngine:
             chunk N safe — a capped row cannot emit past its cap or
             consume liveness headroom during the lag window."""
 
-            per_row = layout != "frontier"
-
-            def chunk(params, state, frontier, rng):
-                if layout == "paged":
+            def chunk(params, state, rng):
+                if paged:
                     (pool, tables) = state[0]
                     state = (
                         kv_blocks.gather_cache(pool, tables), *state[1:]
@@ -547,17 +495,11 @@ class ContinuousBatchingEngine:
                     emit = emit & (budget > 0)
                     budget = budget - emit.astype(jnp.int32)
                     done = done | (budget <= 0)
-                    if per_row:
-                        write_slots = jnp.minimum(row_f, L - 1)
-                        slot_hits = (
-                            jnp.arange(L)[None, :] == write_slots[:, None]
-                        )
-                        row_f = row_f + 1
-                    else:
-                        write_slots = None
-                        slot_hits = (
-                            jnp.arange(L)[None, :] == frontier + t
-                        )
+                    write_slots = jnp.minimum(row_f, L - 1)
+                    slot_hits = (
+                        jnp.arange(L)[None, :] == write_slots[:, None]
+                    )
+                    row_f = row_f + 1
                     kv_valid = kv_valid | slot_hits
                     pos = cur_pos + 1
                     logits, cache = decode_apply(
@@ -580,7 +522,7 @@ class ContinuousBatchingEngine:
                     step, (*state, rng), jnp.arange(d)
                 )
                 new_state = carry[:-1]
-                if layout == "paged":
+                if paged:
                     new_state = (
                         (
                             kv_blocks.scatter_cache(
@@ -643,26 +585,16 @@ class ContinuousBatchingEngine:
 
         self._prefill_fn = jax.jit(prefill_row)
         self._continue_fn = jax.jit(continue_prefill_row, static_argnums=6)
-        if self.layout == "paged":
+        if paged:
             self._admit_fn = jax.jit(paged_admit)
             self._admit_many_fn = jax.jit(paged_admit_many)
         else:
             self._admit_fn = jax.jit(admit)
             self._admit_many_fn = jax.jit(admit_many)
-        # chunk programs are cached per (layout, d): the auto-tuner
-        # changes d between dispatches and each length is one compile
+        # chunk programs are cached per d: the auto-tuner changes d
+        # between dispatches and each length is one compile
         self._chunk_src = make_decode_chunk
-        self._chunk_fns: Dict[tuple, Callable] = {}
-
-        def compact(params, toks, mask):
-            """Batched re-prefill of every live row's history into a
-            fresh cache: frontier drops to the aligned width W."""
-            cache, last_logits, last_pos, kv_valid = prefill_prompt(
-                model, params, toks, mask
-            )
-            return cache, kv_valid, last_logits, last_pos
-
-        self._compact_src = compact
+        self._chunk_fns: Dict[int, Callable] = {}
 
     _NULL_CTX = contextlib.nullcontext()
 
@@ -686,48 +618,32 @@ class ContinuousBatchingEngine:
     def _i32(self, v: int):
         """Cached device scalar: ``jnp.int32(v)`` dispatches a
         conversion op per call (~0.2 ms on CPU), and the scheduler
-        passes the same few slot/width/cap/frontier values every
-        round — host time the dispatch path does not need to pay."""
+        passes the same few slot/width/cap values every round — host
+        time the dispatch path does not need to pay."""
         cache = self.__dict__.setdefault("_i32_cache", {})
         arr = cache.get(v)
         if arr is None:
             arr = cache[v] = jnp.int32(v)
         return arr
 
-    def _compact_for(self, width):
-        if width not in self._compact_fns:
-            self._compact_fns[width] = jax.jit(self._compact_src)
-        return self._compact_fns[width]
-
     def _chunk_for(self, d: int) -> Callable:
-        key = (self.layout, d)
-        if key not in self._chunk_fns:
-            self._chunk_fns[key] = jax.jit(self._chunk_src(*key))
-        return self._chunk_fns[key]
-
-    @staticmethod
-    def _set_cache_frontier(cache, f: int):
-        """Pin the cache's shared write-index scalars (one per layer).
-        Decode writes land at the frontier for EVERY row, so it must
-        never sit below prompt_width — admitted prompts' KV live at
-        slots [0, W) with W <= Pw and would be overwritten."""
-        return jax.tree_util.tree_map(
-            lambda b: jnp.asarray(f, b.dtype) if b.ndim == 0 else b, cache
-        )
+        if d not in self._chunk_fns:
+            self._chunk_fns[d] = jax.jit(self._chunk_src(d))
+        return self._chunk_fns[d]
 
     def _reset_device_state(self):
         V = self.model.config.vocab_size
-        self._frontier = self.Pw  # decode writes start past prompt KV
         if self.layout == "paged":
             # fresh pool: each dense cache leaf (B, L, ...) becomes
-            # (num_blocks, block_size, ...); 0-d write-index scalars
-            # stay pinned like the dense layouts'. Host allocator and
-            # block tables restart with it.
+            # (num_blocks, block_size, ...); the 0-d write-index
+            # scalars ride along unread (``cache_slots`` mode never
+            # reads them). Host allocator and block tables restart
+            # with it.
             bs = self.kv_block_size
             template = init_cache(self.model, 1)
             pool = jax.tree_util.tree_map(
                 lambda leaf: (
-                    jnp.asarray(self._frontier, leaf.dtype)
+                    leaf
                     if leaf.ndim == 0
                     else jnp.zeros(
                         (self._pool.num_blocks, bs) + leaf.shape[2:],
@@ -742,9 +658,7 @@ class ContinuousBatchingEngine:
             self._row_blocks.clear()
             self._prefix_blocks.clear()
         else:
-            cache = self._set_cache_frontier(
-                init_cache(self.model, self.B), self._frontier
-            )
+            cache = init_cache(self.model, self.B)
         self._state = (
             cache,
             jnp.zeros((self.B, self.L), bool),
@@ -753,7 +667,7 @@ class ContinuousBatchingEngine:
             jnp.ones((self.B, V), bool),  # per-row allowed-token mask
             jnp.zeros((self.B,), jnp.int32),  # per-row emission budget
             jnp.ones((self.B,), bool),  # empty slots: done (emit pad)
-            jnp.zeros((self.B,), jnp.int32),  # per-row write frontier
+            jnp.zeros((self.B,), jnp.int32),  # per-row next write slot
         )
 
     # -- host scheduler -------------------------------------------------
@@ -1031,8 +945,8 @@ class ContinuousBatchingEngine:
     @staticmethod
     def _insert_row(batch, row, slot):
         """Insert a [1, ...] prefilled row pytree into the batch cache
-        at ``slot``; 0-d leaves (shared frontier scalars) stay the
-        batch's. Shared by the plain and speculative admit programs."""
+        at ``slot``; 0-d leaves (the write-index scalars, unread in
+        ``cache_slots`` mode) stay the batch's."""
         return jax.tree_util.tree_map(
             lambda b, r: (
                 b
@@ -1044,15 +958,6 @@ class ContinuousBatchingEngine:
             batch,
             row,
         )
-
-    @staticmethod
-    def _align(n: int, unit: int = 16) -> int:
-        """Compaction width alignment: bounds the number of distinct
-        re-prefill program shapes to L/unit (one compile each, and
-        compactions are rare) WITHOUT the overshoot of power-of-two
-        bucketing, which could blow the liveness budget (a bucket can
-        nearly double the longest history)."""
-        return max(unit, ((n + unit - 1) // unit) * unit)
 
     def _bucket_width(self, n: int) -> int:
         """Bucketed prefill width: a 5-token prompt must not pay a
@@ -1073,8 +978,8 @@ class ContinuousBatchingEngine:
     ):
         """Everything an admission needs short of the insert: the
         prefilled row pytree (cache, logits, pos, kv, allow), its
-        bucket width, and the full token history (prefix + suffix for
-        compaction). Shared by the single and the burst insert."""
+        bucket width, and the full token history (prefix + suffix).
+        Shared by the single and the burst insert."""
         V = self.model.config.vocab_size
         if allowed_tokens is None:
             # cached: rebuilding (and re-transferring) an all-True [V]
@@ -1151,8 +1056,6 @@ class ContinuousBatchingEngine:
                     self._state, *row, self._i32(slot),
                     self._i32(width), self._i32(cap),
                 )
-        # full prefix+suffix history: compaction (frontier layout)
-        # rebuilds rows from these tokens
         self._seat(slot, uid, full_prompt, submit_t, cap)
 
     def _seat(self, slot, uid, prompt, submit_t, cap, now=None):
@@ -1311,38 +1214,9 @@ class ContinuousBatchingEngine:
         self._finalize_slot(slot)
         self._retire_device_slot(slot)
 
-    def _compact(self):
-        """Rebuild the cache from live histories; frontier drops from
-        near-L to the longest live history's bucket width."""
-        rows = [
-            (st.prompt + st.emitted) if st.uid >= 0 else []
-            for st in self._slots
-        ]
-        width = self._align(max((len(r) for r in rows), default=1))
-        toks, mask = self._pad_rows(rows, width)
-        with self._ctx():
-            cache, kv_valid, last_logits, cur_pos = self._compact_for(
-                width
-            )(self.params, toks, mask)
-        _, _, _, _, allow, budget, done, row_f = self._state
-        # frontier never drops below Pw: future admissions put prompt
-        # KV at [0, W<=Pw) and decode writes must stay clear of it.
-        # budget rides through: the device counters already hold each
-        # live row's remaining cap (cap minus tokens emitted so far).
-        self._frontier = max(width, self.Pw)
-        cache = self._set_cache_frontier(cache, self._frontier)
-        self._state = (
-            cache, kv_valid, last_logits, cur_pos, allow, budget, done,
-            row_f,
-        )
-
-    # burst insert available (one jitted multi-row admit); the
-    # speculative engine overrides admission wholesale and opts out
-    _burst_admit = True
-
     # tpulint: hotpath — admission runs under the in-flight chunk
     def _admit_free_slots(self, hidden: bool = False) -> None:
-        """Fill empty slots from the queue while the budget allows.
+        """Fill empty slots from the queue.
         The admission device path (prefill + admit programs) runs in
         ``serve.prefill`` spans inside the caller's ``serve.admission``;
         in the overlapped round the whole of it runs while a chunk is
@@ -1358,22 +1232,15 @@ class ContinuousBatchingEngine:
         # overlapped round must hide it); an error surfaces to the
         # driver loop rather than silently corrupting slot state.
         faults.inject("serving.admit", queue_depth=len(self._queue))
-        frontier_layout = self.layout == "frontier"
         paged = self.layout == "paged"
-        burst = self.overlap and self._burst_admit
+        burst = self.overlap
         prefill_phase = "overlap_hidden" if hidden else "prefill"
         batch = []
         for slot, st in enumerate(self._slots):
             if st.uid >= 0 or not self._queue:
                 continue
-            # headroom gate uses the HEAD request's own cap: a short
-            # request can still slip in near the end of the cache.
-            # per_row: a freed slot ALWAYS has room (per-request
-            # liveness was checked at construction).
-            if frontier_layout and (
-                self._frontier + self._queue[0][3] > self.L
-            ):
-                break  # no room for this request until compaction
+            # a freed slot ALWAYS has room (per-request liveness was
+            # checked at construction); only the paged pool can refuse
             table_ids = None
             if paged:
                 # paged admission is bounded by free BLOCKS: plan the
@@ -1395,19 +1262,10 @@ class ContinuousBatchingEngine:
                 "serve.prefill", book=prefill_phase, uid=uid
             ):
                 if not burst:
-                    # table_ids kwarg only when paged: subclasses
-                    # override _admit_one without it (they force dense
-                    # layouts)
-                    if paged:
-                        self._admit_one(
-                            slot, uid, prompt, submit_t, cap, prefix_id,
-                            allowed, table_ids=table_ids,
-                        )
-                    else:
-                        self._admit_one(
-                            slot, uid, prompt, submit_t, cap, prefix_id,
-                            allowed,
-                        )
+                    self._admit_one(
+                        slot, uid, prompt, submit_t, cap, prefix_id,
+                        allowed, table_ids=table_ids,
+                    )
                 else:
                     row, width, full_prompt = self._build_row(
                         uid, prompt, prefix_id, allowed
@@ -1449,57 +1307,15 @@ class ContinuousBatchingEngine:
                     self._row_blocks[slot] = list(table_ids)
                 self._seat(slot, uid, full_prompt, submit_t, cap, now)
 
-    # tpulint: hotpath — drains happen via _drain_inflight, never inline
-    def _frontier_housekeeping(self) -> int:
-        """Frontier-layout cache management (no-op for per_row):
-        idle-reset and compaction. Both are pipeline DRAIN points —
-        compaction rebuilds the cache from host-side histories, which
-        must first catch up with the device. Returns tokens emitted by
-        any drain."""
-        emitted = 0
-        if self.layout != "frontier":
-            return emitted
-        if (
-            not self._inflight
-            and self._queue
-            and all(st.uid < 0 for st in self._slots)
-            and self._frontier > self.Pw
-        ):
-            # Nothing live but the frontier has advanced (admission
-            # may be budget-blocked): a fresh cache beats dispatching
-            # dead all-done chunks until the compaction threshold —
-            # each one is a full device round-trip that emits zero
-            # tokens.
-            self._reset_device_state()
-        if self._frontier + self.d > self.L:
-            emitted += self._drain_inflight()
-            # a batched re-prefill: device work
-            with self.phases.span(
-                "serve.prefill", book="prefill", compaction=1
-            ):
-                self._compact()
-        return emitted
-
     # tpulint: hotpath — dispatch must never read the device back
     def _dispatch_round(self, rng) -> tuple:
         """Enqueue one decode chunk on the device; returns the
         in-flight record (output futures + done futures + the uid
         snapshot) without reading anything back."""
         with self._ctx():
-            chunk_fn = self._chunk_for(self.d)
-            if self.layout == "frontier":
-                self._state, (toks, emits, logps) = chunk_fn(
-                    self.params, self._state,
-                    self._i32(self._frontier), rng,
-                )
-                self._frontier += self.d
-            else:
-                # frontier arg is unused in per_row (write slots come
-                # from the state's per-row frontier); pass a constant
-                # so the one compiled program serves every chunk
-                self._state, (toks, emits, logps) = chunk_fn(
-                    self.params, self._state, self._i32(0), rng
-                )
+            self._state, (toks, emits, logps) = self._chunk_for(self.d)(
+                self.params, self._state, rng
+            )
         self._count_chunk(self.B * self.d)
         return (
             toks, emits, logps, self._state[-2],  # -2: the done flags
@@ -1512,9 +1328,7 @@ class ContinuousBatchingEngine:
         host polls. A slot whose uid changed since dispatch (cancel,
         or cancel + re-admit during the lag window) is skipped: the
         old row's emit mask is the device's own guarantee that a
-        re-admitted request never sees a predecessor's tokens.
-        Overridden by the speculative subclass (round-shaped
-        outputs)."""
+        re-admitted request never sees a predecessor's tokens."""
         toks, emits, logps, done = fetched
         emitted = 0
         now = time.perf_counter()
@@ -1572,9 +1386,7 @@ class ContinuousBatchingEngine:
         are computed into ``self._prefilled`` so the later admission
         pays only the insert program. At most B rows are held (each a
         [1, L] cache); prefix-path requests keep the lazy path (their
-        row derives from the stored prefix state). Overridden to a
-        no-op by the speculative engine, whose admission prefills two
-        models and keeps the classic path."""
+        row derives from the stored prefix state)."""
         if not self._queue:
             return
         held = 0
@@ -1603,14 +1415,13 @@ class ContinuousBatchingEngine:
     def step(self, rng):
         """One scheduler iteration. Returns the number of tokens
         emitted this call. Phase boundaries are stamped into
-        ``self.phases`` so ``stats()`` (and the bench's attribution
-        rung) can report the host/device/hidden split, through spans
-        that also land on a running profiler's trace.
+        ``self.phases`` so ``stats()`` can report the
+        host/device/hidden split, through spans that also land on a
+        running profiler's trace.
 
-        Synchronous round (``overlap=False``): compact if out of
-        headroom (frontier layout only), admit into free slots, decode
-        one chunk, block on its results, retire finished rows — the
-        device idles while the host schedules.
+        Synchronous round (``overlap=False``): admit into free slots,
+        decode one chunk, block on its results, retire finished rows —
+        the device idles while the host schedules.
 
         Overlapped round (default): admit and dispatch chunk N FIRST
         (the device queue stays non-empty), then sync chunk N-1 —
@@ -1644,17 +1455,14 @@ class ContinuousBatchingEngine:
 
     # tpulint: hotpath
     def _step_sync(self, rng):
-        """The host-serial round (pre-pipeline behavior, kept as the
-        measured A/B baseline): dispatch, block, emit, retire."""
+        """The host-serial round (the reference the bit-identity
+        tests hold the overlapped round to): dispatch, block, emit,
+        retire."""
         span = self.phases.span
         # a completed async weight swap lands here, between chunks —
         # the non-blocking check costs ~nothing when none is pending
         with span("serve.admission", book="admission"):
             self._maybe_adopt_pending()
-        # housekeeping books its own compaction span as "prefill" —
-        # it stays outside the admission spans (double-counting it
-        # would inflate serving_host_frac, the metric under test)
-        self._frontier_housekeeping()
         # admission books its self time: the serve.prefill spans
         # inside it (the device path) book as "prefill"
         with span("serve.admission", book="admission"):
@@ -1662,8 +1470,8 @@ class ContinuousBatchingEngine:
         with span("serve.decode_dispatch", book="decode_dispatch"):
             entry = self._dispatch_round(rng)
         with span("serve.host_sync", book="host_sync"):
-            # tpulint: ignore[host-sync] the sync round IS the measured
-            # A/B baseline the overlapped pipeline is compared against
+            # tpulint: ignore[host-sync] the sync round IS the blocking
+            # reference the overlapped pipeline is compared against
             fetched = jax.device_get(entry[:-1])
         with span("serve.retirement", book="retirement"):
             emitted = self._emit_outputs_sync(fetched, entry[-1])
@@ -1672,9 +1480,8 @@ class ContinuousBatchingEngine:
 
     def _emit_outputs_sync(self, fetched, uids) -> int:
         """The synchronous round's per-token host loop, kept verbatim
-        as the measured baseline the overlapped round's fused emission
-        is A/B'd against (greedy equality between the two paths is
-        under test)."""
+        as the reference for the overlapped round's fused emission
+        (greedy equality between the two paths is under test)."""
         toks, emits, logps, done = fetched
         emitted = 0
         for slot, st in enumerate(self._slots):
@@ -1704,7 +1511,6 @@ class ContinuousBatchingEngine:
         # a landed WeightBus push costs one catch-up, never a split
         # round
         self._maybe_adopt_pending()
-        emitted += self._frontier_housekeeping()
         # Zero-lag retirement: when the device already finished the
         # oldest chunk (it outran the host — the host-bound regime
         # this pipeline targets), process it BEFORE dispatching, so
@@ -1829,8 +1635,7 @@ class ContinuousBatchingEngine:
             "swap_failures": self.swap_failures,
             "last_swap_error": self.last_swap_error,
             # host/device attribution (attribution.phases): host_frac
-            # plus per-phase totals, compact enough for /healthz and
-            # the bench line budget
+            # plus per-phase totals, compact enough for /healthz
             "phase_split": self.phases.split().summary(),
         }
 
@@ -1869,12 +1674,8 @@ class ContinuousBatchingEngine:
     def _retire_device_slot(self, slot: int) -> None:
         """Silence a freed slot on the device until the next admission
         (the done bit makes it emit pad)."""
-        state = self._state
-        done_idx = len(state) - 2  # done is always second-to-last
-        done = state[done_idx].at[slot].set(True)
-        self._state = (
-            *state[:done_idx], done, *state[done_idx + 1:]
-        )
+        *head, done, row_f = self._state
+        self._state = (*head, done.at[slot].set(True), row_f)
         if self.layout == "paged":
             # cancel path reaches here without _finalize_slot; the
             # release is idempotent so the retire paths can overlap
@@ -1904,504 +1705,3 @@ class ContinuousBatchingEngine:
             self.step(keys.pop(0))
         out, self._completions = self._completions, []
         return sorted(out, key=lambda c: c.uid)
-
-
-class SpeculativeBatchingEngine(ContinuousBatchingEngine):
-    """Continuous batching WITH in-scheduler speculative decoding.
-
-    vLLM-grade composition: the request-queue scheduler admits and
-    retires mixed-length prompts into decode slots (per-row cache
-    layout), and every device round runs speculation — the draft
-    proposes ``k`` tokens per live row, the target verifies the whole
-    window in ONE forward (a per-row [B, k+1] cache_slots write), and
-    each row emits 1..k+1 tokens per round. Greedy only: the accepted
-    prefix is provably the plain greedy output for ANY draft, so the
-    stream stays token-exact with :class:`ContinuousBatchingEngine`
-    (general-temperature rejection sampling lives in the one-shot
-    engine, models/speculative.py).
-
-    Never-rewind slots (speculative.py's design, applied per row):
-    every round claims k+1 slots at the row's frontier; rejected
-    proposals become kv_valid=False holes, and positions count only
-    valid slots so RoPE/posembs stay exact. Liveness therefore needs
-    ``prompt_width + (k+1) * max_new_tokens + k <= max_seq_len``.
-
-    The draft shares the target's slot layout (its cache is written at
-    the same per-row slots, one validity mask serves both); admission
-    prefills BOTH models on the prompt. Prefix caching is not offered
-    in this mode yet (it would need dual prefix states) — submit with
-    ``prefix_id`` raises.
-    """
-
-    def __init__(
-        self,
-        model,
-        params,
-        *args,
-        sampling: Optional[SamplingConfig] = None,
-        batch_size: Optional[int] = None,
-        prompt_width: Optional[int] = None,
-        draft_model=None,
-        draft_params=None,
-        num_draft: int = 4,
-        decode_chunk: int = 1,
-        mesh=None,
-        rules=None,
-        overlap: bool = True,
-    ):
-        """Two positional shapes are accepted:
-
-        - ``(model, params, sampling, ...)`` — self-drafting (classic);
-        - ``(model, params, draft_model, draft_params, sampling, ...)``
-          — the draft pair rides directly after the target pair, so a
-          separate-draft engine reads like its arguments mean.
-
-        ``draft_model``/``draft_params`` also work as keywords in
-        either shape. ``decode_chunk`` is accepted for constructor
-        parity with :class:`ContinuousBatchingEngine` and ignored: a
-        speculative round IS the dispatch unit (each round emits 1..k+1
-        tokens per row in one draft+verify exchange)."""
-        def _take(name, current, value):
-            # positional/keyword double-supply must raise like a
-            # normal signature would, never silently prefer one
-            if current is not None:
-                raise TypeError(f"got multiple values for {name!r}")
-            return value
-
-        if args:
-            if isinstance(args[0], SamplingConfig):
-                # base-class parity: (sampling[, batch_size[,
-                # prompt_width]]) positionally, like
-                # ContinuousBatchingEngine
-                if len(args) > 3:
-                    raise TypeError(
-                        "too many positional args after sampling"
-                    )
-                tail = args
-            else:
-                if draft_model is not None or draft_params is not None:
-                    raise TypeError(
-                        "don't mix the positional draft pair with "
-                        "draft_model/draft_params keywords"
-                    )
-                if len(args) < 2 or len(args) > 5:
-                    raise TypeError(
-                        "expected (model, params, sampling, ...) or "
-                        "(model, params, draft_model, draft_params, "
-                        "sampling[, batch_size[, prompt_width]], ...)"
-                    )
-                draft_model, draft_params = args[0], args[1]
-                tail = args[2:]
-            if len(tail) >= 1:
-                sampling = _take("sampling", sampling, tail[0])
-            if len(tail) >= 2:
-                batch_size = _take("batch_size", batch_size, tail[1])
-            if len(tail) >= 3:
-                prompt_width = _take(
-                    "prompt_width", prompt_width, tail[2]
-                )
-        if sampling is None or batch_size is None or prompt_width is None:
-            raise TypeError(
-                "sampling, batch_size and prompt_width are required"
-            )
-        if sampling.temperature != 0.0:
-            raise ValueError(
-                "SpeculativeBatchingEngine is greedy-only "
-                "(temperature=0); sampled speculation lives in the "
-                "one-shot engine (models/speculative.py)"
-            )
-        self.draft_model = draft_model if draft_model is not None else model
-        self._pending_draft = None  # in-flight async DRAFT swap
-        self.k = int(num_draft)
-        if self.k < 1:
-            raise ValueError(f"num_draft {num_draft} must be >= 1")
-        L = model.config.max_seq_len
-        dcfg = self.draft_model.config
-        if dcfg.max_seq_len != L:
-            raise ValueError("draft and target must share max_seq_len")
-        if dcfg.vocab_size != model.config.vocab_size:
-            raise ValueError("draft and target must share the vocabulary")
-        need = prompt_width + (self.k + 1) * sampling.max_new_tokens + self.k
-        if need > L:
-            raise ValueError(
-                f"speculative serving liveness: prompt_width + "
-                f"(k+1)*max_new_tokens + k = {need} > max_seq_len {L}"
-            )
-        super().__init__(
-            model, params, sampling, batch_size, prompt_width,
-            decode_chunk=1, mesh=mesh, rules=rules,
-            cache_layout="per_row", overlap=overlap,
-        )
-        self.draft_params = (
-            draft_params if draft_params is not None else self.params
-        )
-        # acceptance accounting (stats()/bench): drafted vs accepted
-        self.rounds = 0
-        self.drafted_total = 0
-        self.accepted_total = 0
-
-    # -- device programs ------------------------------------------------
-
-    def _build_programs(self):
-        super()._build_programs()
-        model, draft = self.model, self.draft_model
-        s, L, k = self.s, self.L, self.k
-
-        def prefill_spec(t_params, d_params, toks, mask):
-            """Prefill BOTH models on one [1, W] prompt; the window
-            slots are shared, so one row kv_valid serves both caches."""
-            t_cache, last_logits, last_pos, kv_valid = prefill_prompt(
-                model, t_params, toks, mask
-            )
-            d_cache = init_cache(draft, toks.shape[0])
-            positions = jnp.maximum(
-                jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1, 0
-            )
-            _, d_cache = decode_apply(
-                draft, d_params, d_cache, toks, positions, kv_valid
-            )
-            return (
-                t_cache, d_cache, last_logits[0], last_pos[0], kv_valid[0]
-            )
-
-        def admit_spec(
-            state, t_row, d_row, row_logits, row_pos, row_kv, slot,
-            next_slot, cap,
-        ):
-            (t_cache, d_cache, kv_valid, last_logits, cur_pos, budget,
-             done, row_f) = state
-            insert = ContinuousBatchingEngine._insert_row
-            return (
-                insert(t_cache, t_row, slot),
-                insert(d_cache, d_row, slot),
-                kv_valid.at[slot].set(row_kv),
-                last_logits.at[slot].set(row_logits),
-                cur_pos.at[slot].set(row_pos),
-                budget.at[slot].set(cap),
-                done.at[slot].set(False),
-                row_f.at[slot].set(next_slot),
-            )
-
-        def spec_round(t_params, d_params, state):
-            """One speculation round for the whole batch. Returns the
-            advanced state plus (window tokens [B, k+1], accepted draft
-            count [B], per-token target logprobs [B, k+1]) — the host
-            emits window[:1 + accepted] per live row.
-
-            Greedy: tok0 = argmax(pending logits) leads the window;
-            the draft proposes k continuations; the target scores the
-            window once; the accepted prefix is exactly what plain
-            greedy decode would have produced, and the logits after
-            the last accepted token become the next round's pending
-            logits (the "bonus" position).
-
-            Device-side cap: each row's remaining-emission budget
-            clamps the accepted count so a round never emits past the
-            request cap, and exhausting it done-masks the row — the
-            overlapped scheduler's one-round lag cannot over-emit or
-            claim window slots for a finished request."""
-            (t_cache, d_cache, kv_valid, last_logits, cur_pos, budget,
-             done, row_f) = state
-            tok0 = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            tok0 = jnp.where(done, s.pad_id, tok0)
-            lp_all = jax.nn.log_softmax(last_logits, axis=-1)
-            lp0 = jnp.take_along_axis(lp_all, tok0[:, None], axis=-1)[:, 0]
-
-            base = jnp.minimum(row_f, L - 1 - k)  # clamp: parked rows
-            # draft proposes k tokens, feeding its own cache per step
-            kv = kv_valid | (
-                jnp.arange(L)[None, :] == base[:, None]
-            )
-            cur = tok0
-            pos = cur_pos + 1
-            d_toks = []
-            dc = d_cache
-            for j in range(k):
-                d_logits, dc = decode_apply(
-                    draft, d_params, dc, cur[:, None], pos[:, None], kv,
-                    cache_slots=jnp.minimum(base + j, L - 1),
-                )
-                nxt = jnp.argmax(
-                    d_logits[:, 0].astype(jnp.float32), axis=-1
-                ).astype(jnp.int32)
-                d_toks.append(nxt)
-                kv = kv | (
-                    jnp.arange(L)[None, :] == (base + 1 + j)[:, None]
-                )
-                cur = nxt
-                pos = pos + 1
-            # align the draft cache: write the last proposal's KV too,
-            # so both caches cover slots [base, base+k]
-            _, dc = decode_apply(
-                draft, d_params, dc, cur[:, None], pos[:, None], kv,
-                cache_slots=jnp.minimum(base + k, L - 1),
-            )
-            drafted = jnp.stack(d_toks, axis=1)  # [B, k]
-
-            # target verifies [tok0, d_1..d_k] in one per-row window
-            win = jnp.concatenate([tok0[:, None], drafted], axis=1)
-            win_pos = (cur_pos + 1)[:, None] + jnp.arange(k + 1)[None, :]
-            win_slots = jnp.minimum(
-                base[:, None] + jnp.arange(k + 1)[None, :], L - 1
-            )
-            t_logits, tc = decode_apply(
-                model, t_params, t_cache, win, win_pos, kv,
-                cache_slots=win_slots,
-            )
-            t_logits = t_logits.astype(jnp.float32)
-
-            ok = drafted == jnp.argmax(t_logits[:, :k], axis=-1)
-            a = jnp.where(
-                ok.all(axis=1), k,
-                jnp.argmin(ok.astype(jnp.int32), axis=1),
-            )
-            a = jnp.where(done, 0, a)
-            # device-side cap: a live row has budget >= 1; accept at
-            # most budget-1 drafts so tok0 + accepted <= budget
-            a = jnp.minimum(a, jnp.maximum(budget - 1, 0))
-            n_emit = jnp.where(done, 0, a + 1)
-
-            # logprobs for the emitted tokens: tok0 under the pending
-            # dist, d_j under the verify dist at position j-1
-            lp_win = jnp.take_along_axis(
-                jax.nn.log_softmax(t_logits[:, :k], axis=-1),
-                drafted[:, :, None],
-                axis=-1,
-            )[:, :, 0]
-            logps = jnp.concatenate([lp0[:, None], lp_win], axis=1)
-
-            # eos among the emitted prefix finishes the row
-            emit_idx = jnp.arange(k + 1)[None, :]
-            emitted_mask = (emit_idx <= a[:, None]) & ~done[:, None]
-            if s.eos_id >= 0:
-                eos_hits = (win == s.eos_id) & emitted_mask
-                done = done | eos_hits.any(axis=1)
-
-            # keep kv bits only for the accepted window prefix: slots
-            # base..base+a stay valid, rejected slots become holes
-            arange_l = jnp.arange(L)[None, :]
-            rejected = (arange_l > (base + a)[:, None]) & (
-                arange_l <= (base + k)[:, None]
-            )
-            kv = kv & ~rejected
-
-            # pending logits = after the last accepted token
-            nxt_logits = jnp.take_along_axis(
-                t_logits, a[:, None, None], axis=1
-            )[:, 0]
-            # budget burn-down AFTER the eos update: an eos'd row is
-            # already done, so its residual budget is irrelevant
-            budget = jnp.maximum(budget - n_emit, 0)
-            done = done | (budget <= 0)
-            return (
-                tc, dc, kv, nxt_logits, cur_pos + 1 + a, budget, done,
-                row_f + k + 1,
-            ), (win, a, logps)
-
-        self._prefill_spec_fn = jax.jit(prefill_spec)
-        self._admit_spec_fn = jax.jit(admit_spec)
-        self._round_fn = jax.jit(spec_round)
-
-    def _reset_device_state(self):
-        V = self.model.config.vocab_size
-        self._frontier = self.Pw  # unused (per-row), kept for stats
-        self._state = (
-            init_cache(self.model, self.B),
-            init_cache(self.draft_model, self.B),
-            jnp.zeros((self.B, self.L), bool),
-            jnp.full((self.B, V), -1e9, jnp.float32),
-            jnp.zeros((self.B,), jnp.int32),
-            jnp.zeros((self.B,), jnp.int32),  # per-row emission budget
-            jnp.ones((self.B,), bool),
-            jnp.zeros((self.B,), jnp.int32),
-        )
-
-    # -- host scheduler -------------------------------------------------
-
-    _NO_PREFIX = (
-        "prefix caching is not available in speculative serving"
-    )
-
-    def register_prefix(self, tokens):
-        # fail at REGISTRATION (a ValueError maps to HTTP 400), not on
-        # every later completion
-        raise ValueError(self._NO_PREFIX)
-
-    def submit(self, tokens, max_new_tokens=None, prefix_id=None,
-               allowed_tokens=None):
-        if prefix_id is not None:
-            raise ValueError(self._NO_PREFIX)
-        if allowed_tokens is not None:
-            raise ValueError(
-                "allowed_tokens is not available in speculative serving"
-            )
-        return super().submit(tokens, max_new_tokens=max_new_tokens)
-
-    def set_params(self, params, draft_params=None) -> float:
-        """Swap target weights (and optionally the draft's). A self-
-        drafting engine whose draft_params were the target's follows
-        the target automatically."""
-        self.set_params_async(params, draft_params=draft_params)
-        jax.block_until_ready(self._pending_params)
-        if self._pending_draft is not None:
-            jax.block_until_ready(self._pending_draft)
-        self._maybe_adopt_pending()
-        return self.swap_latency_s
-
-    def set_params_async(self, params, draft_params=None) -> None:
-        """Non-blocking swap of the target AND (optionally) the draft:
-        both transfers are enqueued now, and adoption is ATOMIC at a
-        round boundary — the engine never runs a round with a new
-        target against an old explicit draft (their logits disagree and
-        acceptance collapses for that round). A self-following draft
-        (draft_params is params) keeps following without a transfer.
-        Superseding pushes compose per component: a later target-only
-        call keeps the latest draft push pending, so target and draft
-        still land together.
-
-        Like every engine method, this must be called from the one
-        driver thread that owns the engine (the serving daemon routes
-        all swaps through its inbox). The draft is staged BEFORE the
-        target as cheap defense in depth: adoption gates on the target
-        being pending, so an out-of-contract concurrent poll between
-        the two stores sees draft-without-target and adopts nothing,
-        rather than target-without-draft."""
-        if draft_params is not None:
-            try:
-                self._pending_draft = _device_put_like(
-                    draft_params, self.draft_params
-                )
-            except Exception as e:  # noqa: BLE001 — swap aborted
-                self._abort_pending_swap(e)
-                return
-        super().set_params_async(params)
-
-    def _abort_pending_swap(self, err: BaseException) -> None:
-        # The pair aborts together: a new draft adopted against the old
-        # target (or vice versa) collapses acceptance — exactly the
-        # mismatch atomic adoption exists to prevent. This covers every
-        # abort source, including a target transfer that fails in
-        # flight under _maybe_adopt_pending.
-        self._pending_draft = None
-        super()._abort_pending_swap(err)
-
-    def _maybe_adopt_pending(self) -> bool:
-        """Atomic target+draft adoption: when an explicit draft swap is
-        in flight, adoption waits until BOTH pytrees have landed; a
-        self-following draft re-aliases to the new target at the same
-        boundary."""
-        pending_draft = self._pending_draft
-        if pending_draft is not None and self._pending_params is not None:
-            try:
-                if not _tree_ready(pending_draft):
-                    return False
-            except Exception as e:  # noqa: BLE001 — failed draft transfer
-                self._abort_pending_swap(e)
-                return False
-        follow = self.draft_params is self.params
-        if super()._maybe_adopt_pending():
-            if pending_draft is not None:
-                self.draft_params = pending_draft
-                self._pending_draft = None
-            elif follow:
-                self.draft_params = self.params
-            return True
-        return False
-
-    def _admit_one(
-        self, slot, uid, prompt, submit_t, cap, prefix_id=None,
-        allowed_tokens=None,
-    ):
-        width = self._bucket_width(len(prompt))
-        toks, mask = self._pad_rows([prompt], width)
-        with self._ctx():
-            t_row, d_row, row_logits, row_pos, row_kv = (
-                self._prefill_spec_fn(
-                    self.params, self.draft_params, toks, mask
-                )
-            )
-            self._state = self._admit_spec_fn(
-                self._state, t_row, d_row, row_logits, row_pos, row_kv,
-                self._i32(slot), self._i32(width), self._i32(cap),
-            )
-        self._seat(slot, uid, prompt, submit_t, cap)
-
-    # tpulint: hotpath — dispatch must never read the device back
-    def _dispatch_round(self, rng) -> tuple:
-        """One speculation round enqueued on the device (draft k,
-        verify once); nothing read back. ``rng`` is accepted for API
-        parity (greedy rounds are deterministic). The base class's
-        step() drives this for both the synchronous and the
-        overlapped scheduler — a speculative ROUND is this engine's
-        pipeline unit, and async weight adoption (target AND draft,
-        atomically) happens only at a drained pipeline, exactly like
-        the plain engine's chunk."""
-        with self._ctx():
-            self._state, (win, accept, logps) = self._round_fn(
-                self.params, self.draft_params, self._state
-            )
-        self._count_chunk(self.B * (self.k + 1))
-        return (
-            win, accept, logps, self._state[-2],  # -2: the done flags
-            [st.uid for st in self._slots],
-        )
-
-    def _emit_outputs(self, fetched, uids) -> int:
-        """Emit one synced round: window[:1+accepted] per row whose
-        uid still matches the dispatch snapshot (a slot cancelled —
-        or cancelled and re-admitted — during the one-round lag gets
-        nothing), with eos/cap truncation on the host exactly as the
-        synchronous round did. Acceptance accounting happens here, per
-        PROCESSED round, so stats stay exact in both modes."""
-        win, accept, logps, done = fetched
-        emitted = 0
-        self.rounds += 1
-        live = [
-            st.uid >= 0 and st.uid == uids[i]
-            for i, st in enumerate(self._slots)
-        ]
-        self.drafted_total += self.k * sum(live)
-        self.accepted_total += int(
-            sum(int(accept[i]) for i, l in enumerate(live) if l)
-        )
-        for slot, st in enumerate(self._slots):
-            if not live[slot]:
-                continue
-            for t in range(1 + int(accept[slot])):
-                if len(st.emitted) >= st.cap:
-                    break
-                tok = int(win[slot, t])
-                if not st.emitted:
-                    self._first_token(st, time.perf_counter())
-                st.emitted.append(tok)
-                st.logprobs.append(float(logps[slot, t]))
-                emitted += 1
-                if self.s.eos_id >= 0 and tok == self.s.eos_id:
-                    break
-            st.finished = bool(done[slot])
-            if st.finished or len(st.emitted) >= st.cap:
-                # the device already done-masked the row (budget/EOS)
-                self._finalize_slot(slot)
-        return emitted
-
-    # the speculative round's emission is identical in both modes (it
-    # was already window-fused); the sync path reuses it
-    _emit_outputs_sync = _emit_outputs
-
-    # speculative admission inserts into BOTH caches through its own
-    # program — the plain engine's burst insert does not apply
-    _burst_admit = False
-
-    def _eager_prefill(self) -> None:
-        """No-op: speculative admission prefills BOTH models through
-        its own program; the plain engine's eager rows don't apply."""
-
-    def stats(self) -> Dict:
-        out = super().stats()
-        out["speculative_num_draft"] = self.k
-        out["self_drafting"] = self.draft_params is self.params
-        out["spec_rounds"] = self.rounds
-        out["spec_acceptance"] = round(
-            self.accepted_total / max(self.drafted_total, 1), 3
-        )
-        return out

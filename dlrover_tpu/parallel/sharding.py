@@ -282,12 +282,11 @@ def sharded_generate_jit(
 ):
     """jit ``fn(*param_trees, *data_args, rng)`` SPMD over ``mesh``.
 
-    The one copy of the sharded-generation wrapper (used by both
-    :mod:`models.generation` and :mod:`models.speculative`): data args
-    shard over the batch axes, the rng replicates, and each entry of
-    ``param_trees`` is a NamedSharding tree — or None, meaning that
-    model's params replicate (e.g. a small speculative draft next to a
-    sharded target). When EVERY tree is None, in_shardings is omitted
+    The one copy of the sharded-generation wrapper (used by
+    :mod:`models.generation`): data args shard over the batch axes, the
+    rng replicates, and each entry of ``param_trees`` is a
+    NamedSharding tree — or None, meaning that model's params
+    replicate. When EVERY tree is None, in_shardings is omitted
     entirely so already-placed device arrays keep their layout. The
     returned callable enters the mesh + logical-rule contexts around
     every call so module constraints resolve.

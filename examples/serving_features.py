@@ -5,12 +5,11 @@ The reference's serving answer is "deploy vLLM next to the trainer"
 framework owns the stack instead. Each section below exercises one
 pillar of models/serving.py on a tiny CPU model:
 
-1. per-row cache layout  — continuous batching with no compaction
+1. per-row cache layout  — continuous batching, slots reused in place
 2. prefix caching        — a shared system prompt prefilled once
 3. constrained decoding  — allowed_tokens (RL action spaces)
 4. cancellation          — abort mid-decode, slot freed
 5. int8 KV cache         — half the cache bytes per slot
-6. speculative serving   — draft K + one-forward verify per round
 
 Run anywhere:
 
@@ -37,7 +36,6 @@ from dlrover_tpu.models.generation import SamplingConfig  # noqa: E402
 from dlrover_tpu.models.gpt import GPT, GPTConfig  # noqa: E402
 from dlrover_tpu.models.serving import (  # noqa: E402
     ContinuousBatchingEngine,
-    SpeculativeBatchingEngine,
 )
 
 CFG = GPTConfig(
@@ -53,10 +51,10 @@ def main():
     )["params"]
     greedy = SamplingConfig(max_new_tokens=12, temperature=0.0)
 
-    # 1. per-row continuous batching (no compaction, per-request slots)
+    # 1. per-row continuous batching (per-request slots, the default)
     eng = ContinuousBatchingEngine(
         model, params, greedy, batch_size=3, prompt_width=16,
-        decode_chunk=4, cache_layout="per_row",
+        decode_chunk=4,
     )
     out = eng.run([[5, 9, 2], [7, 1], [3, 3, 8], [11, 4, 2, 6]])
     print(f"1. per_row: {len(out)} completions, "
@@ -95,21 +93,11 @@ def main():
     # 5. int8 KV cache: same scheduler, half the cache bytes per slot
     eng8 = ContinuousBatchingEngine(
         GPT(dataclasses.replace(CFG, kv_cache_int8=True)), params,
-        greedy, batch_size=6, prompt_width=16, cache_layout="per_row",
+        greedy, batch_size=6, prompt_width=16,
     )
     out = eng8.run([[5, 9, 2], [7, 1]])
     print(f"5. int8 cache: {len(out)} completions at 2x the slots of "
           f"the bf16 HBM budget")
-
-    # 6. speculative serving: self-draft 3, verify in one forward
-    sp = SpeculativeBatchingEngine(
-        model, params, greedy, batch_size=2, prompt_width=16,
-        num_draft=3,
-    )
-    out = sp.run([[5, 9, 2], [7, 1], [3, 3, 8]])
-    st = sp.stats()
-    print(f"6. speculative: {len(out)} completions, acceptance "
-          f"{st['spec_acceptance']} over {st['spec_rounds']} rounds")
 
 
 if __name__ == "__main__":

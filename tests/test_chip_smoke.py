@@ -109,8 +109,19 @@ def test_parent_never_imports_jax():
 
 def test_no_accelerator_means_no_result_line():
     """As the driver runs it in a sandbox: the platform held to the CPU,
-    no ``--tiny``. Non-zero exit and NOTHING on stdout to parse."""
-    proc = _run([])
+    no ``--tiny``. Non-zero exit and NOTHING on stdout to parse — and
+    the native build is left alone: the tests of the interposer run
+    beside this one, on another worker, from the same checkout."""
+    product = os.path.join(_REPO, "native", "tpu_timer", "test_tsan")
+    made = not os.path.exists(product)
+    if made:
+        open(product, "w").close()
+    try:
+        proc = _run([])
+        assert os.path.exists(product)
+    finally:
+        if made:
+            os.unlink(product)
     assert proc.returncode == 3
     assert proc.stdout.strip() == ""
     assert "No result" in proc.stderr

@@ -20,5 +20,5 @@ def test_serving_features_example_runs():
     )
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     for marker in ("1. per_row", "2. prefix", "3. constrained",
-                   "4. cancel", "5. int8", "6. speculative"):
+                   "4. cancel", "5. int8"):
         assert marker in p.stdout, (marker, p.stdout)

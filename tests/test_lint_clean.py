@@ -200,9 +200,9 @@ def test_lock_witness_is_jax_free():
 
 def test_no_run_products_tracked():
     """Repo hygiene: what a run makes is git-ignored, never tracked —
-    bench.py's over-budget sidecar, chip_smoke.py's work dir, the
-    persistent compile cache, the native build products and what the
-    chip tool brings back. The chip check copies only what git would
+    chip_smoke.py's work dir, the persistent compile cache, the native
+    build products and what the chip tool brings back. The chip check
+    copies only what git would
     commit, so a tracked product would also ride to the chip in place
     of a build from the committed sources."""
     import re
@@ -220,7 +220,7 @@ def test_no_run_products_tracked():
 
         pytest.skip("not a git checkout")
     product = re.compile(
-        r"^(BENCH_extra_|\.smoke_work/|\.jax_compile_cache/|chiprun_out/"
+        r"^(\.smoke_work/|\.jax_compile_cache/|chiprun_out/"
         r"|native/.*\.so$|native/.*/test_(driver|tpu_timer|tsan)"
         r"(_tsan)?$)"
     )

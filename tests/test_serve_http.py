@@ -235,7 +235,7 @@ class TestHealthzStats:
         base = server[0]
         with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
             h = json.loads(r.read())
-        assert h["cache_layout"] in ("frontier", "per_row")
+        assert h["cache_layout"] == "per_row"
         assert h["busy_slots"] == 0 and h["queue_depth"] == 0
         assert h["registered_prefixes"] == 0
         assert h["kv_cache_int8"] is False

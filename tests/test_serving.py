@@ -1,12 +1,12 @@
 """Continuous batching scheduler (models/serving.py).
 
-Keystone: greedy output through the slot-admission/compaction engine
-is token-exact with the plain one-shot engine on every request — the
-hole-slot admission and the compaction re-prefill must be invisible to
-the math. Plus the VERDICT r4 #5 done-criteria: a stream of N >> B
-mixed-length prompts sustains >= 0.8x the homogeneous-batch rate, and
-a mid-decode weight hot-swap has a measured latency and changes
-subsequent output.
+Keystone: greedy output through the slot-admission engine is
+token-exact with the plain one-shot engine on every request — the
+hole-slot admission and the reuse of a slot over a retired request's KV
+must be invisible to the math. Plus the VERDICT r4 #5 done-criteria: a
+stream of N >> B mixed-length prompts sustains >= 0.8x the
+homogeneous-batch rate, and a mid-decode weight hot-swap has a measured
+latency and changes subsequent output.
 """
 
 import time
@@ -90,10 +90,11 @@ class TestGreedyExactness:
         # later uids waited in the queue behind a full batch
         assert got[-1].queue_s > got[0].queue_s
 
-    def test_exactness_through_compaction(self):
-        """max_seq_len tight enough that the stream MUST compact
-        mid-flight; greedy parity must survive the re-prefill."""
-        model = _model(seq=48)  # Pw 16 + 2*N 16 = 48: liveness edge
+    def test_exactness_in_a_tight_cache(self):
+        """max_seq_len barely above one request's own need: ten
+        requests through three slots, each admission over a retired
+        row's KV and its parked done-row write; greedy parity holds."""
+        model = _model(seq=48)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
         prompts = _mixed_prompts(10, rng_seed=3)
@@ -327,81 +328,18 @@ class TestWeightSwap:
         assert eng.stats()["swap_pending"] is False
         assert eng.swap_latency_s is not None and eng.swap_latency_s > 0
 
-    def test_async_swap_self_draft_follows(self):
-        """A self-drafting speculative engine keeps draft == target
-        through an ASYNC adoption (the blocking set_params already
-        guarantees this; the async path must too)."""
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = _model(seq=256)
-        p1, p2 = _params(model, 0), _params(model, 1)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        eng = SpeculativeBatchingEngine(
-            model, p1, model, p1, sampling, batch_size=2,
-            prompt_width=8, decode_chunk=4, num_draft=2,
-        )
-        assert eng.draft_params is eng.params
-        eng.submit([5, 9, 2])
-        eng.set_params_async(p2)
-        rng = jax.random.PRNGKey(0)
-        for _ in range(32):
-            rng, sub = jax.random.split(rng)
-            eng.step(sub)
-            if not eng.pending:
-                break
-        assert eng.stats()["swap_pending"] is False
-        assert eng.draft_params is eng.params  # still following
-
-
 class TestPerRowLayout:
-    """cache_layout='per_row': every row writes at its own frontier
-    (gpt._update_decode_cache cache_slots scatter) — no stream-wide
-    frontier, no admission holes past the prompt bucket, and NO
-    compaction ever. The paged-KV property vLLM gets from block tables,
-    here from per-row slot reuse in a static [B, L] cache."""
+    """cache_layout='per_row' (the default): every row writes at its
+    own next slot (gpt._update_decode_cache cache_slots scatter) — no
+    admission holes past the prompt bucket. The paged-KV property vLLM
+    gets from block tables, here from per-row slot reuse in a static
+    [B, L] cache."""
 
-    def test_per_row_stream_matches_plain_decode(self):
-        model = _model(seq=256)
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=10, temperature=0.0)
-        prompts = _mixed_prompts(12)
-        eng = ContinuousBatchingEngine(
-            model, params, sampling, batch_size=4, prompt_width=16,
-            decode_chunk=4, cache_layout="per_row",
-        )
-        got = eng.run(prompts)
-        assert [c.uid for c in got] == list(range(12))
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
-            assert len(c.logprobs) == len(c.tokens)
-
-    def test_per_row_never_compacts(self, monkeypatch):
-        """A cache tight enough that the frontier layout MUST compact:
-        per_row serves the same stream exactly, without ever touching
-        the compaction path."""
-        model = _model(seq=48)
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        prompts = _mixed_prompts(10, rng_seed=3)
-        eng = ContinuousBatchingEngine(
-            model, params, sampling, batch_size=3, prompt_width=16,
-            decode_chunk=4, cache_layout="per_row",
-        )
-
-        def boom(*a, **k):
-            raise AssertionError("per_row must never compact")
-
-        monkeypatch.setattr(eng, "_compact", boom)
-        got = eng.run(prompts)
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
-
-    def test_per_row_serves_caches_frontier_cannot(self):
-        """per_row's liveness bound is per-request (prompt + budget),
-        not stream-wide: a max_seq_len the frontier layout rejects at
-        construction still serves exactly under per_row."""
+    def test_liveness_is_per_request(self):
+        """The liveness bound is per-request (prompt + budget), not
+        stream-wide: a max_seq_len with room for one request's own
+        slots serves a stream exactly; one without is rejected at
+        construction."""
         model = _model(seq=32)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
@@ -410,11 +348,11 @@ class TestPerRowLayout:
         )
         with pytest.raises(ValueError, match="liveness"):
             ContinuousBatchingEngine(
-                model, params, sampling, **kwargs
+                model, params,
+                SamplingConfig(max_new_tokens=17, temperature=0.0),
+                **kwargs
             )
-        eng = ContinuousBatchingEngine(
-            model, params, sampling, cache_layout="per_row", **kwargs
-        )
+        eng = ContinuousBatchingEngine(model, params, sampling, **kwargs)
         prompts = _mixed_prompts(6, rng_seed=7)
         got = eng.run(prompts)
         want = _reference_completions(model, params, prompts, sampling)
@@ -422,9 +360,8 @@ class TestPerRowLayout:
             assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
 
     @pytest.mark.slow  # ~16 s: the long-tail stress variant; slot
-    # reuse over stale KV stays in tier-1 via
-    # test_per_row_never_compacts + test_per_row_stream_matches_
-    # plain_decode on the same layout
+    # reuse over stale KV stays in tier-1 via TestGreedyExactness's
+    # test_exactness_in_a_tight_cache + test_stream_matches_plain_decode
     def test_per_row_long_stream_slot_reuse_over_stale_kv(self):
         """N >> B through 2 slots: every admission rewrites a slot that
         carries a previous request's full KV + a parked done-row write;
@@ -444,48 +381,14 @@ class TestPerRowLayout:
         for c, w in zip(got, want):
             assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
 
-    @pytest.mark.slow  # ~6 s: sharded serving exactness is tier-1 via
-    # the frontier-layout twin (TestShardedServing); this re-proves it
-    # on per_row, whose unsharded exactness is already tier-1
-    def test_per_row_tp_sharded_stream_matches_single_device(self):
-        """SPMD per_row: the cache_slots scatter rides the same tp mesh
-        as the training shardings."""
-        from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
-        from dlrover_tpu.parallel.train_step import (
-            default_optimizer,
-            init_train_state,
-        )
-
-        model = _model(seq=256)
-        mesh = build_mesh(MeshConfig(dp=1, tp=2), jax.devices()[:2])
-        state, _ = init_train_state(
-            model, jnp.zeros((4, 8), jnp.int32), mesh, default_optimizer()
-        )
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        prompts = _mixed_prompts(7, rng_seed=11)
-        eng_s = ContinuousBatchingEngine(
-            model, state.params, sampling, batch_size=3, prompt_width=16,
-            decode_chunk=4, mesh=mesh, cache_layout="per_row",
-        )
-        got = eng_s.run(prompts)
-        host_params = jax.tree.map(
-            jnp.asarray, jax.device_get(state.params)
-        )
-        eng_1 = ContinuousBatchingEngine(
-            model, host_params, sampling, batch_size=3, prompt_width=16,
-            decode_chunk=4, cache_layout="per_row",
-        )
-        want = eng_1.run(prompts)
-        for c, w in zip(got, want):
-            assert c.tokens == w.tokens, (c.uid, c.tokens, w.tokens)
-
-    def test_rejects_unknown_layout(self):
+    @pytest.mark.parametrize("layout", ["ragged", "frontier"])
+    def test_rejects_unknown_layout(self, layout):
         model = _model(seq=256)
         with pytest.raises(ValueError, match="cache_layout"):
             ContinuousBatchingEngine(
                 model, _params(model),
                 SamplingConfig(max_new_tokens=4), batch_size=2,
-                prompt_width=8, cache_layout="ragged",
+                prompt_width=8, cache_layout=layout,
             )
 
 
@@ -494,10 +397,9 @@ class TestPrefixCaching:
     registered prefix's KV is computed once per weight version; each
     admission prefills only its suffix and continues from the stored
     row. The keystone: completions equal the plain engine's on the
-    CONCATENATED prompt, in both layouts."""
+    CONCATENATED prompt."""
 
-    @pytest.mark.parametrize("layout", ["frontier", "per_row"])
-    def test_prefix_completions_match_concatenated(self, layout):
+    def test_prefix_completions_match_concatenated(self):
         model = _model(seq=256)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
@@ -505,7 +407,7 @@ class TestPrefixCaching:
         suffixes = [[7, 1], [3, 3, 8, 2], [19], [4, 4, 4, 4, 4, 4]]
         eng = ContinuousBatchingEngine(
             model, params, sampling, batch_size=2, prompt_width=16,
-            decode_chunk=4, cache_layout=layout,
+            decode_chunk=4,
         )
         pid = eng.register_prefix(prefix)
         for sfx in suffixes:
@@ -619,12 +521,11 @@ class TestPrefixCaching:
 class TestPagedLayout:
     """Paged KV-cache serving memory (models/kv_blocks.py): the block
     pool + per-request tables must be INVISIBLE to the math (bit-exact
-    with both dense layouts), shared prefix blocks must be freed and
+    with the dense layout), shared prefix blocks must be freed and
     refcounted correctly, and pool exhaustion must degrade into the
     bounded queue path — never a wedge, never corruption."""
 
-    @pytest.mark.parametrize("reference", ["per_row", "frontier"])
-    def test_paged_matches_dense_layouts(self, reference):
+    def test_paged_matches_dense_layout(self):
         model = _model(seq=128)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
@@ -638,7 +539,7 @@ class TestPagedLayout:
             return eng, eng.run(prompts)
 
         eng_p, got = run("paged")
-        _, want = run(reference)
+        _, want = run("per_row")
         for c, w in zip(got, want):
             assert c.tokens == w.tokens, f"uid {c.uid}"
             assert c.logprobs == w.logprobs, f"uid {c.uid}"
@@ -817,158 +718,19 @@ class TestPagedLayout:
             dst.submit_prefilled(payload)
 
 
-class TestSpeculativeServing:
-    """In-scheduler speculative decoding (SpeculativeBatchingEngine):
-    continuous batching where every round drafts k tokens and the
-    target verifies the window in one forward. Keystone: the greedy
-    stream is token-exact with the plain engine for ANY draft."""
-
-    def _spec_model(self, seq=512):
-        return _model(seq=seq)
-
-    def test_stream_token_exact_with_arbitrary_draft(self):
-        import dataclasses
-
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = self._spec_model()
-        params = _params(model)
-        draft = type(model)(
-            dataclasses.replace(model.config, num_layers=1)
-        )
-        d_params = draft.init(
-            jax.random.PRNGKey(7), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
-        sampling = SamplingConfig(max_new_tokens=10, temperature=0.0)
-        prompts = _mixed_prompts(10, rng_seed=2)
-        eng = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=3, prompt_width=16,
-            draft_model=draft, draft_params=d_params, num_draft=3,
-        )
-        got = eng.run(prompts)
-        assert [c.uid for c in got] == list(range(10))
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
-            assert len(c.logprobs) == len(c.tokens)
-
-    def test_self_draft_accepts_everything(self):
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = self._spec_model()
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        eng = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            num_draft=3,
-        )
-        prompts = _mixed_prompts(4, rng_seed=5)
-        for p in prompts:
-            eng.submit(p)
-        rounds = 0
-        rng = jax.random.PRNGKey(0)
-        while eng.pending:
-            rng, sub = jax.random.split(rng)
-            eng.step(sub)
-            rounds += 1
-        got = sorted(eng.drain_completions(), key=lambda c: c.uid)
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w
-        # self-draft greedy acceptance is 1.0 (identical programs up to
-        # float noise on CPU): 8 tokens need ceil(8/(k+1)) = 2 rounds
-        # per wave of 2 slots x 2 waves = ~4 rounds — plus the
-        # overlapped scheduler's cold-start and tail-drain step()
-        # calls, still far under the 8-rounds-per-wave (~16+ calls) a
-        # no-acceptance engine would need
-        assert rounds <= 8, rounds
-
-    def test_eos_and_cap_retire_with_slot_reuse(self):
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = self._spec_model()
-        params = _params(model)
-        sampling = SamplingConfig(
-            max_new_tokens=8, temperature=0.0, eos_id=3
-        )
-        prompts = _mixed_prompts(8, rng_seed=9)
-        eng = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            num_draft=2,
-        )
-        got = eng.run(prompts)
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
-        # per-request caps are greedy prefixes too
-        eng2 = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            num_draft=2,
-        )
-        eng2.submit(prompts[0], max_new_tokens=3)
-        short = eng2.run()[0]
-        assert short.tokens == want[0][:3]
-
-    def test_liveness_and_mode_guards(self):
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = self._spec_model(seq=64)
-        params = _params(model)
-        with pytest.raises(ValueError, match="liveness"):
-            SpeculativeBatchingEngine(
-                model, params,
-                SamplingConfig(max_new_tokens=16, temperature=0.0),
-                batch_size=2, prompt_width=16, num_draft=4,
-            )
-        with pytest.raises(ValueError, match="greedy-only"):
-            SpeculativeBatchingEngine(
-                model, params,
-                SamplingConfig(max_new_tokens=4, temperature=1.0),
-                batch_size=2, prompt_width=16,
-            )
-        eng = SpeculativeBatchingEngine(
-            model, params,
-            SamplingConfig(max_new_tokens=4, temperature=0.0),
-            batch_size=2, prompt_width=16, num_draft=2,
-        )
-        with pytest.raises(ValueError, match="prefix"):
-            eng.submit([1, 2], prefix_id=0)
-        with pytest.raises(ValueError, match="prefix"):
-            eng.register_prefix([1, 2])
-        stats = eng.stats()
-        assert stats["speculative_num_draft"] == 2
-        assert stats["self_drafting"] is True
-        # mixing the positional draft pair with the draft keywords is
-        # ambiguous — it must raise, never silently prefer one
-        greedy = SamplingConfig(max_new_tokens=4, temperature=0.0)
-        with pytest.raises(TypeError, match="don't mix"):
-            SpeculativeBatchingEngine(
-                model, params, model, params, greedy,
-                draft_params=_params(model, 1),
-                batch_size=2, prompt_width=16, num_draft=2,
-            )
-        with pytest.raises(TypeError, match="don't mix"):
-            SpeculativeBatchingEngine(
-                model, params, model, params, greedy,
-                draft_model=model,
-                batch_size=2, prompt_width=16, num_draft=2,
-            )
-
-
 class TestCancellation:
     """vLLM-abort semantics: a cancelled request stops consuming
     capacity — queued entries drop, decoding slots free for the next
     admission — and the survivors stay token-exact."""
 
-    @pytest.mark.parametrize("layout", ["frontier", "per_row"])
-    def test_cancel_queued_and_inflight(self, layout):
+    def test_cancel_queued_and_inflight(self):
         model = _model(seq=256)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=12, temperature=0.0)
         prompts = _mixed_prompts(6, rng_seed=4)
         eng = ContinuousBatchingEngine(
             model, params, sampling, batch_size=2, prompt_width=16,
-            decode_chunk=4, cache_layout=layout,
+            decode_chunk=4,
         )
         uids = [eng.submit(p) for p in prompts]
         rng = jax.random.PRNGKey(0)
@@ -984,31 +746,6 @@ class TestCancellation:
         assert set(got) == {uids[0], uids[2], uids[4], uids[5]}
         want = _reference_completions(model, params, prompts, sampling)
         for i in (0, 2, 4, 5):
-            assert got[uids[i]] == want[i], i
-
-    def test_cancel_speculative(self):
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = _model(seq=512)
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        prompts = _mixed_prompts(4, rng_seed=6)
-        eng = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            num_draft=2,
-        )
-        uids = [eng.submit(p) for p in prompts]
-        rng = jax.random.PRNGKey(0)
-        rng, sub = jax.random.split(rng)
-        eng.step(sub)
-        assert eng.cancel(uids[0]) is True
-        while eng.pending:
-            rng, sub = jax.random.split(rng)
-            eng.step(sub)
-        got = {c.uid: c.tokens for c in eng.drain_completions()}
-        want = _reference_completions(model, params, prompts, sampling)
-        assert uids[0] not in got
-        for i in (1, 2, 3):
             assert got[uids[i]] == want[i], i
 
     def test_daemon_timeout_cancels(self):
@@ -1047,26 +784,25 @@ class TestOverlappedPipeline:
     """The double-buffered scheduler round (overlap=True, the engine
     default): chunk N+1 dispatches before chunk N's tokens are read,
     with per-row cap/stop enforcement on the device. Keystones: the
-    emitted stream is BIT-IDENTICAL to the synchronous round in both
-    layouts; cancellation and async weight swaps landing mid-overlap
-    neither lose nor duplicate tokens."""
+    emitted stream is BIT-IDENTICAL to the synchronous round;
+    cancellation and async weight swaps landing mid-overlap neither
+    lose nor duplicate tokens."""
 
-    def _run(self, layout, overlap, prompts, caps=None, seq=256,
+    def _run(self, overlap, prompts, caps=None, seq=256,
              max_new=10, model=None, params=None):
         model = model or _model(seq=seq)
         params = params if params is not None else _params(model)
         sampling = SamplingConfig(max_new_tokens=max_new, temperature=0.0)
         eng = ContinuousBatchingEngine(
             model, params, sampling, batch_size=3, prompt_width=16,
-            decode_chunk=4, cache_layout=layout, overlap=overlap,
+            decode_chunk=4, overlap=overlap,
         )
         for i, p in enumerate(prompts):
             eng.submit(p, max_new_tokens=(caps or {}).get(i))
         out = eng.run()
         return out, eng
 
-    @pytest.mark.parametrize("layout", ["frontier", "per_row"])
-    def test_bit_identical_with_sync_round(self, layout):
+    def test_bit_identical_with_sync_round(self):
         """Mixed stream with per-request caps through both schedulers:
         every completion's tokens AND logprobs must match exactly —
         including rows the device-side budget stops mid-chunk."""
@@ -1077,10 +813,10 @@ class TestOverlappedPipeline:
         prompts = _mixed_prompts(10, rng_seed=21, lo=4, hi=9)
         caps = {1: 3, 4: 7, 9: 1}  # device-side budget paths
         sync_out, _ = self._run(
-            layout, False, prompts, caps, model=model, params=params
+            False, prompts, caps, model=model, params=params
         )
         ovl_out, eng = self._run(
-            layout, True, prompts, caps, model=model, params=params
+            True, prompts, caps, model=model, params=params
         )
         assert [c.uid for c in ovl_out] == [c.uid for c in sync_out]
         for o, s in zip(ovl_out, sync_out):
@@ -1100,15 +836,13 @@ class TestOverlappedPipeline:
         params = _params(model)
         prompts = [[5, 9, 2], [5, 9, 2]]
         out, _ = self._run(
-            "per_row", True, prompts, caps={1: 3}, model=model,
-            params=params,
+            True, prompts, caps={1: 3}, model=model, params=params,
         )
         full, capped = out[0], out[1]
         assert len(full.tokens) == 10 and len(capped.tokens) == 3
         assert capped.tokens == full.tokens[:3]
 
-    @pytest.mark.parametrize("layout", ["frontier", "per_row"])
-    def test_cancel_mid_overlap_no_lost_or_leaked_tokens(self, layout):
+    def test_cancel_mid_overlap_no_lost_or_leaked_tokens(self):
         """Cancel while a chunk is in flight: the freed slot's
         re-admitted request must start from ITS OWN first token (the
         uid snapshot drops the stale chunk's emissions), survivors
@@ -1119,7 +853,7 @@ class TestOverlappedPipeline:
         prompts = _mixed_prompts(6, rng_seed=4, lo=4, hi=9)
         eng = ContinuousBatchingEngine(
             model, params, sampling, batch_size=2, prompt_width=16,
-            decode_chunk=4, cache_layout=layout, overlap=True,
+            decode_chunk=4, overlap=True,
         )
         uids = [eng.submit(p) for p in prompts]
         rng = jax.random.PRNGKey(0)
@@ -1174,60 +908,6 @@ class TestOverlappedPipeline:
         assert eng.stats()["swap_pending"] is False
         assert eng.swap_latency_s is not None and eng.swap_latency_s > 0
 
-    def test_spec_async_swap_mid_overlap_follows_draft(self):
-        """Speculative overlapped round: an async target swap adopts
-        target+draft atomically at the drained pipeline and the stream
-        completes exactly (right count, no dup slots)."""
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = _model(seq=512)
-        p1, p2 = _params(model, 0), _params(model, 1)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        eng = SpeculativeBatchingEngine(
-            model, p1, sampling, batch_size=2, prompt_width=16,
-            num_draft=2, overlap=True,
-        )
-        prompts = _mixed_prompts(4, rng_seed=5)
-        uids = [eng.submit(p) for p in prompts]
-        rng = jax.random.PRNGKey(0)
-        rng, sub = jax.random.split(rng)
-        eng.step(sub)
-        assert eng._inflight
-        eng.set_params_async(p2)  # lands mid-overlap
-        while eng.pending:
-            rng, sub = jax.random.split(rng)
-            eng.step(sub)
-        assert eng.stats()["swap_pending"] is False
-        assert eng.draft_params is eng.params  # still self-following
-        got = eng.drain_completions()
-        assert sorted(c.uid for c in got) == uids
-        for c in got:
-            assert len(c.tokens) == 8
-            assert len(c.logprobs) == len(c.tokens)
-
-    @pytest.mark.parametrize("overlap", [False, True])
-    def test_spec_stream_exact_both_modes(self, overlap):
-        """The speculative scheduler stays token-exact with the plain
-        engine in both round modes (the pipeline unit is the round)."""
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        model = _model(seq=512)
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
-        prompts = _mixed_prompts(5, rng_seed=2, lo=4, hi=9)
-        eng = SpeculativeBatchingEngine(
-            model, params, sampling, batch_size=3, prompt_width=16,
-            num_draft=3, overlap=overlap,
-        )
-        eng.submit(prompts[0], max_new_tokens=4)  # device-cap path
-        for p in prompts[1:]:
-            eng.submit(p)
-        got = eng.run()
-        want = _reference_completions(model, params, prompts, sampling)
-        assert got[0].tokens == want[0][:4]
-        for c, w in zip(got[1:], want[1:]):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
-
     def test_auto_chunk_tuner_retunes_and_stays_exact(self):
         """auto_chunk: the tuner moves decode_chunk with the measured
         host fraction — and a retuned stream stays token-exact."""
@@ -1269,16 +949,6 @@ class TestOverlappedPipeline:
         for c, w in zip(got, want):
             assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
 
-        # frontier candidates respect the compaction liveness bound
-        eng_f = ContinuousBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            decode_chunk=4, cache_layout="frontier", auto_chunk=True,
-        )
-        L, mn = 256, 16
-        aligned = ContinuousBatchingEngine._align(16 + mn)
-        for c in eng_f._tuner.candidates:
-            assert aligned + max(mn, c) <= L
-
 
 class TestConstrainedDecoding:
     """Per-request allowed_tokens (RL action spaces / structured
@@ -1319,8 +989,7 @@ class TestConstrainedDecoding:
             last = nxt[:, 0].astype(jnp.float32)
         return out
 
-    @pytest.mark.parametrize("layout", ["frontier", "per_row"])
-    def test_constrained_matches_masked_reference(self, layout):
+    def test_constrained_matches_masked_reference(self):
         model = _model(seq=256)
         params = _params(model)
         sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
@@ -1328,7 +997,7 @@ class TestConstrainedDecoding:
         prompt = [5, 9, 2]
         eng = ContinuousBatchingEngine(
             model, params, sampling, batch_size=2, prompt_width=8,
-            decode_chunk=4, cache_layout=layout,
+            decode_chunk=4,
         )
         # one constrained and one unconstrained request share the batch
         uid_c = eng.submit(prompt, allowed_tokens=allowed)
@@ -1356,12 +1025,3 @@ class TestConstrainedDecoding:
             eng.submit([1], allowed_tokens=[])
         with pytest.raises(ValueError, match="outside"):
             eng.submit([1], allowed_tokens=[999])
-        from dlrover_tpu.models.serving import SpeculativeBatchingEngine
-
-        sp = SpeculativeBatchingEngine(
-            model, _params(model),
-            SamplingConfig(max_new_tokens=4, temperature=0.0),
-            batch_size=2, prompt_width=8, num_draft=2,
-        )
-        with pytest.raises(ValueError, match="allowed_tokens"):
-            sp.submit([1], allowed_tokens=[3])

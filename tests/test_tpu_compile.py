@@ -283,7 +283,6 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
         batch_size=16,
         prompt_width=64,
         decode_chunk=8,
-        cache_layout="per_row",
     )
 
     def described(tree):
@@ -300,7 +299,6 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
         .lower(
             described(params),
             described(engine._state),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
             described(jax.random.PRNGKey(0)),
         )
         .compile()

@@ -405,8 +405,11 @@ _RESTORE_LOCK = threading.Lock()
 
 
 def _restore_params(model, mesh, ckpt_dir: str):
-    """Flash-checkpoint → serving params (the trainer's pytree, no
-    conversion). Returns (step, params).
+    """Flash-checkpoint → the trainer's params pytree (float32, no
+    conversion: the engine rounds what its model reads rounded as it
+    takes them). Returns (step, params); the template and the rest of
+    the restored state go at the return, so the caller's ``params`` is
+    the only float32 tree left.
 
     - Template uses a STATELESS optimizer: ``_restore_into_template``
       only looks up the template's leaves, so skipping Adam moments in
@@ -760,10 +763,12 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                 swap_async = bool(body.get("async", False))
                 try:
                     step, params = reload_fn()
-                    if swap_async:
-                        daemon.swap_params_async(params)
-                    else:
-                        lat = daemon.swap_params(params)
+                    swap = (daemon.swap_params_async if swap_async
+                            else daemon.swap_params)
+                    lat = swap(params)
+                    # the engine holds its own (rounded) tree by now:
+                    # the float32 one goes with this reference
+                    del params
                 except Exception as e:  # noqa: BLE001
                     self._send(500, {"error": repr(e)[:200]})
                     return
@@ -929,6 +934,9 @@ def main(argv=None) -> int:
         kv_block_size=ns.kv_block_size,
         kv_pool_blocks=ns.kv_pool_blocks,
     )
+    # the engine holds the tree its programs read (the matrices rounded
+    # to the compute dtype); the float32 one goes with this reference
+    del params
     daemon = ServingDaemon(engine).start()
     httpd = serve(daemon, ns.port, reload_fn, replica_id=ns.replica_id,
                   role=ns.role)

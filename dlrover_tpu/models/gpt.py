@@ -494,6 +494,28 @@ class Block(nn.Module):
         return x
 
 
+def dtypes_read_by_name(params, names, dtype):
+    """A tree like ``params`` holding, for each leaf, the dtype its model
+    reads it in: ``dtype`` for a floating leaf whose own name is in
+    ``names`` (every use of it is ``leaf.astype(dtype)``), else the
+    leaf's own. What ``consumed_param_dtypes`` of a model returns."""
+
+    def one(path, leaf):
+        name = getattr(path[-1], "key", None)
+        if name in names and jnp.issubdtype(leaf.dtype, jnp.floating):
+            return jnp.dtype(dtype)
+        return jnp.dtype(leaf.dtype)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+# Every use of these is ``leaf.astype(cfg.dtype)``. LayerNorm's ``scale``
+# and ``bias`` are read in float32 and are not here.
+_READ_IN_COMPUTE_DTYPE = frozenset(
+    {"wte", "wpe", "wqkv", "wo", "w1", "b1", "w2", "b2", "lm_head"}
+)
+
+
 class GPT(nn.Module):
     """Decoder-only LM. ``__call__(tokens[B,T]) -> logits[B,T,V]``.
 
@@ -505,6 +527,16 @@ class GPT(nn.Module):
     """
 
     config: GPTConfig
+
+    @nn.nowrap
+    def consumed_param_dtypes(self, params):
+        """The dtype ``__call__`` reads each leaf of ``params`` in. A
+        holder that rounds a leaf to it once (the serving engine) asks
+        the arithmetic of the float32 tree: ``astype`` of a value
+        already in that dtype is the identity."""
+        return dtypes_read_by_name(
+            params, _READ_IN_COMPUTE_DTYPE, self.config.dtype
+        )
 
     @nn.compact
     def __call__(

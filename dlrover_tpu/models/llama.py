@@ -421,6 +421,14 @@ class LlamaBlock(nn.Module):
         return x
 
 
+# Every use of these is ``leaf.astype(cfg.dtype)``. RMSNorm's ``scale``
+# multiplies in float32 and the MoE's ``w_router`` is scored in float32:
+# they are not here.
+_READ_IN_COMPUTE_DTYPE = frozenset(
+    {"wte", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head"}
+)
+
+
 class Llama(nn.Module):
     """``__call__(tokens[B,T]) -> logits[B,T,V]``.
 
@@ -430,6 +438,16 @@ class Llama(nn.Module):
     """
 
     config: LlamaConfig
+
+    @nn.nowrap
+    def consumed_param_dtypes(self, params):
+        """The dtype ``__call__`` reads each leaf of ``params`` in (the
+        contract of ``GPT.consumed_param_dtypes``)."""
+        from .gpt import dtypes_read_by_name
+
+        return dtypes_read_by_name(
+            params, _READ_IN_COMPUTE_DTYPE, self.config.dtype
+        )
 
     @nn.compact
     def __call__(

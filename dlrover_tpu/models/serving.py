@@ -40,6 +40,13 @@ TPU shape — every device program is static-shape and compiled once:
   parameter argument of the jitted programs (same shapes — no
   recompile), so a WeightBus push lands at the next chunk boundary;
   ``swap_latency_s`` of the last swap is recorded.
+- **The programs get the parameters as the model consumes them**: the
+  engine rounds each matrix to the model's compute dtype once per
+  weight version (construction, each swap: the ``serve.params_cast``
+  span, ``params_casts`` / ``params_device_bytes`` in ``stats()``) and
+  holds that tree, not the float32 one it was given; no program rounds
+  a matrix again, and the arithmetic asked of them is the float32
+  tree's (bit for bit under test on the CPU).
 - **Overlapped (double-buffered) round** (``overlap=True``, the
   default): each ``step()`` dispatches chunk N+1 *before* it syncs and
   retires chunk N, so the device queue never drains between rounds and
@@ -79,6 +86,7 @@ import numpy as np
 
 from ..attribution.phases import PhaseAccumulator
 from ..chaos import faults
+from ..observability.spans import span
 from . import kv_blocks
 from .generation import (
     SamplingConfig,
@@ -229,7 +237,18 @@ class ContinuousBatchingEngine:
         kv_block_size: int = 16,
         kv_pool_blocks: int = 0,
     ):
-        """With ``mesh`` (+ optional logical-axis ``rules``) every
+        """``params`` is the tree as a trainer or a checkpoint holds it
+        (float32). The engine does not keep it: ``engine.params`` is the
+        tree its programs get, each leaf in the dtype the model reads it
+        in (``model.consumed_param_dtypes``: for ``GPT`` and ``Llama``
+        the matrices, embeddings and MLP biases in ``cfg.dtype``, the
+        norms and a router in float32), rounded once here and once per
+        adopted swap (:meth:`_as_consumed`). The model rounds each of
+        those leaves before every use anyway, so the arithmetic is the
+        float32 tree's; a caller that wants the memory back drops its
+        own reference to ``params``.
+
+        With ``mesh`` (+ optional logical-axis ``rules``) every
         device program runs SPMD over it: pass params already placed in
         their trainer shardings (tp/fsdp) and the whole engine serves a
         model bigger than one chip — same scheduler, XLA inserts the
@@ -287,7 +306,6 @@ class ContinuousBatchingEngine:
                 f"max_seq_len {L}"
             )
         self.model = model
-        self.params = params
         self.s = sampling
         self.mesh = mesh
         self.rules = rules
@@ -306,6 +324,9 @@ class ContinuousBatchingEngine:
         # folded into the next step()'s return so the per-call count
         # never silently drops a chunk
         self._drained_uncounted = 0
+        self.params = self._as_consumed(params)
+        # weight versions held so far: this one, then one a swap adopted
+        self.params_casts = 1
         self.swap_latency_s: Optional[float] = None
         self._pending_params = None  # in-flight async weight swap
         self._pending_t0 = 0.0
@@ -846,6 +867,45 @@ class ContinuousBatchingEngine:
         )
         return uid
 
+    def _as_consumed(self, params, like=None):
+        """The tree the programs get, from ``params`` as a trainer, a
+        checkpoint or a WeightBus push holds them: each leaf in the dtype
+        the model says it reads it in (``model.consumed_param_dtypes``:
+        a float32 matrix that every use rounds to bf16 is rounded here,
+        once per weight version), on ``like``'s leaf's sharding where
+        ``like`` is given and on its own otherwise. The programs find the
+        rounding they did in every call already done: the arithmetic
+        asked of them is the float32 tree's (the CPU tests hold the
+        outputs to it bit for bit; a compiler may still sum a product
+        of a leaf that arrives rounded in another order). Leaf by leaf:
+        the float32 copy of a host leaf goes as soon as its cast is
+        enqueued, and nothing here keeps a leaf of ``params`` past the
+        return. A leaf that already has its dtype is taken as it is; a
+        model with no such method is served from ``params`` as given."""
+        dtypes_of = getattr(self.model, "consumed_param_dtypes", None)
+        if dtypes_of is None:
+            return params if like is None else _device_put_like(params, like)
+        cast = dict(leaves=0, bytes_in=0, bytes_out=0)
+
+        def one(leaf, dt, like_leaf=None):
+            sharding = getattr(like_leaf, "sharding", None)
+            if sharding is not None or not isinstance(leaf, jax.Array):
+                leaf = jax.device_put(leaf, sharding)
+            if leaf.dtype == dt:
+                return leaf
+            cast["leaves"] += 1
+            cast["bytes_in"] += leaf.nbytes
+            cast["bytes_out"] += leaf.size * dt.itemsize
+            return leaf.astype(dt)
+
+        with span("serve.params_cast") as sp:
+            held = jax.tree_util.tree_map(
+                one, params, dtypes_of(params),
+                *(() if like is None else (like,)),
+            )
+            sp.set(**cast)
+        return held
+
     def set_params(self, params) -> float:
         """Hot-swap weights between chunks (same pytree shapes — no
         recompile). Returns the swap latency: the time to make the new
@@ -865,7 +925,9 @@ class ContinuousBatchingEngine:
         ``step()`` boundary where every leaf has landed — a WeightBus
         push never stalls the rollout loop (blocking for the whole
         transfer mid-decode is the exact stall this avoids; its
-        duration on the v5e: not measured). A second call
+        duration on the v5e: PR 31, docs/generation.md). The payload is
+        rounded to the held dtypes as it lands (:meth:`_as_consumed`), so
+        "landed" means the rounded tree is ready. A second call
         before adoption supersedes the first (latest weights win).
 
         A transfer that fails to even enqueue (mismatched payload, a
@@ -876,7 +938,7 @@ class ContinuousBatchingEngine:
         self._pending_t0 = time.perf_counter()
         try:
             faults.inject("serving.swap")
-            self._pending_params = _device_put_like(params, self.params)
+            self._pending_params = self._as_consumed(params, self.params)
         except Exception as e:  # noqa: BLE001 — swap aborted, not served
             self._abort_pending_swap(e)
 
@@ -913,6 +975,7 @@ class ContinuousBatchingEngine:
         self._drained_uncounted += self._drain_inflight()
         self.params = pending
         self._pending_params = None
+        self.params_casts += 1
         # stored prefix KV and eager-prefilled rows encode the OLD
         # weights — rebuild lazily / re-prefill at admission
         self._prefix_states.clear()
@@ -1630,6 +1693,14 @@ class ContinuousBatchingEngine:
             "kv_cache_int8": bool(
                 getattr(self.model.config, "kv_cache_int8", False)
             ),
+            # what the engine holds on the device for the programs (the
+            # matrices in the model's compute dtype), and how many weight
+            # versions it has held: start-up, then one a swap adopted
+            "params_device_bytes": sum(
+                leaf.nbytes
+                for leaf in jax.tree_util.tree_leaves(self.params)
+            ),
+            "params_casts": self.params_casts,
             "last_swap_latency_s": self.swap_latency_s,
             "swap_pending": self._pending_params is not None,
             "swap_failures": self.swap_failures,

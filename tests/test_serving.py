@@ -317,11 +317,15 @@ class TestWeightSwap:
             (comp,) = eng.drain_completions()
             return comp.tokens, comp.logprobs, eng
 
+        def swap_async(e):
+            e.set_params_async(p2)
+            # the payload is rounded on the device as it lands: wait for
+            # that here (step() never does), so that adoption happens
+            # at the top of step i=2 — the blocking swap's boundary
+            jax.block_until_ready(e._pending_params)
+
         blk_toks, blk_lps, _ = run(lambda e: e.set_params(p2))
-        # async: same swap point; host-backend transfer completes
-        # immediately, so adoption happens at the top of step i=2 —
-        # the same effective boundary as the blocking swap
-        asy_toks, asy_lps, eng = run(lambda e: e.set_params_async(p2))
+        asy_toks, asy_lps, eng = run(swap_async)
         assert asy_toks == blk_toks
         np.testing.assert_allclose(asy_lps, blk_lps, rtol=1e-5, atol=1e-6)
         # adoption bookkeeping: pending cleared, latency recorded
@@ -899,8 +903,14 @@ class TestOverlappedPipeline:
             (comp,) = eng.drain_completions()
             return comp, eng
 
+        def swap_async(e):
+            e.set_params_async(p2)
+            # the rounding of the payload is a device computation the
+            # next step() would not wait for: the same boundary needs it
+            jax.block_until_ready(e._pending_params)
+
         blk, _ = run(lambda e: e.set_params(p2))
-        asy, eng = run(lambda e: e.set_params_async(p2))
+        asy, eng = run(swap_async)
         assert len(blk.tokens) == 16 and asy.tokens == blk.tokens
         np.testing.assert_allclose(
             asy.logprobs, blk.logprobs, rtol=1e-5, atol=1e-6
@@ -1025,3 +1035,286 @@ class TestConstrainedDecoding:
             eng.submit([1], allowed_tokens=[])
         with pytest.raises(ValueError, match="outside"):
             eng.submit([1], allowed_tokens=[999])
+
+
+# -- the engine holds its matrices in the dtype the model computes in ------
+
+
+def _family(name):
+    from dlrover_tpu.models.llama import Llama, LlamaConfig
+
+    if name == "gpt":
+        return _model(seq=128)
+    kw = dict(vocab_size=64, max_seq_len=128)
+    if name == "llama_moe":
+        kw.update(num_experts=4, moe_every=2)
+    return Llama(LlamaConfig.tiny(**kw))
+
+
+def _says_nothing(model):
+    """The same model with no ``consumed_param_dtypes``: the engine serves
+    it from the float32 tree, through the same programs — the parent
+    commit's server, and ``decode_apply`` over the float32 tree itself."""
+    import types
+
+    return types.SimpleNamespace(
+        config=model.config, apply=model.apply, init=model.init
+    )
+
+
+def _stream(model, params, overlap=True, swap=None, prompts=None):
+    """(tokens, logprobs) of every request, in uid order; ``swap(eng)``
+    runs once before the stream."""
+    eng = ContinuousBatchingEngine(
+        model, params, SamplingConfig(max_new_tokens=10, temperature=0.0),
+        batch_size=3, prompt_width=16, decode_chunk=4, overlap=overlap,
+    )
+    if swap is not None:
+        swap(eng)
+    done = eng.run(prompts or _mixed_prompts(7, rng_seed=5))
+    return [(c.tokens, c.logprobs) for c in done], eng
+
+
+def _named(model, params):
+    return jax.tree.leaves(
+        jax.tree.map(
+            lambda leaf, dt: leaf.dtype != dt,
+            params, model.consumed_param_dtypes(params),
+        )
+    )
+
+
+class TestHeldParams:
+    @pytest.mark.parametrize("name", ["gpt", "llama", "llama_moe"])
+    def test_logits_equal_the_float32_trees_bit_for_bit(self, name):
+        """``decode_apply`` under jit, the float32 tree against the tree
+        the engine holds: a prompt's pass and a one-token step give the
+        same logits and the same cache in every bit."""
+        from dlrover_tpu.models.generation import decode_apply, init_cache
+
+        model = _family(name)
+        params = _params(model)
+        eng = ContinuousBatchingEngine(
+            model, params, SamplingConfig(max_new_tokens=4, temperature=0.0),
+            batch_size=2, prompt_width=8,
+        )
+        fn = jax.jit(
+            lambda p, cache, toks, pos, kv, slots=None: decode_apply(
+                model, p, cache, toks, pos, kv, cache_slots=slots
+            )
+        )
+        L = model.config.max_seq_len
+        toks = jnp.asarray([[3, 9, 4, 1, 7, 2], [5, 5, 8, 2, 6, 1]], jnp.int32)
+        pos = jnp.broadcast_to(jnp.arange(6), (2, 6))
+        kv = jnp.zeros((2, L), bool).at[:, :6].set(True)
+        one_tok = jnp.asarray([[11], [12]], jnp.int32)
+        one_pos = jnp.full((2, 1), 6, jnp.int32)
+        kv1 = kv.at[:, 6].set(True)
+        slots = jnp.full((2,), 6, jnp.int32)
+        outs = []
+        for tree in (params, eng.params):
+            logits, cache = fn(tree, init_cache(model, 2), toks, pos, kv)
+            step_logits, cache = fn(tree, cache, one_tok, one_pos, kv1, slots)
+            outs.append(jax.tree.leaves((logits, step_logits, cache)))
+        for want, got in zip(*outs):
+            assert want.dtype == got.dtype
+            assert np.asarray(want).tobytes() == np.asarray(got).tobytes()
+
+    @pytest.mark.parametrize("overlap", [False, True], ids=["sync", "overlapped"])
+    @pytest.mark.parametrize("name", ["gpt", "llama"])
+    def test_stream_equals_the_float32_trees(self, name, overlap):
+        """Greedy tokens and their log-probabilities through the engine
+        equal, bit for bit, those of the same programs over the float32
+        tree (the model that says nothing: served as given)."""
+        model = _family(name)
+        params = _params(model)
+        got, eng = _stream(model, params, overlap)
+        want, ref = _stream(_says_nothing(model), params, overlap)
+        assert all(
+            leaf.dtype == jnp.float32 for leaf in jax.tree.leaves(ref.params)
+        )
+        assert any(
+            leaf.dtype == jnp.bfloat16 for leaf in jax.tree.leaves(eng.params)
+        )
+        assert got == want  # lists of ints and of floats: exact
+
+    @pytest.mark.parametrize("name", ["gpt", "llama", "llama_moe"])
+    def test_held_tree_rounds_what_the_model_named_and_nothing_else(
+        self, name
+    ):
+        model = _family(name)
+        params = _params(model)
+        eng = ContinuousBatchingEngine(
+            model, params, SamplingConfig(max_new_tokens=4, temperature=0.0),
+            batch_size=2, prompt_width=8,
+        )
+        named = _named(model, params)
+        assert any(named) and not all(named)
+        for given, held, is_named in zip(
+            jax.tree.leaves(params), jax.tree.leaves(eng.params), named
+        ):
+            if is_named:
+                assert given.dtype == jnp.float32
+                assert held.dtype == model.config.dtype == jnp.bfloat16
+                np.testing.assert_array_equal(
+                    np.asarray(held), np.asarray(given.astype(jnp.bfloat16))
+                )
+            else:  # LayerNorm / RMSNorm, the router: the leaf itself
+                assert held is given and held.dtype == jnp.float32
+        # the float32 tree is the caller's still: nothing was consumed
+        assert all(
+            not leaf.is_deleted() and leaf.dtype == jnp.float32
+            for leaf in jax.tree.leaves(params)
+        )
+
+    def test_gpt_names_every_matrix_and_llama_keeps_its_router(self):
+        model = _family("gpt")
+        params = _params(model)
+        for leaf, is_named in zip(jax.tree.leaves(params), _named(model, params)):
+            assert is_named or leaf.ndim == 1  # 99.97% of the bytes at XL
+        moe = _family("llama_moe")
+        dtypes = moe.consumed_param_dtypes(_params(moe))
+        flat = {
+            jax.tree_util.keystr(path): dt
+            for path, dt in jax.tree_util.tree_leaves_with_path(dtypes)
+        }
+        routers = [k for k in flat if "w_router" in k]
+        scales = [k for k in flat if "scale" in k]
+        assert routers and scales
+        assert all(flat[k] == jnp.float32 for k in routers + scales)
+
+    def test_a_model_that_says_nothing_is_served_from_the_tree_as_given(self):
+        model = _family("gpt")
+        params = _params(model)
+        eng = ContinuousBatchingEngine(
+            _says_nothing(model), params,
+            SamplingConfig(max_new_tokens=4, temperature=0.0),
+            batch_size=2, prompt_width=8,
+        )
+        assert eng.params is params
+        assert eng.stats()["params_device_bytes"] == sum(
+            leaf.nbytes for leaf in jax.tree.leaves(params)
+        )
+
+    @pytest.mark.parametrize("how", ["set_params", "set_params_async"])
+    @pytest.mark.parametrize("name", ["gpt", "llama"])
+    def test_a_float32_payload_is_adopted_rounded(self, name, how):
+        """A swap's payload arrives as a trainer or a WeightBus holds it
+        (float32, host arrays); the engine adopts the rounded tree, and
+        serves what an engine built from that payload serves."""
+        model = _family(name)
+        p1, p2 = _params(model, 0), _params(model, 1)
+        payload = jax.tree.map(np.asarray, jax.device_get(p2))
+
+        def swap(eng):
+            getattr(eng, how)(payload)
+            if how == "set_params_async":  # the probe never blocks
+                assert eng.stats()["swap_pending"] is True
+                jax.block_until_ready(eng._pending_params)
+                assert eng.poll_pending_swap() is True
+            assert eng.stats()["swap_pending"] is False
+
+        got, eng = _stream(model, p1, swap=swap)
+        want, built = _stream(model, p2)
+        assert got == want
+        for held, twin, is_named in zip(
+            jax.tree.leaves(eng.params), jax.tree.leaves(built.params),
+            _named(model, p2),
+        ):
+            assert held.dtype == twin.dtype
+            assert (held.dtype == jnp.bfloat16) == is_named
+            np.testing.assert_array_equal(np.asarray(held), np.asarray(twin))
+        assert eng.stats()["params_casts"] == 2
+
+    def test_a_payload_in_the_held_dtypes_is_adopted_as_it_is(self):
+        model = _family("gpt")
+        p1, p2 = _params(model, 0), _params(model, 1)
+        want, built = _stream(model, p2)
+        got, eng = _stream(
+            model, p1, swap=lambda e: e.set_params(built.params)
+        )
+        assert got == want
+        for held, given in zip(
+            jax.tree.leaves(eng.params), jax.tree.leaves(built.params)
+        ):
+            assert held.dtype == given.dtype
+            assert (
+                held.unsafe_buffer_pointer() == given.unsafe_buffer_pointer()
+            )
+
+    def test_second_swap_before_adoption_supersedes_the_first(self):
+        model = _family("gpt")
+        p1, p2, p3 = (_params(model, s) for s in range(3))
+
+        def swap(eng):
+            eng.set_params_async(p2)
+            eng.set_params_async(p3)
+            assert eng.stats()["swap_pending"] is True
+            jax.block_until_ready(eng._pending_params)  # the probe never blocks
+            assert eng.poll_pending_swap() is True
+
+        got, eng = _stream(model, p1, swap=swap)
+        want, _ = _stream(model, p3)
+        assert got == want
+        # two trees were rounded, one was adopted
+        assert eng.stats()["params_casts"] == 2
+        assert eng.stats()["swap_pending"] is False
+
+    def test_a_failed_swap_leaves_the_old_rounded_weights_serving(self):
+        model = _family("gpt")
+        p1 = _params(model, 0)
+        broken = dict(_params(model, 1))
+        broken.pop("wte")  # not the tree the programs take
+
+        held = {}
+
+        def swap(eng):
+            held["before"] = jax.tree.leaves(eng.params)
+            eng.set_params_async(broken)
+            assert eng.stats()["swap_pending"] is False
+
+        got, eng = _stream(model, p1, swap=swap)
+        want, _ = _stream(model, p1)
+        assert got == want
+        stats = eng.stats()
+        assert stats["swap_failures"] == 1 and stats["last_swap_error"]
+        assert stats["params_casts"] == 1
+        assert all(
+            a is b for a, b in zip(held["before"], jax.tree.leaves(eng.params))
+        )
+
+    def test_held_leaves_keep_their_shardings_under_a_mesh(self):
+        """Two CPU devices, tp=2: the rounded leaves sit where the
+        trainer's float32 leaves sat, at start-up and after a swap whose
+        payload is host arrays."""
+        from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+        from dlrover_tpu.parallel.train_step import (
+            default_optimizer,
+            init_train_state,
+        )
+
+        model = _model(seq=128)
+        mesh = build_mesh(MeshConfig(dp=1, tp=2), jax.devices()[:2])
+        state, _ = init_train_state(
+            model, jnp.zeros((4, 8), jnp.int32), mesh, default_optimizer()
+        )
+        eng = ContinuousBatchingEngine(
+            model, state.params, SamplingConfig(max_new_tokens=4, temperature=0.0),
+            batch_size=2, prompt_width=8, mesh=mesh,
+        )
+
+        def check():
+            split = 0
+            for given, held, is_named in zip(
+                jax.tree.leaves(state.params), jax.tree.leaves(eng.params),
+                _named(model, state.params),
+            ):
+                assert held.sharding.is_equivalent_to(given.sharding, given.ndim)
+                assert (held.dtype == jnp.bfloat16) == is_named
+                split += is_named and not held.sharding.is_fully_replicated
+            assert split  # some rounded leaf really is split over tp
+
+        check()
+        eng.set_params(jax.tree.map(np.asarray, jax.device_get(state.params)))
+        assert eng.stats()["params_casts"] == 2
+        check()

@@ -137,3 +137,51 @@ class TestShardedGeneration:
         out, omask, logp = fn(params, toks, mask, jax.random.PRNGKey(1))
         assert out.shape == (2, 3)
         assert np.isfinite(np.asarray(logp)).all()
+
+
+class TestShardedEngineHoldsRoundedLeaves:
+    """The serving engine over a two-device mesh: what it holds (the
+    matrices in the model's compute dtype) sits on the shardings the
+    trainer gave the float32 leaves, for both served families, and
+    serves the float32 tree's own greedy stream."""
+
+    @pytest.mark.parametrize("family", ["gpt", "llama"])
+    def test_rounded_leaves_keep_shardings_and_the_stream(self, family):
+        import types
+
+        from dlrover_tpu.models.serving import ContinuousBatchingEngine
+
+        model = (
+            GPT(GPTConfig.tiny()) if family == "gpt"
+            else Llama(LlamaConfig.tiny())
+        )
+        mesh = build_mesh(MeshConfig(dp=1, tp=2), jax.devices()[:2])
+        params, _ = _sharded_params(model, mesh)
+        sampling = SamplingConfig(max_new_tokens=6, temperature=0.0)
+        prompts = [[3, 7, 11], [9, 1], [4, 4, 4, 2]]
+
+        def run(m):
+            eng = ContinuousBatchingEngine(
+                m, params, sampling, batch_size=2, prompt_width=8,
+                decode_chunk=2, mesh=mesh,
+            )
+            return [(c.tokens, c.logprobs) for c in eng.run(prompts)], eng
+
+        got, eng = run(model)
+        silent = types.SimpleNamespace(
+            config=model.config, apply=model.apply, init=model.init
+        )
+        want, _ = run(silent)  # the float32 tree through the same programs
+        assert got == want
+        dtypes = jax.tree.leaves(model.consumed_param_dtypes(params))
+        rounded_and_split = 0
+        for given, held, dt in zip(
+            jax.tree.leaves(params), jax.tree.leaves(eng.params), dtypes
+        ):
+            assert held.dtype == dt
+            assert held.sharding.is_equivalent_to(given.sharding, given.ndim)
+            rounded_and_split += (
+                held.dtype != given.dtype
+                and not held.sharding.is_fully_replicated
+            )
+        assert rounded_and_split

@@ -707,3 +707,67 @@ def test_the_agents_periodic_jobs_are_incident_spans(tmp_path, monkeypatch):
     assert names == [("agent_metric_tick", "begin"), ("agent_metric_tick", "end")]
     assert seen[1]["content"]["gauges"] == 1
     assert seen[1]["content"]["duration_s"] >= 0.0
+
+
+# -- the serving engine's held parameters (PR 31) -----------------------------
+
+
+@pytest.fixture(scope="module")
+def cast_session(job):
+    """An engine built from a float32 tree, then one swap of a float32
+    host payload, inside a profiler session."""
+    before = booked(spans.process_accumulator())
+
+    def body():
+        eng = tiny_engine(True)
+        given = eng.model.init(
+            jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        first = dict(eng.stats())
+        eng.set_params(jax.tree.map(np.asarray, jax.device_get(given)))
+        return eng, given, first
+
+    (eng, given, first), found = record(job / "trace_cast", body)
+    return dict(spans=found, engine=eng, given=given, first=first,
+                before=before, after=booked(spans.process_accumulator()))
+
+
+def test_params_cast_is_on_the_trace_with_what_it_rounded(cast_session):
+    """One ``serve.params_cast`` at construction and one where the swap's
+    payload lands, each saying how many leaves it rounded and the bytes
+    in and out; both booked in the process's totals, none in the
+    engine's phases (``serve_host_frac`` sums those)."""
+    got = cast_session
+    recorded = got["spans"]["serve.params_cast"]
+    assert len(recorded) == 2
+    given, eng = got["given"], got["engine"]
+    named = [
+        leaf for leaf, dt in zip(
+            jax.tree.leaves(given),
+            jax.tree.leaves(eng.model.consumed_param_dtypes(given)),
+        ) if leaf.dtype != dt
+    ]
+    for _, _, _, stats in recorded:
+        assert int(stats["leaves"]) == len(named) > 0
+        assert int(stats["bytes_in"]) == sum(leaf.nbytes for leaf in named)
+        assert int(stats["bytes_out"]) == int(stats["bytes_in"]) // 2
+    total0, count0 = got["before"].get("serve.params_cast", (0.0, 0))
+    total1, count1 = got["after"]["serve.params_cast"]
+    assert count1 - count0 == 2
+    on_trace = sum(e - s for _, s, e, _ in recorded) / 1e9
+    assert total1 - total0 == pytest.approx(on_trace, abs=1e-3)
+    assert "serve.params_cast" not in eng.phases.stats()
+
+
+def test_stats_say_what_the_engine_holds_and_how_often_it_rounded(cast_session):
+    eng, first = cast_session["engine"], cast_session["first"]
+    held = sum(leaf.nbytes for leaf in jax.tree.leaves(eng.params))
+    given = sum(leaf.nbytes for leaf in jax.tree.leaves(cast_session["given"]))
+    assert first["params_casts"] == 1
+    assert first["params_device_bytes"] == held < given
+    stats = eng.stats()
+    assert stats["params_casts"] == 2
+    assert stats["params_device_bytes"] == held
+    eng.set_params_async({"not": "the tree"})  # aborted: nothing adopted
+    assert eng.stats()["swap_failures"] == 1
+    assert eng.stats()["params_casts"] == 2

@@ -271,10 +271,15 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
 
     cfg = dataclasses.replace(GPTConfig.gpt2_small(), use_remat=False)
     model = GPT(cfg)
-    params = jax.eval_shape(
-        lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
+    # the engine rounds what it is given, so it is given arrays: zeros
+    # in the shapes of the model's own init
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+            )["params"]
+        ),
     )
     engine = ContinuousBatchingEngine(
         model,
@@ -293,11 +298,12 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
 
     row = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((1, 64), jnp.bool_, sharding=one_chip)
-    prefill = engine._prefill_fn.lower(described(params), row, mask).compile()
+    held = described(engine.params)  # the tree the programs get
+    prefill = engine._prefill_fn.lower(held, row, mask).compile()
     chunk = (
         engine._chunk_for(engine.d)
         .lower(
-            described(params),
+            held,
             described(engine._state),
             described(jax.random.PRNGKey(0)),
         )
@@ -307,3 +313,7 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
         assert _device_bytes(compiled) < V5E_HBM_BYTES
     # 16 slots of full-length KV plus the weights: what stays resident
     assert chunk.memory_analysis().argument_size_in_bytes < V5E_HBM_BYTES // 2
+    # the matrices arrive rounded: no program turns a float32 embedding
+    # into a bf16 one before its first token
+    for compiled in (prefill, chunk):
+        assert "f32[50304,768]" not in compiled.as_text()

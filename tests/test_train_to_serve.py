@@ -106,6 +106,47 @@ class TestTrainToServe:
             assert c.tokens == [int(t) for t in live_np[i]], (
                 i, c.tokens, live_np[i]
             )
+        # the server rounded its own copy of the matrices; what the
+        # checkpoint restored (and a trainer would resume from) is the
+        # float32 state still
+        assert any(
+            leaf.dtype == jnp.bfloat16 for leaf in jax.tree.leaves(eng.params)
+        )
+        for kept, trained in zip(
+            jax.tree.leaves(restored.params), jax.tree.leaves(state.params)
+        ):
+            assert kept.dtype == trained.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(kept), np.asarray(trained))
+
+    def test_restored_float32_params_swap_into_a_running_engine(
+        self, tmp_path
+    ):
+        """``/v1/weights/reload``'s path: the trainer's float32 params
+        land in a server that holds another version, rounded as they
+        land; it then serves what a server built from them serves, log
+        probabilities included."""
+        from dlrover_tpu.models.serving import ContinuousBatchingEngine
+
+        model, _, state = _train_some(tmp_path)
+        _, _, fresh = _train_some(tmp_path, steps=0)
+        sampling = SamplingConfig(max_new_tokens=5, temperature=0.0)
+
+        def serve(params, swap_to=None):
+            eng = ContinuousBatchingEngine(
+                model, params, sampling, batch_size=2, prompt_width=8,
+                decode_chunk=4,
+            )
+            if swap_to is not None:
+                eng.set_params(swap_to)
+            return [(c.tokens, c.logprobs) for c in eng.run([[5, 9], [3]])], eng
+
+        want, _ = serve(state.params)
+        got, eng = serve(fresh.params, swap_to=state.params)
+        assert got == want
+        assert eng.stats()["params_casts"] == 2
+        assert all(
+            leaf.dtype == jnp.float32 for leaf in jax.tree.leaves(state.params)
+        )
 
     def test_orbax_export_feeds_generation(self, tmp_path):
         """The Orbax-interop artifact serves too: a consumer with only

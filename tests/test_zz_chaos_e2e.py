@@ -213,6 +213,7 @@ class TestSwapFailureMidOverlap:
         assert "died in flight" in stats["last_swap_error"]
 
         eng.set_params_async(params)
+        jax.block_until_ready(eng._pending_params)  # the probe never blocks
         assert eng._maybe_adopt_pending() is True
 
 

@@ -38,6 +38,7 @@ Run (CPU smoke):
 """
 
 import argparse
+import inspect
 import json
 import os
 import queue
@@ -51,6 +52,10 @@ from typing import Optional
 from ..common.constants import ENV_KNOBS
 from ..common.log import logger
 from ..observability.spans import span
+
+# A round of the loaded engine is a decode chunk long (~0.1 s), a burst of
+# prefills a few times that: an iteration this long is a standstill.
+SLOW_ITERATION_S = 1.0
 
 __all__ = ["ServingDaemon", "main"]
 
@@ -338,6 +343,20 @@ class ServingDaemon:
             elif streaming:
                 self.served += 1
 
+    def _log_slow_iteration(self, began, stepped, phases) -> None:
+        """A server under load that stands still for a second or more
+        (seen once in ~8 benchmark runs, cause not found: PERF.md section 7)
+        says here where the time went: the inbox, the engine's round, and
+        the round's own phases."""
+        now, after = time.perf_counter(), self.eng.phases.totals()
+        spent = {k: round(v - phases.get(k, 0.0), 3) for k, v in after.items()
+                 if v - phases.get(k, 0.0) >= 0.001}
+        logger.warning(
+            "slow serving iteration: %.3f s (inbox %.3f, round %.3f; phases %s; %s)",
+            now - began, stepped - began, now - stepped, spent,
+            {k: self.eng.stats()[k] for k in ("busy_slots", "queue_depth", "inflight_chunks")},
+        )
+
     def _fail_all(self, exc: Exception) -> None:
         """Resolve every in-flight and queued future with ``exc`` — a
         dead driver must fail fast, not leave clients blocking out
@@ -366,10 +385,15 @@ class ServingDaemon:
         while not self._stop.is_set():
             try:
                 # when idle, block briefly on the inbox, don't spin
-                self._drain_inbox(block=not self.eng.pending)
+                busy = bool(self.eng.pending)
+                began, phases = time.perf_counter(), self.eng.phases.totals()
+                self._drain_inbox(block=not busy)
+                stepped = time.perf_counter()
                 if self.eng.pending:
                     self._rng, sub = jax.random.split(self._rng)
                     self.eng.step(sub)
+                    if busy and time.perf_counter() - began > SLOW_ITERATION_S:
+                        self._log_slow_iteration(began, stepped, phases)
                 else:
                     # idle-server swap convergence: step() (which
                     # adopts landed async swaps at chunk boundaries)
@@ -391,14 +415,19 @@ class ServingDaemon:
 # ---------------------------------------------------------------------------
 
 
-def _build_model(family: str, config: dict):
-    if family == "llama":
-        from ..models.llama import Llama, LlamaConfig
+def _init_params(model):
+    """Smoke-mode weights, made in the dtypes the engine holds (one jitted
+    init whose outputs are already rounded: ``models/build.py``), so that
+    start-up's peak is the held tree and never a float32 one beside it."""
+    import jax
 
-        return Llama(LlamaConfig(**config))
-    from ..models.gpt import GPT, GPTConfig
+    from ..models.build import init_params_as_consumed
 
-    return GPT(GPTConfig(**config))
+    with span("serve.params_init") as sp:
+        params = init_params_as_consumed(model, jax.random.PRNGKey(0))
+        leaves = jax.tree_util.tree_leaves(params)
+        sp.set(leaves=len(leaves), bytes=sum(leaf.nbytes for leaf in leaves))
+    return params
 
 
 _RESTORE_LOCK = threading.Lock()
@@ -804,17 +833,29 @@ DEFAULT_CONFIG = dict(
 
 
 def main(argv=None) -> int:
+    from ..models.build import FAMILIES, build_model
+
     ap = argparse.ArgumentParser(
         prog="tpurun-serve",
         description="rollout/serving daemon over the continuous engine",
     )
-    ap.add_argument("--family", choices=["gpt", "llama"], default="gpt")
+    ap.add_argument(
+        "--family", choices=sorted(FAMILIES), default="gpt",
+        help="model family, from the registry trainer and server share "
+        "(models/build.py); one with no decode path is refused",
+    )
     ap.add_argument(
         "--config", default="",
-        help="model config as JSON (kwargs of GPTConfig/LlamaConfig); "
-        "default is a small smoke model",
+        help="model config as JSON (the fields of the family's config "
+        "class); default is a small smoke model",
     )
-    ap.add_argument("--ckpt-dir", default="", help="flash ckpt to restore")
+    ap.add_argument(
+        "--ckpt-dir", default="",
+        help="flash ckpt to restore. The restore goes through a float32 "
+        "template of the trainer's state: a model whose float32 "
+        "parameters do not fit the device beside the tree the engine "
+        "holds cannot be restored this way yet",
+    )
     ap.add_argument("--port", type=int, default=8311)
     ap.add_argument(
         "--replica-id", type=int, default=None,
@@ -899,7 +940,9 @@ def main(argv=None) -> int:
     config = dict(DEFAULT_CONFIG if not ns.config else json.loads(ns.config))
     if ns.kv_int8:
         config["kv_cache_int8"] = True
-    model = _build_model(ns.family, config)
+    model, _ = build_model({"family": ns.family, "config": config})
+    if "decode" not in inspect.signature(model.__call__).parameters:
+        ap.error(f"--family {ns.family} has no decode path: it cannot be served")
     mesh = build_mesh(MeshConfig(dp=-1), jax.devices()[:1])
 
     reload_fn = None
@@ -910,10 +953,7 @@ def main(argv=None) -> int:
         step, params = reload_fn()
         logger.info("restored checkpoint step %s from %s", step, ns.ckpt_dir)
     else:
-        params = model.init(
-            jax.random.PRNGKey(0),
-            jax.numpy.zeros((1, 8), jax.numpy.int32),
-        )["params"]
+        params = _init_params(model)
         logger.warning("no --ckpt-dir: serving RANDOM weights (smoke mode)")
 
     sampling = SamplingConfig(
@@ -935,7 +975,8 @@ def main(argv=None) -> int:
         kv_pool_blocks=ns.kv_pool_blocks,
     )
     # the engine holds the tree its programs read (the matrices rounded
-    # to the compute dtype); the float32 one goes with this reference
+    # to the compute dtype); a restored float32 one goes with this
+    # reference
     del params
     daemon = ServingDaemon(engine).start()
     httpd = serve(daemon, ns.port, reload_fn, replica_id=ns.replica_id,

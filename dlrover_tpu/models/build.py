@@ -1,7 +1,8 @@
 """One way from a configuration's ``model`` entry to a model the train
-step can build: ``{"family": <name>, "config": {<the config class's
-fields>}}``. Callers (the benchmark's worker, a training script) name no
-model class; a new family is one more line in ``FAMILIES``.
+step and the server can build: ``{"family": <name>, "config": {<the config
+class's fields>}}``. Callers (the benchmark's workers, a training script,
+``tpurun-serve``) name no model class; a new family is one more line in
+``FAMILIES``, the one registry trainer and server share.
 """
 
 import dataclasses
@@ -10,7 +11,10 @@ from typing import Any, Callable, Tuple
 
 # family -> (module, model class, config class)
 FAMILIES = {
+    "gpt": ("gpt", "GPT", "GPTConfig"),
+    "llama": ("llama", "Llama", "LlamaConfig"),
     "mla_moe": ("mla_moe", "MlaMoeLM", "MlaMoeConfig"),
+    "lfm2_moe": ("lfm2_moe", "Lfm2MoeLM", "Lfm2MoeConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")
@@ -43,3 +47,23 @@ def build_model(entry: dict) -> Tuple[Any, Callable]:
 
     takes_targets = getattr(config, "ce_chunk", 0) > 0 or getattr(config, "takes_targets", False)
     return getattr(mod, model_cls)(config), token_loss_mean if takes_targets else cross_entropy_loss
+
+
+def init_params_as_consumed(model, rng):
+    """The model's initial parameters in the dtypes it reads them in
+    (``model.consumed_param_dtypes``: what a server holds), made by ONE
+    jitted program whose outputs are already rounded: each leaf is drawn in
+    float32 and rounded inside the program, so the float32 tree never
+    exists (at a size where it would not fit the chip it cannot). The
+    values are those of ``model.init`` on the same key, rounded."""
+    import jax
+    import jax.numpy as jnp
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+        dtypes_of = getattr(model, "consumed_param_dtypes", None)
+        if dtypes_of is None:
+            return params
+        return jax.tree.map(lambda leaf, dt: leaf.astype(dt), params, dtypes_of(params))
+
+    return jax.jit(init)(rng)

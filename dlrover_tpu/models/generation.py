@@ -144,13 +144,22 @@ def sample_logits(
 
 
 def decode_apply(
-    model, params, cache, tokens, positions, kv_valid, cache_slots=None
+    model, params, cache, tokens, positions, kv_valid, cache_slots=None,
+    metrics: bool = False,
 ):
     """One decode-mode model application over an explicit cache pytree.
 
-    Returns (raw logits, updated cache). The single place the decode
-    contract (``decode=True, positions, kv_valid, mutable=["cache"]``)
-    is spelled, shared by the one-shot engine and the continuous-
+    Returns (raw logits, updated cache), and with ``metrics`` a third:
+    what the model sowed under ``"metrics"`` in this call (``{}`` for a
+    model that sows nothing), for a caller that returns counters beside
+    its tokens. The cache is whatever the model keeps under ``"cache"``:
+    positional leaves ``[B, L, ...]`` (keys and values), per-request
+    state leaves ``[B, ...]`` with no position axis (a convolution's last
+    inputs; ``model.cache_state_leaves`` says which), and 0-d write
+    offsets; every leaf's first axis is the batch row.
+
+    The single place the decode contract (``decode=True, positions,
+    kv_valid, mutable=["cache"]``) is spelled, shared by the one-shot engine and the continuous-
     batching scheduler — their token-exactness guarantee depends on
     applying the model identically. ``cache_slots`` selects the
     per-row write-slot mode: [B] for single-token decode (continuous
@@ -165,8 +174,10 @@ def decode_apply(
         positions=positions,
         kv_valid=kv_valid,
         cache_slots=cache_slots,
-        mutable=["cache"],
+        mutable=["cache", "metrics"] if metrics else ["cache"],
     )
+    if metrics:
+        return logits, mut["cache"], mut.get("metrics", {})
     return logits, mut["cache"]
 
 

@@ -78,6 +78,33 @@ BUFFER_OVER_MEAN = 4
 
 
 @dataclass(frozen=True)
+class MoeSizes:
+    """What :class:`MoeLayer` is built from: the sizes of one routed-expert
+    layer and of the chip's share of it, whichever model's config they
+    come from (``MlaMoeConfig.moe_sizes``, ``Lfm2MoeConfig.moe_sizes``)."""
+
+    n_experts: int  # the router's width
+    top_k: int
+    width: int  # each expert's SwiGLU
+    experts_held: int = 0  # 0: all of them
+    expert_offset: int = 0  # the first expert held
+    norm_topk: bool = True  # gates over the chosen ones' sum ...
+    norm_eps: float = 0.0  # ... plus this
+    scale: float = 1.0
+    n_shared: int = 0  # 0 or 1 shared expert, ``n_shared`` widths wide
+    bias_name: str = "e_score_correction_bias"  # "": no selection bias
+    init_std: float = 0.02
+    expert_init_std: float = 0.0  # the routed experts' matrices; 0: ``init_std``
+    bias_init_std: float = 0.01
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.n_experts
+
+
+@dataclass(frozen=True)
 class MlaMoeConfig:
     # -- published keys ---------------------------------------------------
     vocab_size: int = 129280
@@ -144,6 +171,17 @@ class MlaMoeConfig:
     def rms_eps(self) -> float:  # the name ``llama.RMSNorm`` reads
         return self.rms_norm_eps
 
+    @property
+    def moe_sizes(self) -> MoeSizes:
+        return MoeSizes(
+            n_experts=self.n_routed_experts, top_k=self.num_experts_per_tok,
+            width=self.moe_intermediate_size, experts_held=self.experts_held,
+            expert_offset=self.expert_offset, norm_topk=self.norm_topk_prob,
+            scale=self.routed_scaling_factor, n_shared=self.n_shared_experts,
+            init_std=self.init_std, bias_init_std=self.bias_init_std,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+        )
+
     def is_expert_block(self, layer_idx: int) -> bool:
         return layer_idx >= self.first_k_dense_replace
 
@@ -161,9 +199,9 @@ class MlaMoeConfig:
         return MlaMoeConfig(**base)
 
 
-def _weight(name, cfg, shape, axes):
+def _weight(name, cfg, shape, axes, std: float = 0.0):
     return param_with_axes(
-        name, nn.initializers.normal(cfg.init_std), shape,
+        name, nn.initializers.normal(std or cfg.init_std), shape,
         cfg.param_dtype, axes=axes,
     ).astype(cfg.dtype)
 
@@ -222,7 +260,7 @@ class LatentAttention(nn.Module):
 
 
 class SwiGlu(nn.Module):
-    config: MlaMoeConfig
+    config: Any  # reads ``init_std``, ``param_dtype``, ``dtype``
     width: int
 
     @nn.compact
@@ -236,42 +274,51 @@ class SwiGlu(nn.Module):
         return jnp.dot(h, w_down)
 
 
-def route(scores, bias, top_k: int, norm: bool, scale: float):
+def route(scores, bias, top_k: int, norm: bool, scale: float, eps: float = 0.0):
     """(expert ids ``[N, k]``, gates ``[N, E]``) from float32 scores
     ``[N, E]``: the top k of ``scores + bias`` are chosen, and a chosen
-    expert's gate is its *unbiased* score, over the chosen ones' sum with
-    ``norm``, times ``scale``. The gates are given for every expert (a
+    expert's gate is its *unbiased* score, over the chosen ones' sum
+    (plus ``eps``) with ``norm``, times ``scale``. The gates are given for every expert (a
     token's row of them is read at the experts it chose), so that no
     gather by choice, and no scatter behind it, is needed."""
     _, idx = jax.lax.top_k(scores + bias, top_k)
     if norm:
-        chosen = jnp.take_along_axis(scores, idx, axis=-1)
-        scores = scores / jnp.sum(chosen, axis=-1, keepdims=True)
+        chosen = jnp.sum(jnp.take_along_axis(scores, idx, axis=-1), axis=-1, keepdims=True)
+        scores = scores / (chosen + eps if eps else chosen)
     return idx, scores * scale
 
 
 class MoeLayer(nn.Module):
-    """The routed experts held here plus the shared expert."""
+    """The routed experts held here plus the shared expert, if there is
+    one. Built from :class:`MoeSizes`, so that every model with such a
+    layer runs this one: trained over ``[B, T]`` tokens, or inside a
+    server's decode chunk over one token a slot (there ``N x K`` rows are
+    the whole buffer: one pass, and the overflow branch is never built).
+    An expert no row chose has an empty group, which the grouped product
+    does not visit: its weights are not read."""
 
-    config: MlaMoeConfig
+    sizes: MoeSizes
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.config
+        cfg = self.sizes
         B, T, D = x.shape
-        N, K = B * T, cfg.num_experts_per_tok
-        E, Eh, F = cfg.n_routed_experts, cfg.experts_here, cfg.moe_intermediate_size
+        N, K = B * T, cfg.top_k
+        E, Eh, F = cfg.n_experts, cfg.experts_here, cfg.width
         xf = x.reshape(N, D)
 
         w_router = param_with_axes(
             "w_router", nn.initializers.normal(cfg.init_std), (D, E),
             jnp.float32, axes=("embed", None))
-        bias = param_with_axes(
-            "e_score_correction_bias", nn.initializers.normal(cfg.bias_init_std),
-            (E,), jnp.float32, axes=(None,))
-        w_gate = _weight("w_gate", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"))
-        w_up = _weight("w_up", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"))
-        w_down = _weight("w_down", cfg, (Eh, F, D), ("expert", "expert_mlp", "embed"))
+        bias = 0.0
+        if cfg.bias_name:
+            bias = jax.lax.stop_gradient(param_with_axes(
+                cfg.bias_name, nn.initializers.normal(cfg.bias_init_std),
+                (E,), jnp.float32, axes=(None,)))
+        std = cfg.expert_init_std
+        w_gate = _weight("w_gate", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"), std)
+        w_up = _weight("w_up", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"), std)
+        w_down = _weight("w_down", cfg, (Eh, F, D), ("expert", "expert_mlp", "embed"), std)
 
         with jax.named_scope("moe.route"):
             # float32 all the way: a score rounded to bf16 moves the top k
@@ -279,8 +326,7 @@ class MoeLayer(nn.Module):
                              precision=jax.lax.Precision.HIGHEST)
             scores = jax.nn.sigmoid(logits)
             idx, gate_of_expert = route(
-                scores, jax.lax.stop_gradient(bias), K, cfg.norm_topk_prob,
-                cfg.routed_scaling_factor)
+                scores, bias, K, cfg.norm_topk, cfg.scale, cfg.norm_eps)
 
         with jax.named_scope("moe.dispatch"):
             local = idx - cfg.expert_offset
@@ -334,16 +380,19 @@ class MoeLayer(nn.Module):
         if len(firsts) > 1:
             routed = routed + jax.lax.cond(valid[1] > 0, overflow, nothing, xf, gate_of_expert)
 
-        shared = SwiGlu(cfg, F * cfg.n_shared_experts, name="shared")(xf)
+        out = routed
+        if cfg.n_shared:
+            out = out + SwiGlu(cfg, F * cfg.n_shared, name="shared")(xf)
         for name, value in dict(
             assignments_here=n_here,
             assignments_absent=N * K - n_here,
             load_max_over_mean=jnp.max(group_sizes) * Eh / jnp.maximum(n_here, 1).astype(jnp.float32),
+            experts_touched=jnp.sum(group_sizes > 0, dtype=jnp.int32),
             dropped=n_here - sum(valid),  # none: the passes take every row
             extra_passes=sum([(v > 0).astype(jnp.int32) for v in valid[1:]], jnp.int32(0)),
         ).items():
             self.sow("metrics", name, value)
-        return (routed + shared).reshape(B, T, D)
+        return out.reshape(B, T, D)
 
 
 class Block(nn.Module):
@@ -356,7 +405,7 @@ class Block(nn.Module):
         x = x + LatentAttention(cfg, name="attn")(RMSNorm(cfg, name="norm_attn")(x))
         h = RMSNorm(cfg, name="norm_mlp")(x)
         if cfg.is_expert_block(self.layer_idx):
-            y = MoeLayer(cfg, name="moe")(h)
+            y = MoeLayer(cfg.moe_sizes, name="moe")(h)
         else:
             y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
         return _constrain(x + y, "batch", "seq", "embed")
@@ -470,6 +519,27 @@ def step_counters(metrics: dict) -> dict:
     out["moe.layer_steps"] = len(scopes)
     out["moe.assignments_here_by_layer"] = [int(layers[s]["assignments_here"]) for s in scopes]
     return out
+
+
+def decode_step_counters(metrics: dict) -> dict:
+    """The sown ``metrics`` of one decode step as device scalars by counter
+    name, summed over the expert layers: what a server's jitted decode
+    chunk returns beside its tokens, so that they reach the host in the
+    read-back the chunk already has and are booked there
+    (``ContinuousBatchingEngine``). Traceable: nothing here reads a value."""
+    sums = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(metrics)[0]:
+        name = next(k.key for k in reversed(path) if getattr(k, "key", None) is not None)
+        sums.setdefault(name, []).append(leaf)
+    layers = len(sums.get("assignments_here", ()))
+    if not layers:
+        return {}
+    return {
+        "moe.assignments": sum(sums["assignments_here"]).astype(jnp.int32),
+        "moe.experts_touched": sum(sums["experts_touched"]).astype(jnp.int32),
+        "moe.load_max_over_mean": sum(sums["load_max_over_mean"]).astype(jnp.float32),
+        "moe.layer_steps": jnp.int32(layers),
+    }
 
 
 def book_step_counters(metrics: dict) -> dict:

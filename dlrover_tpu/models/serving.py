@@ -27,7 +27,8 @@ TPU shape — every device program is static-shape and compiled once:
   - ``"per_row"`` (the default, and ``tpurun-serve``'s): a static
     dense ``[B, L]`` cache.
   - ``"paged"``: the full vLLM-style serving memory (models/
-    kv_blocks.py). The cache is a pool of fixed-size token blocks;
+    kv_blocks.py); refused at construction for a model with state
+    leaves (below). The cache is a pool of fixed-size token blocks;
     each slot carries a block TABLE, the decode chunk gathers the
     dense view by table, runs the SAME step body (bit-exact
     by construction), and scatters back. Admission is bounded by free
@@ -36,6 +37,21 @@ TPU shape — every device program is static-shape and compiled once:
     refcounted and shared copy-on-write across every row using it,
     and an out-of-blocks burst queues (bounded) instead of OOMing.
 
+- **Two kinds of cache leaf.** The model's ``"cache"`` collection holds
+  positional leaves ``[B, L, ...]`` (keys and values, hidden from
+  attention by ``kv_valid`` where a slot is padding) and may hold
+  per-request *state* leaves ``[B, ...]`` with no position axis (a short
+  convolution's last inputs); ``model.cache_state_leaves`` says which,
+  by name. Every program here moves a row's leaves by their first axis
+  alone (prefill into a fresh one-row cache, the insert at admission,
+  a registered prefix's stored row, the hand-off payload), so a state
+  travels with its row: ``admit`` replaces a slot's state whole, a done
+  or empty row's state may hold anything. What padding means to a state
+  is the model's to keep: prompts are LEFT-padded and a prefix
+  continuation leaves pad holes between prefix and suffix, so the model
+  reads ``kv_valid`` at the slots a call writes and lets only real
+  tokens move its state (``models/lfm2_moe.py``). ``stats()`` reports
+  ``cache_bytes_positional`` and ``cache_bytes_state``.
 - **Weight hot-swap between chunks**: ``set_params`` replaces the
   parameter argument of the jitted programs (same shapes — no
   recompile), so a WeightBus push lands at the next chunk boundary;
@@ -297,6 +313,25 @@ class ContinuousBatchingEngine:
                 f"cache_layout {cache_layout!r}: per_row | paged"
             )
         self.layout = cache_layout
+        # which leaves of the model's cache are per-request state with no
+        # position axis (the model says: ``cache_state_leaves``; a model
+        # that says nothing has none)
+        template = init_cache(model, 1)
+        kinds_of = getattr(model, "cache_state_leaves", None)
+        self._state_leaf = (
+            kinds_of(template) if kinds_of is not None
+            else jax.tree_util.tree_map(lambda _: False, template)
+        )
+        if cache_layout == "paged" and any(
+            jax.tree_util.tree_leaves(self._state_leaf)
+        ):
+            raise ValueError(
+                "cache_layout 'paged': this model keeps per-request state "
+                "with no position axis beside its keys and values "
+                "(model.cache_state_leaves), and a pool of token blocks "
+                "holds only leaves shaped [row, position, ...]; serve it "
+                "with cache_layout 'per_row'"
+            )
         # liveness: each request lives in its own slots
         if prompt_width + sampling.max_new_tokens > L:
             raise ValueError(
@@ -466,10 +501,13 @@ class ContinuousBatchingEngine:
             )
 
         paged = self.layout == "paged"
+        counters_of = getattr(model, "decode_step_counters", None)
 
         def make_decode_chunk(d: int):
             """Build the d-step decode program; returns stacked (toks,
-            emits, logps) [d, B] and the advanced state. Each row
+            emits, logps) [d, B], the model's per-step counters [d]
+            (``model.decode_step_counters``; ``{}`` without) and the
+            advanced state. Each row
             writes at its own next slot (``cache_slots`` scatter);
             done/empty rows keep stepping on pad (static shapes) with
             their write slot parked clamped at L-1 — their kv bit and
@@ -523,10 +561,14 @@ class ContinuousBatchingEngine:
                     row_f = row_f + 1
                     kv_valid = kv_valid | slot_hits
                     pos = cur_pos + 1
-                    logits, cache = decode_apply(
+                    logits, cache, sown = decode_apply(
                         model, params, cache, tok[:, None], pos[:, None],
-                        kv_valid, cache_slots=write_slots,
+                        kv_valid, cache_slots=write_slots, metrics=True,
                     )
+                    # what the model counted in this step (a routed
+                    # layer's load), as device scalars: stacked by the
+                    # scan, read back with the tokens, booked on the host
+                    counters = counters_of(sown) if counters_of else {}
                     return (
                         cache,
                         kv_valid,
@@ -537,7 +579,7 @@ class ContinuousBatchingEngine:
                         done,
                         row_f,
                         rng,
-                    ), (tok, emit, tok_logp)
+                    ), (tok, emit, tok_logp, counters)
 
                 carry, out = jax.lax.scan(
                     step, (*state, rng), jnp.arange(d)
@@ -680,6 +722,18 @@ class ContinuousBatchingEngine:
             self._prefix_blocks.clear()
         else:
             cache = init_cache(self.model, self.B)
+        # bytes of the cache as the device holds it, by kind of leaf (a
+        # paged pool has positional leaves only)
+        positional = state = 0
+        for leaf, is_state in zip(
+            jax.tree_util.tree_leaves(cache[0] if self.layout == "paged" else cache),
+            jax.tree_util.tree_leaves(self._state_leaf),
+        ):
+            if is_state:
+                state += leaf.nbytes
+            else:
+                positional += leaf.nbytes
+        self._cache_bytes = (positional, state)
         self._state = (
             cache,
             jnp.zeros((self.B, self.L), bool),
@@ -757,7 +811,10 @@ class ContinuousBatchingEngine:
         prefill here and return the row as a JSON-safe hand-off
         payload (see :func:`kv_blocks.pack_row_state`). The decode
         replica admits it via :meth:`submit_prefilled` and pays only
-        the insert — long prompts stop stalling its decode rounds."""
+        the insert — long prompts stop stalling its decode rounds. The
+        payload carries every leaf of the row's cache, per-request
+        state leaves included (they are leaves like the others, checked
+        by shape on arrival)."""
         if not tokens:
             raise ValueError("empty prompt")
         if len(tokens) > self.Pw:
@@ -1138,6 +1195,14 @@ class ContinuousBatchingEngine:
             "admit_to_first_token_s", max(now - st.admit_t, 0.0)
         )
 
+    def _book_counters(self, counters: Dict) -> None:
+        """One synced chunk's model counters (``[d]`` host arrays by
+        name, from the chunk's own read-back) into the accumulator: the
+        sum over its steps, for every slot's row, live or not (the
+        device computed them all)."""
+        for name, per_step in counters.items():
+            self.phases.count(name, per_step.sum().item())
+
     def _count_chunk(self, row_steps: int) -> None:
         """One dispatched chunk: ``row_steps`` = slots x positions it
         decodes, whether or not a live row fills them."""
@@ -1376,13 +1441,13 @@ class ContinuousBatchingEngine:
         in-flight record (output futures + done futures + the uid
         snapshot) without reading anything back."""
         with self._ctx():
-            self._state, (toks, emits, logps) = self._chunk_for(self.d)(
-                self.params, self._state, rng
-            )
+            self._state, (toks, emits, logps, counters) = self._chunk_for(
+                self.d
+            )(self.params, self._state, rng)
         self._count_chunk(self.B * self.d)
         return (
             toks, emits, logps, self._state[-2],  # -2: the done flags
-            [st.uid for st in self._slots],
+            counters, [st.uid for st in self._slots],
         )
 
     def _emit_outputs(self, fetched, uids) -> int:
@@ -1392,7 +1457,8 @@ class ContinuousBatchingEngine:
         or cancel + re-admit during the lag window) is skipped: the
         old row's emit mask is the device's own guarantee that a
         re-admitted request never sees a predecessor's tokens."""
-        toks, emits, logps, done = fetched
+        toks, emits, logps, done, counters = fetched
+        self._book_counters(counters)
         emitted = 0
         now = time.perf_counter()
         for slot, st in enumerate(self._slots):
@@ -1545,7 +1611,8 @@ class ContinuousBatchingEngine:
         """The synchronous round's per-token host loop, kept verbatim
         as the reference for the overlapped round's fused emission
         (greedy equality between the two paths is under test)."""
-        toks, emits, logps, done = fetched
+        toks, emits, logps, done, counters = fetched
+        self._book_counters(counters)
         emitted = 0
         for slot, st in enumerate(self._slots):
             if st.uid < 0:
@@ -1693,6 +1760,11 @@ class ContinuousBatchingEngine:
             "kv_cache_int8": bool(
                 getattr(self.model.config, "kv_cache_int8", False)
             ),
+            # the cache on the device: leaves with a position axis (keys
+            # and values, [slots, length, ...] or the block pool) and
+            # per-request state leaves with none ([slots, ...])
+            "cache_bytes_positional": self._cache_bytes[0],
+            "cache_bytes_state": self._cache_bytes[1],
             # what the engine holds on the device for the programs (the
             # matrices in the model's compute dtype), and how many weight
             # versions it has held: start-up, then one a swap adopted

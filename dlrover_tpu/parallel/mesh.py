@@ -63,6 +63,8 @@ MESH_AXIS_REGISTRY: Dict[str, Tuple[str, str]] = {
     "q_lora": ("logical", "latent attention's query rank (kept local)"),
     "kv_lora": ("logical", "latent attention's key/value rank, with its rope vector (kept local)"),
     "mlp": ("logical", "feed-forward hidden dim"),
+    "conv_channels": ("logical", "a gated short convolution's channels, each with its own filter (split like an MLP's hidden dim)"),
+    "conv_taps": ("logical", "a short convolution's filter taps (three; kept local)"),
     "vocab": ("logical", "embedding/logits vocabulary dim"),
     "expert": ("logical", "MoE expert index"),
     "expert_mlp": ("logical", "per-expert feed-forward hidden dim"),

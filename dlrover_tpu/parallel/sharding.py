@@ -31,6 +31,10 @@ DEFAULT_RULES: LogicalRules = [
     ("q_lora", None),  # latent attention's two ranks: a few hundred wide,
     ("kv_lora", None),  # contracted right after they are made; keep local
     ("mlp", "tp"),
+    # a gated short convolution is channel-wise between its two products:
+    # the channels split like an MLP's hidden dim, the taps stay whole
+    ("conv_channels", "tp"),
+    ("conv_taps", None),
     ("vocab", "tp"),
     ("expert", "ep"),  # MoE experts distributed over the ep axis
     ("expert_mlp", "tp"),  # per-expert hidden dim still tensor-parallel

@@ -106,7 +106,7 @@ def test_shares_add_up_to_the_uncut_layer():
     compute, plus the shared expert counted once, are the uncut layer."""
     whole = MlaMoeConfig.tiny(dtype=jnp.float32)
     h = jax.random.normal(jax.random.PRNGKey(3), (B, T, whole.hidden_size))
-    params = MoeLayer(whole).init(jax.random.PRNGKey(4), h)["params"]
+    params = MoeLayer(whole.moe_sizes).init(jax.random.PRNGKey(4), h)["params"]
     want, _ = ref._expert_layer(h, params, hp_of(whole), jnp.float32)
     total, landed = 0.0, 0
     for share in range(4):
@@ -114,7 +114,7 @@ def test_shares_add_up_to_the_uncut_layer():
         mine = dict(params)
         for name in ("w_gate", "w_up", "w_down"):
             mine[name] = params[name][2 * share:2 * share + 2]
-        out, metrics = MoeLayer(cfg).apply({"params": mine}, h, mutable=("metrics",))
+        out, metrics = MoeLayer(cfg.moe_sizes).apply({"params": mine}, h, mutable=("metrics",))
         s = params["shared"]
         shared = ref._swiglu(h, s["w_gate"], s["w_up"], s["w_down"])
         total = total + (out - shared)
@@ -135,13 +135,13 @@ def test_no_token_is_dropped_when_all_choose_the_held_experts(experts, held, ext
     cfg = MlaMoeConfig.tiny(
         dtype=jnp.float32, n_routed_experts=experts, experts_held=held, expert_offset=4)
     h = jax.random.normal(jax.random.PRNGKey(5), (B, T, cfg.hidden_size))
-    params = MoeLayer(cfg).init(jax.random.PRNGKey(6), h)["params"]
+    params = MoeLayer(cfg.moe_sizes).init(jax.random.PRNGKey(6), h)["params"]
     bias = np.zeros(experts, np.float32)
     bias[4:4 + held] = 10.0  # k = 2: a token's choices fall on the held experts first
     params = {**params, "e_score_correction_bias": jnp.asarray(bias)}
 
     def run(p, h):
-        out, m = MoeLayer(cfg).apply({"params": p}, h, mutable=("metrics",))
+        out, m = MoeLayer(cfg.moe_sizes).apply({"params": p}, h, mutable=("metrics",))
         return out, m["metrics"]
 
     out, m = run(params, h)

@@ -565,6 +565,43 @@ def test_inbox_wait_grows_while_the_driver_is_held_in_a_step():
     assert 0.2 <= waited < 30.0
 
 
+def test_a_loaded_server_that_stands_still_says_where(monkeypatch):
+    """One iteration of the daemon's loop that outlasts ``SLOW_ITERATION_S``
+    while the engine has work is logged with its inbox and round seconds and
+    the round's own phases; iterations of the usual length are not."""
+    import logging
+
+    from dlrover_tpu.common.log import logger
+    from dlrover_tpu.launcher import serve
+
+    monkeypatch.setattr(serve, "SLOW_ITERATION_S", 0.25)
+    eng = tiny_engine(overlap=True)
+    eng.run(STREAM[:2])
+    inner, calls = eng.step, []
+
+    def step(rng):
+        calls.append(1)
+        if len(calls) == 2:  # the engine is busy by now: stand still inside a phase
+            with eng.phases.span("serve.host_sync", book="host_sync"):
+                time.sleep(0.4)
+        return inner(rng)
+
+    eng.step = step
+    seen = []
+    handler = logging.Handler()
+    handler.emit = lambda record: seen.append(record.getMessage())
+    logger.addHandler(handler)
+    daemon = serve.ServingDaemon(eng).start()
+    try:
+        daemon.complete([5, 9, 2], timeout=60.0)
+    finally:
+        daemon.stop()
+        logger.removeHandler(handler)
+    slow = [m for m in seen if m.startswith("slow serving iteration")]
+    assert len(calls) > 2 and len(slow) == 1, seen
+    assert "'host_sync': 0.4" in slow[0] and "round 0.4" in slow[0]
+
+
 def test_no_session_records_nothing_and_raises_nothing(tmp_path):
     acc = SpanAccumulator()
     with acc.span("off.outer", step=3) as outer:

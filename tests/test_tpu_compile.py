@@ -177,6 +177,41 @@ def test_grouped_expert_products_compile_for_v5e(
     assert _kernel_text(compiled).count("tpu_custom_call") >= 9
 
 
+@pytest.mark.parametrize("tokens", [16, 1024], ids=["decode_step", "prefill"])
+def test_routed_experts_in_a_serving_program_compile_for_v5e(
+    one_chip, no_persistent_cache, monkeypatch, tokens
+):
+    """``MoeLayer`` as the server runs it at the published LFM2-24B-A2B
+    widths: 64 experts of 2048 x 1536, top-4, over one token a slot (16
+    slots: 64 rows, the whole buffer, one pass) and over a 1024-token
+    prefill. The three grouped products are megablox kernels, which visit
+    the non-empty groups only; the expert weights are read where they
+    lie (no copy of them among the program's temporaries)."""
+    from dlrover_tpu.models.lfm2_moe import Lfm2MoeConfig
+    from dlrover_tpu.models.mla_moe import MoeLayer
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    layer = MoeLayer(Lfm2MoeConfig().moe_sizes)
+    shape = (16, 1, 2048) if tokens == 16 else (1, 1024, 2048)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(shape, jnp.bfloat16))["params"])
+    held = jax.tree.map(  # as the engine holds them: the matrices in bf16
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim == 3 else a.dtype, sharding=one_chip),
+        params,
+    )
+    compiled = jax.jit(
+        lambda p, x: layer.apply({"params": p}, x, mutable=("metrics",))
+    ).lower(held, x).compile()
+    assert _kernel_text(compiled).count("tpu_custom_call") >= 3
+    weights = 3 * 64 * 2048 * 1536 * 2
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes >= weights
+    assert m.temp_size_in_bytes < weights // 8
+
+
 def _gpt2_small_step(devices, mesh_config, batch=32):
     """(lowered-step factory) the GPT-2-small train step exactly as
     ``chip_smoke.py``'s worker builds it, over described devices."""

@@ -15,6 +15,7 @@ FAMILIES = {
     "llama": ("llama", "Llama", "LlamaConfig"),
     "mla_moe": ("mla_moe", "MlaMoeLM", "MlaMoeConfig"),
     "lfm2_moe": ("lfm2_moe", "Lfm2MoeLM", "Lfm2MoeConfig"),
+    "granite_hybrid": ("granite_hybrid", "GraniteHybridLM", "GraniteHybridConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")

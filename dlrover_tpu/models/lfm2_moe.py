@@ -161,6 +161,55 @@ def _rows(a, idx):
     return jax.vmap(lambda rows, i: rows[i])(a, idx)
 
 
+def real_neighbours(s, z, token_valid):
+    """The padding rule of a causal convolution's decode state, for any
+    number of earlier taps: ``s [B, n, D]`` holds the inputs of the row's
+    last ``n`` real tokens (oldest first; zeros before the row's first),
+    ``z [B, T, D]`` this call's inputs, ``token_valid [B, T]`` which of
+    them are real. -> (``n`` arrays ``[B, T, D]``: for each of this call's
+    tokens the input of the real token ``n`` before it, ..., of the one
+    just before it, whatever padding lies between; the state moved on: the
+    inputs of the row's last ``n`` real tokens, this call's included).
+    A padded token reads something nobody uses and leaves the state alone."""
+    B, T, D = z.shape
+    n = s.shape[1]
+    if T == 1:  # a decode step: no neighbour to look for
+        keep = token_valid[:, :, None]
+        moved = jnp.where(keep, jnp.concatenate([s[:, 1:], z], axis=1), s)
+        return [s[:, i:i + 1] for i in range(n)], moved
+    # the row as [state ; this call], the state's entries always real
+    zz = jnp.concatenate([s, z], axis=1)  # [B, T + n, D]
+    real = jnp.concatenate([jnp.ones((B, n), bool), token_valid], axis=1)
+    at = jnp.arange(T + n, dtype=jnp.int32)[None, :]
+    last = jax.lax.cummax(jnp.where(real, at, 0), axis=1)  # the last real one up to here
+    prev = [jnp.concatenate([jnp.zeros((B, 1), jnp.int32), last[:, :-1]], axis=1)]  # ... before here
+    ends = [last[:, -1:]]
+    for _ in range(n - 1):
+        prev.append(jnp.take_along_axis(prev[0], prev[-1], axis=1))  # ... and the one before that
+        ends.append(jnp.take_along_axis(prev[0], ends[-1], axis=1))
+    moved = _rows(zz, jnp.concatenate(ends[::-1], axis=1))
+    return [_rows(zz, p[:, n:]) for p in prev[::-1]], moved
+
+
+def token_valid_at(module, B, T, kv_valid, cache_slots):
+    """Which of this call's tokens are real: ``kv_valid`` at the slots
+    the call writes, found as ``gpt._update_decode_cache`` finds them
+    (the shared write offset, kept on ``module`` as ``index``; or the
+    per-row ``cache_slots``). With no ``kv_valid`` every token is. For
+    the top module of a model that keeps a state with no position axis."""
+    index = module.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
+    if cache_slots is not None:
+        if kv_valid is None:
+            raise ValueError("cache_slots mode needs explicit kv_valid")
+        slots = cache_slots[:, None] if cache_slots.ndim == 1 else cache_slots
+        return jnp.take_along_axis(kv_valid, slots, axis=1)
+    offset = index.value
+    index.value = offset + T
+    if kv_valid is None:
+        return jnp.ones((B, T), bool)
+    return jax.lax.dynamic_slice(kv_valid, (0, offset), (B, T))
+
+
 class ShortConv(nn.Module):
     """The gated short convolution. ``token_valid`` ``[B, T]`` (decode
     only) says which of this call's tokens are real."""
@@ -196,24 +245,10 @@ class ShortConv(nn.Module):
         row's last two real tokens, this call's included."""
         B, T, D = z.shape
         state = self.variable("cache", "conv_state", jnp.zeros, (B, 2, D), z.dtype)
-        s = state.value
         if token_valid is None:
             token_valid = jnp.ones((B, T), bool)
-        if T == 1:  # a decode step: no neighbour to look for
-            keep = token_valid[:, :, None]
-            state.value = jnp.where(keep, jnp.concatenate([s[:, 1:], z], axis=1), s)
-            return s[:, :1], s[:, 1:]
-        # the row as [state ; this call], the state's two always real
-        zz = jnp.concatenate([s, z], axis=1)  # [B, T + 2, D]
-        real = jnp.concatenate([jnp.ones((B, 2), bool), token_valid], axis=1)
-        at = jnp.arange(T + 2, dtype=jnp.int32)[None, :]
-        last = jax.lax.cummax(jnp.where(real, at, 0), axis=1)  # the last real one up to here
-        prev1 = jnp.concatenate([jnp.zeros((B, 1), jnp.int32), last[:, :-1]], axis=1)  # ... before here
-        prev2 = jnp.take_along_axis(prev1, prev1, axis=1)  # ... and the one before that
-        end1 = last[:, -1:]
-        end2 = jnp.take_along_axis(prev1, end1, axis=1)
-        state.value = _rows(zz, jnp.concatenate([end2, end1], axis=1))
-        return _rows(zz, prev2[:, 2:]), _rows(zz, prev1[:, 2:])
+        (z2, z1), state.value = real_neighbours(state.value, z, token_valid)
+        return z2, z1
 
 
 class Attention(nn.Module):
@@ -333,7 +368,7 @@ class Lfm2MoeLM(nn.Module):
         wte = _weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
         x = _constrain(wte[tokens], "batch", "seq", "embed")
         if decode:
-            token_valid = self._token_valid(B, T, kv_valid, cache_slots)
+            token_valid = token_valid_at(self, B, T, kv_valid, cache_slots)
             for i in range(cfg.num_hidden_layers):
                 x = Block(cfg, layer_idx=i, name=f"block_{i}")(
                     x, decode=True, positions=positions, kv_valid=kv_valid,
@@ -350,20 +385,3 @@ class Lfm2MoeLM(nn.Module):
             return _chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
         logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
         return _constrain(logits, "batch", "seq", "vocab")
-
-    def _token_valid(self, B, T, kv_valid, cache_slots):
-        """Which of this call's tokens are real: ``kv_valid`` at the slots
-        the call writes, found as ``gpt._update_decode_cache`` finds them
-        (the shared write offset, kept here as ``index``; or the per-row
-        ``cache_slots``). With no ``kv_valid`` every token is."""
-        index = self.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
-        if cache_slots is not None:
-            if kv_valid is None:
-                raise ValueError("cache_slots mode needs explicit kv_valid")
-            slots = cache_slots[:, None] if cache_slots.ndim == 1 else cache_slots
-            return jnp.take_along_axis(kv_valid, slots, axis=1)
-        offset = index.value
-        index.value = offset + T
-        if kv_valid is None:
-            return jnp.ones((B, T), bool)
-        return jax.lax.dynamic_slice(kv_valid, (0, offset), (B, T))

@@ -50,8 +50,11 @@ TPU shape — every device program is static-shape and compiled once:
   is the model's to keep: prompts are LEFT-padded and a prefix
   continuation leaves pad holes between prefix and suffix, so the model
   reads ``kv_valid`` at the slots a call writes and lets only real
-  tokens move its state (``models/lfm2_moe.py``). ``stats()`` reports
-  ``cache_bytes_positional`` and ``cache_bytes_state``.
+  tokens move its state (``models/lfm2_moe.py``; for a recurrent state
+  that every token rewrites whole, ``models/granite_hybrid.py``: a step
+  size of exactly 0 at a padded token). ``stats()`` reports
+  ``cache_bytes_positional`` and ``cache_bytes_state``, and counts
+  ``prefill_tokens_real`` against ``prefill_tokens_padded`` at admission.
 - **Weight hot-swap between chunks**: ``set_params`` replaces the
   parameter argument of the jitted programs (same shapes — no
   recompile), so a WeightBus push lands at the next chunk boundary;
@@ -1149,6 +1152,14 @@ class ContinuousBatchingEngine:
                         self._prefill_fn(self.params, toks, mask)
                     )
                 full_prompt = prompt
+        # what this admission's prefill covers: the request's own tokens
+        # (a stored prefix's are not computed again) against the width of
+        # the bucket they were padded to. A scan, unlike attention, pays
+        # for every padded position.
+        self.phases.count("prefill_tokens_real", len(prompt))
+        self.phases.count(
+            "prefill_tokens_padded", self._bucket_width(len(prompt))
+        )
         row = (row_cache, row_logits, row_pos, row_kv, row_allow)
         return row, width, full_prompt
 

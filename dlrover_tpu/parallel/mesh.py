@@ -65,6 +65,10 @@ MESH_AXIS_REGISTRY: Dict[str, Tuple[str, str]] = {
     "mlp": ("logical", "feed-forward hidden dim"),
     "conv_channels": ("logical", "a gated short convolution's channels, each with its own filter (split like an MLP's hidden dim)"),
     "conv_taps": ("logical", "a short convolution's filter taps (three; kept local)"),
+    "mamba_proj": ("logical", "a Mamba-2 mixer's fused input projection [z ; x B C ; dt]: heads' channels beside the groups' B and C (kept local)"),
+    "mamba_channels": ("logical", "the channels [x ; B ; C] of a Mamba-2 mixer's short convolution (kept local)"),
+    "mamba_inner": ("logical", "a Mamba-2 mixer's inner width, heads x channels, where its output projection contracts it (split like an MLP's hidden dim)"),
+    "mamba_heads": ("logical", "a Mamba-2 mixer's per-head floats: dt_bias, A_log, D (kept local)"),
     "vocab": ("logical", "embedding/logits vocabulary dim"),
     "expert": ("logical", "MoE expert index"),
     "expert_mlp": ("logical", "per-expert feed-forward hidden dim"),

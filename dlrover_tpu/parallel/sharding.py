@@ -35,6 +35,13 @@ DEFAULT_RULES: LogicalRules = [
     # the channels split like an MLP's hidden dim, the taps stay whole
     ("conv_channels", "tp"),
     ("conv_taps", None),
+    # a Mamba-2 mixer: the fused input projection's columns mix the heads'
+    # channels with B and C, which every head reads, and stay whole; the
+    # output projection contracts the inner width like an MLP's second matrix
+    ("mamba_proj", None),
+    ("mamba_channels", None),
+    ("mamba_inner", "tp"),
+    ("mamba_heads", None),
     ("vocab", "tp"),
     ("expert", "ep"),  # MoE experts distributed over the ep axis
     ("expert_mlp", "tp"),  # per-expert hidden dim still tensor-parallel

@@ -352,3 +352,34 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
     # into a bf16 one before its first token
     for compiled in (prefill, chunk):
         assert "f32[50304,768]" not in compiled.as_text()
+
+
+def test_ssd_scan_and_step_compile_at_the_published_widths(
+    one_chip, no_persistent_cache
+):
+    """The chunked scan over a 512-wide prefill of one row and the
+    one-token step for 32 rows at Granite 4.0-H Micro's widths (64 heads of
+    64, state 128, chunks of 256): block products XLA compiles, no kernel.
+    The step's program holds the state as argument and as output and next
+    to nothing else: one pass in, one out."""
+    from dlrover_tpu.ops.ssd_scan import ssd_scan, ssd_step
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, p, n, t, rows = 64, 64, 128, 512, 32
+    scan = jax.jit(ssd_scan, static_argnums=5).lower(
+        shaped(1, t, heads, p, dtype=jnp.bfloat16), shaped(1, t, heads),
+        shaped(heads), shaped(1, t, 1, n, dtype=jnp.bfloat16),
+        shaped(1, t, 1, n, dtype=jnp.bfloat16), 256, shaped(1, heads, p, n),
+    ).compile()
+    assert _device_bytes(scan) < V5E_HBM_BYTES // 16
+    state = rows * heads * p * n * 4
+    step = jax.jit(ssd_step).lower(
+        shaped(rows, heads, p, n), shaped(rows, heads, p, dtype=jnp.bfloat16),
+        shaped(rows, heads), shaped(heads),
+        shaped(rows, 1, n, dtype=jnp.bfloat16), shaped(rows, 1, n, dtype=jnp.bfloat16),
+    ).compile()
+    memory = step.memory_analysis()
+    assert memory.argument_size_in_bytes < 1.02 * state
+    assert memory.temp_size_in_bytes < state // 8  # no second copy of the state

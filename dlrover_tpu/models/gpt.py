@@ -116,9 +116,34 @@ def _dequant_kv(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots=None):
+def _fold_heads(x):
+    """``[B, T, H, Hd]`` -> ``[B, T, lanes]``: a token's heads side by
+    side in the minor dimension, then zeros up to a multiple of 128.
+
+    The TPU tiles an array's two minor dimensions, (16, 128) for bf16.
+    A ``[H, Hd]`` pair pads head by head: [25, 64] takes 2.56x its size
+    in HBM, and every decode step reads all of it. ``H*Hd`` lanes pad at
+    most to the next 128 ([1600] -> [1664], 1.04x). The zeros make that
+    padding the array's own, so that no program is handed a
+    ``[.., L, 1600]`` array laid out with the positions minor."""
+    B, T, H, Hd = x.shape
+    return jnp.pad(
+        x.reshape(B, T, H * Hd), ((0, 0), (0, 0), (0, -(H * Hd) % 128))
+    )
+
+
+def _update_decode_cache(
+    module, max_len, k, v, kv_valid, cache_slots=None, *, fold=False
+):
     """Write this call's K/V into the module's decode cache; return the
     full cache plus the attention mask for the queries of this call.
+
+    The positional leaves ``k`` and ``v`` are ``[B, max_len, KVH, Hd]``,
+    or with ``fold`` ``[B, max_len, lanes]`` (:func:`_fold_heads`; the
+    caller asks for it where queries and keys have the same heads, see
+    :func:`cached_decode_attention`; the int8 cache never folds). Axis
+    1 is the position either way and both write rules below are the
+    same for both.
 
     Incremental decoding the flax way (``"cache"`` variable collection),
     shared by GPT and Llama attention. The DEFAULT path follows the
@@ -155,11 +180,15 @@ def _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots=None):
         k_store, v_store = k, v
         k_scale = v_scale = None
         store_dtype = k.dtype
+    if fold and not int8_cache:
+        k_store, v_store = _fold_heads(k_store), _fold_heads(v_store)
     ck = module.variable(
-        "cache", "k", jnp.zeros, (B, max_len) + k.shape[2:], store_dtype
+        "cache", "k", jnp.zeros, (B, max_len) + k_store.shape[2:],
+        store_dtype,
     )
     cv = module.variable(
-        "cache", "v", jnp.zeros, (B, max_len) + v.shape[2:], store_dtype
+        "cache", "v", jnp.zeros, (B, max_len) + v_store.shape[2:],
+        store_dtype,
     )
     if int8_cache:
         csk = module.variable(
@@ -213,12 +242,9 @@ def _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots=None):
         mask = kv_valid[:, None, :] & causal  # [B, T, max_len]
         return _read(mask)
     offset = cidx.value
-    ck.value = jax.lax.dynamic_update_slice(
-        ck.value, k_store, (0, offset, 0, 0)
-    )
-    cv.value = jax.lax.dynamic_update_slice(
-        cv.value, v_store, (0, offset, 0, 0)
-    )
+    at = (0, offset) + (0,) * (k_store.ndim - 2)
+    ck.value = jax.lax.dynamic_update_slice(ck.value, k_store, at)
+    cv.value = jax.lax.dynamic_update_slice(cv.value, v_store, at)
     if int8_cache:
         csk.value = jax.lax.dynamic_update_slice(
             csk.value, k_scale, (0, offset, 0)
@@ -244,12 +270,25 @@ def cached_decode_attention(
     """Update the module's decode cache with this call's K/V, then run
     attention in the cache's STORAGE precision: the bf16 cache feeds
     the plain masked einsum; the int8 cache feeds the int8 x int8 MXU
-    path. The single decode-attention entry point for GPT and Llama.
+    path. The single decode-attention entry point for GPT, Llama, LFM2
+    and Granite.
+
+    Two bf16 leaves, chosen by what the call is given: where queries
+    and keys have the same heads (GPT-2, an ungrouped Llama) the cache
+    is folded (:func:`_fold_heads`, read by
+    :func:`_masked_attention_folded`); a GQA-narrow cache stays
+    ``[B, max_len, KVH, Hd]`` under the grouped einsums, and so does
+    the int8 cache. Two for now: ``docs/generation.md`` says why.
     """
-    res = _update_decode_cache(module, max_len, k, v, kv_valid, cache_slots)
+    res = _update_decode_cache(
+        module, max_len, k, v, kv_valid, cache_slots,
+        fold=q.shape[2] == k.shape[2],
+    )
     if len(res) == 3:
         k_full, v_full, mask = res
-        return _masked_attention(q, k_full, v_full, mask, wo, cfg)
+        folded = k_full.ndim == 3
+        attend = _masked_attention_folded if folded else _masked_attention
+        return attend(q, k_full, v_full, mask, wo, cfg)
     k8, ks, v8, vs, mask = res
     return _masked_attention_int8(q, k8, ks, v8, vs, mask, wo, cfg)
 
@@ -313,7 +352,8 @@ def _masked_attention(q, k, v, mask, wo, cfg):
     (k/v head count < q head count) the contraction is grouped instead
     of widening the cache: re-materializing [B, max_len, H, Hd] every
     single-token step would multiply exactly the HBM traffic the narrow
-    cache exists to avoid.
+    cache exists to avoid. (``H == KVH`` reaches here from the folded
+    body's ``T > 1`` view only.)
     """
     Hd = q.shape[-1]
     H, KVH = q.shape[2], k.shape[2]
@@ -336,6 +376,45 @@ def _masked_attention(q, k, v, mask, wo, cfg):
         )
         out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
     y = jnp.einsum("bqhk,hkd->bqd", out, wo.astype(cfg.dtype))
+    return _constrain(y, "batch", "seq", "embed")
+
+
+def _masked_attention_folded(q, k, v, mask, wo, cfg):
+    """:func:`_masked_attention` over a folded cache: ``k`` and ``v`` are
+    ``[B, L, lanes]`` with head h in lanes ``[h*Hd, (h+1)*Hd)``
+    (:func:`_fold_heads`), ``q`` is ``[B, T, H, Hd]``. The same sums of
+    the same terms: bf16 operands, float32 softmax, the same mask.
+
+    A decode step (``T == 1``) costs what it reads of the cache, so it
+    leaves the leaf where it lies and contracts the whole lane
+    dimension. The query goes in spread block-diagonally
+    (``spread[b, h*Hd + d, h] = q[b, h, d]``, zero elsewhere), so
+    ``K @ spread`` is one matrix product a row that streams K once,
+    lane-dense; ``probs @ V`` gives every head all the lanes, of which
+    it keeps its own ``Hd``. The zeros add nothing to a sum; the extra
+    arithmetic is ``H`` times a one-token attention's, nothing beside
+    the bytes. ``T > 1`` (a prefill into the cache, a prefix's
+    continuation) would pay ``H * T`` times, so it views the leaf as
+    ``[B, L, H, Hd]`` and contracts head by head: one relayout of a
+    row's cache is nothing beside a prefill.
+    """
+    B, T, H, Hd = q.shape
+    if T > 1:
+        k4 = k[..., : H * Hd].reshape(B, -1, H, Hd)
+        v4 = v[..., : H * Hd].reshape(B, -1, H, Hd)
+        return _masked_attention(q, k4, v4, mask, wo, cfg)
+    scale = 1.0 / jnp.sqrt(Hd).astype(q.dtype)
+    own = jnp.eye(H, dtype=q.dtype)
+    spread = jnp.einsum("bhd,hg->bhdg", q[:, 0], own).reshape(B, H * Hd, H)
+    spread = jnp.pad(spread, ((0, 0), (0, k.shape[2] - H * Hd), (0, 0)))
+    logits = jnp.einsum("bsj,bjh->bhs", k, spread) * scale
+    logits = jnp.where(mask, logits, -1e9)  # [B, 1, L] over [B, H, L]
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(
+        q.dtype
+    )
+    full = jnp.einsum("bhs,bsj->bhj", probs, v)[..., : H * Hd]
+    out = jnp.einsum("bhgd,hg->bhd", full.reshape(B, H, H, Hd), own)
+    y = jnp.einsum("bqhk,hkd->bqd", out[:, None], wo.astype(cfg.dtype))
     return _constrain(y, "batch", "seq", "embed")
 
 

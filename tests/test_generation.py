@@ -32,6 +32,13 @@ def _init(model, rng=0):
     )["params"]
 
 
+def _one_layer_gpt(**heads):
+    return GPTConfig(
+        vocab_size=256, max_seq_len=64, num_layers=1, embed_dim=32,
+        use_remat=False, **heads,
+    )
+
+
 MODELS = {
     "gpt": lambda: GPT(GPTConfig.tiny()),
     "gpt_remat": lambda: GPT(
@@ -49,7 +56,17 @@ MODELS = {
     "llama_moe": lambda: Llama(
         LlamaConfig.tiny(num_experts=4, moe_every=2)
     ),
+    # the folded decode cache (queries and keys with the same heads): a
+    # width that is no multiple of 128 lanes (GPT-2 XL's 25 x 64 = 1600,
+    # stored as 1664), one that is (GPT-2 small's 12 x 64 = 768), and a
+    # Llama without grouping (4 x 8 = 32, stored as 128)
+    "gpt_25x64": lambda: GPT(_one_layer_gpt(num_heads=25, head_dim=64)),
+    "gpt_12x64": lambda: GPT(_one_layer_gpt(num_heads=12, head_dim=64)),
+    "llama_ungrouped": lambda: Llama(LlamaConfig.tiny(num_kv_heads=4)),
 }
+# the models whose cache leaves are ``[B, L, lanes]``; "llama" and
+# "llama_moe" (4 query heads over 2 kv heads) keep ``[B, L, KVH, Hd]``
+FOLDED = ["gpt", "gpt_12x64", "gpt_25x64", "llama_ungrouped"]
 
 
 class TestDecodeMatchesFullForward:
@@ -80,8 +97,9 @@ class TestDecodeMatchesFullForward:
             np.asarray(pred), np.asarray(out[0, :5])
         )
 
-    def test_decode_logprobs_match_full_forward(self):
-        model = MODELS["llama"]()
+    @pytest.mark.parametrize("name", ["llama"] + FOLDED)
+    def test_decode_logprobs_match_full_forward(self, name):
+        model = MODELS[name]()
         params = _init(model)
         toks, mask = left_pad_prompts([[5, 6, 7]], pad_id=0)
         out, _, logp = generate(
@@ -103,10 +121,121 @@ class TestDecodeMatchesFullForward:
         )
 
 
+def _full_logprobs(model, params, seq):
+    """float32 log-probabilities after ``seq``, by the uncached forward."""
+    logits = model.apply({"params": params}, jnp.asarray([seq]))
+    return np.asarray(
+        jax.nn.log_softmax(logits[0, -1].astype(jnp.float32), axis=-1)
+    )
+
+
+def _assert_rows_match_full_forward(model, params, last, seqs):
+    """``last`` [B, V]: the cached path's logits after each of ``seqs``."""
+    got = np.asarray(jax.nn.log_softmax(last.astype(jnp.float32), axis=-1))
+    for row, seq in zip(got, seqs):
+        want = _full_logprobs(model, params, seq)
+        assert int(row.argmax()) == int(want.argmax())
+        np.testing.assert_allclose(row, want, rtol=2e-2, atol=2e-2)
+
+
+class TestCachedPathMatchesFullForward:
+    """Every way the engines write and read the decode cache, against the
+    uncached forward over the same tokens: for the folded leaf (a one-token
+    step contracts all its lanes at once, a longer call views it head by
+    head) and, as the control, the grouped leaf."""
+
+    PROMPTS = [[5, 9, 2, 17, 3], [7, 1, 4]]
+    WIDTH = 8
+
+    def _prefilled(self, name):
+        from dlrover_tpu.models.generation import prefill_prompt
+
+        model = MODELS[name]()
+        params = _init(model)
+        toks, mask = left_pad_prompts(self.PROMPTS, width=self.WIDTH)
+        return model, params, prefill_prompt(model, params, toks, mask)
+
+    @pytest.mark.parametrize("name", FOLDED)
+    def test_leaf_is_positions_by_lanes(self, name):
+        model = MODELS[name]()
+        cfg = model.config
+        lanes = -(-cfg.num_heads * cfg.head_dim // 128) * 128
+        leaves = [
+            leaf
+            for leaf in jax.tree_util.tree_leaves(init_cache(model, 3))
+            if leaf.ndim
+        ]
+        assert len(leaves) == 2 * cfg.num_layers
+        assert all(
+            leaf.shape == (3, cfg.max_seq_len, lanes) for leaf in leaves
+        )
+
+    @pytest.mark.parametrize("name", ["llama"] + FOLDED)
+    def test_per_row_slots_at_different_positions(self, name):
+        """``cache_slots``: each row writes at its own slot (the serving
+        engine's layout); row 1 starts five slots further on, and the
+        slots in between stay invalid."""
+        from dlrover_tpu.models.generation import decode_apply
+
+        model, params, (cache, last, pos, kvv) = self._prefilled(name)
+        L = model.config.max_seq_len
+        slots = jnp.asarray([self.WIDTH, self.WIDTH + 5])
+        seqs = [list(p) for p in self.PROMPTS]
+        _assert_rows_match_full_forward(model, params, last, seqs)
+        for _ in range(3):
+            tok = jnp.argmax(last, axis=-1)
+            for seq, t in zip(seqs, tok.tolist()):
+                seq.append(t)
+            kvv = kvv | (jnp.arange(L)[None, :] == slots[:, None])
+            pos = pos + 1
+            logits, cache = decode_apply(
+                model, params, cache, tok[:, None], pos[:, None], kvv,
+                cache_slots=slots,
+            )
+            last = logits[:, 0]
+            slots = slots + 1
+            _assert_rows_match_full_forward(model, params, last, seqs)
+
+    @pytest.mark.parametrize("name", ["llama"] + FOLDED)
+    def test_continuation_onto_a_stored_prefix(self, name):
+        """A three-token call (T > 1) written behind a left-padded prefix
+        at the shared offset, then one-token steps that read it back."""
+        from dlrover_tpu.models.generation import decode_apply
+
+        model, params, (cache, _, pos, kvv) = self._prefilled(name)
+        L, W, T = model.config.max_seq_len, self.WIDTH, 3
+        suffix = jnp.asarray([[11, 12, 13], [21, 22, 23]])
+        slot = jnp.arange(L)[None, :]
+        kvv = kvv | ((slot >= W) & (slot < W + T))
+        positions = pos[:, None] + 1 + jnp.arange(T)[None, :]
+        logits, cache = decode_apply(
+            model, params, cache, suffix, positions, kvv
+        )
+        for i in range(T):
+            seqs = [
+                p + suffix[b, : i + 1].tolist()
+                for b, p in enumerate(self.PROMPTS)
+            ]
+            _assert_rows_match_full_forward(model, params, logits[:, i], seqs)
+        pos, last = positions[:, -1], logits[:, -1]
+        for step in range(2):
+            tok = jnp.argmax(last.astype(jnp.float32), axis=-1)
+            seqs = [seq + [t] for seq, t in zip(seqs, tok.tolist())]
+            kvv = kvv | (slot == W + T + step)
+            pos = pos + 1
+            logits, cache = decode_apply(
+                model, params, cache, tok[:, None], pos[:, None], kvv
+            )
+            last = logits[:, 0]
+            _assert_rows_match_full_forward(model, params, last, seqs)
+
+
 class TestLeftPadding:
     """Left-padded batch rows behave exactly like unpadded rows."""
 
-    @pytest.mark.parametrize("name", ["gpt", "llama"])
+    @pytest.mark.parametrize(
+        "name", ["gpt", "llama", "gpt_12x64", "gpt_25x64", "llama_ungrouped"]
+    )
     def test_padded_row_matches_unpadded(self, name):
         model = MODELS[name]()
         params = _init(model)

@@ -263,6 +263,32 @@ def test_cache_leaves_and_their_kinds():
     assert kinds["index"] is False
     assert sum(jax.tree.leaves(kinds)) == 3  # three convolution layers of four
 
+def test_attention_keeps_the_grouped_cache_leaf_and_contraction():
+    """Queries over fewer kv heads: the keys and values stay ``[B, L, KVH,
+    Hd]`` and a decode step contracts them group by group, as
+    ``gpt._masked_attention`` writes it (the folded ``[B, L, lanes]`` leaf
+    is the ungrouped models'; this cell's programs are to stay as measured
+    until the grouped body is folded too: ``docs/generation.md``)."""
+    cfg = Lfm2MoeConfig.tiny()
+    model = Lfm2MoeLM(cfg)
+    rows, L, kvh, hd = 3, cfg.max_seq_len, cfg.num_key_value_heads, cfg.head_size
+    groups = cfg.num_attention_heads // kvh
+    assert groups > 1
+    cache = jax.eval_shape(lambda: init_cache(model, rows))
+    attn = [layer["attn"] for layer in cache.values() if isinstance(layer, dict) and "attn" in layer]
+    assert attn and all(a[name].shape == (rows, L, kvh, hd) for a in attn for name in ("k", "v"))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    one = jax.ShapeDtypeStruct((rows, 1), jnp.int32)
+    text = jax.jit(lambda p, c, tok, pos, kv, slots: decode_apply(model, p, c, tok, pos, kv, cache_slots=slots)).lower(
+        params, cache, one, one, jax.ShapeDtypeStruct((rows, L), jnp.bool_), jax.ShapeDtypeStruct((rows,), jnp.int32)
+    ).as_text()
+    leaf, query = f"tensor<{rows}x{L}x{kvh}x{hd}xbf16>", f"tensor<{rows}x1x{kvh}x{groups}x{hd}xbf16>"
+    products = [line for line in text.split("\n") if "dot_general" in line and leaf in line]
+    assert len(products) == 2 * len(attn)  # q.k and p.v of each attention layer
+    assert sum(query in line and "batching_dims = [0, 2] x [0, 2]" in line for line in products) == len(attn)
+    assert f"tensor<{rows}x{L}x128xbf16>" not in text
+
+
 
 def test_decode_counters_are_the_layers_sums():
     cfg = Lfm2MoeConfig.tiny(dtype=jnp.float32, num_hidden_layers=4, num_dense_layers=1)

@@ -8,6 +8,7 @@ one-shot engine; weight reload hot-swaps from a flash checkpoint.
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -245,8 +246,16 @@ class TestHealthzStats:
         serving_host_frac headline plus the per-phase table."""
         base, *_ = server
         _post(base, "/v1/completions", {"prompt": [5, 9, 2]})
-        with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
-            h = json.loads(r.read())
+        # the reply leaves when the request's last token is read; the
+        # chunk dispatched behind it is read a moment later, and only a
+        # chunk with nothing in flight behind it books "retirement"
+        deadline = time.monotonic() + 30
+        while True:
+            with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
+                h = json.loads(r.read())
+            if "retirement_ms" in h["phase_split"] or time.monotonic() > deadline:
+                break
+            time.sleep(0.02)
         assert "serving_host_frac" in h
         split = h["phase_split"]
         assert split["rounds"] > 0

@@ -1037,6 +1037,100 @@ class TestConstrainedDecoding:
             eng.submit([1], allowed_tokens=[999])
 
 
+# -- the two bf16 cache leaves through the engine --------------------------
+
+
+def _cache_family(name):
+    """One-layer models by the leaf their decode cache stores: folded
+    ``[B, L, lanes]`` where queries and keys have the same heads (25 x 64
+    = 1600 lanes stored as 1664, 12 x 64 = 768 as they are, an ungrouped
+    Llama's 4 x 8 = 32 as 128), ``[B, L, KVH, Hd]`` under grouping."""
+    from dlrover_tpu.models.llama import Llama, LlamaConfig
+
+    if name.startswith("gpt_"):
+        heads, head_dim = (int(n) for n in name[4:].split("x"))
+        return GPT(
+            GPTConfig(
+                vocab_size=64, max_seq_len=64, num_layers=1, embed_dim=32,
+                num_heads=heads, head_dim=head_dim, use_remat=False,
+            )
+        )
+    kv_heads = {"llama_ungrouped": 4, "llama_grouped": 2}[name]
+    return Llama(
+        LlamaConfig.tiny(
+            vocab_size=64, max_seq_len=64, num_layers=1,
+            num_kv_heads=kv_heads,
+        )
+    )
+
+
+CACHE_FAMILIES = ["gpt_25x64", "gpt_12x64", "llama_ungrouped", "llama_grouped"]
+
+
+class TestCacheLeavesThroughTheEngine:
+    PREFIX = [11, 23, 5, 42, 9]
+    SUFFIXES = [[7, 1], [3, 3, 8, 2]]
+
+    def _stream(self, model, params, overlap=True):
+        eng = ContinuousBatchingEngine(
+            model, params, SamplingConfig(max_new_tokens=6, temperature=0.0),
+            batch_size=3, prompt_width=16, decode_chunk=4, overlap=overlap,
+        )
+        prompts = _mixed_prompts(5, rng_seed=3, lo=4, hi=9)
+        for p in prompts:
+            eng.submit(p)
+        pid = eng.register_prefix(self.PREFIX)
+        for sfx in self.SUFFIXES:
+            eng.submit(sfx, prefix_id=pid)
+        whole = prompts + [self.PREFIX + sfx for sfx in self.SUFFIXES]
+        return eng, eng.run(), whole
+
+    @pytest.mark.parametrize("name", CACHE_FAMILIES)
+    def test_stream_matches_plain_decode_and_the_uncached_forward(self, name):
+        """Rows admitted at different positions (per-row slots), one-token
+        steps after left-padded prefills, and suffixes continued onto a
+        stored prefix (a T > 1 call over a filled cache): greedy tokens
+        equal the plain engine's, and each token's log-probability the
+        full forward's over prompt + completion."""
+        model = _cache_family(name)
+        params = _params(model)
+        eng, got, whole = self._stream(model, params)
+        leaves = [
+            leaf for leaf in jax.tree.leaves(eng._state[0]) if leaf.ndim
+        ]
+        cfg = model.config
+        if name == "llama_grouped":
+            want_shape = (3, 64, cfg.num_kv_heads, cfg.head_dim)
+        else:
+            lanes = -(-cfg.num_heads * cfg.head_dim // 128) * 128
+            want_shape = (3, 64, lanes)
+        assert [leaf.shape for leaf in leaves] == [want_shape] * 2
+        sampling = SamplingConfig(max_new_tokens=6, temperature=0.0)
+        want = _reference_completions(model, params, whole, sampling)
+        for c, prompt, w in zip(got, whole, want):
+            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
+            logits = model.apply(
+                {"params": params}, jnp.asarray([prompt + c.tokens])
+            ).astype(jnp.float32)
+            logp = np.asarray(jax.nn.log_softmax(logits[0], axis=-1))
+            full = [
+                logp[len(prompt) - 1 + i, t] for i, t in enumerate(c.tokens)
+            ]
+            np.testing.assert_allclose(
+                c.logprobs, full, rtol=2e-2, atol=2e-2
+            )
+
+    @pytest.mark.parametrize("name", CACHE_FAMILIES)
+    def test_overlapped_round_is_bit_identical_with_the_sync_round(self, name):
+        model = _cache_family(name)
+        params = _params(model)
+        _, sync, _ = self._stream(model, params, overlap=False)
+        _, ovl, _ = self._stream(model, params, overlap=True)
+        assert [(c.uid, c.tokens, c.logprobs) for c in ovl] == [
+            (c.uid, c.tokens, c.logprobs) for c in sync
+        ]
+
+
 # -- the engine holds its matrices in the dtype the model computes in ------
 
 

@@ -246,6 +246,20 @@ def _gpt2_small_step(devices, mesh_config, batch=32):
     return step_fn.lower(state, data, data), state
 
 
+def _described(tree, sharding, rows=None):
+    """The arrays of ``tree`` as shapes on a described device; with
+    ``rows``, a serving engine's state for that many slots (each of its
+    arrays has the slots first)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            (rows,) + a.shape[1:] if rows and a.ndim else a.shape,
+            a.dtype,
+            sharding=sharding,
+        ),
+        tree,
+    )
+
+
 def _device_bytes(compiled):
     m = compiled.memory_analysis()
     return (
@@ -325,22 +339,16 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
         decode_chunk=8,
     )
 
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree,
-        )
-
     row = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((1, 64), jnp.bool_, sharding=one_chip)
-    held = described(engine.params)  # the tree the programs get
+    held = _described(engine.params, one_chip)  # the tree the programs get
     prefill = engine._prefill_fn.lower(held, row, mask).compile()
     chunk = (
         engine._chunk_for(engine.d)
         .lower(
             held,
-            described(engine._state),
-            described(jax.random.PRNGKey(0)),
+            _described(engine._state, one_chip),
+            _described(jax.random.PRNGKey(0), one_chip),
         )
         .compile()
     )
@@ -352,6 +360,85 @@ def test_serving_prefill_and_decode_chunk_compile_for_v5e(
     # into a bf16 one before its first token
     for compiled in (prefill, chunk):
         assert "f32[50304,768]" not in compiled.as_text()
+
+
+def test_xl_decode_chunk_carries_the_folded_cache_for_v5e(
+    one_chip, no_persistent_cache, request
+):
+    """The engine's chunk program (8 steps) at GPT-2 XL's widths, 4 slots,
+    prompt width 512, 128 new tokens: what ``gpt2xl-serve-closed`` runs.
+    The cache's leaves are ``[4, 1024, 1664]`` (25 heads of 64 side by
+    side in the lanes), so the loop's carry holds them at 1.04 times their
+    content and not, as ``[4, 1024, 25, 64]`` did, at 2.56 times: the
+    temporaries were 3.49 GB, nearly all of it that padded copy. Under
+    ``pytest -s`` the same program over 8 and 16 rows is compiled too and
+    the sizes printed for ``PERF.md``'s slots table (not judged: whether
+    they fit is a cell's to say; not compiled where nobody would read
+    them: 17 s of every core each)."""
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+
+    model = GPT(dataclasses.replace(GPTConfig.gpt2_xl(), use_remat=False))
+    shapes = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+    )
+    # zeros in the dtypes the engine holds (3.1 GB of host memory): it
+    # takes them as they are
+    params = jax.tree.map(
+        lambda a, dtype: jnp.zeros(a.shape, dtype),
+        shapes,
+        model.consumed_param_dtypes(shapes),
+    )
+    slots = 4
+    engine = ContinuousBatchingEngine(
+        model,
+        params,
+        SamplingConfig(max_new_tokens=128, temperature=0.0),
+        batch_size=slots,
+        prompt_width=512,
+        decode_chunk=8,
+    )
+    leaves = [a for a in jax.tree.leaves(engine._state[0]) if a.ndim > 0]
+    assert len(leaves) == 2 * 48
+    assert all(a.shape == (slots, 1024, 1664) for a in leaves)
+
+    def compiled_for(rows):
+        return (
+            engine._chunk_for(8)
+            .lower(
+                _described(engine.params, one_chip),
+                _described(engine._state, one_chip, rows),
+                _described(jax.random.PRNGKey(0), one_chip),
+            )
+            .compile()
+        )
+
+    chunk = compiled_for(slots)
+    memory = chunk.memory_analysis()
+    assert _device_bytes(chunk) < V5E_HBM_BYTES
+    assert memory.temp_size_in_bytes < 1.6e9, memory
+    text = chunk.as_text()
+    assert "bf16[4,1024,1664]" in text
+    assert "[4,1024,25,64]" not in text
+
+    def sizes(m):
+        return (
+            f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB, output "
+            f"{m.output_size_in_bytes / 1e9:.3f}, temporaries "
+            f"{m.temp_size_in_bytes / 1e9:.3f}"
+        )
+
+    if request.config.getoption("capture") != "no":
+        return
+    print(f"\nXL decode chunk, described v5e, {slots} slots: {sizes(memory)}")
+    for rows in (8, 16):
+        try:
+            print(f"{rows} slots: {sizes(compiled_for(rows).memory_analysis())}")
+        except jax.errors.JaxRuntimeError as e:  # refused: a finding too
+            print(f"{rows} slots: refused: {str(e)[:300]}")
 
 
 def test_ssd_scan_and_step_compile_at_the_published_widths(

@@ -20,4 +20,11 @@ The TPU compute path (models, parallelism, kernels) lives in
 :mod:`dlrover_tpu.models`, :mod:`dlrover_tpu.parallel`, :mod:`dlrover_tpu.ops`.
 """
 
+import time as _time
+
+# The earliest stamp this package can take of its process: a start-up record
+# (attribution/recovery.py) falls back on it where the kernel's own start
+# time of the process cannot be read.
+FIRST_LINE_UNIX_NS = _time.time_ns()
+
 __version__ = "0.1.0"

@@ -19,6 +19,10 @@ import threading
 import time
 from typing import Dict, Optional
 
+from ..attribution.recovery import (
+    startup_from_process_start,
+    write_startup_record,
+)
 from ..chaos import faults
 from ..checkpoint.saver import AsyncCheckpointSaver
 from ..common.constants import (
@@ -32,6 +36,7 @@ from ..common.log import logger
 from ..master.diagnosis.action import DiagnosisActionType
 from ..observability import trace
 from ..observability.metrics import get_registry, maybe_start_metrics_server
+from ..observability.spans import startup_span
 from ..rpc.client import MasterClient
 from .config import ElasticLaunchConfig
 from .diagnosis_agent import DiagnosisAgent, WorkerFailure
@@ -156,6 +161,9 @@ class ElasticTrainingAgent:
             # which is on the recovery critical path — can adopt a
             # warm interpreter.
             self._replenish_spare(delay_s=0.0)
+            # everything so far (the launcher, the standalone master with
+            # it, the pre-check) is the start-up phase ``agent_up``
+            startup_from_process_start("agent_up")
             self._initialize_workers()
             return self._invoke_run()
         finally:
@@ -199,7 +207,7 @@ class ElasticTrainingAgent:
             # the worker's restore pays no peer fetch after the join.
             AsyncCheckpointSaver.prefetch_restore_async()
             t_rdzv = time.monotonic()
-            with self._evt.duration(
+            with startup_span("rdzv"), self._evt.duration(
                 "rendezvous", node_rank=self._config.node_rank
             ) as span:
                 self._world = self._rdzv_handler.next_rendezvous()
@@ -211,19 +219,25 @@ class ElasticTrainingAgent:
                     }
                 )
             # MTTR phase attribution: rdzv_s is the agent's phase of
-            # the recovery breakdown (attribution/recovery.py); the
-            # spool no-ops without DLROVER_RECOVERY_DIR.
-            from ..attribution.recovery import record_phase_file
+            # the recovery breakdown (attribution/recovery.py)
+            rdzv_s = round(time.monotonic() - t_rdzv, 3)
+        with startup_span("spawn"):
+            self._start_worker()
+        # One record a worker start, written at the spawn: the agent's
+        # phases since its own start, or since the death it saw (a file
+        # only where DLROVER_RECOVERY_DIR or --log_dir says where).
+        payload = {
+            "round": self._world.round,
+            "restart": self._restart_count,
+            "node_rank": self._config.node_rank,
+            "worker_pid": self._worker.pid,
+        }
+        if world is None:
+            payload["rdzv_s"] = rdzv_s
+        write_startup_record("rdzv", payload, emitter=self._evt, close=False)
 
-            record_phase_file(
-                "rdzv",
-                {
-                    "rdzv_s": round(time.monotonic() - t_rdzv, 3),
-                    "round": self._world.round,
-                    "restart": self._restart_count,
-                    "node_rank": self._config.node_rank,
-                },
-            )
+    def _start_worker(self) -> None:
+        """World formed -> worker process started."""
         registry = get_registry()
         registry.counter("dlrover_agent_rendezvous_rounds_total").inc()
         registry.gauge("dlrover_agent_world_size").set(self._world.world_size)
@@ -446,7 +460,8 @@ class ElasticTrainingAgent:
         get_registry().counter("dlrover_agent_worker_restarts_total").inc()
         self._evt.instant("restart_worker", reason=reason)
         if self._worker is not None:
-            self._worker.stop()
+            with startup_span("worker_stop"):
+                self._worker.stop()
         self._restart_count += 1
         self._initialize_workers(world=world)
 
@@ -558,29 +573,31 @@ class ElasticTrainingAgent:
 
     def _handle_worker_failure(self, result: RunResult) -> Optional[int]:
         """Breakpoint-save, diagnose, restart or relaunch (training.py:1074)."""
-        logger.error(
-            "worker failed rc=%s signal=%s restart=%s",
-            result.returncode,
-            result.signal,
-            self._restart_count,
-        )
-        self._begin_incident(
-            "worker_failure",
-            returncode=result.returncode,
-            signal=result.signal,
-            node_rank=self._config.node_rank,
-        )
-        if self._config.save_at_breakpoint:
-            self._save_ckpt_at_breakpoint()
-        failure = WorkerFailure(
-            node_rank=self._config.node_rank,
-            restart_count=self._restart_count,
-            returncode=result.returncode,
-            signal=result.signal,
-            log_tail=self._worker.tail_log(),
-        )
-        self._diagnosis.report_failure(failure)
-        action = self._diagnosis.diagnose_training_failure(failure)
+        # death seen -> respawn decided: the first phase of a restart's record
+        with startup_span("respawn_decide"):
+            logger.error(
+                "worker failed rc=%s signal=%s restart=%s",
+                result.returncode,
+                result.signal,
+                self._restart_count,
+            )
+            self._begin_incident(
+                "worker_failure",
+                returncode=result.returncode,
+                signal=result.signal,
+                node_rank=self._config.node_rank,
+            )
+            if self._config.save_at_breakpoint:
+                self._save_ckpt_at_breakpoint()
+            failure = WorkerFailure(
+                node_rank=self._config.node_rank,
+                restart_count=self._restart_count,
+                returncode=result.returncode,
+                signal=result.signal,
+                log_tail=self._worker.tail_log(),
+            )
+            self._diagnosis.report_failure(failure)
+            action = self._diagnosis.diagnose_training_failure(failure)
         if (
             action == DiagnosisActionType.RESTART_WORKER
             and self._remaining_restarts > 0

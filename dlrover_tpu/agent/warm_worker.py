@@ -52,6 +52,11 @@ def main() -> int:
     line = sys.stdin.readline()
     if not line.strip():
         return 0  # agent closed the pipe: spare no longer needed
+    # For the start-up record this process begins now, at the hand-off: the
+    # spare's own start and imports were paid while another worker trained.
+    from dlrover_tpu.attribution.recovery import restart_startup_clock
+
+    restart_startup_clock()
     contract = json.loads(line)
     os.environ.update({k: str(v) for k, v in contract["env"].items()})
     entrypoint = contract["entrypoint"]

@@ -527,8 +527,9 @@ def run_recovery_ab(
 
     Returns ``{"cold": ..., "warm": ..., "mttr_delta_s",
     "cold_compile_s", "warm_compile_s"}`` or None when either leg
-    timed out. The warm leg's ``compile_s ≈ 0`` (and strictly lower
-    MTTR) is the acceptance number for the warm-restart fast path.
+    timed out. The warm leg's ``compile_s`` (measured: tracing, lowering
+    and a cache read, against the cold leg's XLA compile) and its strictly
+    lower MTTR are the acceptance numbers for the warm-restart fast path.
     """
     os.makedirs(workdir, exist_ok=True)
     params = dict(_AB_STORM)

@@ -25,6 +25,7 @@ from typing import List, Optional, Tuple
 
 from ..agent.config import ElasticLaunchConfig
 from ..agent.training_agent import ElasticTrainingAgent
+from ..attribution.recovery import default_recovery_dir
 from ..common.constants import (
     Accelerators,
     DefaultValues,
@@ -371,6 +372,9 @@ def run(ns: argparse.Namespace) -> int:
 
     init_error_handler()
     config = config_from_args(ns)
+    # start-up records of the agent and its workers: <log_dir>/startup
+    # unless DLROVER_RECOVERY_DIR names another place
+    default_recovery_dir(config.log_dir)
     master_handle: Optional[LocalMasterHandle] = None
     if ns.standalone and not config.master_addr:
         master_handle = launch_local_master(

@@ -50,8 +50,9 @@ from http.server import ThreadingHTTPServer
 from typing import Optional
 
 from ..common.constants import ENV_KNOBS
+from ..common.events import EventEmitter
 from ..common.log import logger
-from ..observability.spans import span
+from ..observability.spans import span, startup_span
 
 # A round of the loaded engine is a decode chunk long (~0.1 s), a burst of
 # prefills a few times that: an iteration this long is a standstill.
@@ -495,6 +496,7 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
 
         def do_GET(self):
             if self.path == "/healthz":
+                from ..common.compile_cache import compile_records
                 from ..common.platform import device_summary
 
                 stats = daemon.eng.stats()
@@ -521,6 +523,10 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                         "serving_host_frac": (
                             stats.get("phase_split") or {}
                         ).get("serving_host_frac"),
+                        # the last programs built, by name and seconds
+                        # (common/compile_cache.py): one built under load
+                        # is here, and in the log, with its time
+                        "compiles": compile_records(),
                         **stats,
                     },
                 )
@@ -921,15 +927,12 @@ def main(argv=None) -> int:
     )
     ns = ap.parse_args(argv)
 
+    from ..attribution.recovery import (
+        startup_from_process_start,
+        write_startup_record,
+    )
+    from ..common.compile_cache import watch_compiles
     from ..common.platform import force_virtual_cpu, pin_accelerator
-
-    if ns.cpu:
-        force_virtual_cpu(1)
-    else:
-        # no hidden CPU: without --cpu (or a caller's own JAX_PLATFORMS)
-        # a failed TPU initialization raises here instead of serving
-        # from the host
-        pin_accelerator()
 
     import jax
 
@@ -937,50 +940,84 @@ def main(argv=None) -> int:
     from ..models.serving import ContinuousBatchingEngine
     from ..parallel.mesh import MeshConfig, build_mesh
 
-    config = dict(DEFAULT_CONFIG if not ns.config else json.loads(ns.config))
-    if ns.kv_int8:
-        config["kv_cache_int8"] = True
-    model, _ = build_model({"family": ns.family, "config": config})
-    if "decode" not in inspect.signature(model.__call__).parameters:
-        ap.error(f"--family {ns.family} has no decode path: it cannot be served")
-    mesh = build_mesh(MeshConfig(dp=-1), jax.devices()[:1])
+    # Start-up names its own time (attribution/recovery.py): the phases
+    # below partition this thread's time up to the listening socket. The
+    # programs are built later, on first requests; those seconds are
+    # measured by the compile listeners, one INFO line a program, and
+    # ride with the phases in /healthz (``phase_split``, ``compiles``).
+    startup_from_process_start("imports")
+    with startup_span("backend"):
+        if ns.cpu:
+            force_virtual_cpu(1)
+        else:
+            # no hidden CPU: without --cpu (or a caller's own
+            # JAX_PLATFORMS) a failed TPU initialization raises here
+            # instead of serving from the host
+            pin_accelerator()
+        # listeners only: the cache's own options stay the caller's
+        watch_compiles(quiet_after_startup=True)
+        devices = jax.devices()
+
+    with startup_span("build_model"):
+        config = dict(
+            DEFAULT_CONFIG if not ns.config else json.loads(ns.config)
+        )
+        if ns.kv_int8:
+            config["kv_cache_int8"] = True
+        model, _ = build_model({"family": ns.family, "config": config})
+        if "decode" not in inspect.signature(model.__call__).parameters:
+            ap.error(
+                f"--family {ns.family} has no decode path: it cannot be served"
+            )
+        mesh = build_mesh(MeshConfig(dp=-1), devices[:1])
 
     reload_fn = None
-    if ns.ckpt_dir:
-        reload_fn = lambda: _restore_params(  # noqa: E731
-            model, mesh, ns.ckpt_dir
-        )
-        step, params = reload_fn()
-        logger.info("restored checkpoint step %s from %s", step, ns.ckpt_dir)
-    else:
-        params = _init_params(model)
-        logger.warning("no --ckpt-dir: serving RANDOM weights (smoke mode)")
+    with startup_span("params"):
+        if ns.ckpt_dir:
+            reload_fn = lambda: _restore_params(  # noqa: E731
+                model, mesh, ns.ckpt_dir
+            )
+            step, params = reload_fn()
+            logger.info(
+                "restored checkpoint step %s from %s", step, ns.ckpt_dir
+            )
+        else:
+            params = _init_params(model)
+            logger.warning(
+                "no --ckpt-dir: serving RANDOM weights (smoke mode)"
+            )
 
-    sampling = SamplingConfig(
-        max_new_tokens=ns.max_new_tokens,
-        temperature=ns.temperature,
-        top_k=ns.top_k,
-        top_p=ns.top_p,
-        eos_id=ns.eos_id,
+    with startup_span("engine"):
+        sampling = SamplingConfig(
+            max_new_tokens=ns.max_new_tokens,
+            temperature=ns.temperature,
+            top_k=ns.top_k,
+            top_p=ns.top_p,
+            eos_id=ns.eos_id,
+        )
+        engine = ContinuousBatchingEngine(
+            model, params, sampling,
+            batch_size=ns.batch_size,
+            prompt_width=ns.prompt_width,
+            decode_chunk=ns.decode_chunk,
+            cache_layout=ns.cache_layout,
+            overlap=not ns.sync_round,
+            auto_chunk=ns.auto_chunk,
+            kv_block_size=ns.kv_block_size,
+            kv_pool_blocks=ns.kv_pool_blocks,
+        )
+        # the engine holds the tree its programs read (the matrices
+        # rounded to the compute dtype); a restored float32 one goes with
+        # this reference
+        del params
+    with startup_span("listen"):
+        daemon = ServingDaemon(engine).start()
+        httpd = serve(daemon, ns.port, reload_fn, replica_id=ns.replica_id,
+                      role=ns.role)
+    write_startup_record(
+        "server", {"replica_id": ns.replica_id, "port": ns.port},
+        emitter=EventEmitter("serve"),
     )
-    engine = ContinuousBatchingEngine(
-        model, params, sampling,
-        batch_size=ns.batch_size,
-        prompt_width=ns.prompt_width,
-        decode_chunk=ns.decode_chunk,
-        cache_layout=ns.cache_layout,
-        overlap=not ns.sync_round,
-        auto_chunk=ns.auto_chunk,
-        kv_block_size=ns.kv_block_size,
-        kv_pool_blocks=ns.kv_pool_blocks,
-    )
-    # the engine holds the tree its programs read (the matrices rounded
-    # to the compute dtype); a restored float32 one goes with this
-    # reference
-    del params
-    daemon = ServingDaemon(engine).start()
-    httpd = serve(daemon, ns.port, reload_fn, replica_id=ns.replica_id,
-                  role=ns.role)
     logger.info(
         "tpurun-serve on :%s — %s slots × %s new tokens, prompt width %s",
         httpd.server_address[1], ns.batch_size, ns.max_new_tokens,

@@ -104,6 +104,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..attribution.phases import PhaseAccumulator
+from ..attribution.recovery import startup_summary
 from ..chaos import faults
 from ..observability.spans import span
 from . import kv_blocks
@@ -1789,8 +1790,12 @@ class ContinuousBatchingEngine:
             "swap_failures": self.swap_failures,
             "last_swap_error": self.last_swap_error,
             # host/device attribution (attribution.phases): host_frac
-            # plus per-phase totals, compact enough for /healthz
-            "phase_split": self.phases.split().summary(),
+            # plus per-phase totals, compact enough for /healthz; beside
+            # them this process's start-up phases and compile totals
+            # (attribution.recovery), which no round ever books
+            "phase_split": {
+                **self.phases.split().summary(), **startup_summary()
+            },
         }
 
     def partial(self, uid: int):

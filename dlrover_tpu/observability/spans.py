@@ -21,11 +21,18 @@ to hold the last events before a death. Incident spans (``ckpt_save``,
 ``rendezvous``) keep ``DurationSpan``, which opens an annotation of its
 own name through :func:`annotation` when used as a ``with`` block.
 
+Start-up phases use the same primitive (:func:`startup_span`): a span
+named ``startup.<phase>`` whose begin (``unix_ns``) and length are also
+kept, one record a phase, so that a start can be laid out on one clock
+with the starts of other processes (``attribution/recovery.py`` writes
+the record). They run once a start and never in a loop.
+
 This module imports nothing heavy: the agent, the master and the
 launcher never import JAX (the chip belongs to the worker), so the
 annotation class is looked up only in a process that already has.
 """
 
+import functools
 import sys
 import threading
 import time
@@ -106,6 +113,27 @@ class _Span:
             self._acc._book(self._key, dur_s, dur_s - self._child_s)
 
 
+class _StartupSpan(_Span):
+    """A start-up phase: a span that also leaves ``{name, unix_ns, s}`` in
+    its accumulator's list of phases."""
+
+    __slots__ = ("_unix_ns",)
+
+    def __init__(self, acc: "SpanAccumulator", name: str, stats: dict):
+        self._unix_ns = time.time_ns()
+        super().__init__(acc, name, name, dict(stats, unix_ns=self._unix_ns))
+
+    def __enter__(self) -> "_StartupSpan":
+        self._acc._local.startup_open = True
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur_s = time.perf_counter() - self._t0
+        super().__exit__(exc_type, exc, tb)
+        self._acc._local.startup_open = False
+        self._acc._keep_phase(self._key, self._unix_ns, dur_s)
+
+
 class SpanAccumulator:
     """Running per-name totals of spans, and plain counters. Booking is a
     few dict ops under a lock — cheap enough to leave always-on (a few
@@ -116,12 +144,53 @@ class SpanAccumulator:
         self._counters: Dict[str, float] = {}
         self._lock = threading.Lock()
         self._local = threading.local()  # the innermost open span per thread
+        # start-up phases in the order they ended, until the start's
+        # record is written (``take_startup_phases(close=True)``)
+        self._phases: List[dict] = []
+        self.startup_closed = False
+        self.startup_taken = 0  # records written so far (an agent: many)
 
     def span(self, name: str, book: Optional[str] = None, **stats) -> _Span:
         """``with acc.span(name, **stats):`` — annotate under ``name`` and
         book under ``book`` (default ``name``; ``""`` books nothing, for a
         span that only frames its children on the trace)."""
         return _Span(self, name, name if book is None else book, stats)
+
+    def startup_span(self, phase: str, **stats) -> _Span:
+        """``with acc.startup_span("backend"):`` — the span
+        ``startup.<phase>``, kept as a phase of this start. Once the
+        start's record is written, or inside another phase on the same
+        thread, it only annotates: a phase's code called again in steady
+        state (a reload, a re-plan) is not start-up, and phases never
+        nest, so their seconds add up to wall time."""
+        name = "startup." + phase
+        if self.startup_closed or getattr(self._local, "startup_open", False):
+            return _Span(self, name, "", stats)
+        return _StartupSpan(self, name, stats)
+
+    def add_startup_phase(self, phase: str, unix_ns: int, dur_s: float) -> None:
+        """A phase measured elsewhere: the time before this module could
+        be imported (process start to the first line that ran)."""
+        if not self.startup_closed:
+            self.add("startup." + phase, dur_s)
+            self._keep_phase("startup." + phase, unix_ns, dur_s)
+
+    def _keep_phase(self, name: str, unix_ns: int, dur_s: float) -> None:
+        with self._lock:
+            self._phases.append(
+                {"name": name, "unix_ns": unix_ns, "s": round(max(dur_s, 0.0), 6)}
+            )
+
+    def take_startup_phases(self, close: bool = False) -> List[dict]:
+        """The phases kept so far, handed over (the list starts anew: an
+        agent writes one record a worker start). ``close`` ends this
+        process's start-up: later ``startup_span`` calls keep nothing."""
+        with self._lock:
+            phases, self._phases = self._phases, []
+            self.startup_taken += 1
+            if close:
+                self.startup_closed = True
+        return phases
 
     def add(self, name: str, dur_s: float) -> None:
         """Book one duration measured elsewhere."""
@@ -166,6 +235,9 @@ class SpanAccumulator:
         with self._lock:
             self._stats.clear()
             self._counters.clear()
+            self._phases.clear()
+            self.startup_closed = False
+            self.startup_taken = 0
 
 
 _process = SpanAccumulator()
@@ -179,3 +251,22 @@ def process_accumulator() -> SpanAccumulator:
 def span(name: str, **stats) -> _Span:
     """``with span("ckpt.save", step=7):`` on the process accumulator."""
     return _Span(_process, name, name, stats)
+
+
+def startup_span(phase: str, **stats) -> _Span:
+    """``with startup_span("backend"):`` on the process accumulator."""
+    return _process.startup_span(phase, **stats)
+
+
+def startup_phase(phase: str):
+    """Decorator: the whole call is the start-up phase ``phase``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _process.startup_span(phase):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap

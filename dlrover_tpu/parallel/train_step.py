@@ -17,6 +17,7 @@ from flax.core import unfreeze
 from flax.linen import partitioning as nn_partitioning
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..observability.spans import startup_phase
 from .mesh import current_mesh
 from .sharding import DEFAULT_RULES, apply_rules, data_sharding_for
 
@@ -164,6 +165,7 @@ def state_shardings(
         return abstract_state, sharding_tree
 
 
+@startup_phase("init_state")
 def init_train_state(
     model,
     example_input,
@@ -195,6 +197,7 @@ def init_train_state(
     return state, sharding_tree
 
 
+@startup_phase("build_step")
 def build_train_step(
     model,
     tx: optax.GradientTransformation,

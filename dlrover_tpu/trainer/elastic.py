@@ -73,12 +73,24 @@ class ElasticContext:
         return max(1, self.num_processes * max(1, local))
 
     def initialize_jax(self) -> None:
-        """Bring up the multi-host JAX runtime for this world.
+        """Bring up the multi-host JAX runtime for this world, to a live
+        backend: the start-up phase ``startup.backend`` ends with the
+        first ``jax.devices()``, so that the PJRT client's seconds are
+        named here and not inside whatever touches a device first.
 
         Single-process worlds skip ``jax.distributed`` entirely — that is
         also the standalone/test path where the process uses the local
         (or virtual CPU) devices directly.
         """
+        from ..observability.spans import startup_span
+
+        with startup_span("backend"):
+            self._initialize_distributed()
+            import jax
+
+            jax.devices()
+
+    def _initialize_distributed(self) -> None:
         from ..profiler.stack_dump import (
             install_stack_dump_handler,
             start_ring_dump_watcher,
@@ -170,9 +182,11 @@ def elastic_context(initialize: bool = True) -> ElasticContext:
         # placement rule lives in common/compile_cache.py): applied
         # here, before any compilation, so a restart's re-compile is a
         # cache read.
+        from ..attribution.recovery import startup_from_process_start
         from ..common.compile_cache import enable_compile_cache
 
-        enable_compile_cache()
+        enable_compile_cache()  # imports JAX where the script has not
+        startup_from_process_start("imports")
         _context = ElasticContext.from_env()
         if initialize:
             _context.initialize_jax()

@@ -16,15 +16,16 @@ import time
 from typing import Any, Callable, Iterable, Optional, Tuple
 
 # Imported at module load on purpose: _write_recovery_record runs right
-# after the first steady step, and a package import at that point means
+# after the first step, and a package import at that point means
 # dataclass machinery + a GC burst in the middle of live training — the
 # exact moment a worker can least afford allocator churn.
-from ..attribution.recovery import record_phase_file
+from ..attribution.recovery import write_startup_record
+from ..common.compile_cache import compile_seconds_since
 from ..common.constants import NodeEnv
 from ..common.events import EventEmitter
 from ..common.log import logger
 from ..observability.metrics import get_registry
-from ..observability.spans import span
+from ..observability.spans import process_accumulator, span, startup_span
 
 # Process-wide GC tracer installed by the first loop run (gc.callbacks
 # hooks must not stack when run() is called repeatedly).
@@ -170,6 +171,8 @@ class ElasticTrainLoop:
         # the phases this process owns, spooled to DLROVER_RECOVERY_DIR.
         self.last_restore_s = 0.0
         self.last_first_step_s = 0.0
+        # measured (common/compile_cache.py: JAX's own events): seconds of
+        # tracing, lowering and compile or cache read inside the first step
         self.last_compile_s: Optional[float] = None
         self._recovery_written = False
         # Cooperative step-boundary stop (chip-pool revocation,
@@ -207,7 +210,9 @@ class ElasticTrainLoop:
         source before any collective placement runs.
         """
         t0 = time.monotonic()
-        with self._evt.duration("train_restore") as span:
+        with startup_span("restore"), self._evt.duration(
+            "train_restore"
+        ) as span:
             loaded, restored = self.engine.load_consistent(state)
             span.end({"loaded_step": loaded})
         self.last_restore_s = time.monotonic() - t0
@@ -313,36 +318,36 @@ class ElasticTrainLoop:
 
     # -- warm-restart instrumentation --------------------------------------
 
-    def _record_boot_step(self, idx: int, loss, t0: float) -> None:
-        """Time the first two steps after (re)start. The first carries
-        the XLA (re)compile; the second is steady state, so their
-        difference attributes ``compile_s`` — the phase the persistent
-        compile cache (and compile-ahead) collapses. Blocks on the loss
-        so the measurement covers execution, not just dispatch — paid
-        on exactly two steps."""
+    def _record_boot_step(self, loss, t0: float) -> None:
+        """Time the first step after (re)start, call to result ready: it
+        carries the XLA (re)compile, or the cache read the persistent
+        compile cache (and compile-ahead) turns it into. ``compile_s`` is
+        what JAX's own events measured of the programs built inside it.
+        Blocks on the loss so the measurement covers execution, not just
+        dispatch — paid on exactly one step."""
         try:
             import jax
 
             jax.block_until_ready(loss)
-        # tpulint: ignore[exception-swallow] non-jax step outputs land here EVERY step; logging at step cadence would spam, and the timing fallback is the designed behavior
+        # tpulint: ignore[exception-swallow] a non-jax step output lands here once a start; the timing fallback is the designed behavior
         except Exception:  # noqa: BLE001 — non-jax step_fn outputs
             pass
         dt = time.monotonic() - t0
-        if idx == 0:
-            self.last_first_step_s = dt
-            # Start anticipating only now: the service must never
-            # compete with the live first compile for the CPU.
-            self._start_compile_ahead()
-        else:
-            self.last_compile_s = max(0.0, self.last_first_step_s - dt)
-            # Steady state reached: the incident (if any) is over.
-            self._evt.instant(
-                "train_resume",
-                restore_s=round(self.last_restore_s, 3),
-                first_step_s=round(self.last_first_step_s, 3),
-                compile_s=round(self.last_compile_s, 3),
-            )
-            self._write_recovery_record()
+        began_ns = time.time_ns() - int(dt * 1e9)
+        self.last_first_step_s = dt
+        self.last_compile_s = compile_seconds_since(began_ns)
+        process_accumulator().add_startup_phase("first_step", began_ns, dt)
+        # Start anticipating only now: the service must never
+        # compete with the live first compile for the CPU.
+        self._start_compile_ahead()
+        # The watermark moves again: the incident (if any) is over.
+        self._evt.instant(
+            "train_resume",
+            restore_s=round(self.last_restore_s, 3),
+            first_step_s=round(self.last_first_step_s, 3),
+            compile_s=round(self.last_compile_s, 3),
+        )
+        self._write_recovery_record()
 
     def _anticipation_current(self) -> int:
         """The "current world" the compile-ahead ladder pivots on:
@@ -464,8 +469,10 @@ class ElasticTrainLoop:
         return state
 
     def _write_recovery_record(self) -> None:
-        """Spool this boot's phase breakdown for the storm/bench
-        aggregator (no-op without DLROVER_RECOVERY_DIR)."""
+        """This start's record, once: the recovery breakdown's keys and
+        the whole start-up beside them (attribution/recovery.py), to the
+        spool (no file without DLROVER_RECOVERY_DIR), the event stream
+        and the log."""
         if self._recovery_written:
             return
         self._recovery_written = True
@@ -477,8 +484,7 @@ class ElasticTrainLoop:
         }
         if self.last_compile_s is not None:
             payload["compile_s"] = round(self.last_compile_s, 3)
-        if record_phase_file("worker", payload):
-            logger.info("recovery breakdown: %s", payload)
+        write_startup_record("worker", payload, emitter=self._evt)
 
     # tpulint: hotpath — scalar fetch at log cadence only
     def _report(self, step, loss) -> None:
@@ -596,12 +602,12 @@ class ElasticTrainLoop:
                 self.ctx.start_step_timer()
             if tt_begin is not None:
                 tt_begin(step)
-            timed = step - start < 2  # first step = compile + step
+            timed = step == start  # first step = compile + step
             t_step0 = time.monotonic() if timed else 0.0
             with span("train.step_dispatch", step=step):
                 state, loss = self.step_fn(state, *batch)
             if timed:
-                self._record_boot_step(step - start, loss, t_step0)
+                self._record_boot_step(loss, t_step0)
             if tt_end is not None:
                 tt_end(step)
             # Cadence saves stage asynchronously (device-side snapshot +
@@ -624,10 +630,6 @@ class ElasticTrainLoop:
             with span("train.report"):
                 self._report(step, loss)
             step += 1
-        if step > start and not self._recovery_written:
-            # one-step runs never saw a steady step: record without the
-            # compile split rather than not at all
-            self._write_recovery_record()
         if last_save_ok and not self.engine.wait_staged_all():
             last_save_ok = False  # async stage failed — redo blocking below
         if step > start and not last_save_ok:

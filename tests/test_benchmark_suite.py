@@ -30,6 +30,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_program_spans",
     "benchmark.tests.test_reduce_trace",
     "benchmark.tests.test_rehearsal",
+    "benchmark.tests.test_metrics_startup",
 )
 
 from benchmark.tests.test_metrics import *  # noqa: E402,F401,F403
@@ -39,3 +40,4 @@ from benchmark.tests.test_metrics_granite_hybrid import *  # noqa: E402,F401,F40
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reduce_trace import *  # noqa: E402,F401,F403
 from benchmark.tests.test_rehearsal import *  # noqa: E402,F401,F403
+from benchmark.tests.test_metrics_startup import *  # noqa: E402,F401,F403

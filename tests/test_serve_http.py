@@ -241,6 +241,52 @@ class TestHealthzStats:
         assert h["registered_prefixes"] == 0
         assert h["kv_cache_int8"] is False
 
+    def test_healthz_carries_startup_phases_and_compile_totals(self, server):
+        """The start-up phases and the compile totals of the process ride
+        in ``phase_split`` as counters (no key of theirs ends in ``_ms``:
+        readers sum those into a round's total), and ``compiles`` lists the
+        last programs by name."""
+        from dlrover_tpu.common.compile_cache import watch_compiles
+        from dlrover_tpu.observability.spans import (
+            process_accumulator,
+            startup_span,
+        )
+
+        base, *_, daemon = server
+        watch_compiles(quiet_after_startup=True)
+        acc = process_accumulator()
+        closed = acc.startup_closed
+        acc.startup_closed = False
+        try:
+            with startup_span("engine"):
+                pass
+        finally:
+            acc.startup_closed = closed
+        _post(base, "/v1/completions", {"prompt": [4, 8, 1, 6]})
+        with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
+            h = json.loads(r.read())
+        split = h["phase_split"]
+        assert split["rounds"] > 0
+        ours = {k: v for k, v in split.items()
+                if k.startswith(("startup.", "compile."))}
+        assert "startup.engine_s_sum" in ours
+        assert ours["compile.programs_n"] >= 1
+        assert ours["compile.backend_s_sum"] + ours.get(
+            "compile.cache_read_s_sum", 0.0
+        ) > 0
+        assert not any(k.endswith("_ms") for k in ours)
+        # the engine's own summary is what it was: phases in _ms, its
+        # counters, and nothing of the start-up inside the accumulator
+        own = daemon.eng.phases.split().summary()
+        assert not any(k.startswith(("startup.", "compile.")) for k in own)
+        # (read a moment later: a phase may have been booked meanwhile)
+        assert {k for k in split if k.endswith("_ms")} <= {
+            k for k in own if k.endswith("_ms")
+        }
+        assert isinstance(h["compiles"], list) and h["compiles"]
+        assert {"fun_name", "unix_ns", "trace_s", "lower_s", "backend_s",
+                "cache", "thread"} <= set(h["compiles"][-1])
+
     def test_healthz_exposes_attribution_breakdown(self, server):
         """/healthz carries the host/device split: the top-level
         serving_host_frac headline plus the per-phase table."""

@@ -15,11 +15,32 @@ Layout: inputs are ``[batch, seq, heads, head_dim]`` (the model's
 ``bqhk``); kernels operate on ``[batch*heads, seq, head_dim]``. Blocks
 default to 1024×1024, fp32 softmax, inputs in bf16 on TPU.
 
+Two sets of kernels, chosen from the call's static shapes in one place
+(:func:`_sub_block`), with no argument or flag:
+
+- **The causal walk** (the training shape: causal self-attention over
+  whole square tiles): a 1024×1024 block is what one DMA brings, not what
+  one product computes. The diagonal tile is walked in 256-wide
+  sub-blocks that compute only what the mask leaves (10 of its 16
+  sub-squares) and mask only the squares on the diagonal; a tile under
+  the diagonal carries no mask; a tile above it is neither run nor
+  fetched; where a head is one tile (T <= 1024) a backward sub-block is
+  one pass, with no state between pieces, and the forward keeps the
+  general kernel. These kernels hold the scores transposed (keys × query
+  rows): the softmax's max and sum then run down sublanes, on the vector
+  unit, where reductions along lanes kept the forward waiting on the
+  cross-lane unit; ``m``, ``l``, lse and delta are lane-dense rows in
+  VMEM; and dk/dv's products take ``p^T`` and ``ds^T`` as they lie, with
+  no transpose of a score tile. In HBM the backward kernels take lse
+  and delta as the general ones do, ``[.., T, 8]`` (tiled (8, 128): 512
+  bytes a query row), and turn a block into a row once a grid step.
+- **The general kernels** (``t_q != t_kv``, a ragged T, no mask, unequal
+  blocks, T under one sub-block): one masked-everywhere body a tile.
+
 Two head sizes: q and k share ``d`` (the score's contraction), v and the
 output share ``d_v``, and the two may differ (latent attention:
 ``d = 192`` for nope + rope, ``d_v = 128``). dq and dk come back ``d``
-wide, dv ``d_v`` wide. With ``d_v == d`` the kernels are the same
-programs as before the split. The default scale is ``1 / sqrt(d)``.
+wide, dv ``d_v`` wide. The default scale is ``1 / sqrt(d)``.
 
 On the CPU backend (tests, rehearsals) the same kernels run in Pallas
 interpret mode, so CPU tests cover the kernel logic bit-for-bit. A
@@ -37,15 +58,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.spans import span
+
 # Tuned on v5e silicon (in-device scan timing, B=32/H=12/T=1024/D=64 and
 # B=4/T=4096): 1024×1024 beats 512×1024 by ~27% fwd-only and ~10%
-# fwd+bwd — fewer grid steps amortize the online-softmax rescale and the
-# per-block mask/iota work, and the 4 MB f32 probability tile still
-# leaves VMEM headroom (2048-wide tiles fail to compile).
+# fwd+bwd — fewer grid steps amortize the per-step DMA and the
+# online-softmax rescale (2048-wide tiles fail to compile). Under the
+# causal walk the block is the DMA tile only: on the diagonal the
+# products, the exp and the mask run on sub-block-wide pieces of it, so
+# the largest score tile in VMEM there is 1024×256 (1 MB of f32).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
-# Trailing lanes used to materialize per-row scalars (lse/delta) in HBM.
+# Side of the sub-squares a diagonal tile is walked in (the causal walk):
+# a multiple of the 128 lanes and of the 16 sublanes of bf16.
+_SUB_BLOCK = 256
+# Trailing lanes that carry a per-row scalar (lse, delta) in HBM; the
+# walk's forward writes its lse as rows instead, in this many sublanes.
 _LSE_LANES = 8
+_ROW_SUBLANES = 8
 _NEG_INF = -1e30
 
 
@@ -138,8 +168,8 @@ def _fwd_kernel(
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
         lse = m_ref[:, :1] + jnp.log(l_safe)
         # lse carries a trailing dim of 8 — the smallest the Mosaic block
-        # rules allow (equal to the overall array dim), 16x leaner than a
-        # full 128-lane tile.
+        # rules allow (equal to the overall array dim). In HBM the rows
+        # are tiled (8, 128) all the same: 8 lanes buy nothing there.
         lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LSE_LANES))
 
 
@@ -167,6 +197,367 @@ def _pad_to(x, size, axis):
     return jnp.pad(x, widths)
 
 
+# ---------------------------------------------------------------------------
+# the causal walk (the training shape)
+# ---------------------------------------------------------------------------
+
+_TRANS_A = (((0,), (0,)), ((), ()))  # a^T @ b
+_TRANS_B = (((1,), (1,)), ((), ()))  # a @ b^T
+_PLAIN = (((1,), (0,)), ((), ()))
+
+
+def _sub_block(
+    causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, forward: bool
+) -> int:
+    """The side of the sub-squares a diagonal tile is walked in, or 0 for
+    the general kernels. The one place that decides, from what the call's
+    shapes say: the walk is for causal self-attention over whole square
+    tiles (no padding, no offset between rows and keys) that hold at
+    least one sub-block; anything else keeps the masked-everywhere body.
+
+    The forward walks only where a head is more than one tile; at one
+    tile a head it keeps the general kernel and its outputs' bits
+    (ROADMAP.md B9 says why, and when that exception goes)."""
+    walked = (
+        causal
+        and t_q == t_kv
+        and block_q == block_k
+        and t_q % block_q == 0
+        and block_q % _SUB_BLOCK == 0
+    )
+    if not walked or (forward and t_q == block_q):
+        return 0
+    return _SUB_BLOCK
+
+
+def _diagonal_pieces(block: int, sub: int, by_keys: bool):
+    """A diagonal tile as ``(row0, rows, key0, keys)`` pieces, one a
+    sub-block. Forward and dq accumulate by q rows: rows ``[i*sub,
+    (i+1)*sub)`` against keys ``[0, (i+1)*sub)``, whose last ``sub`` keys
+    are the square on the diagonal. dk/dv accumulate by key columns
+    (``by_keys``): keys ``[j*sub, (j+1)*sub)`` against q rows ``[j*sub,
+    block)``, whose first ``sub`` rows are the square. Only the square
+    needs the mask."""
+    if by_keys:
+        return [(lo, block - lo, lo, sub) for lo in range(0, block, sub)]
+    return [(lo, sub, 0, lo + sub) for lo in range(0, block, sub)]
+
+
+def _walk_causal(body, iq, ik, block: int, sub: int, n_tiles: int, by_keys: bool):
+    """Run ``body(rows, keys, where)`` over what the causal mask leaves of
+    tile (iq, ik): a tile under the diagonal whole and unmasked (``where``
+    None), the diagonal tile one sub-block at a time, a tile above it not
+    at all (its blocks are not fetched either: the index maps repeat the
+    last tile that runs). ``where(x, fill)`` fills what the mask removes
+    of a ``[keys, rows]`` piece: only the piece's square on the diagonal
+    is selected, the rest of ``x`` passes as it is."""
+
+    def diagonal():
+        key = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+        keep = key <= row
+
+        def where(x, fill):
+            if x.shape == keep.shape:
+                return jnp.where(keep, x, fill)
+            if by_keys:  # the square is the first ``sub`` q rows (lanes)
+                return jnp.concatenate(
+                    [jnp.where(keep, x[:, :sub], fill), x[:, sub:]], axis=1
+                )
+            return jnp.concatenate(  # the last ``sub`` keys (sublanes)
+                [x[:-sub], jnp.where(keep, x[-sub:], fill)], axis=0
+            )
+
+        for row0, n_rows, key0, n_keys in _diagonal_pieces(block, sub, by_keys):
+            body(slice(row0, row0 + n_rows), slice(key0, key0 + n_keys), where)
+
+    if n_tiles == 1:  # the one tile is the diagonal's: no branch at all
+        diagonal()
+        return
+    pl.when(ik < iq)(lambda: body(slice(None), slice(None), None))
+    pl.when(ik == iq)(diagonal)
+
+
+def _kernel_plan(causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, sub: int):
+    """What one head's grid does, for the ``flash.kernel_built`` record:
+    grid steps, steps whose body runs, and the scores computed as a share
+    of the ``t_q x t_kv`` square (the mask itself keeps 0.5 of it)."""
+    n_q = _round_up(t_q, block_q) // block_q
+    n_k = _round_up(t_kv, block_k) // block_k
+    if sub:
+        tiles_run = n_q * (n_q + 1) // 2
+        scores = (tiles_run - n_q) * block_q * block_k + n_q * sum(
+            rows * keys
+            for _, rows, _, keys in _diagonal_pieces(block_q, sub, False)
+        )
+    else:
+        tiles_run = sum(
+            not causal or j * block_k <= i * block_q + block_q - 1 + t_kv - t_q
+            for i in range(n_q)
+            for j in range(n_k)
+        )
+        scores = tiles_run * block_q * block_k
+    return {
+        "path": "causal_tiled" if sub else "general",
+        "tiles_visited": n_q * n_k,
+        "tiles_run": tiles_run,
+        "score_share": scores / (t_q * t_kv),
+    }
+
+
+def _built(kernel: str, plan: dict):
+    """The span around one kernel's tracing: ``flash.kernel_built`` with
+    the plan as stats."""
+    return span("flash.kernel_built", kernel=kernel, **plan)
+
+
+def _as_row(ref):
+    """A block of per-row scalars as it lies in HBM, ``(1, rows, 8)``
+    with the value in every lane, as a ``(1, rows)`` row."""
+    return ref[0].T[:1]
+
+
+def _walk_fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, sm_scale: float, block: int, sub: int, n_tiles: int,
+):
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def body(rows, keys, where):
+        """One online-softmax update of q ``rows`` by ``keys`` of the
+        tiles in VMEM, the scores held ``[keys, rows]``."""
+        q = q_ref[0, rows]  # keep input dtype: bf16 rides the MXU
+        k = k_ref[0, keys]
+        v = v_ref[0, keys]
+        s = jax.lax.dot_general(k, q, _TRANS_B, preferred_element_type=jnp.float32)
+        s *= sm_scale
+        if where is not None:
+            s = where(s, _NEG_INF)
+        m_prev = m_ref[:1, rows]  # (1, rows)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:1, rows] = alpha * l_ref[:1, rows] + jnp.sum(
+            p, axis=0, keepdims=True
+        )
+        m_ref[:1, rows] = m_new
+        pv = jax.lax.dot_general(  # (p @ v)^T: (d_v, rows)
+            v, p.astype(v.dtype), _TRANS_A, preferred_element_type=jnp.float32
+        )
+        acc_ref[:, rows] = acc_ref[:, rows] * alpha + pv
+
+    _walk_causal(body, iq, ik, block, sub, n_tiles, False)
+
+    @pl.when(ik == n_tiles - 1)
+    def _finish():
+        l = l_ref[:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[:] / l_safe).T.astype(o_ref.dtype)
+        lse = m_ref[:1] + jnp.log(l_safe)
+        lse_ref[0] = jnp.broadcast_to(lse, (_ROW_SUBLANES, block))
+
+
+def _walk_bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs_and_scratch,
+    sm_scale: float, block: int, sub: int, n_tiles: int, by_keys: bool,
+):
+    """dk/dv (``by_keys``: grid ``(head, key tile, q tile)``, outputs and
+    accumulators ``[keys, d]`` and ``[keys, d_v]``) or dq (grid ``(head, q
+    tile, key tile)``, its accumulator held transposed, ``[d, rows]``)."""
+    n_out = len(outs_and_scratch) // 2
+    outs, accs = outs_and_scratch[:n_out], outs_and_scratch[n_out:]
+    it, inner = pl.program_id(1), pl.program_id(2)
+    iq, ik = (inner, it) if by_keys else (it, inner)
+    # One tile a head (T <= the block): each sub-block's result is whole
+    # after its one piece and is written where it goes; no accumulator,
+    # nothing to start or finish.
+    one_pass = n_tiles == 1
+
+    if not one_pass:
+
+        @pl.when(inner == 0)
+        def _init():
+            for acc in accs:
+                acc[:] = jnp.zeros_like(acc)
+
+    lse_row, delta_row = _as_row(lse_ref), _as_row(delta_ref)
+
+    def body(rows, keys, where):
+        q = q_ref[0, rows]
+        k = k_ref[0, keys]
+        v = v_ref[0, keys]
+        do = do_ref[0, rows]
+        lse = lse_row[:, rows]  # (1, rows)
+        delta = delta_row[:, rows]
+        s = jax.lax.dot_general(k, q, _TRANS_B, preferred_element_type=jnp.float32)
+        s *= sm_scale
+        p = jnp.exp(s - lse)  # (keys, rows)
+        if where is not None:
+            p = where(p, 0.0)
+        # dp^T = v @ do^T ; ds^T = p^T * (dp^T - delta) * scale
+        dp = jax.lax.dot_general(v, do, _TRANS_B, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        if by_keys:  # dk = ds^T @ q ; dv = p^T @ do: as the tiles lie
+            found = (
+                jax.lax.dot_general(ds, q, _PLAIN, preferred_element_type=jnp.float32),
+                jax.lax.dot_general(
+                    p.astype(do.dtype), do, _PLAIN, preferred_element_type=jnp.float32
+                ),
+            )
+            for out, acc, x in zip(outs, accs, found):
+                if one_pass:
+                    out[0, keys] = x.astype(out.dtype)
+                else:
+                    acc[keys] += x
+        else:  # dq^T = k^T @ ds^T
+            dq = jax.lax.dot_general(k, ds, _TRANS_A, preferred_element_type=jnp.float32)
+            if one_pass:
+                outs[0][0, rows] = dq.T.astype(outs[0].dtype)
+            else:
+                accs[0][:, rows] += dq
+
+    _walk_causal(body, iq, ik, block, sub, n_tiles, by_keys)
+    if one_pass:
+        return
+
+    @pl.when(inner == n_tiles - 1)
+    def _finish():
+        if by_keys:
+            for out, acc in zip(outs, accs):
+                out[0] = acc[:].astype(out.dtype)
+        else:
+            outs[0][0] = accs[0][:].T.astype(outs[0].dtype)
+
+
+# Each walked kernel is a jitted function of its own, inlined where it is
+# called: a model's layers call it with one set of shapes, so the Python
+# body (the unrolled walk) is traced once a process and every further layer
+# re-binds the traced equations, where the general kernels trace theirs
+# anew at each of a step's call sites (XL: 48 layers x 3). Inlined, each
+# call is lowered under its caller's scope, so a device trace names the
+# kernel after the model's layer as it names the general ones.
+# ``interpret`` is an argument because it is part of what was traced.
+_walk_jit = functools.partial(
+    jax.jit,
+    static_argnames=("sm_scale", "block", "sub", "interpret"),
+    inline=True,
+)
+
+
+@_walk_jit
+def _walk_fwd(q, k, v, *, sm_scale: float, block: int, sub: int, interpret: bool):
+    """The forward of the causal walk: whole square tiles, no padding."""
+    bh, t, d = q.shape
+    d_v = v.shape[2]
+    n = t // block
+    # a tile above the diagonal names the diagonal's blocks: no copy
+    at_k = lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _walk_fwd_kernel, sm_scale=sm_scale, block=block, sub=sub, n_tiles=n
+        ),
+        grid=(bh, n, n),
+        in_specs=[
+            _vmem_spec((1, block, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block, d), at_k),
+            _vmem_spec((1, block, d_v), at_k),
+        ],
+        out_specs=[
+            _vmem_spec((1, block, d_v), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, _ROW_SUBLANES, block), lambda b, i, j: (b, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d_v), q.dtype),
+            jax.ShapeDtypeStruct((bh, _ROW_SUBLANES, t), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d_v, block), jnp.float32),
+            pltpu.VMEM((_ROW_SUBLANES, block), jnp.float32),
+            pltpu.VMEM((_ROW_SUBLANES, block), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, v)
+    return out, lse[:, 0]
+
+
+@_walk_jit
+def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
+    """dk and dv of the causal walk; lse and delta ``(bh, t, 8)``."""
+    bh, t, d = q.shape
+    d_v = v.shape[2]
+    n = t // block
+    per_row = (1, block, _LSE_LANES)
+    # the streamed axis: a tile above the diagonal names the diagonal's
+    # blocks, which come next: no copy
+    rows_at = lambda b, j, i: (b, jnp.maximum(i, j), 0)
+    return pl.pallas_call(
+        functools.partial(
+            _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
+            n_tiles=n, by_keys=True,
+        ),
+        grid=(bh, n, n),
+        in_specs=[
+            _vmem_spec((1, block, d), rows_at),
+            _vmem_spec((1, block, d), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block, d_v), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block, d_v), rows_at),
+            _vmem_spec(per_row, rows_at),
+            _vmem_spec(per_row, rows_at),
+        ],
+        out_specs=[
+            _vmem_spec((1, block, d), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block, d_v), lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, t, d_v), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block, d), jnp.float32),
+            pltpu.VMEM((block, d_v), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+
+
+@_walk_jit
+def _walk_dq(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
+    """dq of the causal walk; lse and delta ``(bh, t, 8)``."""
+    bh, t, d = q.shape
+    d_v = v.shape[2]
+    n = t // block
+    per_row = (1, block, _LSE_LANES)
+    # a tile above the diagonal names the diagonal's blocks, which are
+    # there: no copy
+    keys_at = lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    return pl.pallas_call(
+        functools.partial(
+            _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
+            n_tiles=n, by_keys=False,
+        ),
+        grid=(bh, n, n),
+        in_specs=[
+            _vmem_spec((1, block, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block, d), keys_at),
+            _vmem_spec((1, block, d_v), keys_at),
+            _vmem_spec((1, block, d_v), lambda b, i, j: (b, i, 0)),
+            _vmem_spec(per_row, lambda b, i, j: (b, i, 0)),
+            _vmem_spec(per_row, lambda b, i, j: (b, i, 0)),
+        ],
+        out_specs=[_vmem_spec((1, block, d), lambda b, i, j: (b, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)],
+        scratch_shapes=[pltpu.VMEM((d, block), jnp.float32)],
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)[0]
+
+
 def _flash_fwd(
     q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int
 ) -> Tuple[jax.Array, jax.Array]:
@@ -174,13 +565,21 @@ def _flash_fwd(
     bh, t_q, d = q.shape
     t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
+    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, forward=True)
+    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub)
+    if sub:
+        with _built("fwd", plan):
+            return _walk_fwd(
+                q, k, v, sm_scale=sm_scale, block=block_q, sub=sub,
+                interpret=_use_interpret(),
+            )
+
     tq_pad = _round_up(t_q, block_q)
     tk_pad = _round_up(t_kv, block_k)
     qp = _pad_to(q, tq_pad, 1)
     kp = _pad_to(k, tk_pad, 1)
     vp = _pad_to(v, tk_pad, 1)
     grid = (bh, tq_pad // block_q, tk_pad // block_k)
-
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale,
@@ -190,29 +589,30 @@ def _flash_fwd(
         kv_len=t_kv,
         q_len=t_q,
     )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_pad, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, tq_pad, _LSE_LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d_v), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=_use_interpret(),
-    )(qp, kp, vp)
+    with _built("fwd", plan):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+                _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+            ],
+            out_specs=[
+                _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+                _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, tq_pad, d_v), q.dtype),
+                jax.ShapeDtypeStruct((bh, tq_pad, _LSE_LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d_v), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
+            interpret=_use_interpret(),
+        )(qp, kp, vp)
     return out[:, :t_q], lse[:, :t_q, 0]
 
 
@@ -368,9 +768,13 @@ def _flash_bwd(
     bh, t_q, d = q.shape
     t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, forward=False)
+    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub)
+    built = functools.partial(_built, plan=plan)
+
     tq_pad = _round_up(t_q, block_q)
     tk_pad = _round_up(t_kv, block_k)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     qp = _pad_to(q, tq_pad, 1)
     kp = _pad_to(k, tk_pad, 1)
     vp = _pad_to(v, tk_pad, 1)
@@ -382,6 +786,17 @@ def _flash_bwd(
     deltap = jnp.broadcast_to(
         _pad_to(delta, tq_pad, 1)[..., None], (bh, tq_pad, _LSE_LANES)
     )
+    operands = (qp, kp, vp, dop, lsep, deltap)
+
+    if sub:  # whole tiles: nothing was padded
+        walk = dict(
+            sm_scale=sm_scale, block=block_q, sub=sub, interpret=_use_interpret()
+        )
+        with built("dkdv"):
+            dk, dv = _walk_dkdv(*operands, **walk)
+        with built("dq"):
+            dq = _walk_dq(*operands, **walk)
+        return dq, dk, dv
 
     common = dict(
         sm_scale=sm_scale,
@@ -391,48 +806,50 @@ def _flash_bwd(
         kv_len=t_kv,
         q_len=t_q,
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, **common),
-        grid=(bh, tk_pad // block_k, tq_pad // block_q),
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),
-            _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
-            _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk_pad, d_v), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d_v), jnp.float32),
-        ],
-        interpret=_use_interpret(),
-    )(qp, kp, vp, dop, lsep, deltap)
+    with built("dkdv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkdv_kernel, **common),
+            grid=(bh, tk_pad // block_k, tq_pad // block_q),
+            in_specs=[
+                _vmem_spec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+                _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
+                _vmem_spec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),
+                _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
+                _vmem_spec((1, block_q, _LSE_LANES), lambda b, j, i: (b, i, 0)),
+            ],
+            out_specs=[
+                _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                _vmem_spec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, tk_pad, d), k.dtype),
+                jax.ShapeDtypeStruct((bh, tk_pad, d_v), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d_v), jnp.float32),
+            ],
+            interpret=_use_interpret(),
+        )(*operands)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(bh, tq_pad // block_q, tk_pad // block_k),
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
-            _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
-            _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=[_vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_use_interpret(),
-    )(qp, kp, vp, dop, lsep, deltap)[0]
+    with built("dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **common),
+            grid=(bh, tq_pad // block_q, tk_pad // block_k),
+            in_specs=[
+                _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                _vmem_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+                _vmem_spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+                _vmem_spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+                _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
+                _vmem_spec((1, block_q, _LSE_LANES), lambda b, i, j: (b, i, 0)),
+            ],
+            out_specs=[_vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((bh, tq_pad, d), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=_use_interpret(),
+        )(*operands)[0]
     return dq[:, :t_q], dk[:, :t_kv], dv[:, :t_kv]
 
 

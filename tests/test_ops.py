@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     reference_attention,
@@ -26,6 +27,36 @@ def _qkv(b=2, t=32, h=2, d=16, dtype=jnp.float32, seed=0):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (b, t, h, d)
     return tuple(jax.random.normal(k, shape, dtype) for k in keys)
+
+
+def _grads(attend, w):
+    return jax.grad(lambda *a: (attend(*a) * w).sum(), argnums=(0, 1, 2))
+
+
+def _out_and_grads(attend, q, k, v, w):
+    """Output and the three gradients of ``sum(attend(q, k, v) * w)``."""
+    return (attend(q, k, v),) + _grads(attend, w)(q, k, v)
+
+
+@pytest.fixture()
+def walked(monkeypatch):
+    """``walked(fn, *args)``: the kernels of the causal walk among those
+    that tracing ``fn`` builds (``fwd`` / ``dkdv`` / ``dq``), sorted."""
+    seen = []
+    real = fa._built
+
+    def spy(kernel, plan):
+        seen.append((kernel, plan["path"]))
+        return real(kernel, plan)
+
+    monkeypatch.setattr(fa, "_built", spy)
+
+    def trace(fn, *args):
+        del seen[:]
+        jax.make_jaxpr(fn)(*args)
+        return sorted(k for k, path in seen if path == "causal_tiled")
+
+    return trace
 
 
 class TestFlashAttention:
@@ -94,6 +125,176 @@ class TestFlashAttention:
         for a, b in zip(g_fa, g_ref):
             assert a.shape == b.shape
             np.testing.assert_allclose(a, b, atol=5e-5)
+
+    # -- the causal walk: causal self-attention over whole square tiles ----
+
+    # T 256 is one sub-block, 1024 one tile (the one-pass backward), 2048
+    # and 4096 have tiles under, on and above the diagonal; 64/64 is GPT-2's
+    # head, 192/128 latent attention's (q and k carry nope + rope)
+    @pytest.mark.parametrize("d,d_v", [(64, 64), (192, 128)], ids=["d64", "d192_v128"])
+    @pytest.mark.parametrize("t", [256, 1024, 2048, 4096])
+    def test_walk_matches_reference(self, t, d, d_v, walked):
+        keys = jax.random.split(jax.random.PRNGKey(t + d), 4)
+        q = jax.random.normal(keys[0], (1, t, 1, d))
+        k = jax.random.normal(keys[1], (1, t, 1, d))
+        v = jax.random.normal(keys[2], (1, t, 1, d_v))
+        w = jax.random.normal(keys[3], (1, t, 1, d_v))
+        # the backward walks at every such T, the forward beyond one tile
+        # (under grad the forward is traced once more, for its residuals)
+        want = ["dkdv", "dq"] + ["fwd"] * (t > 1024)
+        assert walked(_grads(flash_attention, w), q, k, v) == want
+        got = _out_and_grads(flash_attention, q, k, v, w)
+        want = _out_and_grads(reference_attention, q, k, v, w)
+        for a, b, atol in zip(got, want, (2e-5, 5e-5, 5e-5, 5e-5)):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=atol)
+
+    @pytest.mark.parametrize("t,block", [(512, 256), (1024, 512), (1024, 256)])
+    def test_walk_at_smaller_tiles_matches_reference(self, t, block, walked):
+        """2x2 and 4x4 grids at a size the CPU runs in a second; two heads
+        and two batch rows, so the head axis of the grid is walked too."""
+        q, k, v = _qkv(t=t)
+        w = _qkv(t=t, seed=1)[0]
+        attend = functools.partial(
+            flash_attention, causal=True, sm_scale=None, block_q=block, block_k=block
+        )
+        assert walked(_grads(attend, w), q, k, v) == ["dkdv", "dq", "fwd"]
+        got = _out_and_grads(attend, q, k, v, w)
+        want = _out_and_grads(reference_attention, q, k, v, w)
+        for a, b, atol in zip(got, want, (2e-5, 5e-5, 5e-5, 5e-5)):
+            np.testing.assert_allclose(a, b, atol=atol)
+
+    def test_walk_bf16_inputs(self):
+        q, k, v = _qkv(b=1, t=1024, dtype=jnp.bfloat16)
+        w = _qkv(b=1, t=1024, seed=1)[0].astype(jnp.bfloat16)
+        attend = functools.partial(
+            flash_attention, causal=True, sm_scale=None, block_q=512, block_k=512
+        )
+        got = _out_and_grads(attend, q, k, v, w)
+        want = _out_and_grads(reference_attention, q, k, v, w)
+        for a, b in zip(got, want):
+            assert a.dtype == jnp.bfloat16
+            np.testing.assert_allclose(
+                a.astype(jnp.float32), b.astype(jnp.float32), atol=6e-2
+            )
+
+    @pytest.mark.parametrize(
+        "t_q,t_kv,causal,block_q,block_k,forward",
+        [
+            (300, 300, True, 1024, 1024, False),  # ragged T: a padded tile
+            (1280, 1280, True, 1024, 1024, False),  # whole tiles and a ragged one
+            (256, 512, True, 1024, 1024, False),  # t_q != t_kv (ring attention)
+            (512, 512, False, 1024, 1024, False),  # no mask
+            (512, 512, True, 256, 512, False),  # unequal blocks
+            (128, 128, True, 1024, 1024, False),  # under one sub-block
+            (1024, 1024, True, 1024, 1024, True),  # the one-tile forward
+            (512, 512, True, 1024, 1024, True),  # ... the XL server's width
+        ],
+        ids=["ragged", "ragged_tail", "tq_ne_tkv", "non_causal", "unequal_blocks",
+             "under_one_sub_block", "one_tile_forward", "one_tile_forward_512"],
+    )
+    def test_other_calls_keep_the_general_kernels(
+        self, t_q, t_kv, causal, block_q, block_k, forward, walked
+    ):
+        blocks = fa._clamp_blocks(jnp.dtype(jnp.bfloat16), t_q, t_kv, block_q, block_k)
+        assert fa._sub_block(causal, t_q, t_kv, *blocks, forward) == 0
+        plan = fa._kernel_plan(causal, t_q, t_kv, *blocks, 0)
+        assert plan["path"] == "general"
+        q = jax.ShapeDtypeStruct((1, t_q, 2, 16), jnp.float32)
+        kv = jax.ShapeDtypeStruct((1, t_kv, 2, 16), jnp.float32)
+        attend = functools.partial(
+            flash_attention, causal=causal, sm_scale=None, block_q=block_q, block_k=block_k
+        )
+        if forward:
+            assert walked(attend, q, kv, kv) == []
+        else:
+            assert walked(_grads(attend, 1.0), q, kv, kv) == []
+
+    @pytest.mark.parametrize("t,block,forward,share", [
+        (1024, 1024, False, 0.625), (4096, 1024, False, 0.53125),
+        (512, 512, False, 0.75), (256, 256, False, 1.0), (768, 768, False, 2 / 3),
+        (4096, 1024, True, 0.53125), (1024, 512, True, 0.625),
+        (1536, 768, True, 7 / 12), (512, 256, True, 0.75),
+    ])
+    def test_walk_covers_the_mask_exactly_once(self, t, block, forward, share):
+        """Every (query, key) pair the mask keeps is computed once, by
+        either order of the walk; a pair above the diagonal only inside a
+        masked square on it; tiles above the diagonal not at all."""
+        sub = fa._sub_block(True, t, t, block, block, forward)
+        assert sub == fa._SUB_BLOCK == 256
+        keep = np.tril(np.ones((t, t), bool))
+        squares = np.zeros((t, t), bool)
+        for lo in range(0, t, sub):
+            squares[lo:lo + sub, lo:lo + sub] = True
+        n = t // block
+        plan = fa._kernel_plan(True, t, t, block, block, sub)
+        for by_keys in (False, True):
+            seen = np.zeros((t, t), np.int8)
+            for iq in range(n):
+                for ik in range(iq):
+                    seen[iq * block:(iq + 1) * block, ik * block:(ik + 1) * block] += 1
+                at = iq * block
+                pieces = fa._diagonal_pieces(block, sub, by_keys)
+                assert len(pieces) == block // sub  # one a sub-block
+                for r0, nr, k0, nk in pieces:
+                    seen[at + r0:at + r0 + nr, at + k0:at + k0 + nk] += 1
+                    # what the mask removes of a piece lies in its square on
+                    # the diagonal: the last keys of a row block, the first
+                    # rows of a key block
+                    rest = (np.s_[at + r0 + sub:at + r0 + nr, at + k0:at + k0 + nk]
+                            if by_keys else
+                            np.s_[at + r0:at + r0 + nr, at + k0:at + k0 + nk - sub])
+                    assert keep[rest].all()
+            assert (seen[keep] == 1).all()
+            assert (seen[~keep] == squares[~keep]).all()
+            assert plan["score_share"] == pytest.approx(seen.sum() / t**2)
+        assert plan["path"] == "causal_tiled"
+        assert plan["score_share"] == pytest.approx(share)
+        assert (plan["tiles_visited"], plan["tiles_run"]) == (n * n, n * (n + 1) // 2)
+
+    def test_general_plan_is_the_parents_grid(self):
+        # the general kernel at T 1024 and 4096: the whole square, 10 of 16 tiles
+        for t, share, run in ((1024, 1.0, 1), (4096, 0.625, 10)):
+            plan = fa._kernel_plan(True, t, t, 1024, 1024, 0)
+            assert (plan["path"], plan["score_share"], plan["tiles_run"]) == (
+                "general", share, run)
+        # t_q != t_kv: the mask is aligned at the end, the first key tile runs
+        plan = fa._kernel_plan(True, 256, 512, 256, 256, 0)
+        assert (plan["tiles_visited"], plan["tiles_run"], plan["score_share"]) == (2, 2, 1.0)
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkdv"])
+    def test_a_tile_above_the_diagonal_is_neither_run_nor_read(self, kernel, walked):
+        """T 2048 in tiles of 1024: tile (q 0, keys 1) lies above the
+        diagonal. What it would read is filled with NaN (for the forward and
+        dq the keys and values of tile 1, for dk/dv the rows of tile 0): the
+        results that tile would have touched are finite and the reference's,
+        so it was not computed, not even to be masked (0 * NaN is NaN)."""
+        t, half, scale = 2048, 1024, 0.125
+        keys = jax.random.split(jax.random.PRNGKey(11), 4)
+        q, k, v, do = (jax.random.normal(key, (2, t, 64)) for key in keys)
+        assert walked(
+            lambda *a: fa._flash_fwd(*a, scale, True, half, half), q, k, v
+        ) == ["fwd"]
+        out, lse = fa._flash_fwd(q, k, v, scale, True, half, half)
+        dq, dk, dv = fa._flash_bwd(q, k, v, out, lse, do, scale, True, half, half)
+        tail = lambda x: x.at[:, half:].set(jnp.nan)
+        head = lambda x: x.at[:, :half].set(jnp.nan)
+        if kernel == "fwd":
+            got, _ = fa._flash_fwd(q, tail(k), tail(v), scale, True, half, half)
+            got, want = got[:, :half], out[:, :half]
+        elif kernel == "dq":
+            got = fa._flash_bwd(
+                q, tail(k), tail(v), out, lse, do, scale, True, half, half
+            )[0]
+            got, want = got[:, :half], dq[:, :half]
+        else:
+            got = fa._flash_bwd(
+                head(q), k, v, head(out), head(lse), head(do), scale, True, half, half
+            )[1:]
+            got = jnp.concatenate([g[:, half:] for g in got], axis=-1)
+            want = jnp.concatenate([dk[:, half:], dv[:, half:]], axis=-1)
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_array_equal(got, want)
 
 
 class TestRingAttention:

@@ -602,6 +602,88 @@ def test_a_loaded_server_that_stands_still_says_where(monkeypatch):
     assert "'host_sync': 0.4" in slow[0] and "round 0.4" in slow[0]
 
 
+def test_tracing_the_gpt2_small_step_books_every_flash_kernel(tmp_path, monkeypatch):
+    """``flash.kernel_built``: one span a kernel where a program is traced,
+    with the path the call's shapes chose and what a head's grid does.
+    GPT-2-small's b32x1024 step as ``chip_smoke.py``'s worker builds it: the
+    backward kernels walk the one 1024 x 1024 tile in sub-blocks of 256
+    (10 of its 16 sub-squares), the forward keeps the general kernel."""
+    import dataclasses
+
+    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+    from dlrover_tpu.ops import flash_attention as fa
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+    from dlrover_tpu.parallel.train_step import (
+        build_train_step, default_optimizer, state_shardings,
+    )
+
+    cfg = dataclasses.replace(GPTConfig.gpt2_small(), attention_impl="flash")
+    model, tx = GPT(cfg), default_optimizer()
+    mesh = build_mesh(MeshConfig(dp=-1), jax.devices()[:1])
+    tokens = jax.ShapeDtypeStruct((32, cfg.max_seq_len), jnp.int32)
+    abstract, shardings = state_shardings(
+        model, jnp.zeros(tokens.shape, tokens.dtype), mesh, tx
+    )
+    step_fn = build_train_step(model, tx, cross_entropy_loss, mesh, shardings)
+    acc = spans.process_accumulator()
+    n_before = booked(acc).get("flash.kernel_built", (0, 0))[1]
+    walked_bodies = {"n": 0}
+    real = fa._walk_bwd_kernel
+
+    def counted(*args, **kwargs):
+        walked_bodies["n"] += 1
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(fa, "_walk_bwd_kernel", counted)
+    _, found = record(tmp_path, lambda: step_fn.lower(abstract, tokens, tokens))
+    built = [st for _, _, _, st in found["flash.kernel_built"]]
+    by_kernel = {}
+    for st in built:
+        by_kernel.setdefault(st["kernel"], []).append(st)
+    # a layer's forward, its forward again under remat, dk/dv, dq
+    n = cfg.num_layers
+    assert {k: len(v) for k, v in by_kernel.items()} == {"fwd": 2 * n, "dkdv": n, "dq": n}
+    for kernel, path, share in (("fwd", "general", 1.0), ("dkdv", "causal_tiled", 0.625),
+                                ("dq", "causal_tiled", 0.625)):
+        for st in by_kernel[kernel]:
+            assert (st["path"], float(st["score_share"])) == (path, share)
+            assert (st["tiles_visited"], st["tiles_run"]) == (1, 1)
+    assert booked(acc)["flash.kernel_built"][1] == n_before + 4 * n
+    # the walk's Python bodies ran at most once a kernel, not once a layer
+    assert walked_bodies["n"] <= 2
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_a_servers_phase_split_does_not_see_the_flash_span(overlap):
+    """``flash.kernel_built`` is booked in the process's totals, where a
+    program is traced; a server's ``phase_split`` (``/healthz``) and the
+    benchmark's ``serve_host_frac``, which sums every ``*_ms`` key of it,
+    read what they read before the name existed."""
+    import runpy
+    import types
+
+    from dlrover_tpu.ops.flash_attention import flash_attention
+
+    x = jnp.ones((1, 256, 1, 16), jnp.float32)
+    jax.make_jaxpr(jax.grad(lambda q: flash_attention(q, q, q).sum()))(x)
+    assert booked(spans.process_accumulator())["flash.kernel_built"][1] >= 3
+    eng = tiny_engine(overlap)
+    first = dict(eng.stats()["phase_split"])
+    eng.run(STREAM)
+    last = eng.stats()["phase_split"]
+    assert not [k for k in last if "flash" in k]
+    assert {k for k in last if k.endswith("_ms")} == PARENT_MS_KEYS[overlap]
+    reader = runpy.run_path(
+        os.path.join(ROOT, "benchmark", "layer_metrics", "serve_host_frac.py")
+    )["read"]
+    ctx = types.SimpleNamespace(
+        stamps={"phase_split_open": first, "healthz": {"phase_split": last}}
+    )
+    spent = {k: last[k] - first.get(k, 0.0) for k in PARENT_MS_KEYS[overlap]}
+    host = sum(spent[k] for k in ("admission_ms", "decode_dispatch_ms", "retirement_ms"))
+    assert reader(ctx) == pytest.approx(100.0 * host / sum(spent.values()))
+
+
 def test_no_session_records_nothing_and_raises_nothing(tmp_path):
     acc = SpanAccumulator()
     with acc.span("off.outer", step=3) as outer:

@@ -80,10 +80,22 @@ def _llama_shape():
 
 FLASH_SHAPES = {
     "gpt2s_b32_t1024": (32, 1024, 12, 64),
+    # one chip's share of the four-chip cell: 100 heads x 1024 under shard_map
+    "gpt2xl_shard_b4_t1024": (4, 1024, 25, 64),
     "longseq_b4_t4096": (4, 4096, 12, 64),
     "llama_default": _llama_shape,
     "ragged_t100": (2, 100, 12, 64),
 }
+
+
+def fwd(q, k, v):  # (PARENT_PROGRAMS' texts carry the two functions' names)
+    return fa.flash_attention(q, k, v, causal=True)
+
+
+def fwd_bwd(q, k, v):
+    return jax.grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
 
 
 def _kernel_text(compiled):
@@ -101,18 +113,11 @@ def test_flash_attention_compiles_for_v5e(
     shape = shape() if callable(shape) else shape
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
-    def fwd(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True)
-
-    def fwd_bwd(q, k, v):
-        return jax.grad(
-            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
-        )(q, k, v)
-
     compiled = jax.jit(fwd_bwd if backward else fwd).lower(x, x, x).compile()
     text = _kernel_text(compiled)
-    # forward is one kernel; backward adds the dk/dv and the dq passes
-    assert text.count("tpu_custom_call") >= (3 if backward else 1)
+    # forward is one kernel; backward adds the dk/dv and the dq passes,
+    # whichever kernels the shapes chose
+    assert text.count("tpu_custom_call") == (3 if backward else 1)
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
@@ -124,16 +129,8 @@ def test_flash_attention_two_head_sizes_compiles_for_v5e(
     qk = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
 
-    def fwd(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True)
-
-    def fwd_bwd(q, k, v):
-        return jax.grad(
-            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
-        )(q, k, v)
-
     compiled = jax.jit(fwd_bwd if backward else fwd).lower(qk, qk, v).compile()
-    assert _kernel_text(compiled).count("tpu_custom_call") >= (3 if backward else 1)
+    assert _kernel_text(compiled).count("tpu_custom_call") == (3 if backward else 1)
     out = compiled.output_shardings  # shapes: out is v wide; dq, dk 192, dv 128
     assert len(jax.tree.leaves(out)) == (3 if backward else 1)
 
@@ -244,6 +241,26 @@ def _gpt2_small_step(devices, mesh_config, batch=32):
         sharding=data_sharding_for(tokens, mesh, DEFAULT_RULES),
     )
     return step_fn.lower(state, data, data), state
+
+
+def _zeros_as_held(model):
+    """Zeros in the shapes of the model's own init and the dtypes a serving
+    engine holds them in: it takes them as they are."""
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    )
+    return jax.tree.map(
+        lambda a, dtype: jnp.zeros(a.shape, dtype),
+        shapes, model.consumed_param_dtypes(shapes),
+    )
+
+
+def _prompt_row(width, sharding):
+    """``prefill_row``'s tokens and mask for one ``width``-wide prompt."""
+    return (
+        jax.ShapeDtypeStruct((1, width), jnp.int32, sharding=sharding),
+        jax.ShapeDtypeStruct((1, width), jnp.bool_, sharding=sharding),
+    )
 
 
 def _described(tree, sharding, rows=None):
@@ -380,18 +397,7 @@ def test_xl_decode_chunk_carries_the_folded_cache_for_v5e(
     from dlrover_tpu.models.serving import ContinuousBatchingEngine
 
     model = GPT(dataclasses.replace(GPTConfig.gpt2_xl(), use_remat=False))
-    shapes = jax.eval_shape(
-        lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
-    )
-    # zeros in the dtypes the engine holds (3.1 GB of host memory): it
-    # takes them as they are
-    params = jax.tree.map(
-        lambda a, dtype: jnp.zeros(a.shape, dtype),
-        shapes,
-        model.consumed_param_dtypes(shapes),
-    )
+    params = _zeros_as_held(model)  # 3.1 GB of host memory
     slots = 4
     engine = ContinuousBatchingEngine(
         model,
@@ -439,6 +445,152 @@ def test_xl_decode_chunk_carries_the_folded_cache_for_v5e(
             print(f"{rows} slots: {sizes(compiled_for(rows).memory_analysis())}")
         except jax.errors.JaxRuntimeError as e:  # refused: a finding too
             print(f"{rows} slots: refused: {str(e)[:300]}")
+
+
+# -- the programs the causal walk must not touch (PR 41) ---------------------
+#
+# sha256 of the parent's lowered text (commit da72202, before the kernel file
+# was touched; ``jax_traceback_in_locations_limit`` 0, because a Mosaic
+# kernel's body carries the file's line numbers and the general kernels moved
+# down the file), for a described v5e. A call outside the walk, and every
+# one-tile forward, lowers to the parent's program, text for text. A later PR
+# that changes these programs on purpose takes the hashes anew and says so.
+PARENT_PROGRAMS = {
+    # the forward at one tile a head: the XL server's prompt width, and the
+    # GPT-2 training cells' forward (their canary's bits)
+    "fwd_b1_t512_h25": "1b2f6ba62b549f04b938b03420a25094b9696ff557077edb11481b07e1c9e3dd",
+    "fwd_b32_t1024_h12": "1be2ea73825ee394a05ef3180e56dbd628f5bfcb1316fcae9d0beca315cccba4",
+    # forward and backward outside the walk: a ragged T, t_q != t_kv (ring)
+    "fwd_bwd_ragged_t100": "890fdc293626c14d24812f24b29cd4a7f8e3919d749dcf40195cf2d71e2f187e",
+    "fwd_bwd_tq256_tkv512": "b74362bb21000cade758ef5ffd373724602c57312c772ae291387fdb0629bc79",
+    # the XL server's 512-wide ``prefill_row`` (855,523 characters and no
+    # kernel: a prefill runs in decode mode over the cache, dense)
+    "xl_prefill_row_512": "0bf1b673e1f4f51d2ef0e410dc97ce4f1e3f46286f1ef95a44217fa4ba6e12ad",
+}
+
+
+@pytest.fixture()
+def no_locations():
+    prev = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.clear_caches()  # a program traced before carries its locations
+    yield
+    jax.config.update("jax_traceback_in_locations_limit", prev)
+
+
+def _sha256(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("program", [n for n in PARENT_PROGRAMS if n.startswith("fwd")])
+def test_calls_outside_the_walk_lower_to_the_parents_program(
+    program, one_chip, on_chip_kernels, no_locations
+):
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, kv = {
+        "fwd_b1_t512_h25": (shaped(1, 512, 25, 64),) * 2,
+        "fwd_b32_t1024_h12": (shaped(32, 1024, 12, 64),) * 2,
+        "fwd_bwd_ragged_t100": (shaped(2, 100, 12, 64),) * 2,
+        "fwd_bwd_tq256_tkv512": (shaped(2, 256, 12, 64), shaped(2, 512, 12, 64)),
+    }[program]
+
+    fn = fwd_bwd if program.startswith("fwd_bwd") else fwd
+    text = jax.jit(fn).lower(q, kv, kv).as_text()
+    assert "_walk_" not in text
+    assert _sha256(text) == PARENT_PROGRAMS[program]
+
+
+def test_xl_servers_prefill_is_the_parents_program(
+    one_chip, on_chip_kernels, no_locations
+):
+    """``gpt2xl-serve-closed``'s widest prefill, built as the cell builds it
+    (``attention_impl: flash``): the parent's text, and no kernel in it."""
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+
+    model = GPT(dataclasses.replace(
+        GPTConfig.gpt2_xl(), use_remat=False, attention_impl="flash"))
+    params = _zeros_as_held(model)
+    engine = ContinuousBatchingEngine(
+        model, params, SamplingConfig(max_new_tokens=128, temperature=0.0),
+        batch_size=4, prompt_width=512, decode_chunk=8,
+    )
+    text = engine._prefill_fn.lower(
+        _described(engine.params, one_chip), *_prompt_row(512, one_chip)
+    ).as_text()
+    assert "tpu_custom_call" not in text
+    assert _sha256(text) == PARENT_PROGRAMS["xl_prefill_row_512"]
+
+
+@pytest.mark.parametrize("config,layers,width,new_tokens,kernels", [
+    # the first attention layer's period: conv, conv, attention (an expert
+    # layer: its grouped products are megablox kernels, named "kernel")
+    ("lfm2-24b-a2b-l10", 3, 1024, 512, {"kernel"}),
+    # five Mamba-2 layers and the first attention layer
+    ("granite-4.0-h-micro", 6, 512, 256, set()),
+])
+def test_served_state_families_build_no_flash_kernel(
+    config, layers, width, new_tokens, kernels, one_chip, no_persistent_cache,
+    monkeypatch,
+):
+    """``lfm2-moe-serve-rollout-16`` and ``granite-h-micro-serve-chat``: the
+    servers built from the benchmark's configurations (every key and width
+    as the file gives it, the depth cut to the first layers that hold one of
+    each kind, which is what a test can hold in memory) construct no flash
+    kernel, and their prefill and chunk programs hold none: a server runs
+    the model's decode pass, which attends over the cache and returns before
+    ``attention_impl`` is read (the full-size entries leave it at its
+    default, ``flash``; ``dense`` is the rehearsal's). The *module* is
+    imported all the same: ``models/mla_moe.py`` imports it at its top, and
+    both families take their SwiGLU and ``MoeLayer`` from there."""
+    import json
+    import os
+    import re
+
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+    from dlrover_tpu.observability import spans
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+        entry = json.load(f)["model"]
+    cut = dict(entry["config"], num_hidden_layers=layers)
+    cut["layer_types"] = cut["layer_types"][:layers]
+    assert len(set(cut["layer_types"])) == 2
+
+    def kernels_built():
+        stat = spans.process_accumulator().stats().get("flash.kernel_built")
+        return stat.count if stat else 0
+
+    model, _ = build_model({"family": entry["family"], "config": cut})
+    assert model.config.attention_impl == "flash"
+    # the init's own forward is the non-decode pass: over its 8 tokens it
+    # traces one general kernel, whose output is dead in the init program
+    params = _zeros_as_held(model)
+    built_before = kernels_built()
+    engine = ContinuousBatchingEngine(
+        model, params, SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
+        batch_size=16, prompt_width=width, decode_chunk=8,
+    )
+    held = _described(engine.params, one_chip)
+    prefill = engine._prefill_fn.lower(held, *_prompt_row(width, one_chip)).as_text()
+    chunk = engine._chunk_for(8).lower(
+        held, _described(engine._state, one_chip),
+        _described(jax.random.PRNGKey(0), one_chip),
+    ).as_text()
+    for text in (prefill, chunk):
+        assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == kernels
+        assert ("tpu_custom_call" in text) == bool(kernels)
+    assert kernels_built() == built_before
 
 
 def test_ssd_scan_and_step_compile_at_the_published_widths(

@@ -16,6 +16,7 @@ FAMILIES = {
     "mla_moe": ("mla_moe", "MlaMoeLM", "MlaMoeConfig"),
     "lfm2_moe": ("lfm2_moe", "Lfm2MoeLM", "Lfm2MoeConfig"),
     "granite_hybrid": ("granite_hybrid", "GraniteHybridLM", "GraniteHybridConfig"),
+    "qwen3_next": ("qwen3_next", "Qwen3NextLM", "Qwen3NextConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")

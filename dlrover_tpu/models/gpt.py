@@ -279,6 +279,8 @@ def cached_decode_attention(
     :func:`_masked_attention_folded`); a GQA-narrow cache stays
     ``[B, max_len, KVH, Hd]`` under the grouped einsums, and so does
     the int8 cache. Two for now: ``docs/generation.md`` says why.
+    ``wo`` None (the bf16 GQA-narrow cache only) returns the heads'
+    outputs ``[B, T, H, Hd]`` unprojected, for a model that gates them.
     """
     res = _update_decode_cache(
         module, max_len, k, v, kv_valid, cache_slots,
@@ -375,6 +377,8 @@ def _masked_attention(q, k, v, mask, wo, cfg):
             q.dtype
         )
         out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
+    if wo is None:  # the heads as they are: the caller gates them, then projects
+        return out
     y = jnp.einsum("bqhk,hkd->bqd", out, wo.astype(cfg.dtype))
     return _constrain(y, "batch", "seq", "embed")
 

@@ -92,9 +92,15 @@ class MoeSizes:
     norm_eps: float = 0.0  # ... plus this
     scale: float = 1.0
     n_shared: int = 0  # 0 or 1 shared expert, ``n_shared`` widths wide
+    shared_gate: bool = False  # the shared expert behind ``sigmoid(x w_s)``, a float a token
+    score_fn: str = "sigmoid"  # of the router's logits, over all experts: sigmoid | softmax
     bias_name: str = "e_score_correction_bias"  # "": no selection bias
     init_std: float = 0.02
     expert_init_std: float = 0.0  # the routed experts' matrices; 0: ``init_std``
+    # the matrices that write to the residual stream (``w_down``, routed and
+    # shared), where a model draws them narrower; 0: as the others. The routed
+    # ones keep their factor over ``init_std``
+    down_init_std: float = 0.0
     bias_init_std: float = 0.01
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -262,6 +268,7 @@ class LatentAttention(nn.Module):
 class SwiGlu(nn.Module):
     config: Any  # reads ``init_std``, ``param_dtype``, ``dtype``
     width: int
+    down_init_std: float = 0.0  # ``w_down``, which writes to the residual stream; 0: ``init_std``
 
     @nn.compact
     def __call__(self, x):
@@ -269,7 +276,7 @@ class SwiGlu(nn.Module):
         D, F = x.shape[-1], self.width
         w_gate = _weight("w_gate", cfg, (D, F), ("embed", "mlp"))
         w_up = _weight("w_up", cfg, (D, F), ("embed", "mlp"))
-        w_down = _weight("w_down", cfg, (F, D), ("mlp", "embed"))
+        w_down = _weight("w_down", cfg, (F, D), ("mlp", "embed"), self.down_init_std)
         h = jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up)
         return jnp.dot(h, w_down)
 
@@ -286,6 +293,9 @@ def route(scores, bias, top_k: int, norm: bool, scale: float, eps: float = 0.0):
         chosen = jnp.sum(jnp.take_along_axis(scores, idx, axis=-1), axis=-1, keepdims=True)
         scores = scores / (chosen + eps if eps else chosen)
     return idx, scores * scale
+
+
+_SCORE_FNS = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
 
 
 class MoeLayer(nn.Module):
@@ -318,13 +328,16 @@ class MoeLayer(nn.Module):
         std = cfg.expert_init_std
         w_gate = _weight("w_gate", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"), std)
         w_up = _weight("w_up", cfg, (Eh, D, F), ("expert", "embed", "expert_mlp"), std)
-        w_down = _weight("w_down", cfg, (Eh, F, D), ("expert", "expert_mlp", "embed"), std)
+        down_std = std
+        if cfg.down_init_std:
+            down_std = cfg.down_init_std * (std or cfg.init_std) / cfg.init_std
+        w_down = _weight("w_down", cfg, (Eh, F, D), ("expert", "expert_mlp", "embed"), down_std)
 
         with jax.named_scope("moe.route"):
             # float32 all the way: a score rounded to bf16 moves the top k
             logits = jnp.dot(xf.astype(jnp.float32), w_router,
                              precision=jax.lax.Precision.HIGHEST)
-            scores = jax.nn.sigmoid(logits)
+            scores = _SCORE_FNS[cfg.score_fn](logits)
             idx, gate_of_expert = route(
                 scores, bias, K, cfg.norm_topk, cfg.scale, cfg.norm_eps)
 
@@ -382,7 +395,15 @@ class MoeLayer(nn.Module):
 
         out = routed
         if cfg.n_shared:
-            out = out + SwiGlu(cfg, F * cfg.n_shared, name="shared")(xf)
+            shared = SwiGlu(cfg, F * cfg.n_shared, cfg.down_init_std, name="shared")(xf)
+            if cfg.shared_gate:
+                with jax.named_scope("moe.shared_gate"):
+                    w_s = param_with_axes(
+                        "w_shared_gate", nn.initializers.normal(cfg.init_std), (D, 1),
+                        jnp.float32, axes=("embed", None))
+                    gate = jax.nn.sigmoid(jnp.dot(xf.astype(jnp.float32), w_s))
+                    shared = (gate * shared.astype(jnp.float32)).astype(cfg.dtype)
+            out = out + shared
         for name, value in dict(
             assignments_here=n_here,
             assignments_absent=N * K - n_here,
@@ -521,12 +542,14 @@ def step_counters(metrics: dict) -> dict:
     return out
 
 
-def decode_step_counters(metrics: dict) -> dict:
+def decode_step_counters(metrics: dict, share: bool = False) -> dict:
     """The sown ``metrics`` of one decode step as device scalars by counter
     name, summed over the expert layers: what a server's jitted decode
     chunk returns beside its tokens, so that they reach the host in the
     read-back the chunk already has and are booked there
-    (``ContinuousBatchingEngine``). Traceable: nothing here reads a value."""
+    (``ContinuousBatchingEngine``). Traceable: nothing here reads a value.
+    ``share``, for a chip that holds a share of the experts: also the
+    assignments that landed here and those routed to absent experts."""
     sums = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(metrics)[0]:
         name = next(k.key for k in reversed(path) if getattr(k, "key", None) is not None)
@@ -534,12 +557,16 @@ def decode_step_counters(metrics: dict) -> dict:
     layers = len(sums.get("assignments_here", ()))
     if not layers:
         return {}
-    return {
+    counters = {
         "moe.assignments": sum(sums["assignments_here"]).astype(jnp.int32),
         "moe.experts_touched": sum(sums["experts_touched"]).astype(jnp.int32),
         "moe.load_max_over_mean": sum(sums["load_max_over_mean"]).astype(jnp.float32),
         "moe.layer_steps": jnp.int32(layers),
     }
+    if share:
+        counters["moe.assignments_here"] = counters["moe.assignments"]
+        counters["moe.assignments_absent"] = sum(sums["assignments_absent"]).astype(jnp.int32)
+    return counters
 
 
 def book_step_counters(metrics: dict) -> dict:

@@ -69,6 +69,10 @@ MESH_AXIS_REGISTRY: Dict[str, Tuple[str, str]] = {
     "mamba_channels": ("logical", "the channels [x ; B ; C] of a Mamba-2 mixer's short convolution (kept local)"),
     "mamba_inner": ("logical", "a Mamba-2 mixer's inner width, heads x channels, where its output projection contracts it (split like an MLP's hidden dim)"),
     "mamba_heads": ("logical", "a Mamba-2 mixer's per-head floats: dt_bias, A_log, D (kept local)"),
+    "delta_proj": ("logical", "a gated delta-rule mixer's fused input projection [q ; k ; v ; z] (kept local)"),
+    "delta_channels": ("logical", "the channels [q ; k ; v] of a gated delta-rule mixer's short convolution (kept local)"),
+    "delta_inner": ("logical", "a gated delta-rule mixer's value width, heads x channels, where its output projection contracts it (split like an MLP's hidden dim)"),
+    "delta_heads": ("logical", "a gated delta-rule mixer's per-head floats and their projection: beta, a, dt_bias, A_log (kept local)"),
     "vocab": ("logical", "embedding/logits vocabulary dim"),
     "expert": ("logical", "MoE expert index"),
     "expert_mlp": ("logical", "per-expert feed-forward hidden dim"),

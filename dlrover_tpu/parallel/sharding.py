@@ -42,6 +42,12 @@ DEFAULT_RULES: LogicalRules = [
     ("mamba_channels", None),
     ("mamba_inner", "tp"),
     ("mamba_heads", None),
+    # a gated delta-rule mixer, by the same reasoning: the fused projection
+    # and the convolution's channels whole, the output projection contracted
+    ("delta_proj", None),
+    ("delta_channels", None),
+    ("delta_inner", "tp"),
+    ("delta_heads", None),
     ("vocab", "tp"),
     ("expert", "ep"),  # MoE experts distributed over the ep axis
     ("expert_mlp", "tp"),  # per-expert hidden dim still tensor-parallel

@@ -27,6 +27,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_metrics_mla_moe",
     "benchmark.tests.test_metrics_lfm2_moe",
     "benchmark.tests.test_metrics_granite_hybrid",
+    "benchmark.tests.test_metrics_qwen3_next",
     "benchmark.tests.test_program_spans",
     "benchmark.tests.test_reduce_trace",
     "benchmark.tests.test_rehearsal",
@@ -37,6 +38,7 @@ from benchmark.tests.test_metrics import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_mla_moe import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_lfm2_moe import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_granite_hybrid import *  # noqa: E402,F401,F403
+from benchmark.tests.test_metrics_qwen3_next import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reduce_trace import *  # noqa: E402,F401,F403
 from benchmark.tests.test_rehearsal import *  # noqa: E402,F401,F403

@@ -622,3 +622,175 @@ def test_ssd_scan_and_step_compile_at_the_published_widths(
     memory = step.memory_analysis()
     assert memory.argument_size_in_bytes < 1.02 * state
     assert memory.temp_size_in_bytes < state // 8  # no second copy of the state
+
+
+def test_qwen3_next_served_programs_compile_for_v5e(
+    one_chip, no_persistent_cache, monkeypatch, request
+):
+    """``qwen3next-serve-rag-16``: the server built from the benchmark's
+    configuration (every key and width as the file gives it: 128 of 512
+    experts held, the router 512 wide), cut to the first period of its
+    layer pattern (delta, delta, delta, attention), 16 slots, prompts 2,048
+    wide, 512 new tokens. The widest prefill and the chunk fit; the
+    grouped products are megablox kernels and nothing else is one (the
+    chunked delta rule and its one-token step are block products XLA
+    compiles); the chunk's carry holds every slot's matrix state in
+    float32. Under ``pytest -s`` all 12 layers are compiled (10.9 GB of
+    zeros on the host, minutes of every core) and the sizes of the init, the
+    three prefill widths, the chunk and ``admit_many`` printed for
+    ``PERF.md`` section 4 (not judged here: the cell's own run is)."""
+    import re
+    import time
+
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    entry = _benchmark_model_entry("qwen3-next-80b-a3b-ep4-l12")
+    slots, width, new_tokens = 16, 2048, 512
+
+    def served(layers):
+        model, _ = build_model({"family": entry["family"],
+                                "config": dict(entry["config"], num_hidden_layers=layers)})
+        engine = ContinuousBatchingEngine(
+            model, _zeros_as_held(model), SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
+            batch_size=slots, prompt_width=width, decode_chunk=8)
+        return model, engine, _described(engine.params, one_chip)
+
+    def chunk_of(engine, held):
+        return engine._chunk_for(8).lower(
+            held, _described(engine._state, one_chip), _described(jax.random.PRNGKey(0), one_chip))
+
+    _, engine, held = served(4)
+    state = [a for a in jax.tree.leaves(engine._state[0]) if a.shape[1:] == (32, 128, 128)]
+    assert len(state) == 3 and all(a.dtype == jnp.float32 and a.shape[0] == slots for a in state)
+    prefill = engine._prefill_fn.lower(held, *_prompt_row(width, one_chip))
+    chunk = chunk_of(engine, held)
+    for lowered, scopes in ((prefill, ("gdn.chunk", "qwen3next.attend", "moe.shared_gate")),
+                            (chunk, ("gdn.step", "gdn.gate_norm", "moe.shared_gate"))):
+        text = lowered.as_text(debug_info=True)
+        assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {"kernel"}
+        assert all(scope in text for scope in scopes)
+        assert _device_bytes(lowered.compile()) < V5E_HBM_BYTES
+
+    if request.config.getoption("capture") != "no":
+        return
+
+    def sizes(lowered):
+        t0 = time.time()
+        m = lowered.compile().memory_analysis()
+        return (f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB, output {m.output_size_in_bytes / 1e9:.3f}, "
+                f"temporaries {m.temp_size_in_bytes / 1e9:.3f}, aliased {m.alias_size_in_bytes / 1e9:.3f} "
+                f"(compiled in {time.time() - t0:.0f} s)")
+
+    model, engine, held = served(entry["config"]["num_hidden_layers"])
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def init(k):  # ``init_params_as_consumed``'s program: drawn in float32, rounded inside
+        params = model.init(k, jnp.zeros((1, 8), jnp.int32))["params"]
+        return jax.tree.map(lambda leaf, dt: leaf.astype(dt), params, model.consumed_param_dtypes(params))
+
+    print(f"\nqwen3-next-80b-a3b-ep4-l12, described v5e, {slots} slots")
+    print("init:", sizes(jax.jit(init).lower(key)))
+    for w in (width // 4, width // 2, width):
+        print(f"prefill_row {w}:", sizes(engine._prefill_fn.lower(held, *_prompt_row(w, one_chip))))
+    print("chunk of 8 steps:", sizes(chunk_of(engine, held)))
+    row = jax.eval_shape(engine._prefill_fn, engine.params, *(
+        jnp.zeros((1, width // 4), dtype) for dtype in (jnp.int32, jnp.bool_)))
+    row = _described(row + (jax.ShapeDtypeStruct((model.config.vocab_size,), jnp.bool_),), one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    for k in (1, 8):
+        print(f"admit_many of {k}:", sizes(engine._admit_many_fn.lower(
+            _described(engine._state, one_chip), (row,) * k, (i32,) * k, (i32,) * k, (i32,) * k)))
+
+
+# sha256 of the parent's lowered text (commit 452bf4c, before ``MoeSizes``
+# grew a score function and a shared gate and ``_masked_attention`` an
+# unprojected return for the ``qwen3_next`` family): the served families that
+# share ``MoeLayer``, ``cached_decode_attention``, ``real_neighbours`` and the
+# engine keep the parent's programs, text for text, and so does the trained
+# ``mla_moe`` model's forward and backward.
+PARENT_FAMILY_PROGRAMS = {
+    "lfm2-24b-a2b-l10.prefill": "71d26a6e5345e4ae386cf7c9def6e19867488806242331808febac189b826037",
+    "lfm2-24b-a2b-l10.chunk": "4790e7e85494130771257fb9f5d72c39fb01b3ecc0e8b93bad133b55518fa381",
+    "granite-4.0-h-micro.prefill": "d7b20c70771f80c106e7e7b5c264e7c5967ccbc440143b6479609c3e11e8fa38",
+    "granite-4.0-h-micro.chunk": "53441fb221dd45af451d27b8513c5653328d326284c25b0f283ef47bc9ac2ce1",
+    "joyai-llm-flash-ep16.loss_and_grads": "3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294",
+}
+
+
+def _benchmark_model_entry(config):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+        return json.load(f)["model"]
+
+
+@pytest.mark.parametrize("config,layers,width,new_tokens", [
+    ("lfm2-24b-a2b-l10", 3, 1024, 512),
+    ("granite-4.0-h-micro", 6, 512, 256),
+])
+def test_served_families_programs_are_the_parents(
+    config, layers, width, new_tokens, one_chip, no_persistent_cache, no_locations, monkeypatch
+):
+    """``lfm2-moe-serve-rollout-16`` and ``granite-h-micro-serve-chat``: the
+    widest prefill and the chunk of the servers built from the benchmark's
+    configurations (the depth cut as in the test above), lowered for the
+    described chip: the parent's text."""
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    entry = _benchmark_model_entry(config)
+    cut = dict(entry["config"], num_hidden_layers=layers)
+    cut["layer_types"] = cut["layer_types"][:layers]
+    model, _ = build_model({"family": entry["family"], "config": cut})
+    engine = ContinuousBatchingEngine(
+        model, _zeros_as_held(model), SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
+        batch_size=16, prompt_width=width, decode_chunk=8,
+    )
+    held = _described(engine.params, one_chip)
+    prefill = engine._prefill_fn.lower(held, *_prompt_row(width, one_chip)).as_text()
+    chunk = engine._chunk_for(8).lower(
+        held, _described(engine._state, one_chip),
+        _described(jax.random.PRNGKey(0), one_chip),
+    ).as_text()
+    got = {f"{config}.prefill": _sha256(prefill), f"{config}.chunk": _sha256(chunk)}
+    assert got == {name: PARENT_FAMILY_PROGRAMS[name] for name in got}, got
+
+
+def test_trained_moe_models_loss_and_grads_are_the_parents(
+    one_chip, on_chip_kernels, no_locations, monkeypatch
+):
+    """``joyai-flash-train-ep16share``: the model built from the benchmark's
+    configuration (two layers: the dense one and the first expert layer,
+    with its MTP module), its losses and their gradients over b1 x 1024,
+    lowered for the described chip: the parent's text."""
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    entry = _benchmark_model_entry("joyai-llm-flash-ep16")
+    model, loss_fn = build_model(
+        {"family": entry["family"], "config": dict(entry["config"], num_hidden_layers=2)})
+    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+    def loss_and_grads(params, tokens, targets):
+        def loss(p):
+            losses, sown = model.apply({"params": p}, tokens, targets=targets, mutable=("objective", "metrics"))
+            return loss_fn(losses, targets) + sum(jax.tree.leaves(sown["objective"]))
+
+        return jax.value_and_grad(loss)(params)
+
+    text = jax.jit(loss_and_grads).lower(_described(params, one_chip), tokens, tokens).as_text()
+    assert "tpu_custom_call" in text
+    assert _sha256(text) == PARENT_FAMILY_PROGRAMS["joyai-llm-flash-ep16.loss_and_grads"], _sha256(text)

@@ -1,0 +1,94 @@
+"""``mla_flash_calls_per_step`` on synthetic traces: it counts the device
+events that ``joyai-llm-flash-ep16``'s ``trace_names.mla_flash_kernel``
+finds, over the traced steps, and reads nothing where there is nothing to
+read (an untraced run, another configuration, a program with no such
+kernel)."""
+
+import json
+import os
+import types
+
+import pytest
+
+from benchmark.tests.test_metrics import cycle, reader
+from benchmark.tests.test_metrics_mla_moe import CONFIG, TOKENS
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+NAME = "mla_flash_calls_per_step"
+STEPS = 20
+FORWARD_S, DKDV_S, DQ_S = 0.0073, 0.0108, 0.0100  # a call's device seconds
+
+
+def flash_event(n, shape="bf16[128,4096,128]{2,1,0}"):
+    return (f'%mla.attend.{n} = {shape} custom-call(%a, %b, %c), '
+            f'custom_call_target="tpu_custom_call", operand_layout_constraints={{}}')
+
+
+def traced(events):
+    """A context whose trace holds ``STEPS`` executions of the step and
+    ``events``: operation name -> (seconds, count) inside the window."""
+    return types.SimpleNamespace(
+        stamps=dict(cycles=[cycle(100.0, 5.0, 0.0)], t_open=100.0, steps_per_cycle=10,
+                    tokens_per_step=TOKENS, saves=False, counters={}),
+        peaks=dict(bf16_flops_per_s=197e12, hbm_bytes_per_s=819e9),
+        config=CONFIG, traffic=dict(params=dict(seq=4096, batch=4)), run=types.SimpleNamespace(chips=1),
+        trace=types.SimpleNamespace(used_planes=lambda: ["/device:TPU:0"], op_seconds=lambda: events,
+                                    main_module=lambda: ("jit_step_fn", [0.7] * STEPS)))
+
+
+def step_events(forward_calls):
+    """Six blocks' kernels as the device names them: each instruction of the
+    program is one name, met once a step."""
+    events = {flash_event(n): (STEPS * FORWARD_S, STEPS) for n in range(forward_calls)}
+    events.update({flash_event(100 + n, "(bf16[128,4096,192]{2,1,0}, bf16[128,4096,128]{2,1,0})"):
+                   (STEPS * DKDV_S, STEPS) for n in range(6)})
+    events.update({flash_event(200 + n, "bf16[128,4096,192]{2,1,0}"): (STEPS * DQ_S, STEPS)
+                   for n in range(6)})
+    # not the flash kernel: a fusion inside the scope, a grouped product, another scope's kernel
+    events["%mla.attend.7 = bf16[4,4096,32,128]{3,2,1,0} fusion(%x), kind=kLoop"] = (0.3, 6 * STEPS)
+    events['%gmm.7 = bf16[32768,768]{1,0} custom-call(%x), custom_call_target="tpu_custom_call"'] = (0.2, 15 * STEPS)
+    return events
+
+
+@pytest.mark.parametrize("forward_calls,want", [(12, 24.0), (6, 18.0)],
+                         ids=["forward-run-again", "results-kept"])
+def test_counts_the_flash_kernels_of_a_step(forward_calls, want):
+    assert reader("layer_metrics", NAME)(traced(step_events(forward_calls))) == want
+
+
+def test_moves_with_the_roofline_share_it_stands_beside():
+    """Six forward calls fewer at the same time a call: the share of the
+    roofline rises by the kernel time saved, the count by 24 / 18."""
+    before, after = traced(step_events(12)), traced(step_events(6))
+    share = reader("layer_metrics", "mla_flash_roofline")
+    backward_s = 6 * (DKDV_S + DQ_S)
+    assert share(after) / share(before) == pytest.approx(
+        (12 * FORWARD_S + backward_s) / (6 * FORWARD_S + backward_s), rel=1e-9)
+    assert 0 < share(before) < share(after) < 100
+
+
+def test_flash_calls_with_nothing_to_read_is_none():
+    untraced = traced({})
+    untraced.trace = None
+    assert reader("layer_metrics", NAME)(untraced) is None
+    assert reader("layer_metrics", NAME)(traced({})) is None  # no such kernel in the program
+    no_steps = traced(step_events(6))
+    no_steps.trace.main_module = lambda: (None, [])
+    assert reader("layer_metrics", NAME)(no_steps) is None
+    other = traced(step_events(6))  # another driver's stamps, another configuration
+    other.stamps.pop("cycles")
+    assert reader("layer_metrics", NAME)(other) is None
+    gpt = traced(step_events(6))
+    gpt.config = dict(gpt_config={})
+    assert reader("layer_metrics", NAME)(gpt) is None
+
+
+def test_benchmark_lists_it_for_the_cell_that_has_the_kernel():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    entry = bench["per_layer"][-1]
+    assert entry == dict(name=NAME, unit="calls", better="lower", source="device_trace",
+                         layer="models / kernels", moves="train_tokens_per_s",
+                         workloads=["joyai-flash-train-ep16share"])
+    beside = next(m for m in bench["per_layer"] if m["name"] == "mla_flash_roofline")
+    assert {k: beside[k] for k in ("layer", "moves", "workloads")} == {
+        k: entry[k] for k in ("layer", "moves", "workloads")}

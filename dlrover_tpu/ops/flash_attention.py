@@ -55,6 +55,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -890,7 +891,11 @@ def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     out3, lse = _flash_fwd(
         _to_bht(q), _to_bht(k), _to_bht(v), scale, causal, block_q, block_k
     )
-    out = _from_bht(out3, b, h)
+    # The two results the backward kernels need, named for a caller's remat
+    # policy (``save_only_these_names``): a name is an identity that lowers
+    # to nothing, and a policy that reads no names sees nothing new.
+    out = checkpoint_name(_from_bht(out3, b, h), "flash.out")
+    lse = checkpoint_name(lse, "flash.lse")
     return out, (q, k, v, out, lse)
 
 

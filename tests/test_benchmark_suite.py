@@ -32,6 +32,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_reduce_trace",
     "benchmark.tests.test_rehearsal",
     "benchmark.tests.test_metrics_startup",
+    "benchmark.tests.test_metrics_mla_flash_calls",
 )
 
 from benchmark.tests.test_metrics import *  # noqa: E402,F401,F403
@@ -43,3 +44,4 @@ from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reduce_trace import *  # noqa: E402,F401,F403
 from benchmark.tests.test_rehearsal import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_startup import *  # noqa: E402,F401,F403
+from benchmark.tests.test_metrics_mla_flash_calls import *  # noqa: E402,F401,F403

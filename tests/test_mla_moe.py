@@ -6,6 +6,7 @@ step's counters and a flash-checkpoint round trip of the expert state.
 """
 
 import dataclasses
+import json
 import os
 
 import jax
@@ -45,15 +46,20 @@ def init(cfg, seed=1):
     return model, jax.tree.map(np.asarray, params)
 
 
-def model_losses_and_grads(model, params, x, y):
-    """(total, trunk, mtp, landed-by-layer), grads: the objective exactly
-    as ``build_train_step`` assembles it."""
+def objective(model, x, y):
+    """params -> (loss, metrics): the objective exactly as
+    ``build_train_step`` assembles it."""
     def total(p):
         tl, mut = model.apply({"params": p}, x, targets=y, mutable=("objective", "metrics"))
         loss = token_loss_mean(tl, y) + sum(jnp.sum(v) for v in jax.tree.leaves(mut["objective"]))
         return loss, mut["metrics"]
 
-    (loss, metrics), grads = jax.value_and_grad(total, has_aux=True)(params)
+    return total
+
+
+def model_losses_and_grads(model, params, x, y):
+    """(total, trunk, mtp, landed-by-layer), grads of ``objective``."""
+    (loss, metrics), grads = jax.value_and_grad(objective(model, x, y), has_aux=True)(params)
     c = mla_moe.step_counters(metrics)
     return (float(loss), c["train.trunk_loss"], c["train.mtp_loss"],
             c["moe.assignments_here_by_layer"]), grads
@@ -99,6 +105,109 @@ def test_losses_and_every_gradient_match_the_reference(compute, use_remat):
         assert err < tol["grad"], f"{jax.tree_util.keystr(path)}: {err}"
     # the selection bias enters the selection only
     assert float(jnp.max(jnp.abs(grads["block_1"]["moe"]["e_score_correction_bias"]))) == 0.0
+
+
+def _rehearsal_model(compute, monkeypatch, policy):
+    """The benchmark's ``joyai`` model at its rehearsal widths, its blocks
+    rematerialised as the cell's are; ``policy`` stands in for what a block
+    keeps (the parent's: ``nothing_saveable``)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "joyai-llm-flash-ep16.json")) as f:
+        config = json.load(f)["rehearsal"]["model"]["config"]
+    monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", policy)
+    model, _ = build_model({"family": "mla_moe", "config": dict(config, use_remat=True, dtype=compute)})
+    return model
+
+
+def _count_primitive(jaxpr, name):
+    """Equations of one primitive in a jaxpr and every jaxpr its equations carry."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_primitive(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_keeping_the_flash_kernels_results_moves_no_bit(compute, monkeypatch):
+    """A rematerialised block that keeps its flash kernel's ``out`` and
+    ``lse`` against one that keeps nothing and runs the kernel again, at the
+    benchmark's rehearsal size (b2 x 32, two layers and the MTP module): the
+    same kernel on the same inputs, stored instead of repeated, so the loss,
+    both of its parts and every gradient leaf are equal bit for bit."""
+    def run(policy):
+        model = _rehearsal_model(compute, monkeypatch, policy)
+        params = model.init(jax.random.PRNGKey(1), jnp.zeros((2, 32), jnp.int32))["params"]
+        x, y = batch(model.config, b=2, t=32)
+        return model_losses_and_grads(model, params, x, y)
+
+    kept, kept_grads = run(mla_moe._KEEP_FLASH_RESULTS)
+    again, again_grads = run(jax.checkpoint_policies.nothing_saveable)
+    assert kept == again
+    flat, again_flat = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (kept_grads, again_grads))
+    assert len(flat) == len(again_flat) > 30
+    for (path, g), (_, r) in zip(flat, again_flat):
+        assert np.array_equal(np.asarray(g), np.asarray(r)), jax.tree_util.keystr(path)
+    assert any(float(jnp.max(jnp.abs(g))) > 0 for _, g in flat)
+
+
+@pytest.mark.parametrize("policy,kernels", [
+    # a block: forward, forward again, dk/dv + dq
+    pytest.param(jax.checkpoint_policies.nothing_saveable, 12, id="nothing_saveable"),
+    pytest.param(mla_moe._KEEP_FLASH_RESULTS, 9, id="kept"),
+])
+def test_a_blocks_backward_pass_holds_no_second_forward_kernel(policy, kernels, monkeypatch):
+    """The jaxpr of losses and gradients over three blocks (two layers and
+    the MTP module's), before XLA sees anything: with ``out`` and ``lse``
+    kept, the rematerialised body takes them as inputs and the forward
+    kernel is gone from it. (At T 32 the backward is the one general kernel
+    call for dk/dv and one for dq.)"""
+    model = _rehearsal_model("float32", monkeypatch, policy)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(1), jnp.zeros((2, 32), jnp.int32))["params"])
+    x, y = batch(model.config, b=2, t=32)
+    jaxpr = jax.make_jaxpr(jax.grad(objective(model, x, y), has_aux=True))(params).jaxpr
+    assert _count_primitive(jaxpr, "pallas_call") == kernels
+    # each kept value is named once where it is made and once more in each
+    # trace of the block that the backward pass takes apart
+    assert _count_primitive(jaxpr, "name") >= 2 * 3
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_the_kept_names_are_seen_through_the_sharded_kernels_shard_map(devices):
+    """``flash_attention_sharded`` runs the kernel under ``shard_map`` on a
+    mesh of more than one device (the four-chip cell's path; no cell trains
+    this family there). **The names are still seen**: JAX takes a
+    ``shard_map``'s body apart with the caller's policy, so under
+    ``save_only_these_names`` the gradient holds three kernels (forward,
+    dk/dv, dq) on two devices as on one, where ``nothing_saveable`` holds
+    four; the values are equal on both meshes."""
+    from flax.linen import partitioning as nn_partitioning
+
+    from dlrover_tpu.ops.flash_attention import flash_attention_sharded
+
+    mesh = build_mesh(MeshConfig(dp=-1), jax.devices()[:devices])
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 128, 2, 16)), jnp.float32) for _ in range(3))
+
+    def grads(policy):
+        def f(q, k, v):
+            with nn_partitioning.axis_rules([("batch", "dp"), ("seq", None), ("heads", None), ("kv", None)]):
+                return jnp.sum(flash_attention_sharded(q * 2.0, k, v, mesh) ** 2)
+        return jax.grad(jax.checkpoint(f, policy=policy), argnums=(0, 1, 2))
+
+    kept = grads(mla_moe._KEEP_FLASH_RESULTS)
+    again = grads(jax.checkpoint_policies.nothing_saveable)
+    kept_jaxpr = jax.make_jaxpr(kept)(q, k, v).jaxpr
+    assert _count_primitive(kept_jaxpr, "shard_map") == (3 if devices > 1 else 0)
+    assert _count_primitive(kept_jaxpr, "pallas_call") == 3
+    assert _count_primitive(jax.make_jaxpr(again)(q, k, v).jaxpr, "pallas_call") == 4
+    for a, b in zip(jax.jit(kept)(q, k, v), jax.jit(again)(q, k, v)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_shares_add_up_to_the_uncut_layer():

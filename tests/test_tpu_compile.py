@@ -209,27 +209,14 @@ def test_routed_experts_in_a_serving_program_compile_for_v5e(
     assert m.temp_size_in_bytes < weights // 8
 
 
-def _gpt2_small_step(devices, mesh_config, batch=32):
-    """(lowered-step factory) the GPT-2-small train step exactly as
-    ``chip_smoke.py``'s worker builds it, over described devices."""
-    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
-    from dlrover_tpu.parallel.mesh import build_mesh
+def _lowered_step(model, loss_fn, tx, mesh, tokens, **step_options):
+    """(lowered train step, described state) over a mesh of described
+    devices: the state and the batch as shapes with the step's shardings."""
     from dlrover_tpu.parallel.sharding import DEFAULT_RULES, data_sharding_for
-    from dlrover_tpu.parallel.train_step import (
-        build_train_step,
-        default_optimizer,
-        state_shardings,
-    )
+    from dlrover_tpu.parallel.train_step import build_train_step, state_shardings
 
-    cfg = dataclasses.replace(GPTConfig.gpt2_small(), attention_impl="flash")
-    model = GPT(cfg)
-    tx = default_optimizer()
-    mesh = build_mesh(mesh_config, devices)
-    tokens = jnp.zeros((batch, cfg.max_seq_len), jnp.int32)
     abstract, shardings = state_shardings(model, tokens, mesh, tx)
-    step_fn = build_train_step(
-        model, tx, cross_entropy_loss, mesh, shardings
-    )
+    step_fn = build_train_step(model, tx, loss_fn, mesh, shardings, **step_options)
     state = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         abstract,
@@ -241,6 +228,20 @@ def _gpt2_small_step(devices, mesh_config, batch=32):
         sharding=data_sharding_for(tokens, mesh, DEFAULT_RULES),
     )
     return step_fn.lower(state, data, data), state
+
+
+def _gpt2_small_step(devices, mesh_config, batch=32):
+    """(lowered step, state) the GPT-2-small train step exactly as
+    ``chip_smoke.py``'s worker builds it, over described devices."""
+    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+    from dlrover_tpu.parallel.mesh import build_mesh
+    from dlrover_tpu.parallel.train_step import default_optimizer
+
+    cfg = dataclasses.replace(GPTConfig.gpt2_small(), attention_impl="flash")
+    return _lowered_step(
+        GPT(cfg), cross_entropy_loss, default_optimizer(), build_mesh(mesh_config, devices),
+        jnp.zeros((batch, cfg.max_seq_len), jnp.int32),
+    )
 
 
 def _zeros_as_held(model):
@@ -461,11 +462,23 @@ PARENT_PROGRAMS = {
     "fwd_b1_t512_h25": "1b2f6ba62b549f04b938b03420a25094b9696ff557077edb11481b07e1c9e3dd",
     "fwd_b32_t1024_h12": "1be2ea73825ee394a05ef3180e56dbd628f5bfcb1316fcae9d0beca315cccba4",
     # forward and backward outside the walk: a ragged T, t_q != t_kv (ring)
-    "fwd_bwd_ragged_t100": "890fdc293626c14d24812f24b29cd4a7f8e3919d749dcf40195cf2d71e2f187e",
+    # (taken anew in PR 44: ``_fa_fwd`` names its two results, and the second
+    # ``name`` equation takes one number from the module's symbol table, so
+    # the private functions lowered after it are ``@_pad_6``, ``@_pad_7`` where
+    # the parent's were ``@_pad_5``, ``@_pad_6``: the operations are the
+    # parent's, ``test_the_flash_results_names_emit_no_operation``)
+    "fwd_bwd_ragged_t100": "c2bb5925565e06c8ec4791170a30fda7a611cfa44fc4dcddea263e9c20c65507",
     "fwd_bwd_tq256_tkv512": "b74362bb21000cade758ef5ffd373724602c57312c772ae291387fdb0629bc79",
     # the XL server's 512-wide ``prefill_row`` (855,523 characters and no
     # kernel: a prefill runs in decode mode over the cache, dense)
     "xl_prefill_row_512": "0bf1b673e1f4f51d2ef0e410dc97ce4f1e3f46286f1ef95a44217fa4ba6e12ad",
+}
+
+
+# the parent's (commit 623ba44) hash of the one program above whose text PR 44's
+# two names renumber; it still lowers to this with the names taken out
+UNNAMED_PROGRAMS = {
+    "fwd_bwd_ragged_t100": "890fdc293626c14d24812f24b29cd4a7f8e3919d749dcf40195cf2d71e2f187e",
 }
 
 
@@ -502,6 +515,32 @@ def test_calls_outside_the_walk_lower_to_the_parents_program(
     text = jax.jit(fn).lower(q, kv, kv).as_text()
     assert "_walk_" not in text
     assert _sha256(text) == PARENT_PROGRAMS[program]
+
+
+def test_the_flash_results_names_emit_no_operation(
+    one_chip, on_chip_kernels, no_locations, monkeypatch
+):
+    """``_fa_fwd`` names ``out`` and ``lse`` for a caller's remat policy. A
+    ``name`` equation lowers to nothing, but each distinct one is first a
+    private function ``@name`` in the module's symbol table, and the second
+    takes a number from the table's one counter: private functions lowered
+    later (``jnp.pad``'s, at a ragged T) carry a number one higher. With
+    those numbers struck out the text is the parent's, and with the names
+    taken out it is the parent's to the character."""
+    import re
+
+    x = jax.ShapeDtypeStruct((2, 100, 12, 64), jnp.bfloat16, sharding=one_chip)
+    named = jax.jit(fwd_bwd).lower(x, x, x).as_text()
+    monkeypatch.setattr(fa, "checkpoint_name", lambda value, name: value)
+    jax.clear_caches()
+    unnamed = jax.jit(fwd_bwd).lower(x, x, x).as_text()
+    assert _sha256(unnamed) == UNNAMED_PROGRAMS["fwd_bwd_ragged_t100"]
+    assert named != unnamed
+
+    def unnumbered(text):
+        return re.sub(r"@(_pad)_\d+", r"@\1_N", text)
+
+    assert unnumbered(named) == unnumbered(unnamed)
 
 
 def test_xl_servers_prefill_is_the_parents_program(
@@ -710,14 +749,22 @@ def test_qwen3_next_served_programs_compile_for_v5e(
 # grew a score function and a shared gate and ``_masked_attention`` an
 # unprojected return for the ``qwen3_next`` family): the served families that
 # share ``MoeLayer``, ``cached_decode_attention``, ``real_neighbours`` and the
-# engine keep the parent's programs, text for text, and so does the trained
-# ``mla_moe`` model's forward and backward.
+# engine keep the parent's programs, text for text, and so did the trained
+# ``mla_moe`` model's forward and backward until PR 44 changed it on purpose.
 PARENT_FAMILY_PROGRAMS = {
     "lfm2-24b-a2b-l10.prefill": "71d26a6e5345e4ae386cf7c9def6e19867488806242331808febac189b826037",
     "lfm2-24b-a2b-l10.chunk": "4790e7e85494130771257fb9f5d72c39fb01b3ecc0e8b93bad133b55518fa381",
     "granite-4.0-h-micro.prefill": "d7b20c70771f80c106e7e7b5c264e7c5967ccbc440143b6479609c3e11e8fa38",
     "granite-4.0-h-micro.chunk": "53441fb221dd45af451d27b8513c5653328d326284c25b0f283ef47bc9ac2ce1",
-    "joyai-llm-flash-ep16.loss_and_grads": "3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294",
+    # taken anew in PR 44, whose blocks keep their flash kernel's two results
+    # (the parent's, 623ba44: 3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294,
+    # which the same model still lowers to with the block's policy set back)
+    "joyai-llm-flash-ep16.loss_and_grads": "9402bb6e3df9dfc63616451b28715df93e0301a20b27d2b63763baaeb00e1789",
+    "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294",
+    # taken on commit 623ba44 (PR 44, before any program file was edited): the
+    # cell PR 43's check lost a run of, one PR old then and without a pin
+    "qwen3-next-80b-a3b-ep4-l12.prefill": "f9c8a75c113d4e719823fa099aa71facd0149de68aae8b83e1dcd4c170be3915",
+    "qwen3-next-80b-a3b-ep4-l12.chunk": "44ebf5c33ce41eb26bf15dc39579325b109c321d2417a67d811693f1036d8034",
 }
 
 
@@ -733,6 +780,9 @@ def _benchmark_model_entry(config):
 @pytest.mark.parametrize("config,layers,width,new_tokens", [
     ("lfm2-24b-a2b-l10", 3, 1024, 512),
     ("granite-4.0-h-micro", 6, 512, 256),
+    # the first period of its pattern (delta, delta, delta, attention), as
+    # ``test_qwen3_next_served_programs_compile_for_v5e`` cuts it
+    ("qwen3-next-80b-a3b-ep4-l12", 4, 2048, 512),
 ])
 def test_served_families_programs_are_the_parents(
     config, layers, width, new_tokens, one_chip, no_persistent_cache, no_locations, monkeypatch
@@ -750,7 +800,8 @@ def test_served_families_programs_are_the_parents(
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
     entry = _benchmark_model_entry(config)
     cut = dict(entry["config"], num_hidden_layers=layers)
-    cut["layer_types"] = cut["layer_types"][:layers]
+    if "layer_types" in cut:  # (``qwen3_next`` derives its pattern from an interval)
+        cut["layer_types"] = cut["layer_types"][:layers]
     model, _ = build_model({"family": entry["family"], "config": cut})
     engine = ContinuousBatchingEngine(
         model, _zeros_as_held(model), SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
@@ -763,20 +814,39 @@ def test_served_families_programs_are_the_parents(
         _described(jax.random.PRNGKey(0), one_chip),
     ).as_text()
     got = {f"{config}.prefill": _sha256(prefill), f"{config}.chunk": _sha256(chunk)}
-    assert got == {name: PARENT_FAMILY_PROGRAMS[name] for name in got}, got
+    assert got == {name: PARENT_FAMILY_PROGRAMS.get(name) for name in got}, got
 
 
-def test_trained_moe_models_loss_and_grads_are_the_parents(
-    one_chip, on_chip_kernels, no_locations, monkeypatch
+@pytest.mark.parametrize("kept,flash_kernels,pin", [
+    # a block: forward, dk/dv, dq, and under the parent's policy the forward again
+    ("flash_results", {"_fwd_kernel": 3, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads"),
+    ("nothing", {"_fwd_kernel": 6, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads.nothing_kept"),
+])
+def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
+    kept, flash_kernels, pin, one_chip, on_chip_kernels, no_locations, monkeypatch
 ):
     """``joyai-flash-train-ep16share``: the model built from the benchmark's
     configuration (two layers: the dense one and the first expert layer,
-    with its MTP module), its losses and their gradients over b1 x 1024,
-    lowered for the described chip: the parent's text."""
+    with its MTP module: three blocks), its losses and their gradients over
+    b1 x 1024, lowered for the described chip. Each rematerialised block
+    keeps its flash kernel's ``out`` and ``lse`` (PR 44), so the text holds
+    one flash ``tpu_custom_call`` a block fewer than the parent's: 9, not 12,
+    and nothing else but the kernel's four transposes a block and one
+    ``reduce_precision`` on the kept ``out``. With the block's policy set
+    back to ``nothing_saveable`` and the two names taken out of ``_fa_fwd``
+    (they emit no operation, but number later private functions one higher)
+    the text is the parent's to the character."""
+    import collections
+    import re
+
+    from dlrover_tpu.models import mla_moe
     from dlrover_tpu.models.build import build_model
     from dlrover_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    if kept == "nothing":  # the parent's blocks, and no names in ``_fa_fwd``
+        monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
+        monkeypatch.setattr(fa, "checkpoint_name", lambda value, name: value)
     entry = _benchmark_model_entry("joyai-llm-flash-ep16")
     model, loss_fn = build_model(
         {"family": entry["family"], "config": dict(entry["config"], num_hidden_layers=2)})
@@ -792,5 +862,61 @@ def test_trained_moe_models_loss_and_grads_are_the_parents(
         return jax.value_and_grad(loss)(params)
 
     text = jax.jit(loss_and_grads).lower(_described(params, one_chip), tokens, tokens).as_text()
-    assert "tpu_custom_call" in text
-    assert _sha256(text) == PARENT_FAMILY_PROGRAMS["joyai-llm-flash-ep16.loss_and_grads"], _sha256(text)
+    names = collections.Counter(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert names.pop("kernel") == 13  # megablox's, the same on both sides
+    assert names == flash_kernels
+    assert text.count("stablehlo.reduce_precision") == (3 if kept == "flash_results" else 0)
+    assert _sha256(text) == PARENT_FAMILY_PROGRAMS[pin], _sha256(text)
+
+
+def test_joyai_cells_whole_step_fits_beside_the_kept_flash_results(
+    topo, on_chip_kernels, monkeypatch, request
+):
+    """``joyai-flash-train-ep16share``'s train step as its worker builds it
+    (5 layers and the MTP module, b4 x 4096, the optimizer, the counters),
+    compiled for the described chip: six blocks each keep ``out
+    bf16[4,4096,32,128]`` and ``lse f32[128,4096]`` from the forward pass to
+    their turn in the backward pass, 136 MB a block, and arguments and
+    temporaries together stay under 15.0 GiB of the chip's 15.75. When this
+    was written: 7.605 + 6.029 = 13.634 GiB, against 7.605 + 6.858 = 14.463
+    with nothing kept (``pytest -s`` compiles that side too and prints
+    both): the compiler's temporaries *fall* by 0.83 GiB where the kept
+    values alone would add 0.76, because the rematerialised block no longer
+    holds the forward kernel's operands and result beside the backward's."""
+    import re
+
+    from dlrover_tpu.models import mla_moe
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.ops import grouped_matmul as gm
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+    from dlrover_tpu.parallel.train_step import default_optimizer
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    entry = _benchmark_model_entry("joyai-llm-flash-ep16")
+    mesh = build_mesh(MeshConfig(dp=-1), topo.devices[:1])
+
+    def sizes():
+        """(arguments + temporaries, flash kernel calls, both in words) of the step compiled now."""
+        model, loss_fn = build_model(entry)
+        lowered, _ = _lowered_step(
+            model, loss_fn, default_optimizer(learning_rate=1e-3, warmup_steps=2), mesh,
+            jnp.zeros((4, 4096), jnp.int32), return_metrics=True)
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        held = m.argument_size_in_bytes + m.temp_size_in_bytes
+        flash = len(re.findall(r'mla\.attend\.\d+ = .*custom_call_target="tpu_custom_call"', compiled.as_text()))
+        return held, flash, (f"arguments {m.argument_size_in_bytes / 2**30:.3f} GiB + temporaries "
+                             f"{m.temp_size_in_bytes / 2**30:.3f} = {held / 2**30:.3f} GiB (output "
+                             f"{m.output_size_in_bytes / 2**30:.3f}, aliased {m.alias_size_in_bytes / 2**30:.3f}), "
+                             f"{flash} flash kernel calls")
+
+    held, flash, line = sizes()
+    assert flash == 18, line  # 6 blocks x forward, dk/dv, dq
+    assert held < 15.0 * 2**30, line
+    if request.config.getoption("capture") != "no":
+        return
+    print(f"\njoyai step, described v5e, b4 x 4096, out and lse kept: {line}")
+    monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
+    held_parent, _, line = sizes()
+    print(f"nothing kept (the parent's blocks): {line}")
+    print(f"the kept results cost {(held - held_parent) / 1e6:.1f} MB")

@@ -17,6 +17,7 @@ FAMILIES = {
     "lfm2_moe": ("lfm2_moe", "Lfm2MoeLM", "Lfm2MoeConfig"),
     "granite_hybrid": ("granite_hybrid", "GraniteHybridLM", "GraniteHybridConfig"),
     "qwen3_next": ("qwen3_next", "Qwen3NextLM", "Qwen3NextConfig"),
+    "mellum": ("mellum", "MellumLM", "MellumConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")

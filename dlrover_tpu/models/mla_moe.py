@@ -102,6 +102,12 @@ class MoeSizes:
     # ones keep their factor over ``init_std``
     down_init_std: float = 0.0
     bias_init_std: float = 0.01
+    # False: the gates are constants in the backward pass. For a chip's share
+    # of a router that no frozen bias steers: through the gates the task loss
+    # reaches the router by the held experts' outputs alone (the group's
+    # all-reduce would add the others'), and that partial sum trains the
+    # chip's share of the assignments up or down, not the choice among experts
+    train_gates: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -343,6 +349,8 @@ class MoeLayer(nn.Module):
             scores = _SCORE_FNS[cfg.score_fn](logits)
             idx, gate_of_expert = route(
                 scores, bias, K, cfg.norm_topk, cfg.scale, cfg.norm_eps)
+            if not cfg.train_gates:
+                gate_of_expert = jax.lax.stop_gradient(gate_of_expert)
 
         with jax.named_scope("moe.dispatch"):
             local = idx - cfg.expert_offset

@@ -37,6 +37,17 @@ Two sets of kernels, chosen from the call's static shapes in one place
 - **The general kernels** (``t_q != t_kv``, a ragged T, no mask, unequal
   blocks, T under one sub-block): one masked-everywhere body a tile.
 
+**A window** (``window=w``, causal only: row ``i`` sees key ``j`` iff ``0
+<= i - j < w``). Where the call takes the causal walk and ``w`` is a whole
+number of blocks, the walked kernels run only the band: the diagonal tile
+as above, the tile ``w / block`` tiles before it in the same sub-blocks
+with the complementary mask (``key_local > row_local``), the tiles between
+the two unmasked, and no tile further back is run or fetched (the index
+maps repeat the nearest tile that runs). Any other windowed call takes the
+general kernels with the window as one more term of their mask. Without a
+window, or with one that cuts nothing off, every program is what it was
+before the band existed, text for text.
+
 Two head sizes: q and k share ``d`` (the score's contraction), v and the
 output share ``d_v``, and the two may differ (latent attention:
 ``d = 192`` for nope + rope, ``d_v = 128``). dq and dk come back ``d``
@@ -88,6 +99,15 @@ def _vmem_spec(block_shape, index_map):
     return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
+def _reaches_window(iq, ik, block_q: int, block_k: int, causal_off: int, window: int):
+    """Whether key tile ``ik`` holds a key that some row of q tile ``iq``
+    still sees under a window: row ``i`` sees keys ``(i + causal_off -
+    window, i + causal_off]``, so the tile's last key has to lie past the
+    first row's lower bound (the general kernels' skip; the walk names its
+    tiles outright)."""
+    return ik * block_k + block_k - 1 > iq * block_q + causal_off - window
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -109,6 +129,7 @@ def _fwd_kernel(
     block_k: int,
     kv_len: int,
     q_len: int,
+    window: Optional[int] = None,
 ):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -128,6 +149,8 @@ def _fwd_kernel(
     run = True
     if causal:
         run = ik * block_k <= iq * block_q + block_q - 1 + causal_off
+    if window is not None:
+        run = jnp.logical_and(run, _reaches_window(iq, ik, block_q, block_k, causal_off, window))
 
     @pl.when(run)
     def _body():
@@ -147,6 +170,8 @@ def _fwd_kernel(
         mask = k_idx < kv_len
         if causal:
             mask = jnp.logical_and(mask, k_idx <= q_idx + causal_off)
+        if window is not None:
+            mask = jnp.logical_and(mask, k_idx > q_idx + causal_off - window)
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_ref[:, :1]  # (block_q, 1)
@@ -208,7 +233,8 @@ _PLAIN = (((1,), (0,)), ((), ()))
 
 
 def _sub_block(
-    causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, forward: bool
+    causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, forward: bool,
+    window: Optional[int] = None,
 ) -> int:
     """The side of the sub-squares a diagonal tile is walked in, or 0 for
     the general kernels. The one place that decides, from what the call's
@@ -218,92 +244,147 @@ def _sub_block(
 
     The forward walks only where a head is more than one tile; at one
     tile a head it keeps the general kernel and its outputs' bits
-    (ROADMAP.md B9 says why, and when that exception goes)."""
+    (ROADMAP.md B9 says why, and when that exception goes). A ``window``
+    (:func:`_window_of`: one that cuts something off) is walked where it
+    is a whole number of blocks: the band then ends on tile edges."""
     walked = (
         causal
         and t_q == t_kv
         and block_q == block_k
         and t_q % block_q == 0
         and block_q % _SUB_BLOCK == 0
+        and (window is None or window % block_q == 0)
     )
     if not walked or (forward and t_q == block_q):
         return 0
     return _SUB_BLOCK
 
 
-def _diagonal_pieces(block: int, sub: int, by_keys: bool):
+def _window_of(window: Optional[int], causal: bool, t_kv: int) -> Optional[int]:
+    """The window as the kernels take it: None where it cuts nothing off
+    (row ``i`` sees the ``window`` keys that end at its own, and no row has
+    ``t_kv`` or more before it), so that such a call is the causal one."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window of {window} keys needs causal=True and window >= 1")
+    return window if window < t_kv else None
+
+
+def _diagonal_pieces(block: int, sub: int, by_keys: bool, far: bool = False):
     """A diagonal tile as ``(row0, rows, key0, keys)`` pieces, one a
     sub-block. Forward and dq accumulate by q rows: rows ``[i*sub,
     (i+1)*sub)`` against keys ``[0, (i+1)*sub)``, whose last ``sub`` keys
     are the square on the diagonal. dk/dv accumulate by key columns
     (``by_keys``): keys ``[j*sub, (j+1)*sub)`` against q rows ``[j*sub,
     block)``, whose first ``sub`` rows are the square. Only the square
-    needs the mask."""
+    needs the mask.
+
+    ``far``: the tile where a window's band ends, ``window // block``
+    tiles before the diagonal's, which keeps the complement, ``key_local >
+    row_local``: rows ``[i*sub, (i+1)*sub)`` against keys ``[i*sub,
+    block)``, whose *first* keys are the square; by key columns, keys
+    ``[j*sub, (j+1)*sub)`` against q rows ``[0, (j+1)*sub)``, whose *last*
+    rows are."""
+    if far:
+        if by_keys:
+            return [(0, lo + sub, lo, sub) for lo in range(0, block, sub)]
+        return [(lo, sub, lo, block - lo) for lo in range(0, block, sub)]
     if by_keys:
         return [(lo, block - lo, lo, sub) for lo in range(0, block, sub)]
     return [(lo, sub, 0, lo + sub) for lo in range(0, block, sub)]
 
 
-def _walk_causal(body, iq, ik, block: int, sub: int, n_tiles: int, by_keys: bool):
+def _walk_causal(
+    body, iq, ik, block: int, sub: int, n_tiles: int, by_keys: bool, band: int = 0
+):
     """Run ``body(rows, keys, where)`` over what the causal mask leaves of
     tile (iq, ik): a tile under the diagonal whole and unmasked (``where``
     None), the diagonal tile one sub-block at a time, a tile above it not
     at all (its blocks are not fetched either: the index maps repeat the
     last tile that runs). ``where(x, fill)`` fills what the mask removes
     of a ``[keys, rows]`` piece: only the piece's square on the diagonal
-    is selected, the rest of ``x`` passes as it is."""
+    is selected, the rest of ``x`` passes as it is.
 
-    def diagonal():
+    ``band``: a window of ``band`` whole tiles. Tile ``iq - band`` is the
+    band's far end and is walked like the diagonal's with the complementary
+    mask (:func:`_diagonal_pieces`); the tiles between the two carry no
+    mask; a tile further back is neither run nor fetched."""
+
+    def masked_tile(far: bool = False):
         key = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
         row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-        keep = key <= row
+        keep = key > row if far else key <= row
+        # by key columns the square is a piece's first ``sub`` q rows
+        # (lanes), by q rows its last ``sub`` keys (sublanes); at the far
+        # end the other way round
+        axis, square_first = (1, not far) if by_keys else (0, far)
 
         def where(x, fill):
             if x.shape == keep.shape:
                 return jnp.where(keep, x, fill)
-            if by_keys:  # the square is the first ``sub`` q rows (lanes)
-                return jnp.concatenate(
-                    [jnp.where(keep, x[:, :sub], fill), x[:, sub:]], axis=1
-                )
-            return jnp.concatenate(  # the last ``sub`` keys (sublanes)
-                [x[:-sub], jnp.where(keep, x[-sub:], fill)], axis=0
-            )
+            cut = sub if square_first else x.shape[axis] - sub
+            at = (slice(None),) * axis
+            first = x[at + (slice(None, cut),)]
+            if square_first:
+                first = jnp.where(keep, first, fill)
+            last = x[at + (slice(cut, None),)]
+            if not square_first:
+                last = jnp.where(keep, last, fill)
+            return jnp.concatenate([first, last], axis=axis)
 
-        for row0, n_rows, key0, n_keys in _diagonal_pieces(block, sub, by_keys):
+        for row0, n_rows, key0, n_keys in _diagonal_pieces(block, sub, by_keys, far):
             body(slice(row0, row0 + n_rows), slice(key0, key0 + n_keys), where)
 
     if n_tiles == 1:  # the one tile is the diagonal's: no branch at all
-        diagonal()
+        masked_tile()
         return
-    pl.when(ik < iq)(lambda: body(slice(None), slice(None), None))
-    pl.when(ik == iq)(diagonal)
+    whole = lambda: body(slice(None), slice(None), None)
+    if not band:
+        pl.when(ik < iq)(whole)
+    else:
+        if band > 1:
+            pl.when(jnp.logical_and(ik < iq, ik > iq - band))(whole)
+        pl.when(ik == iq - band)(lambda: masked_tile(far=True))
+    pl.when(ik == iq)(masked_tile)
 
 
-def _kernel_plan(causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, sub: int):
+def _kernel_plan(
+    causal: bool, t_q: int, t_kv: int, block_q: int, block_k: int, sub: int,
+    window: Optional[int] = None,
+):
     """What one head's grid does, for the ``flash.kernel_built`` record:
     grid steps, steps whose body runs, and the scores computed as a share
-    of the ``t_q x t_kv`` square (the mask itself keeps 0.5 of it)."""
+    of the ``t_q x t_kv`` square (the mask itself keeps 0.5 of it, or with
+    a ``window`` (:func:`_window_of`) ``window / t`` less half its square)."""
     n_q = _round_up(t_q, block_q) // block_q
     n_k = _round_up(t_kv, block_k) // block_k
     if sub:
-        tiles_run = n_q * (n_q + 1) // 2
-        scores = (tiles_run - n_q) * block_q * block_k + n_q * sum(
+        band = window // block_q if window else n_q  # tiles back to the far one
+        masked = n_q + max(n_q - band, 0)  # the diagonal's, and the far ones
+        tiles_run = sum(min(i, band) + 1 for i in range(n_q))
+        scores = (tiles_run - masked) * block_q * block_k + masked * sum(
             rows * keys
             for _, rows, _, keys in _diagonal_pieces(block_q, sub, False)
         )
     else:
+        off = t_kv - t_q
         tiles_run = sum(
-            not causal or j * block_k <= i * block_q + block_q - 1 + t_kv - t_q
+            (not causal or j * block_k <= i * block_q + block_q - 1 + off)
+            and (window is None or _reaches_window(i, j, block_q, block_k, off, window))
             for i in range(n_q)
             for j in range(n_k)
         )
         scores = tiles_run * block_q * block_k
-    return {
-        "path": "causal_tiled" if sub else "general",
+    plan = {
+        "path": ("window_tiled" if window else "causal_tiled") if sub else "general",
         "tiles_visited": n_q * n_k,
         "tiles_run": tiles_run,
         "score_share": scores / (t_q * t_kv),
     }
+    if window:
+        plan["window"] = window
+    return plan
 
 
 def _built(kernel: str, plan: dict):
@@ -320,7 +401,7 @@ def _as_row(ref):
 
 def _walk_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale: float, block: int, sub: int, n_tiles: int,
+    *, sm_scale: float, block: int, sub: int, n_tiles: int, band: int = 0,
 ):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -354,7 +435,7 @@ def _walk_fwd_kernel(
         )
         acc_ref[:, rows] = acc_ref[:, rows] * alpha + pv
 
-    _walk_causal(body, iq, ik, block, sub, n_tiles, False)
+    _walk_causal(body, iq, ik, block, sub, n_tiles, False, band)
 
     @pl.when(ik == n_tiles - 1)
     def _finish():
@@ -367,7 +448,7 @@ def _walk_fwd_kernel(
 
 def _walk_bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs_and_scratch,
-    sm_scale: float, block: int, sub: int, n_tiles: int, by_keys: bool,
+    sm_scale: float, block: int, sub: int, n_tiles: int, by_keys: bool, band: int = 0,
 ):
     """dk/dv (``by_keys``: grid ``(head, key tile, q tile)``, outputs and
     accumulators ``[keys, d]`` and ``[keys, d_v]``) or dq (grid ``(head, q
@@ -424,7 +505,7 @@ def _walk_bwd_kernel(
             else:
                 accs[0][:, rows] += dq
 
-    _walk_causal(body, iq, ik, block, sub, n_tiles, by_keys)
+    _walk_causal(body, iq, ik, block, sub, n_tiles, by_keys, band)
     if one_pass:
         return
 
@@ -447,22 +528,34 @@ def _walk_bwd_kernel(
 # ``interpret`` is an argument because it is part of what was traced.
 _walk_jit = functools.partial(
     jax.jit,
-    static_argnames=("sm_scale", "block", "sub", "interpret"),
+    static_argnames=("sm_scale", "block", "sub", "interpret", "band"),
     inline=True,
 )
 
 
+def _keys_at(band: int):
+    """The key tile grid step ``(b, i, j)`` names, rows streamed over keys
+    (forward, dq). A tile above the diagonal names the diagonal's blocks:
+    no copy. Under a window of ``band`` tiles, a tile before the band names
+    the band's first, which comes next and is fetched once."""
+    if not band:
+        return lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    return lambda b, i, j: (b, jnp.clip(j, jnp.maximum(i - band, 0), i), 0)
+
+
 @_walk_jit
-def _walk_fwd(q, k, v, *, sm_scale: float, block: int, sub: int, interpret: bool):
+def _walk_fwd(
+    q, k, v, *, sm_scale: float, block: int, sub: int, interpret: bool, band: int = 0
+):
     """The forward of the causal walk: whole square tiles, no padding."""
     bh, t, d = q.shape
     d_v = v.shape[2]
     n = t // block
-    # a tile above the diagonal names the diagonal's blocks: no copy
-    at_k = lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    at_k = _keys_at(band)
     out, lse = pl.pallas_call(
         functools.partial(
-            _walk_fwd_kernel, sm_scale=sm_scale, block=block, sub=sub, n_tiles=n
+            _walk_fwd_kernel, sm_scale=sm_scale, block=block, sub=sub, n_tiles=n,
+            band=band,
         ),
         grid=(bh, n, n),
         in_specs=[
@@ -489,7 +582,7 @@ def _walk_fwd(q, k, v, *, sm_scale: float, block: int, sub: int, interpret: bool
 
 
 @_walk_jit
-def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
+def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret, band=0):
     """dk and dv of the causal walk; lse and delta ``(bh, t, 8)``."""
     bh, t, d = q.shape
     d_v = v.shape[2]
@@ -498,10 +591,12 @@ def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
     # the streamed axis: a tile above the diagonal names the diagonal's
     # blocks, which come next: no copy
     rows_at = lambda b, j, i: (b, jnp.maximum(i, j), 0)
+    if band:  # a row tile past the band names the band's last, which is there
+        rows_at = lambda b, j, i: (b, jnp.clip(i, j, jnp.minimum(j + band, n - 1)), 0)
     return pl.pallas_call(
         functools.partial(
             _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
-            n_tiles=n, by_keys=True,
+            n_tiles=n, by_keys=True, band=band,
         ),
         grid=(bh, n, n),
         in_specs=[
@@ -529,19 +624,17 @@ def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
 
 
 @_walk_jit
-def _walk_dq(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
+def _walk_dq(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret, band=0):
     """dq of the causal walk; lse and delta ``(bh, t, 8)``."""
     bh, t, d = q.shape
     d_v = v.shape[2]
     n = t // block
     per_row = (1, block, _LSE_LANES)
-    # a tile above the diagonal names the diagonal's blocks, which are
-    # there: no copy
-    keys_at = lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    keys_at = _keys_at(band)
     return pl.pallas_call(
         functools.partial(
             _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
-            n_tiles=n, by_keys=False,
+            n_tiles=n, by_keys=False, band=band,
         ),
         grid=(bh, n, n),
         in_specs=[
@@ -560,19 +653,21 @@ def _walk_dq(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret):
 
 
 def _flash_fwd(
-    q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int
+    q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q,k: (BH, T, D), v: (BH, T, Dv) → (out (BH,T,Dv), lse (BH,T))."""
     bh, t_q, d = q.shape
     t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
-    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, forward=True)
-    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub)
+    window = _window_of(window, causal, t_kv)
+    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, True, window)
+    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub, window)
     if sub:
         with _built("fwd", plan):
             return _walk_fwd(
                 q, k, v, sm_scale=sm_scale, block=block_q, sub=sub,
-                interpret=_use_interpret(),
+                interpret=_use_interpret(), band=window // block_q if window else 0,
             )
 
     tq_pad = _round_up(t_q, block_q)
@@ -589,6 +684,7 @@ def _flash_fwd(
         block_k=block_k,
         kv_len=t_kv,
         q_len=t_q,
+        window=window,
     )
     with _built("fwd", plan):
         out, lse = pl.pallas_call(
@@ -640,6 +736,7 @@ def _bwd_dkdv_kernel(
     block_k: int,
     kv_len: int,
     q_len: int,
+    window: Optional[int] = None,
 ):
     ik = pl.program_id(1)
     iq = pl.program_id(2)
@@ -653,6 +750,9 @@ def _bwd_dkdv_kernel(
     run = True
     if causal:
         run = ik * block_k <= iq * block_q + block_q - 1 + (kv_len - q_len)
+    if window is not None:
+        run = jnp.logical_and(
+            run, _reaches_window(iq, ik, block_q, block_k, kv_len - q_len, window))
 
     @pl.when(run)
     def _body():
@@ -675,6 +775,8 @@ def _bwd_dkdv_kernel(
         mask = jnp.logical_and(k_idx < kv_len, q_idx < q_len)
         if causal:
             mask = jnp.logical_and(mask, k_idx <= q_idx + (kv_len - q_len))
+        if window is not None:
+            mask = jnp.logical_and(mask, k_idx > q_idx + (kv_len - q_len) - window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (block_q, block_k)
         # dv += p^T @ do
         dv_acc[:] += jax.lax.dot_general(
@@ -714,6 +816,7 @@ def _bwd_dq_kernel(
     block_k: int,
     kv_len: int,
     q_len: int,
+    window: Optional[int] = None,
 ):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -726,6 +829,9 @@ def _bwd_dq_kernel(
     run = True
     if causal:
         run = ik * block_k <= iq * block_q + block_q - 1 + (kv_len - q_len)
+    if window is not None:
+        run = jnp.logical_and(
+            run, _reaches_window(iq, ik, block_q, block_k, kv_len - q_len, window))
 
     @pl.when(run)
     def _body():
@@ -748,6 +854,8 @@ def _bwd_dq_kernel(
         mask = jnp.logical_and(k_idx < kv_len, q_idx < q_len)
         if causal:
             mask = jnp.logical_and(mask, k_idx <= q_idx + (kv_len - q_len))
+        if window is not None:
+            mask = jnp.logical_and(mask, k_idx > q_idx + (kv_len - q_len) - window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -764,14 +872,15 @@ def _bwd_dq_kernel(
 
 
 def _flash_bwd(
-    q, k, v, out, lse, do, sm_scale, causal, block_q, block_k
+    q, k, v, out, lse, do, sm_scale, causal, block_q, block_k, window=None
 ):
     bh, t_q, d = q.shape
     t_kv, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, t_q, t_kv, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, forward=False)
-    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub)
+    window = _window_of(window, causal, t_kv)
+    sub = _sub_block(causal, t_q, t_kv, block_q, block_k, False, window)
+    plan = _kernel_plan(causal, t_q, t_kv, block_q, block_k, sub, window)
     built = functools.partial(_built, plan=plan)
 
     tq_pad = _round_up(t_q, block_q)
@@ -791,7 +900,8 @@ def _flash_bwd(
 
     if sub:  # whole tiles: nothing was padded
         walk = dict(
-            sm_scale=sm_scale, block=block_q, sub=sub, interpret=_use_interpret()
+            sm_scale=sm_scale, block=block_q, sub=sub, interpret=_use_interpret(),
+            band=window // block_q if window else 0,
         )
         with built("dkdv"):
             dk, dv = _walk_dkdv(*operands, **walk)
@@ -806,6 +916,7 @@ def _flash_bwd(
         block_k=block_k,
         kv_len=t_kv,
         q_len=t_q,
+        window=window,
     )
     with built("dkdv"):
         dk, dv = pl.pallas_call(
@@ -869,7 +980,7 @@ def _from_bht(x, b, h):
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q,
     k,
@@ -878,18 +989,25 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    window: Optional[int] = None,
 ):
     """Flash attention over ``[batch, seq, heads, head_dim]`` tensors;
-    v's (and the output's) head size may differ from q's and k's."""
-    out, _ = _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    v's (and the output's) head size may differ from q's and k's.
+
+    ``window`` (static, causal only): row ``i`` sees key ``j`` iff ``0 <= i
+    - j < window`` (end-aligned like the causal mask where ``t_q != t_kv``).
+    Where the call takes the causal walk and the window is a whole number
+    of blocks, the kernels run and fetch only the band's tiles; any other
+    windowed call takes the general kernels with the window in their mask."""
+    out, _ = _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, window)
     return out
 
 
-def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, window):
     b, t, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     out3, lse = _flash_fwd(
-        _to_bht(q), _to_bht(k), _to_bht(v), scale, causal, block_q, block_k
+        _to_bht(q), _to_bht(k), _to_bht(v), scale, causal, block_q, block_k, window
     )
     # The two results the backward kernels need, named for a caller's remat
     # policy (``save_only_these_names``): a name is an identity that lowers
@@ -899,7 +1017,7 @@ def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, sm_scale, block_q, block_k, residuals, g):
+def _fa_bwd(causal, sm_scale, block_q, block_k, window, residuals, g):
     q, k, v, out, lse = residuals
     b, t, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -914,6 +1032,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, residuals, g):
         causal,
         block_q,
         block_k,
+        window,
     )
     return _from_bht(dq3, b, h), _from_bht(dk3, b, h), _from_bht(dv3, b, h)
 
@@ -921,7 +1040,9 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, residuals, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
+def flash_attention_sharded(
+    q, k, v, mesh=None, causal: bool = True, window: Optional[int] = None
+):
     """:func:`flash_attention` under the model's layout on ``mesh``.
 
     A Mosaic kernel cannot be partitioned by GSPMD (lowering a sharded
@@ -934,7 +1055,7 @@ def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
     With no mesh (or one device) this is the plain kernel call.
     """
     if mesh is None or mesh.size == 1:
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, window=window)
     from flax.linen import partitioning as nn_partitioning
     from flax.linen import spmd as flax_spmd
     from jax.sharding import PartitionSpec
@@ -965,7 +1086,7 @@ def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
             f"attention_impl='ring'"
         )
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal),
+        functools.partial(flash_attention, causal=causal, window=window),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
@@ -973,15 +1094,18 @@ def flash_attention_sharded(q, k, v, mesh=None, causal: bool = True):
     )(q, k, v)
 
 
-def reference_attention(q, k, v, causal: bool = True, sm_scale=None):
+def reference_attention(q, k, v, causal: bool = True, sm_scale=None, window=None):
     """Naive einsum attention — the correctness oracle for kernel tests
-    (v may be narrower or wider than q and k)."""
+    (v may be narrower or wider than q and k). ``window``: row ``i`` sees
+    the ``window`` keys that end at its own."""
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         t_q, t_k = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((t_q, t_k), dtype=bool), k=t_k - t_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((t_q, t_k), dtype=bool), k=t_k - t_q - window)
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(probs.dtype)).astype(

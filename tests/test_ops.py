@@ -297,6 +297,141 @@ class TestFlashAttention:
         np.testing.assert_array_equal(got, want)
 
 
+class TestFlashAttentionWindow:
+    """``flash_attention(..., window=w)``: row ``i`` sees the ``w`` keys that
+    end at its own. Walked (the band's tiles only) where the call takes the
+    causal walk and the window is whole blocks; the general kernels with
+    the window in their mask otherwise."""
+
+    @staticmethod
+    def _paths(fn, *args, monkeypatch):
+        seen = []
+        real = fa._built
+
+        def spy(kernel, plan):
+            seen.append((kernel, plan["path"], plan.get("window")))
+            return real(kernel, plan)
+
+        monkeypatch.setattr(fa, "_built", spy)
+        jax.make_jaxpr(fn)(*args)
+        return sorted(set(seen))
+
+    # blocks of 256 (one sub-block a tile) and 512 (a tile walked in 2 x 2
+    # sub-squares, so both masked pieces and an unmasked one): T of 2, 4 and
+    # 8 blocks, a window of one block and of two
+    @pytest.mark.parametrize("blocks_back", [1, 2])
+    @pytest.mark.parametrize("t,block", [(512, 256), (1024, 256), (2048, 256),
+                                         (1024, 512), (2048, 512), (4096, 512)])
+    def test_band_matches_reference(self, t, block, blocks_back, monkeypatch):
+        window = blocks_back * block
+        keys = jax.random.split(jax.random.PRNGKey(t + window), 4)
+        q, k, v, w = (jax.random.normal(key, (1, t, 2, 16)) for key in keys)
+        attend = functools.partial(
+            flash_attention, causal=True, sm_scale=None, block_q=block, block_k=block,
+            window=window)
+        want_path = "window_tiled" if window < t else "causal_tiled"
+        assert self._paths(_grads(attend, w), q, k, v, monkeypatch=monkeypatch) == [
+            (kernel, want_path, window if window < t else None) for kernel in ("dkdv", "dq", "fwd")]
+        got = _out_and_grads(attend, q, k, v, w)
+        want = _out_and_grads(functools.partial(reference_attention, window=window), q, k, v, w)
+        for a, b, atol in zip(got, want, (2e-5, 5e-5, 5e-5, 5e-5)):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=atol)
+
+    @pytest.mark.parametrize("t,block,window", [
+        (1000, 512, 300),  # a ragged T
+        (768, 256, 300),  # whole tiles, a window that is no whole number of blocks
+        (100, 32, 7), (64, 64, 1),
+    ])
+    def test_other_windows_take_the_general_kernels(self, t, block, window, monkeypatch):
+        q, k, v = _qkv(b=1, t=t)
+        w = _qkv(b=1, t=t, seed=1)[0]
+        attend = functools.partial(
+            flash_attention, causal=True, sm_scale=None, block_q=block, block_k=block,
+            window=window)
+        assert self._paths(_grads(attend, w), q, k, v, monkeypatch=monkeypatch) == [
+            (kernel, "general", window) for kernel in ("dkdv", "dq", "fwd")]
+        got = _out_and_grads(attend, q, k, v, w)
+        want = _out_and_grads(functools.partial(reference_attention, window=window), q, k, v, w)
+        for a, b, atol in zip(got, want, (2e-5, 5e-5, 5e-5, 5e-5)):
+            np.testing.assert_allclose(a, b, atol=atol)
+
+    def test_end_aligned_window_over_a_longer_kv(self):
+        """``t_q != t_kv``: the window ends where the causal mask does."""
+        q = _qkv(b=1, t=24)[0]
+        _, k, v = _qkv(b=1, t=40, seed=1)
+        out = flash_attention(q, k, v, True, None, 16, 16, 5)
+        np.testing.assert_allclose(out, reference_attention(q, k, v, True, window=5), atol=2e-5)
+
+    def test_a_window_that_cuts_nothing_is_the_causal_call(self):
+        q, k, v = _qkv(t=64)
+        np.testing.assert_array_equal(
+            flash_attention(q, k, v, True, None, 32, 32, 64), flash_attention(q, k, v, True, None, 32, 32))
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(q, k, v, False, None, 32, 32, 8)
+
+    def test_window_reaches_the_sharded_call(self, monkeypatch):
+        from dlrover_tpu.ops.flash_attention import flash_attention_sharded
+
+        q, k, v = _qkv(t=64)
+        np.testing.assert_allclose(
+            flash_attention_sharded(q, k, v, None, causal=True, window=9),
+            reference_attention(q, k, v, True, window=9), atol=2e-5)
+
+    @pytest.mark.parametrize("by_keys", [False, True])
+    @pytest.mark.parametrize("t,block,window", [(8192, 1024, 1024), (2048, 512, 1024), (1024, 256, 256)])
+    def test_band_covers_the_mask_exactly_once(self, t, block, window, by_keys):
+        """Every (query, key) pair the window's mask keeps is computed once,
+        by either order of the walk; a pair outside it only inside a masked
+        square at one end of the band; tiles outside the band not at all."""
+        sub, n, band = 256, t // block, window // block
+        i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+        keep = (j <= i) & (i - j < window)
+        seen = np.zeros((t, t), np.int8)
+        for iq in range(n):
+            for ik in range(max(iq - band, 0), iq + 1):
+                far, diag = ik == iq - band, ik == iq
+                pieces = ([(0, block, 0, block)] if not (far or diag) else
+                          fa._diagonal_pieces(block, sub, by_keys, far))
+                for r0, nr, k0, nk in pieces:
+                    seen[iq * block + r0:iq * block + r0 + nr, ik * block + k0:ik * block + k0 + nk] += 1
+        assert (seen[keep] == 1).all() and seen.max() == 1
+        plan = fa._kernel_plan(True, t, t, block, block, sub, window)
+        assert plan["path"] == "window_tiled" and plan["window"] == window
+        assert plan["score_share"] == pytest.approx(seen.sum() / t**2)
+        assert plan["tiles_run"] == sum(min(iq, band) + 1 for iq in range(n))
+        # what is computed beyond the mask lies in 256-wide squares on the band's two edges
+        extra = seen.astype(bool) & ~keep
+        edge = ((i // sub == j // sub) | ((i - window) // sub == j // sub))
+        assert (extra <= edge).all()
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkdv"])
+    def test_a_tile_before_the_band_is_neither_run_nor_read(self, kernel):
+        """T 1024 in tiles of 256 under a window of 256: row tile 3 needs key
+        tiles 2 and 3, key tile 0 needs row tiles 0 and 1. What lies outside
+        is filled with NaN: the results are finite and the reference's, so
+        those tiles were not computed, not even to be masked."""
+        t, b, scale = 1024, 256, 0.125
+        keys = jax.random.split(jax.random.PRNGKey(12), 4)
+        q, k, v, do = (jax.random.normal(key, (2, t, 64)) for key in keys)
+        out, lse = fa._flash_fwd(q, k, v, scale, True, b, b, b)
+        dq, dk, dv = fa._flash_bwd(q, k, v, out, lse, do, scale, True, b, b, b)
+        early = lambda x: x.at[:, :2 * b].set(jnp.nan)  # key tiles 0 and 1
+        late = lambda x: x.at[:, 2 * b:].set(jnp.nan)  # row tiles 2 and 3
+        if kernel == "fwd":
+            got = fa._flash_fwd(q, early(k), early(v), scale, True, b, b, b)[0][:, 3 * b:]
+            want = out[:, 3 * b:]
+        elif kernel == "dq":
+            got = fa._flash_bwd(q, early(k), early(v), out, lse, do, scale, True, b, b, b)[0][:, 3 * b:]
+            want = dq[:, 3 * b:]
+        else:
+            got = fa._flash_bwd(late(q), k, v, late(out), late(lse), late(do), scale, True, b, b, b)[1:]
+            got = jnp.concatenate([g[:, :b] for g in got], axis=-1)
+            want = jnp.concatenate([dk[:, :b], dv[:, :b]], axis=-1)
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_array_equal(got, want)
+
+
 class TestRingAttention:
     def _mesh(self, sp):
         devices = np.array(jax.devices()[:sp]).reshape(sp)

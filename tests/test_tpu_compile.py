@@ -920,3 +920,62 @@ def test_joyai_cells_whole_step_fits_beside_the_kept_flash_results(
     held_parent, _, line = sizes()
     print(f"nothing kept (the parent's blocks): {line}")
     print(f"the kept results cost {(held - held_parent) / 1e6:.1f} MB")
+
+
+# -- the window band (PR 47) ---------------------------------------------------
+
+
+@pytest.mark.parametrize("window,path,tiles_run", [(1024, "window_tiled", 15), (None, "causal_tiled", 36)])
+def test_windowed_flash_kernels_compile_at_the_mellum_cells_shape(
+    window, path, tiles_run, one_chip, on_chip_kernels
+):
+    """``mellum2-train-ep4share``'s attention as its layers call it, b2 x
+    8192, 32 heads of 128, forward and backward, for the described chip: the
+    window layers' band (2 key tiles a row tile, 15 of 64 a head) and the
+    full layer's causal walk at 8 tiles a head, a shape no other cell runs."""
+    x = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, True, window=window).astype(jnp.float32).sum()
+
+    from dlrover_tpu.observability import spans
+
+    compiled = jax.jit(jax.grad(attend, (0, 1, 2))).lower(x, x, x).compile()
+    assert _kernel_text(compiled).count("tpu_custom_call") >= 3
+    plan = fa._kernel_plan(True, 8192, 8192, 1024, 1024, 256, window)
+    assert (plan["path"], plan["tiles_run"], plan["tiles_visited"]) == (path, tiles_run, 64)
+    assert spans.process_accumulator().stats()["flash.kernel_built"].count >= 3
+
+
+def test_mellum_cells_whole_step_fits_one_v5e(topo, on_chip_kernels, monkeypatch, request):
+    """``mellum2-train-ep4share``'s train step as its worker builds it (4
+    layers, b2 x 8192, the optimizer, the counters), compiled for the
+    described chip: 12 flash kernel calls (a layer's forward, dk/dv and dq;
+    9 under ``swa.attend_window``, 3 under ``swa.attend_full``), and
+    arguments and temporaries together under 14.5 GiB of the chip's 15.75."""
+    import re
+
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.ops import grouped_matmul as gm
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+    from dlrover_tpu.parallel.train_step import default_optimizer
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    model, loss_fn = build_model(_benchmark_model_entry("mellum2-12b-a2.5b-ep4-l4"))
+    mesh = build_mesh(MeshConfig(dp=-1), topo.devices[:1])
+    lowered, _ = _lowered_step(
+        model, loss_fn, default_optimizer(learning_rate=1e-3, warmup_steps=2), mesh,
+        jnp.zeros((2, 8192), jnp.int32), return_metrics=True)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.temp_size_in_bytes
+    text = compiled.as_text()
+    line = (f"arguments {m.argument_size_in_bytes / 2**30:.3f} GiB + temporaries "
+            f"{m.temp_size_in_bytes / 2**30:.3f} = {held / 2**30:.3f} GiB")
+    kernels = {kind: len(re.findall(
+        rf'swa\.attend_{kind}\.\d+ = .*custom_call_target="tpu_custom_call"', text))
+        for kind in ("window", "full")}
+    assert kernels == {"window": 9, "full": 3}, kernels
+    assert held < 14.5 * 2**30, line
+    if request.config.getoption("capture") == "no":
+        print(f"\nmellum step, described v5e, b2 x 8192: {line}")

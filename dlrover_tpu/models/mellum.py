@@ -34,7 +34,15 @@ the last layer, then an untied head.
   router by the held experts' outputs alone, a partial sum of what the
   group's all-reduce gives it, and a router trained on that sum alone moves
   the chip's share of the assignments (measured: 9% to 33% of them from
-  seed to seed at one init, 61-64% at another: PERF.md, PR 47).
+  seed to seed at one init, 61-64% at another: PERF.md, PR 47). With no
+  gradient and no bias nothing steers the selection, the load on the experts
+  held stays near the mean (0.97-1.07 times it in every layer of a first
+  step; past twice the mean in one layer-step of ~6,900: PERF.md, PR 48),
+  and ``MoeLayer`` moves its rows through a buffer of twice the mean load
+  and not of four times (``MoeSizes.buffer_over_mean``: at 16 of 64 held,
+  half the assignments and not all of them; a load past it would take the
+  layer's overflow pass, as anywhere). With all experts held the gates are
+  trained and the buffer is every assignment, as before.
 
 The embedding is drawn at ``EMBED_INIT_STD``, the matrices at ``init_std``:
 with both at 0.02 the first attention layer writes about twice what the

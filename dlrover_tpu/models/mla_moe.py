@@ -38,10 +38,15 @@ exchange. (``experts_held = 0`` holds them all.)
 **How the experts held are computed.** Assignments are sorted by expert;
 those that land here go, a row buffer at a time, through three grouped
 products (``ops/grouped_matmul.py``). The buffer holds
-``BUFFER_OVER_MEAN`` times the mean load, which one pass nearly always
-fits; a step whose load is past it takes as many further passes over the
-same buffer as its load needs, so the layer never drops a token whatever
-the router does, and has no second way of computing an expert.
+``MoeSizes.buffer_over_mean`` times the mean load, which one pass nearly
+always fits: 4 where something in the step steers the selection (a trained
+router, a frozen bias), 2 where nothing does (a chip's share of a router
+whose gates are constants in the backward pass and that has no bias: the
+load on the experts held read 0.97-1.07 times the mean on a first step and
+passed twice the mean in one layer-step of ~6,900). A step whose
+load is past the buffer takes as many further passes over the same buffer
+as its load needs, so the layer never drops a token whatever the router
+does, and has no second way of computing an expert.
 
 With ``targets`` the model returns the trunk's per-token losses (the
 fused-CE contract of ``gpt.py``) and sows the weighted MTP loss under
@@ -66,22 +71,14 @@ from .llama import RMSNorm, _constrain  # the same norm and constraint; eps belo
 
 param_with_axes = nn_partitioning.param_with_axes
 
-# The row buffer of the grouped products, as a multiple of the mean load
-# (the assignments that land on the experts held when routing is even).
-# Moving rows costs by the buffer, whatever the load, and a load past it
-# costs a further pass, so it is sized to hold nearly every step: under
-# training with a fixed selection bias the router sends a layer's tokens to
-# one hot expert for steps at a time, and on the v5e 19% of a run's
-# (layer, step) pairs passed 2x the mean, 1-5% passed 4x, none 8x (PERF.md,
-# PR 27).
-BUFFER_OVER_MEAN = 4
-
-
 @dataclass(frozen=True)
 class MoeSizes:
     """What :class:`MoeLayer` is built from: the sizes of one routed-expert
     layer and of the chip's share of it, whichever model's config they
-    come from (``MlaMoeConfig.moe_sizes``, ``Lfm2MoeConfig.moe_sizes``)."""
+    come from (``MlaMoeConfig.moe_sizes``, ``Lfm2MoeConfig.moe_sizes``,
+    ``Qwen3NextConfig.moe_sizes``, ``MellumConfig.moe_sizes``). The layer
+    derives the size of its row buffer from them (``buffer_over_mean``):
+    nothing sets it."""
 
     n_experts: int  # the router's width
     top_k: int
@@ -114,6 +111,31 @@ class MoeSizes:
     @property
     def experts_here(self) -> int:
         return self.experts_held or self.n_experts
+
+    @property
+    def buffer_over_mean(self) -> int:
+        """The row buffer of the grouped products, as a multiple of the mean
+        load (the assignments that land on the experts held when routing is
+        even). Moving rows costs by the buffer, whatever the load (every
+        gather, gate select, sort permutation and scatter runs over all of
+        it; only the grouped products skip its empty tiles), and a load past
+        it costs a further pass, so it is sized to hold nearly every step,
+        by what can steer the load:
+
+        - **4** where the step trains the router (``train_gates``) or a
+          selection bias stands on it (``bias_name``): under training with a
+          fixed bias the router sends a layer's tokens to one hot expert for
+          steps at a time, and on the v5e 19% of a run's (layer, step) pairs
+          passed 2x the mean, 1-5% passed 4x, none 8x (PERF.md, PR 27).
+        - **2** where neither does: the selection is the router's init over
+          fresh tokens, and the load on the 16 of 64 experts held read
+          0.97-1.07x the mean in every layer of a first step, 24.8-26.1% of
+          a window's assignments in every seed, and passed 2x in one
+          layer-step of ~6,900 while a window memorised its batches (PERF.md,
+          PRs 47-48). There 4x the mean would be every assignment, a quarter
+          of it filled.
+        """
+        return 4 if self.train_gates or self.bias_name else 2
 
 
 @dataclass(frozen=True)
@@ -362,7 +384,7 @@ class MoeLayer(nn.Module):
             order = jnp.argsort(key, stable=True)  # held first, by expert
             ends = jnp.cumsum(group_sizes)  # of each expert's group among the sorted rows
             mean_load = N * K * Eh / E
-            rows = min(N * K, -(-int(BUFFER_OVER_MEAN * mean_load) // 8) * 8)  # whole sublanes
+            rows = min(N * K, -(-int(cfg.buffer_over_mean * mean_load) // 8) * 8)  # whole sublanes
             firsts = range(0, N * K, rows)  # a pass takes the sorted rows [first, first + rows)
             valid = [jnp.clip(n_here - first, 0, rows) for first in firsts]
 
@@ -401,7 +423,9 @@ class MoeLayer(nn.Module):
             return out
 
         routed = grouped(0, xf, gate_of_expert)
-        if len(firsts) > 1:
+        # (initialising wants the parameters, which the branch has none of, and tracing
+        # a second pass costs a start ~0.3 s a layer: set-up is a bounded metric)
+        if len(firsts) > 1 and not self.is_initializing():
             routed = routed + jax.lax.cond(valid[1] > 0, overflow, nothing, xf, gate_of_expert)
 
         out = routed

@@ -296,6 +296,51 @@ def test_four_shares_add_up_to_the_uncut_layer():
     assert float(jnp.max(jnp.abs(want - alike))) > 3e-4 and float(jnp.max(jnp.abs(attn))) > 1e-3
 
 
+@pytest.mark.parametrize("load,extra", [(1.0, 0), (2.5, 1), (4.0, 1)],
+                         ids=["the_mean", "2.5x_the_mean", "4x_the_mean"])
+def test_a_shares_buffer_of_twice_the_mean_load_takes_every_row(load, extra):
+    """A share's layer (2 of 8 experts held, top-2, gates constant in the
+    backward pass, no bias) moves its rows through a buffer of twice the
+    mean load. A router skewed so that 1.0, 2.5 and 4 times the mean land
+    here: the output and the gradients of the input and of the three expert
+    matrices are the plain reference's, in one pass where the load fits the
+    buffer and through the overflow pass where it does not; nothing is
+    dropped."""
+    cfg = MellumConfig.tiny(dtype=jnp.float32, experts_held=2, expert_offset=4, init_std=0.2)
+    sizes, n = cfg.moe_sizes, B * T
+    assert not sizes.train_gates and not sizes.bias_name and sizes.buffer_over_mean == 2
+    mean_load = n * sizes.top_k * sizes.experts_here // sizes.n_experts
+    tokens_here = int(load * mean_load) // sizes.top_k  # each sends both its choices here
+    h = np.array(jax.random.normal(jax.random.PRNGKey(5), (n, cfg.hidden_size)))
+    here = np.random.default_rng(6).permutation(n) < tokens_here
+    h[:, 0] = np.where(here, 1.0, -1.0)  # the channel the router is skewed along
+    h = jnp.asarray(h.reshape(B, T, cfg.hidden_size))
+    layer = mellum.MoeLayer(sizes)
+    params = jax.tree.map(np.array, layer.init(jax.random.PRNGKey(7), h)["params"])
+    params["w_router"][0] = 0.0
+    params["w_router"][0, 4:6] = 6.0
+
+    def run(p, h):
+        out, sown = layer.apply({"params": p}, h, mutable=("metrics",))
+        return out, sown["metrics"]
+
+    out, m = run(params, h)
+    want, landed = ref._expert_layer(h, params, hp_of(cfg), jnp.float32)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    assert int(m["assignments_here"][0]) == int(landed) == int(load * mean_load)
+    assert int(m["dropped"][0]) == 0
+    assert int(m["extra_passes"][0]) == extra
+    w = jax.random.normal(jax.random.PRNGKey(8), out.shape)
+    g = jax.grad(lambda p, h: jnp.sum(run(p, h)[0] * w), argnums=(0, 1))(params, h)
+    r = jax.grad(lambda p, h: jnp.sum(ref._expert_layer(h, p, hp_of(cfg), jnp.float32)[0] * w),
+                 argnums=(0, 1))(params, h)
+    assert not np.any(np.asarray(g[0]["w_router"]))  # nothing reaches the router
+    for name in ("w_gate", "w_up", "w_down"):
+        assert float(jnp.max(jnp.abs(r[0][name]))) > 1e-3
+        np.testing.assert_allclose(g[0][name], r[0][name], atol=2e-5, err_msg=name)
+    np.testing.assert_allclose(g[1], r[1], atol=2e-5)
+
+
 # -- the benchmark's configuration ----------------------------------------------------
 
 def benchmark_config():

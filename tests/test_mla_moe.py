@@ -6,6 +6,7 @@ step's counters and a flash-checkpoint round trip of the expert state.
 """
 
 import dataclasses
+import importlib
 import json
 import os
 
@@ -16,7 +17,7 @@ import pytest
 
 from benchmark.reference import mla_moe as ref
 from dlrover_tpu.models import mla_moe
-from dlrover_tpu.models.build import build_model
+from dlrover_tpu.models.build import FAMILIES, build_model
 from dlrover_tpu.models.gpt import token_loss_mean
 from dlrover_tpu.models.mla_moe import MlaMoeConfig, MlaMoeLM, MoeLayer
 from dlrover_tpu.ops.grouped_matmul import collect_rows, grouped_matmul, spread_rows
@@ -267,6 +268,49 @@ def test_no_token_is_dropped_when_all_choose_the_held_experts(experts, held, ext
                  argnums=(0, 1))(params, h)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(r)):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# -- the row buffer's size follows what can steer the load (PR 48) ------------------
+
+@pytest.mark.parametrize("train_gates,bias_name,multiple", [
+    (False, "", 2),  # nothing steers the selection: a share of a router with no bias
+    (True, "", 4),  # a trained router (every served share too: no server sets train_gates)
+    (False, "e_score_correction_bias", 4),  # a frozen bias stands on the selection
+    (True, "e_score_correction_bias", 4),  # the PR 27 cell: 19% of its (layer, step) pairs pass 2x
+])
+def test_the_row_buffer_is_twice_the_mean_load_only_where_nothing_steers_it(
+        train_gates, bias_name, multiple):
+    sizes = mla_moe.MoeSizes(n_experts=64, top_k=8, width=16, experts_held=16,
+                             train_gates=train_gates, bias_name=bias_name)
+    assert sizes.buffer_over_mean == multiple
+
+
+@pytest.mark.parametrize("family", ["mla_moe", "lfm2_moe", "qwen3_next", "mellum"])
+def test_every_familys_published_config_keeps_the_buffer_of_four_times_the_mean(family):
+    """The published configs hold every expert and train their routers:
+    ``N x K`` rows at any multiple, and the multiple is 4."""
+    module, _, config = FAMILIES[family]
+    sizes = getattr(importlib.import_module(f"dlrover_tpu.models.{module}"), config)().moe_sizes
+    assert sizes.train_gates and sizes.experts_here == sizes.n_experts
+    assert sizes.buffer_over_mean == 4
+
+
+@pytest.mark.parametrize("train_gates,bias_name,conds", [
+    (False, "", 1),  # a buffer of half the assignments: the overflow pass is built, under a cond
+    (True, "", 0), (False, "e_score_correction_bias", 0), (True, "e_score_correction_bias", 0),
+])
+def test_only_the_smaller_buffers_program_gains_the_overflow_branch(train_gates, bias_name, conds):
+    """A quarter of the experts held: at 4x the mean the buffer is every
+    assignment, one pass, and no ``cond`` is in the program (a served
+    share's chunk and prefill are the parent's); at 2x it is half of them,
+    and what is past it goes through the overflow pass under a ``cond``."""
+    sizes = mla_moe.MoeSizes(n_experts=8, top_k=2, width=16, experts_held=2, expert_offset=2,
+                             train_gates=train_gates, bias_name=bias_name, dtype=jnp.float32)
+    h = jnp.zeros((B, T, 32))
+    layer = MoeLayer(sizes)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), h)["params"])
+    jaxpr = jax.make_jaxpr(lambda p, h: layer.apply({"params": p}, h, mutable=("metrics",)))(params, h)
+    assert _count_primitive(jaxpr.jaxpr, "cond") == conds
 
 
 @pytest.mark.parametrize("shape", [(2, 16, 8), (2, 16, 3, 8), (1, 5, 2, 4)])

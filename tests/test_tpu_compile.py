@@ -762,7 +762,9 @@ PARENT_FAMILY_PROGRAMS = {
     "joyai-llm-flash-ep16.loss_and_grads": "9402bb6e3df9dfc63616451b28715df93e0301a20b27d2b63763baaeb00e1789",
     "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294",
     # taken on commit 623ba44 (PR 44, before any program file was edited): the
-    # cell PR 43's check lost a run of, one PR old then and without a pin
+    # cell PR 43's check lost a run of, one PR old then and without a pin. Held
+    # again in PR 48: a served share sets no ``train_gates``, so its buffer stays
+    # 4x the mean load, ``N x K`` rows, and no overflow branch enters its programs
     "qwen3-next-80b-a3b-ep4-l12.prefill": "f9c8a75c113d4e719823fa099aa71facd0149de68aae8b83e1dcd4c170be3915",
     "qwen3-next-80b-a3b-ep4-l12.chunk": "44ebf5c33ce41eb26bf15dc39579325b109c321d2417a67d811693f1036d8034",
 }
@@ -952,7 +954,16 @@ def test_mellum_cells_whole_step_fits_one_v5e(topo, on_chip_kernels, monkeypatch
     layers, b2 x 8192, the optimizer, the counters), compiled for the
     described chip: 12 flash kernel calls (a layer's forward, dk/dv and dq;
     9 under ``swa.attend_window``, 3 under ``swa.attend_full``), and
-    arguments and temporaries together under 14.5 GiB of the chip's 15.75."""
+    arguments and temporaries together under 14.5 GiB of the chip's 15.75.
+    Since PR 48 a share's row buffer is twice the mean load (``MoeSizes.
+    buffer_over_mean``): no value of the step has all 131,072 assignments
+    x 2,304 any more, the rows move 65,536 at a time, and each layer's
+    overflow pass stands under a conditional in the forward pass, in the
+    block's second forward and in the backward pass. When this was written:
+    6.652 + 5.205 = 11.857 GiB (the parent's 6.652 + 4.443 = 11.095; with
+    the overflow branch left out 9.998, with it taken unconditionally
+    10.822: the conditionals cost 1.86 GiB where the halved buffer gives
+    back 1.10: ``PERF.md`` section 6, PR 48)."""
     import re
 
     from dlrover_tpu.models.build import build_model
@@ -977,5 +988,7 @@ def test_mellum_cells_whole_step_fits_one_v5e(topo, on_chip_kernels, monkeypatch
         for kind in ("window", "full")}
     assert kernels == {"window": 9, "full": 3}, kernels
     assert held < 14.5 * 2**30, line
+    assert "[131072,2304]" not in text and "[65536,2304]" in text
+    assert len(re.findall(r" conditional\(", text)) == 3 * 4
     if request.config.getoption("capture") == "no":
         print(f"\nmellum step, described v5e, b2 x 8192: {line}")

@@ -19,7 +19,7 @@ it by AST without importing jax. Per file:
   (aliases resolved through the file's imports) must be a registered
   axis (mesh or logical — both legitimately appear in specs);
 - ``param_with_axes(..., axes=...)`` and
-  ``with_logical_constraint``/``_constrain`` string arguments must be
+  ``with_logical_constraint``/``constrain`` string arguments must be
   registered *logical* axes (a mesh axis there is exactly the
   silent-no-constraint drift);
 - ``axis_name=``/``*_axis`` keyword values and string parameter
@@ -60,7 +60,7 @@ _SHARDING_POSIX = "dlrover_tpu/parallel/sharding.py"
 _SCAN_DIRS = ("parallel", "models", "ops", "trainer")
 _SCAN_FILES = ("checkpoint/meta.py",)
 
-_LOGICAL_CALLS = {"param_with_axes", "with_logical_constraint", "_constrain"}
+_LOGICAL_CALLS = {"param_with_axes", "with_logical_constraint", "constrain"}
 _COLLECTIVE_CALLS = {
     "psum", "pmean", "pmax", "pmin", "axis_index", "ppermute",
     "all_gather", "psum_scatter", "all_to_all",

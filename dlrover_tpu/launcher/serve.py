@@ -140,12 +140,14 @@ class ServingDaemon:
 
     def submit_streaming(
         self, prompt, max_new_tokens=None, prefix_id=None,
-        allowed_tokens=None, timeout: float = 60.0,
+        allowed_tokens=None, *, timeout: float,
     ) -> int:
         """Submit WITHOUT blocking for the completion: returns the uid
         as soon as the driver enqueues the request. Pair with
         :meth:`partial` to stream tokens as they are emitted and with
-        :meth:`result` to collect the final Completion."""
+        :meth:`result` to collect the final Completion. ``timeout`` bounds
+        the wait for the loop to take the request and has no default: it
+        is part of the request's one deadline (ROADMAP S0 (iv))."""
         return self._submit_item(
             "req_stream", (list(prompt), max_new_tokens, prefix_id,
                            allowed_tokens),
@@ -194,6 +196,8 @@ class ServingDaemon:
             logger.debug("cancel of uid=%s not delivered: %r", uid, e)
             return False
 
+    # The two prefix calls keep a minute of their own: control calls, with
+    # no request and so no request's timeout to wait by.
     def register_prefix(self, tokens, timeout: float = 60.0) -> int:
         """Register a shared prompt prefix on the engine (computed
         lazily, invalidated by weight swaps)."""
@@ -539,16 +543,25 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
             poll with NEW tokens, then a final line with the full
             completion + metrics. ANY socket failure (client gone,
             reset, timeout) cancels the request on the engine — a dead
-            client must not keep consuming decode capacity."""
+            client must not keep consuming decode capacity. ``timeout``
+            is the request's one deadline: its wait for the serving loop
+            to take it counts (a loop that compiles two prefill widths
+            inside one round is away for over a minute: ROADMAP S0 (iv))."""
+            deadline = time.monotonic() + timeout
             try:
                 uid = daemon.submit_streaming(
                     prompt, max_new_tokens=max_tokens,
                     prefix_id=prefix_id, allowed_tokens=allowed,
+                    timeout=timeout,
                 )
             except ValueError as e:
                 self._send(400, {"error": repr(e)[:200]})
                 return
             except Exception as e:  # noqa: BLE001
+                logger.warning(
+                    "streamed completion refused with 500: %r (%d prompt tokens)",
+                    e, len(prompt),
+                )
                 self._send(500, {"error": repr(e)[:200]})
                 return
 
@@ -559,7 +572,6 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                 self.wfile.flush()
 
             sent = 0
-            deadline = time.monotonic() + timeout
             try:
                 self.send_response(200)
                 self.send_header(
@@ -885,12 +897,6 @@ def main(argv=None) -> int:
         "behind device execution at a one-chunk emission latency.",
     )
     ap.add_argument(
-        "--auto-chunk", action="store_true",
-        help="retune --decode-chunk between dispatches from the "
-        "measured serving_host_frac (grow when host-bound, shrink "
-        "when device-bound)",
-    )
-    ap.add_argument(
         "--kv-int8", action="store_true",
         help="int8 decode KV cache (halves cache HBM; lossy — see "
         "docs/generation.md)",
@@ -1002,7 +1008,6 @@ def main(argv=None) -> int:
             decode_chunk=ns.decode_chunk,
             cache_layout=ns.cache_layout,
             overlap=not ns.sync_round,
-            auto_chunk=ns.auto_chunk,
             kv_block_size=ns.kv_block_size,
             kv_pool_blocks=ns.kv_pool_blocks,
         )

@@ -2,7 +2,57 @@
 step and the server can build: ``{"family": <name>, "config": {<the config
 class's fields>}}``. Callers (the benchmark's workers, a training script,
 ``tpurun-serve``) name no model class; a new family is one more line in
-``FAMILIES``, the one registry trainer and server share.
+``FAMILIES``, the one registry trainer and server share, and one file that
+imports ``layers``, ``moe``, ``ops/`` and ``parallel/`` and no other family
+(``tests/test_models_layering.py``).
+
+**The contract**, written here once: what a family's model may give, and
+who reads it. No base class and no protocol type: a holder probes with
+``getattr`` and a model that lacks a piece is served or trained without it.
+
+- ``model(tokens[B, T], *, targets=None, decode=False, positions=None,
+  kv_valid=None, cache_slots=None)`` -> logits ``[B, T, V]``. With
+  ``targets`` (``targets[:, i]`` the token after ``tokens[:, i]``; -1 is
+  ignored) the per-token losses ``[B, T]`` in float32, 0.0 where ignored:
+  the *fused-CE contract*, paired with ``layers.token_loss_mean``
+  (``layers.chunked_token_ce`` computes them a chunk at a time, so the
+  ``[B, T, V]`` logits never exist whole). A family that is only trained
+  takes ``targets`` alone. With ``decode=True`` the model reads and writes
+  the ``"cache"`` collection (``generation.decode_apply`` is the one place
+  that call is spelt): ``positions [B, T]`` absolute, ``kv_valid [B, L]``
+  the cache slots that hold real tokens, ``cache_slots [B]`` a one-token
+  step's per-row write slots (without it the call's tokens go to the
+  shared write offset).
+- On the config: ``ce_chunk`` (> 0: the train step hands the targets in;
+  the size of a loss chunk) and ``takes_targets`` (hand them in whatever
+  ``ce_chunk`` says: the model sows terms or counters on the way),
+  ``frozen_leaves`` (leaf names that take neither gradient nor weight
+  decay): ``parallel/train_step.py: build_train_step`` (``:272-275``),
+  ``build_eval_step`` (``:405``) and ``build_model`` below.
+  ``kv_cache_int8``: ``layers._update_decode_cache``, and
+  ``ContinuousBatchingEngine.stats`` (``serving.py:1697``).
+- ``consumed_param_dtypes(params)`` -> a tree of the dtype ``__call__``
+  reads each leaf in (``layers.dtypes_read_by_name`` over the names the
+  family lists). A holder that rounds a leaf to it once asks the
+  arithmetic of the float32 tree, since ``astype`` of a value already in
+  that dtype is the identity: ``ContinuousBatchingEngine._as_consumed``
+  (``serving.py:875``) and ``init_params_as_consumed`` below. Without it
+  the tree is held as given.
+- ``cache_state_leaves(cache)`` -> a tree that is True where a leaf of the
+  ``"cache"`` collection is a per-request *state* ``[B, ...]`` with no
+  position axis (``layers.state_leaves_by_name``): the engine moves such a
+  leaf whole with its row and never slices it by position
+  (``ContinuousBatchingEngine.__init__``, ``serving.py:255``). Without it
+  no leaf is a state.
+- ``decode_step_counters(metrics)`` -> named device scalars from what one
+  decode step sowed under ``"metrics"`` (``moe.decode_step_counters``):
+  traced inside the decode chunk and read back with its tokens
+  (``_build_programs``, ``serving.py:438``). Without it the chunk returns
+  no counters.
+- ``book_step_counters(metrics)`` -> one train step's returned ``metrics``
+  booked into the process accumulator (``moe.book_step_counters``), called
+  by the holder of the step at a sync
+  (``benchmark/workers/model_train_worker.py:76``).
 """
 
 import dataclasses
@@ -46,7 +96,7 @@ def build_model(entry: dict) -> Tuple[Any, Callable]:
         if isinstance(values.get(name), str):
             values[name] = jnp.dtype(values[name]).type
     config = config_type(**values)
-    from .gpt import cross_entropy_loss, token_loss_mean
+    from .layers import cross_entropy_loss, token_loss_mean
 
     takes_targets = getattr(config, "ce_chunk", 0) > 0 or getattr(config, "takes_targets", False)
     return getattr(mod, model_cls)(config), token_loss_mean if takes_targets else cross_entropy_loss

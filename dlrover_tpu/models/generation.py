@@ -161,11 +161,10 @@ def decode_apply(
     The single place the decode contract (``decode=True, positions,
     kv_valid, mutable=["cache"]``) is spelled, shared by the one-shot engine and the continuous-
     batching scheduler — their token-exactness guarantee depends on
-    applying the model identically. ``cache_slots`` selects the
-    per-row write-slot mode: [B] for single-token decode (continuous
-    batching's per-row cache layout) or [B, T] for a T-token window
-    written at per-row slots (no caller at present: ROADMAP D13); see
-    gpt._update_decode_cache.
+    applying the model identically. ``cache_slots`` [B] selects the
+    per-row write-slot mode of a single-token step (continuous
+    batching's per-row cache layout); see
+    layers._update_decode_cache. The whole contract: ``models/build.py``.
     """
     logits, mut = model.apply(
         {"params": params, "cache": cache},

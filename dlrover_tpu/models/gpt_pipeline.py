@@ -31,7 +31,8 @@ from ..parallel.pipeline import (
     stack_stage_params,
     stage_sharding,
 )
-from .gpt import GPTConfig, cross_entropy_loss
+from .gpt import GPTConfig
+from .layers import cross_entropy_loss
 
 
 def init_gpt_pipeline_params(

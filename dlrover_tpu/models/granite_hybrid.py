@@ -33,7 +33,7 @@ last layer, then ``logits = (h wte^T) / logits_scaling`` (the head tied).
 
 **Decoding** (``decode=True``, the contract ``generation.decode_apply``
 spells; ``positions`` is accepted and unused). An attention layer keeps
-keys and values through ``gpt.cached_decode_attention``. A Mamba layer
+keys and values through ``layers.cached_decode_attention``. A Mamba layer
 keeps two *states with no position axis*: ``ssm_state [B, H, P, N]``
 (float32: the recurrence multiplies it by a decay near 1 at every token)
 and ``conv_state [B, K - 1, H P + 2 G N]`` (the ``xBC`` of the request's
@@ -41,9 +41,9 @@ last ``K - 1`` real tokens). The rule model and engine keep together: **a
 padded token leaves both states alone and is invisible to every real
 token after it.** For the recurrence that is ``delta = 0`` at a padded
 token, exactly (decay ``exp(0) = 1``, input 0), not softplus of
-something; for the convolution it is ``lfm2_moe.real_neighbours``, three
+something; for the convolution it is ``layers.real_neighbours``, three
 deep. Which tokens of a call are real is read from ``kv_valid`` at the
-slots the call writes (``lfm2_moe.token_valid_at``). A continuation of a
+slots the call writes (``layers.token_valid_at``). A continuation of a
 stored prefix starts the scan from the stored row's state.
 """
 
@@ -54,32 +54,16 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from flax.linen import partitioning as nn_partitioning
 
 from ..ops.ssd_scan import ssd_scan, ssd_step
-from .gpt import _chunked_token_ce, cached_decode_attention, dtypes_read_by_name
-from .lfm2_moe import real_neighbours, token_valid_at
-from .llama import RMSNorm, _constrain
-from .mla_moe import SwiGlu, _weight
-
-param_with_axes = nn_partitioning.param_with_axes
+from .layers import (
+    CONV_INIT_STD, RMSNorm, SwiGlu, a_log_init, cached_decode_attention, chunked_token_ce,
+    constrain, dt_bias_init, dtypes_read_by_name, param_with_axes, real_neighbours,
+    state_leaves_by_name, token_valid_at, weight)
 
 MAMBA, ATTENTION = "mamba", "attention"
-
-
-# The init the config does not state. The step sizes are drawn log-uniform
-# in DT_RANGE through ``dt_bias`` (Mamba-2's own rule) and ``A`` log-spaced
-# over the heads in A_RANGE. Not ``A`` = 1 .. H: at the published widths a
-# layer's output would then owe 1-2% to state older than 64 tokens (``D x``
-# outweighs it) and no comparison of outputs would see the state; in
-# [1/16, 1] the slowest heads remember over a thousand tokens and that share
-# is a quarter to a third (``benchmark/reference/granite_hybrid.py:
-# old_state_share``; the configuration's file has the numbers). The 4-tap
-# filters and their bias at torch ``Conv1d``'s default spread (uniform in
-# +-1/sqrt(4): std 0.2887).
-DT_RANGE = (0.001, 0.1)
-A_RANGE = (0.0625, 1.0)
-CONV_INIT_STD = 0.5 / math.sqrt(3.0)
+# The init the config does not state (``dt_bias``, ``A_log``, the taps and
+# their bias) is ``layers.dt_bias_init`` and its neighbours, which say why.
 
 
 @dataclass(frozen=True)
@@ -155,7 +139,7 @@ class GraniteHybridConfig:
         return self.head_dim or self.hidden_size // self.num_attention_heads
 
     @property
-    def rms_eps(self) -> float:  # the name ``llama.RMSNorm`` reads
+    def rms_eps(self) -> float:  # the name ``layers.RMSNorm`` reads
         return self.rms_norm_eps
 
     @property
@@ -179,16 +163,6 @@ class GraniteHybridConfig:
         return GraniteHybridConfig(**base)
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """``softplus(dt_bias)`` log-uniform in ``DT_RANGE``."""
-    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, *(math.log(v) for v in DT_RANGE)))
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)  # softplus's inverse
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    return jnp.linspace(*(math.log(v) for v in A_RANGE), shape[0]).astype(dtype)
-
-
 class MambaMixer(nn.Module):
     """The Mamba-2 mixer. ``token_valid`` ``[B, T]`` (decode only) says
     which of this call's tokens are real."""
@@ -201,15 +175,15 @@ class MambaMixer(nn.Module):
         B, T, D = u.shape
         H, P, N, G = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups
         inner, width, K = cfg.mamba_inner, cfg.mamba_conv_width, cfg.mamba_d_conv
-        w_in = _weight("w_in", cfg, (D, inner + width + H), ("embed", "mamba_proj"))
-        w_out = _weight("w_out", cfg, (inner, D), ("mamba_inner", "embed"))
+        w_in = weight("w_in", cfg, (D, inner + width + H), ("embed", "mamba_proj"))
+        w_out = weight("w_out", cfg, (inner, D), ("mamba_inner", "embed"))
         f32 = jnp.float32
         taps = param_with_axes("conv_kernel", nn.initializers.normal(CONV_INIT_STD),
                                (K, width), f32, axes=("conv_taps", "mamba_channels"))
         conv_bias = param_with_axes("conv_bias", nn.initializers.normal(CONV_INIT_STD),
                                     (width,), f32, axes=("mamba_channels",))
-        dt_bias = param_with_axes("dt_bias", _dt_bias_init, (H,), f32, axes=("mamba_heads",))
-        a_log = param_with_axes("A_log", _a_log_init, (H,), f32, axes=("mamba_heads",))
+        dt_bias = param_with_axes("dt_bias", dt_bias_init, (H,), f32, axes=("mamba_heads",))
+        a_log = param_with_axes("A_log", a_log_init, (H,), f32, axes=("mamba_heads",))
         skip = param_with_axes("D", nn.initializers.ones, (H,), f32, axes=("mamba_heads",))
 
         with jax.named_scope("mamba.in_proj"):
@@ -252,7 +226,7 @@ class MambaMixer(nn.Module):
             y = RMSNorm(cfg, name="gate_norm")(gated)
         with jax.named_scope("mamba.out_proj"):
             out = jnp.dot(y, w_out)
-        return _constrain(out, "batch", "seq", "embed")
+        return constrain(out, "batch", "seq", "embed")
 
 
 class Attention(nn.Module):
@@ -266,10 +240,10 @@ class Attention(nn.Module):
         cfg = self.config
         B, T, D = x.shape
         H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_size
-        wq = _weight("wq", cfg, (D, H, d), ("embed", "heads", "kv"))
-        wk = _weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wv = _weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wo = _weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"))
+        wq = weight("wq", cfg, (D, H, d), ("embed", "heads", "kv"))
+        wk = weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wv = weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wo = weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"))
         q = jnp.einsum("btd,dhk->bthk", x, wq)
         k = jnp.einsum("btd,dgk->btgk", x, wk)
         v = jnp.einsum("btd,dgk->btgk", x, wv)
@@ -281,9 +255,9 @@ class Attention(nn.Module):
                 return cached_decode_attention(
                     self, cfg.max_seq_len, q, k, v, kv_valid, cache_slots, wo, cfg)
             k, v = jnp.repeat(k, H // G, axis=2), jnp.repeat(v, H // G, axis=2)
-            q = _constrain(q, "batch", "seq", "heads", "kv")
-            k = _constrain(k, "batch", "seq", "heads", "kv")
-            v = _constrain(v, "batch", "seq", "heads", "kv")
+            q = constrain(q, "batch", "seq", "heads", "kv")
+            k = constrain(k, "batch", "seq", "heads", "kv")
+            v = constrain(v, "batch", "seq", "heads", "kv")
             if cfg.attention_impl == "flash":
                 from ..ops.flash_attention import flash_attention
 
@@ -297,8 +271,8 @@ class Attention(nn.Module):
                 out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
             else:
                 raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
-        out = _constrain(out, "batch", "seq", "heads", "kv")
-        return _constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
+        out = constrain(out, "batch", "seq", "heads", "kv")
+        return constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
 
 
 class Block(nn.Module):
@@ -321,7 +295,7 @@ class Block(nn.Module):
                 u, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots)
         x = x + scaled(mix)
         y = SwiGlu(cfg, cfg.shared_intermediate_size, name="mlp")(RMSNorm(cfg, name="post_norm")(x))
-        return _constrain(x + scaled(y), "batch", "seq", "embed")
+        return constrain(x + scaled(y), "batch", "seq", "embed")
 
 
 # Every use of these is ``leaf.astype(cfg.dtype)``. The norms' scales, the
@@ -334,33 +308,28 @@ _STATE_LEAVES = frozenset({"conv_state", "ssm_state"})
 
 class GraniteHybridLM(nn.Module):
     """``__call__(tokens[B, T]) -> logits[B, T, V]`` (float32); with
-    ``targets`` the per-token losses ``[B, T]`` (``gpt.py``'s fused-CE
-    contract); with ``decode=True`` through the ``"cache"`` collection."""
+    ``targets`` the per-token losses ``[B, T]``; with ``decode=True``
+    through the ``"cache"`` collection. The two optional methods are the
+    contract's (``models/build.py``)."""
 
     config: GraniteHybridConfig
 
     @nn.nowrap
     def consumed_param_dtypes(self, params):
-        """The dtype ``__call__`` reads each leaf of ``params`` in (the
-        contract of ``GPT.consumed_param_dtypes``)."""
         return dtypes_read_by_name(params, _READ_IN_COMPUTE_DTYPE, self.config.dtype)
 
     @nn.nowrap
     def cache_state_leaves(self, cache):
-        """True where a leaf of ``cache`` is a per-request *state* with no
-        position axis (``Lfm2MoeLM.cache_state_leaves``'s contract): by
-        the leaf's name."""
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: getattr(path[-1], "key", None) in _STATE_LEAVES, cache)
+        return state_leaves_by_name(cache, _STATE_LEAVES)
 
     @nn.compact
     def __call__(self, tokens, *, targets=None, decode: bool = False, positions=None,
                  kv_valid=None, cache_slots=None):
         cfg = self.config
         B, T = tokens.shape
-        wte = _weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
+        wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
         x = (wte[tokens].astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
-        x = _constrain(x, "batch", "seq", "embed")
+        x = constrain(x, "batch", "seq", "embed")
         if decode:  # ``positions`` is the contract's; nothing here encodes a position
             token_valid = token_valid_at(self, B, T, kv_valid, cache_slots)
             for i in range(cfg.num_hidden_layers):
@@ -378,6 +347,6 @@ class GraniteHybridLM(nn.Module):
         if targets is not None:
             # the fused loss knows no divisor: it goes onto the hidden state
             h = (h.astype(jnp.float32) / cfg.logits_scaling).astype(h.dtype)
-            return _chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
+            return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
         logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
-        return _constrain(logits / cfg.logits_scaling, "batch", "seq", "vocab")
+        return constrain(logits / cfg.logits_scaling, "batch", "seq", "vocab")

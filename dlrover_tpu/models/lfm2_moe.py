@@ -23,12 +23,12 @@ one RMSNorm after the last layer, then the head (tied to the embedding).
   ``s = sigmoid(x W_r)`` in float32, the top k of ``s + expert_bias``
   (selection only, no gradient), gates the unbiased ``s`` of the chosen
   over (their sum + ``gate_norm_eps``), times ``routed_scaling_factor``.
-  It is ``mla_moe.MoeLayer`` (the sorted row buffer and the grouped
+  It is ``moe.MoeLayer`` (the sorted row buffer and the grouped
   products of ``ops/grouped_matmul.py``), given this model's sizes.
 
 **Decoding** (``decode=True``, the contract ``generation.decode_apply``
 spells). An attention layer keeps keys and values through
-``gpt.cached_decode_attention``, as GPT and Llama do. A convolution layer
+``layers.cached_decode_attention``, as GPT and Llama do. A convolution layer
 keeps a *state with no position axis*: ``conv_state [B, 2, d]``, the ``z``
 of the request's last two real tokens. The rule model and engine keep
 together: **the convolution at a real token reads the ``z`` of the two
@@ -47,13 +47,12 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from flax.linen import partitioning as nn_partitioning
 
-from .gpt import _chunked_token_ce, cached_decode_attention, dtypes_read_by_name
-from .llama import RMSNorm, _constrain, apply_rope, apply_rope_at, rope_tables
-from .mla_moe import MoeLayer, MoeSizes, SwiGlu, _weight, decode_step_counters
-
-param_with_axes = nn_partitioning.param_with_axes
+from .layers import (
+    RMSNorm, SwiGlu, apply_rope, apply_rope_at, cached_decode_attention, chunked_token_ce,
+    constrain, dtypes_read_by_name, param_with_axes, real_neighbours, rope_tables,
+    state_leaves_by_name, token_valid_at, weight)
+from .moe import MoeLayer, MoeSizes, decode_step_counters
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -125,7 +124,7 @@ class Lfm2MoeConfig:
         return self.head_dim or self.hidden_size // self.num_attention_heads
 
     @property
-    def rms_eps(self) -> float:  # the name ``llama.RMSNorm`` reads
+    def rms_eps(self) -> float:  # the name ``layers.RMSNorm`` reads
         return self.norm_eps
 
     @property
@@ -156,60 +155,6 @@ class Lfm2MoeConfig:
         return Lfm2MoeConfig(**base)
 
 
-def _rows(a, idx):
-    """``a[b, idx[b, t]]``: whole rows of ``a [B, S, d]`` by ``idx [B, T]``."""
-    return jax.vmap(lambda rows, i: rows[i])(a, idx)
-
-
-def real_neighbours(s, z, token_valid):
-    """The padding rule of a causal convolution's decode state, for any
-    number of earlier taps: ``s [B, n, D]`` holds the inputs of the row's
-    last ``n`` real tokens (oldest first; zeros before the row's first),
-    ``z [B, T, D]`` this call's inputs, ``token_valid [B, T]`` which of
-    them are real. -> (``n`` arrays ``[B, T, D]``: for each of this call's
-    tokens the input of the real token ``n`` before it, ..., of the one
-    just before it, whatever padding lies between; the state moved on: the
-    inputs of the row's last ``n`` real tokens, this call's included).
-    A padded token reads something nobody uses and leaves the state alone."""
-    B, T, D = z.shape
-    n = s.shape[1]
-    if T == 1:  # a decode step: no neighbour to look for
-        keep = token_valid[:, :, None]
-        moved = jnp.where(keep, jnp.concatenate([s[:, 1:], z], axis=1), s)
-        return [s[:, i:i + 1] for i in range(n)], moved
-    # the row as [state ; this call], the state's entries always real
-    zz = jnp.concatenate([s, z], axis=1)  # [B, T + n, D]
-    real = jnp.concatenate([jnp.ones((B, n), bool), token_valid], axis=1)
-    at = jnp.arange(T + n, dtype=jnp.int32)[None, :]
-    last = jax.lax.cummax(jnp.where(real, at, 0), axis=1)  # the last real one up to here
-    prev = [jnp.concatenate([jnp.zeros((B, 1), jnp.int32), last[:, :-1]], axis=1)]  # ... before here
-    ends = [last[:, -1:]]
-    for _ in range(n - 1):
-        prev.append(jnp.take_along_axis(prev[0], prev[-1], axis=1))  # ... and the one before that
-        ends.append(jnp.take_along_axis(prev[0], ends[-1], axis=1))
-    moved = _rows(zz, jnp.concatenate(ends[::-1], axis=1))
-    return [_rows(zz, p[:, n:]) for p in prev[::-1]], moved
-
-
-def token_valid_at(module, B, T, kv_valid, cache_slots):
-    """Which of this call's tokens are real: ``kv_valid`` at the slots
-    the call writes, found as ``gpt._update_decode_cache`` finds them
-    (the shared write offset, kept on ``module`` as ``index``; or the
-    per-row ``cache_slots``). With no ``kv_valid`` every token is. For
-    the top module of a model that keeps a state with no position axis."""
-    index = module.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
-    if cache_slots is not None:
-        if kv_valid is None:
-            raise ValueError("cache_slots mode needs explicit kv_valid")
-        slots = cache_slots[:, None] if cache_slots.ndim == 1 else cache_slots
-        return jnp.take_along_axis(kv_valid, slots, axis=1)
-    offset = index.value
-    index.value = offset + T
-    if kv_valid is None:
-        return jnp.ones((B, T), bool)
-    return jax.lax.dynamic_slice(kv_valid, (0, offset), (B, T))
-
-
 class ShortConv(nn.Module):
     """The gated short convolution. ``token_valid`` ``[B, T]`` (decode
     only) says which of this call's tokens are real."""
@@ -220,8 +165,8 @@ class ShortConv(nn.Module):
     def __call__(self, u, *, decode: bool = False, token_valid=None):
         cfg = self.config
         B, T, D = u.shape
-        w_in = _weight("w_in", cfg, (D, 3, D), ("embed", None, "conv_channels"))
-        w_out = _weight("w_out", cfg, (D, D), ("conv_channels", "embed"))
+        w_in = weight("w_in", cfg, (D, 3, D), ("embed", None, "conv_channels"))
+        w_out = weight("w_out", cfg, (D, D), ("conv_channels", "embed"))
         taps = param_with_axes(
             "conv_kernel", nn.initializers.normal(cfg.conv_init_std),
             (cfg.conv_L_cache, D), jnp.float32, axes=("conv_taps", "conv_channels"))
@@ -237,7 +182,7 @@ class ShortConv(nn.Module):
             c = (taps[0] * z2.astype(jnp.float32) + taps[1] * z1.astype(jnp.float32)
                  + taps[2] * z.astype(jnp.float32))
             y = jnp.dot((gate_c.astype(jnp.float32) * c).astype(cfg.dtype), w_out)
-        return _constrain(y, "batch", "seq", "embed")
+        return constrain(y, "batch", "seq", "embed")
 
     def _from_state(self, z, token_valid):
         """(``z`` of the real token two before, of the one before) for each
@@ -262,10 +207,10 @@ class Attention(nn.Module):
         cfg = self.config
         B, T, D = x.shape
         H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_size
-        wq = _weight("wq", cfg, (D, H, d), ("embed", "heads", "kv"))
-        wk = _weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wv = _weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wo = _weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"))
+        wq = weight("wq", cfg, (D, H, d), ("embed", "heads", "kv"))
+        wk = weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wv = weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wo = weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"))
         q = RMSNorm(cfg, name="q_norm")(jnp.einsum("btd,dhk->bthk", x, wq))
         k = RMSNorm(cfg, name="k_norm")(jnp.einsum("btd,dgk->btgk", x, wk))
         v = jnp.einsum("btd,dgk->btgk", x, wv)
@@ -276,15 +221,15 @@ class Attention(nn.Module):
                 cos_t, sin_t = rope_tables(cfg.max_seq_len, d, cfg.rope_theta)
                 q = apply_rope_at(q, cos_t, sin_t, positions)
                 k = apply_rope_at(k, cos_t, sin_t, positions)
-                # the narrow cache and the grouped contraction are gpt.py's
+                # the narrow cache and the grouped contraction are ``layers``'
                 return cached_decode_attention(
                     self, cfg.max_seq_len, q, k, v, kv_valid, cache_slots, wo, cfg)
             cos, sin = rope_tables(T, d, cfg.rope_theta)
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             k, v = jnp.repeat(k, H // G, axis=2), jnp.repeat(v, H // G, axis=2)
-            q = _constrain(q, "batch", "seq", "heads", "kv")
-            k = _constrain(k, "batch", "seq", "heads", "kv")
-            v = _constrain(v, "batch", "seq", "heads", "kv")
+            q = constrain(q, "batch", "seq", "heads", "kv")
+            k = constrain(k, "batch", "seq", "heads", "kv")
+            v = constrain(v, "batch", "seq", "heads", "kv")
             if cfg.attention_impl == "flash":
                 from ..ops.flash_attention import flash_attention_sharded
                 from ..parallel.mesh import get_current_mesh
@@ -298,8 +243,8 @@ class Attention(nn.Module):
                 out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
             else:
                 raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
-        out = _constrain(out, "batch", "seq", "heads", "kv")
-        return _constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
+        out = constrain(out, "batch", "seq", "heads", "kv")
+        return constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
 
 
 class Block(nn.Module):
@@ -322,42 +267,34 @@ class Block(nn.Module):
             y = MoeLayer(cfg.moe_sizes, name="moe")(h)
         else:
             y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
-        return _constrain(x + y, "batch", "seq", "embed")
+        return constrain(x + y, "batch", "seq", "embed")
 
 
 # Every use of these is ``leaf.astype(cfg.dtype)``. The norms' scales, the
 # router, its selection bias and the convolution's taps are read in float32.
 _READ_IN_COMPUTE_DTYPE = frozenset(
     {"wte", "w_in", "w_out", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"})
+_STATE_LEAVES = frozenset({"conv_state"})
 
 
 class Lfm2MoeLM(nn.Module):
     """``__call__(tokens[B, T]) -> logits[B, T, V]`` (float32); with
-    ``targets`` the per-token losses ``[B, T]`` (``gpt.py``'s fused-CE
-    contract); with ``decode=True`` through the ``"cache"`` collection."""
+    ``targets`` the per-token losses ``[B, T]``; with ``decode=True``
+    through the ``"cache"`` collection. The three optional methods are
+    the contract's (``models/build.py``)."""
 
     config: Lfm2MoeConfig
 
     @nn.nowrap
     def consumed_param_dtypes(self, params):
-        """The dtype ``__call__`` reads each leaf of ``params`` in (the
-        contract of ``GPT.consumed_param_dtypes``)."""
         return dtypes_read_by_name(params, _READ_IN_COMPUTE_DTYPE, self.config.dtype)
 
     @nn.nowrap
     def cache_state_leaves(self, cache):
-        """A tree like ``cache`` (the ``"cache"`` collection, at any batch
-        size) that is True where a leaf is a per-request *state* ``[B, ...]``
-        with no position axis, False where it is positional ``[B, L, ...]``
-        or a scalar. By the leaf's name, never by its shape: a cache of
-        two positions is as long as the convolution's state."""
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: getattr(path[-1], "key", None) == "conv_state", cache)
+        return state_leaves_by_name(cache, _STATE_LEAVES)
 
     @nn.nowrap
     def decode_step_counters(self, metrics):
-        """What one decode step sowed under ``"metrics"``, as the named
-        device scalars a server books (``mla_moe.decode_step_counters``)."""
         return decode_step_counters(metrics)
 
     @nn.compact
@@ -365,8 +302,8 @@ class Lfm2MoeLM(nn.Module):
                  kv_valid=None, cache_slots=None):
         cfg = self.config
         B, T = tokens.shape
-        wte = _weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
-        x = _constrain(wte[tokens], "batch", "seq", "embed")
+        wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
+        x = constrain(wte[tokens], "batch", "seq", "embed")
         if decode:
             token_valid = token_valid_at(self, B, T, kv_valid, cache_slots)
             for i in range(cfg.num_hidden_layers):
@@ -382,6 +319,6 @@ class Lfm2MoeLM(nn.Module):
                 x = block(cfg, layer_idx=i, name=f"block_{i}")(x)
         h = RMSNorm(cfg, name="embedding_norm")(x)  # the family's name; applied at the END
         if targets is not None:
-            return _chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
+            return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
         logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
-        return _constrain(logits, "batch", "seq", "vocab")
+        return constrain(logits, "batch", "seq", "vocab")

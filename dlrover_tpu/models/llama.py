@@ -21,9 +21,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from flax.linen import partitioning as nn_partitioning
 
-param_with_axes = nn_partitioning.param_with_axes
+from .layers import (
+    RMSNorm, apply_rope, apply_rope_at, cached_decode_attention, chunked_token_ce, constrain,
+    cross_entropy_loss, dtypes_read_by_name, param_with_axes, rope_tables)
 
 
 @dataclass(frozen=True)
@@ -42,11 +43,11 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     use_remat: bool = True
     # >0: targets passed to __call__ fuse head+CE over seq chunks of
-    # this size (see gpt.GPTConfig.ce_chunk — same contract/math)
+    # this size (the contract: ``models/build.py``)
     ce_chunk: int = 0
     attention_impl: str = ""  # "" → dense; flash|ring as in gpt.py
-    # int8 decode KV cache with per-token per-kv-head scales (see
-    # gpt.GPTConfig.kv_cache_int8 — same contract/math)
+    # int8 decode KV cache with per-token per-kv-head scales
+    # (``layers._update_decode_cache``)
     kv_cache_int8: bool = False
     # MoE: num_experts > 0 replaces every `moe_every`-th block's MLP with
     # a top-2 expert layer (0 = dense model).
@@ -88,66 +89,6 @@ class LlamaConfig:
         )
         base.update(overrides)
         return LlamaConfig(**base)
-
-
-def _constrain(x, *axes):
-    from ..parallel.sharding import with_logical_constraint
-
-    return with_logical_constraint(x, *axes)
-
-
-class RMSNorm(nn.Module):
-    config: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        scale = param_with_axes(
-            "scale",
-            nn.initializers.ones,
-            (x.shape[-1],),
-            cfg.param_dtype,
-            axes=("norm",),
-        )
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = x32 * jax.lax.rsqrt(var + cfg.rms_eps)
-        return (y * scale).astype(cfg.dtype)
-
-
-def rope_tables(seq_len: int, head_dim: int, theta: float):
-    """(cos, sin) [T, head_dim//2] in fp32 — computed once per trace."""
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
-    angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), freqs)
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def apply_rope(x, cos, sin):
-    """Rotate pairs of channels; x is [B, T, H, Hd]."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
-    return jnp.concatenate(
-        (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1
-    ).astype(x.dtype)
-
-
-def apply_rope_at(x, cos_table, sin_table, positions):
-    """RoPE at per-row absolute positions; x [B,T,H,Hd], positions [B,T].
-
-    The decode path's variant of :func:`apply_rope`: left-padded rows
-    sit at different absolute token positions for the same cache slot,
-    so the angle tables are gathered per (row, slot) instead of shared
-    across the batch.
-    """
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = cos_table[positions][:, :, None, :]  # [B, T, 1, Hd//2]
-    sin = sin_table[positions][:, :, None, :]
-    return jnp.concatenate(
-        (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1
-    ).astype(x.dtype)
 
 
 class LlamaAttention(nn.Module):
@@ -200,8 +141,6 @@ class LlamaAttention(nn.Module):
             # carry a per-row position array), then cache the SMALL
             # pre-repeat GQA k/v — the KVH-wide cache is the whole point
             # of grouped-query attention at decode time.
-            from .gpt import cached_decode_attention
-
             cos_t, sin_t = rope_tables(
                 cfg.max_seq_len, Hd, cfg.rope_theta
             )
@@ -226,9 +165,9 @@ class LlamaAttention(nn.Module):
         # XLA when the kv tensor is small (KVH << H is the GQA point).
         k = jnp.repeat(k, H // KVH, axis=2)
         v = jnp.repeat(v, H // KVH, axis=2)
-        q = _constrain(q, "batch", "seq", "heads", "kv")
-        k = _constrain(k, "batch", "seq", "heads", "kv")
-        v = _constrain(v, "batch", "seq", "heads", "kv")
+        q = constrain(q, "batch", "seq", "heads", "kv")
+        k = constrain(k, "batch", "seq", "heads", "kv")
+        v = constrain(v, "batch", "seq", "heads", "kv")
 
         impl = cfg.attention_impl or "dense"
         if impl == "ring":
@@ -257,9 +196,9 @@ class LlamaAttention(nn.Module):
             out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
         else:
             raise ValueError(f"unknown attention_impl {impl!r}")
-        out = _constrain(out, "batch", "seq", "heads", "kv")
+        out = constrain(out, "batch", "seq", "heads", "kv")
         y = jnp.einsum("bqhk,hkd->bqd", out, wo.astype(cfg.dtype))
-        return _constrain(y, "batch", "seq", "embed")
+        return constrain(y, "batch", "seq", "embed")
 
 
 class SwiGluMlp(nn.Module):
@@ -293,9 +232,9 @@ class SwiGluMlp(nn.Module):
         h = jax.nn.silu(jnp.dot(x, w_gate.astype(cfg.dtype))) * jnp.dot(
             x, w_up.astype(cfg.dtype)
         )
-        h = _constrain(h, "batch", "seq", "mlp")
+        h = constrain(h, "batch", "seq", "mlp")
         y = jnp.dot(h, w_down.astype(cfg.dtype))
-        return _constrain(y, "batch", "seq", "embed")
+        return constrain(y, "batch", "seq", "embed")
 
 
 class MoeMlp(nn.Module):
@@ -305,7 +244,7 @@ class MoeMlp(nn.Module):
     are dropped; the ``[B,S,E,C]`` dispatch mask grows with tokens x
     experts, which rules it out at hundreds of experts. The dropless
     layer (sigmoid scores, top-k of hundreds, sorted grouped products
-    over the experts a chip holds) is ``models/mla_moe.py: MoeLayer``.
+    over the experts a chip holds) is ``models/moe.py: MoeLayer``.
 
     Static shapes throughout: gating produces a [B,S,E,C] dispatch mask
     via one-hot position-in-expert bookkeeping; dispatch and combine are
@@ -392,14 +331,14 @@ class MoeMlp(nn.Module):
 
         # -- dispatch -> expert compute -> combine ------------------------
         xe = jnp.einsum("bsec,bsd->becd", dispatch, x)  # [B,E,C,D]
-        xe = _constrain(xe, "batch", "expert", None, "embed")
+        xe = constrain(xe, "batch", "expert", None, "embed")
         h = jax.nn.silu(
             jnp.einsum("becd,edf->becf", xe, w_gate.astype(cfg.dtype))
         ) * jnp.einsum("becd,edf->becf", xe, w_up.astype(cfg.dtype))
-        h = _constrain(h, "batch", "expert", None, "expert_mlp")
+        h = constrain(h, "batch", "expert", None, "expert_mlp")
         ye = jnp.einsum("becf,efd->becd", h, w_down.astype(cfg.dtype))
         y = jnp.einsum("bsec,becd->bsd", combine, ye)
-        return _constrain(y, "batch", "seq", "embed")
+        return constrain(y, "batch", "seq", "embed")
 
 
 class LlamaBlock(nn.Module):
@@ -433,8 +372,7 @@ class Llama(nn.Module):
     """``__call__(tokens[B,T]) -> logits[B,T,V]``.
 
     ``targets`` given → per-token losses ``[B, T]`` through the fused
-    chunked-CE path (gpt.py contract; pair with
-    :func:`dlrover_tpu.models.gpt.token_loss_mean`).
+    chunked-CE path (pair with ``layers.token_loss_mean``).
     """
 
     config: LlamaConfig
@@ -442,9 +380,7 @@ class Llama(nn.Module):
     @nn.nowrap
     def consumed_param_dtypes(self, params):
         """The dtype ``__call__`` reads each leaf of ``params`` in (the
-        contract of ``GPT.consumed_param_dtypes``)."""
-        from .gpt import dtypes_read_by_name
-
+        contract: ``models/build.py``)."""
         return dtypes_read_by_name(
             params, _READ_IN_COMPUTE_DTYPE, self.config.dtype
         )
@@ -470,7 +406,7 @@ class Llama(nn.Module):
             axes=("vocab", "embed"),
         )
         x = wte.astype(cfg.dtype)[tokens]
-        x = _constrain(x, "batch", "seq", "embed")
+        x = constrain(x, "batch", "seq", "embed")
         # decode bypasses remat: no backward pass, and the decode kwargs
         # must not cross jax.checkpoint (it would trace the bool).
         if cfg.use_remat and not decode:
@@ -500,9 +436,7 @@ class Llama(nn.Module):
             axes=("embed", "vocab"),
         )
         if targets is not None:
-            from .gpt import _chunked_token_ce
-
-            return _chunked_token_ce(
+            return chunked_token_ce(
                 x,
                 w_lm.astype(cfg.dtype),
                 targets,
@@ -510,13 +444,11 @@ class Llama(nn.Module):
                 vocab_first=False,
             )
         logits = jnp.dot(x, w_lm.astype(cfg.dtype))
-        return _constrain(logits, "batch", "seq", "vocab")
+        return constrain(logits, "batch", "seq", "vocab")
 
 
 def llama_loss(model_vars_or_logits, targets=None, aux_weight: float = 0.01):
     """CE loss; when applied through ``apply(..., mutable=["losses"])`` the
     caller adds the sowed MoE aux terms — this helper covers the plain
     logits path used by the generic train step."""
-    from .gpt import cross_entropy_loss
-
     return cross_entropy_loss(model_vars_or_logits, targets)

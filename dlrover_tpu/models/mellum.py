@@ -11,7 +11,7 @@ the last layer, then an untied head.
 - **``Attn_l``**: ``q = x W_q`` in ``num_attention_heads`` heads, ``k`` and
   ``v`` in ``num_key_value_heads`` (query head ``h`` reads key/value head
   ``h // (H / KV)``), RoPE over the whole head in the rotate-half pairing
-  (``llama.apply_rope``), scores over ``sqrt(head_dim)``, softmax, ``W_o``.
+  (``layers.apply_rope``), scores over ``sqrt(head_dim)``, softmax, ``W_o``.
   What differs by ``layer_types[l]``:
 
   - ``sliding_attention``: row ``i`` sees key ``j`` iff ``0 <= i - j <
@@ -26,7 +26,7 @@ the last layer, then an untied head.
 - **``MoE``**: ``p = softmax(z W_r)`` in float32 over all ``num_experts``,
   the ``num_experts_per_tok`` largest, gates ``p`` of the chosen over their
   sum (``norm_topk_prob``), each expert a SwiGLU; no shared expert, no
-  selection bias. It is ``mla_moe.MoeLayer`` given this model's sizes;
+  selection bias. It is ``moe.MoeLayer`` given this model's sizes;
   ``experts_held`` / ``expert_offset`` say which experts live here (the
   chip's share: what the absent ones would add is another chip's, counted
   and left out). On a share the gates are constants in the backward pass
@@ -57,8 +57,8 @@ as ``llama.py`` and ``lfm2_moe.py`` do; a kernel that reads them through
 its index map is queued in ROADMAP.md. The published family is described
 with an MTP head that no config key sizes: it is not built.
 
-With ``targets`` the model returns per-token losses (the fused-CE contract
-of ``gpt.py``) and sows its counters under ``"metrics"``, as ``mla_moe``.
+With ``targets`` the model returns per-token losses (the fused-CE contract:
+``models/build.py``) and sows its counters under ``"metrics"``, as ``mla_moe``.
 """
 
 import math
@@ -71,9 +71,9 @@ import jax.numpy as jnp
 
 from ..ops.flash_attention import flash_attention_sharded
 from ..parallel.mesh import get_current_mesh
-from .gpt import _chunked_token_ce, token_loss_mean
-from .llama import RMSNorm, _constrain, apply_rope
-from .mla_moe import _KEEP_FLASH_RESULTS, MoeLayer, MoeSizes, _weight, book_step_counters
+from .layers import (
+    KEEP_FLASH_RESULTS, RMSNorm, apply_rope, chunked_token_ce, constrain, token_loss_mean, weight)
+from .moe import MoeLayer, MoeSizes, book_step_counters
 
 LAYER_TYPES = ("sliding_attention", "full_attention")
 # ``torch.nn.Embedding``'s own default, N(0, 1), as ``models/qwen3_next.py``'s
@@ -134,7 +134,7 @@ class MellumConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     # each block recomputed in the backward pass from its input and its flash
-    # kernel's two results, which are kept (``mla_moe._KEEP_FLASH_RESULTS``)
+    # kernel's two results, which are kept (``layers.KEEP_FLASH_RESULTS``)
     use_remat: bool = True
     ce_chunk: int = 0  # 0: the head's losses in one chunk
 
@@ -167,7 +167,7 @@ class MellumConfig:
             raise ValueError("the experts held do not lie inside the routed ones")
 
     @property
-    def rms_eps(self) -> float:  # the name ``llama.RMSNorm`` reads
+    def rms_eps(self) -> float:  # the name ``layers.RMSNorm`` reads
         return self.rms_norm_eps
 
     def layer_type(self, layer_idx: int) -> str:
@@ -232,7 +232,7 @@ def rope_inv_freq(head_dim: int, rope: dict):
 
 
 def rope_table(seq_len: int, head_dim: int, rope: dict):
-    """(cos, sin) ``[seq_len, head_dim / 2]`` for ``llama.apply_rope``."""
+    """(cos, sin) ``[seq_len, head_dim / 2]`` for ``layers.apply_rope``."""
     inv_freq, scale = rope_inv_freq(head_dim, rope)
     angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), inv_freq)
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
@@ -247,10 +247,10 @@ class Attention(nn.Module):
         cfg = self.config
         B, T, D = x.shape
         H, KV, Hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        w_q = _weight("w_q", cfg, (D, H, Hd), ("embed", "heads", "kv"))
-        w_k = _weight("w_k", cfg, (D, KV, Hd), ("embed", "kv_heads", "kv"))
-        w_v = _weight("w_v", cfg, (D, KV, Hd), ("embed", "kv_heads", "kv"))
-        w_o = _weight("w_o", cfg, (H, Hd, D), ("heads", "kv", "embed"))
+        w_q = weight("w_q", cfg, (D, H, Hd), ("embed", "heads", "kv"))
+        w_k = weight("w_k", cfg, (D, KV, Hd), ("embed", "kv_heads", "kv"))
+        w_v = weight("w_v", cfg, (D, KV, Hd), ("embed", "kv_heads", "kv"))
+        w_o = weight("w_o", cfg, (H, Hd, D), ("heads", "kv", "embed"))
 
         cos, sin = rope_table(T, Hd, cfg.rope_of(self.layer_type))
         q = apply_rope(jnp.einsum("btd,dhk->bthk", x, w_q), cos, sin)
@@ -259,17 +259,17 @@ class Attention(nn.Module):
         # the kernel takes equal head counts: each key/value head 8 times
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)
-        q = _constrain(q, "batch", "seq", "heads", "kv")
-        k = _constrain(k, "batch", "seq", "heads", "kv")
-        v = _constrain(v, "batch", "seq", "heads", "kv")
+        q = constrain(q, "batch", "seq", "heads", "kv")
+        k = constrain(k, "batch", "seq", "heads", "kv")
+        v = constrain(v, "batch", "seq", "heads", "kv")
         windowed = self.layer_type == "sliding_attention"
         # a device event says which kind of layer its kernel belongs to
         with jax.named_scope("swa.attend_window" if windowed else "swa.attend_full"):
             out = flash_attention_sharded(
                 q, k, v, get_current_mesh(), causal=True,
                 window=cfg.sliding_window if windowed else None)
-        out = _constrain(out, "batch", "seq", "heads", "kv")
-        return _constrain(jnp.einsum("bthk,hkd->btd", out, w_o), "batch", "seq", "embed")
+        out = constrain(out, "batch", "seq", "heads", "kv")
+        return constrain(jnp.einsum("bthk,hkd->btd", out, w_o), "batch", "seq", "embed")
 
 
 class Block(nn.Module):
@@ -282,7 +282,7 @@ class Block(nn.Module):
         attn = Attention(cfg, cfg.layer_type(self.layer_idx), name="attn")
         x = x + attn(RMSNorm(cfg, name="norm_attn")(x))
         y = MoeLayer(cfg.moe_sizes, name="moe")(RMSNorm(cfg, name="norm_mlp")(x))
-        return _constrain(x + y, "batch", "seq", "embed")
+        return constrain(x + y, "batch", "seq", "embed")
 
 
 def _block(cfg: MellumConfig):
@@ -290,7 +290,7 @@ def _block(cfg: MellumConfig):
         return Block
     # as ``mla_moe._block``: recomputed from its input, except what its flash
     # kernel wrote (tokens x heads x (2 x head_dim + 4) bytes a block)
-    return nn.remat(Block, prevent_cse=True, policy=_KEEP_FLASH_RESULTS)
+    return nn.remat(Block, prevent_cse=True, policy=KEEP_FLASH_RESULTS)
 
 
 class MellumLM(nn.Module):
@@ -303,22 +303,22 @@ class MellumLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, *, targets=None):
         cfg = self.config
-        wte = _weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size),
-                      ("vocab", "embed"), EMBED_INIT_STD)
-        w_head = _weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size),
-                         ("embed", "vocab"))
-        x = _constrain(wte[tokens], "batch", "seq", "embed")
+        wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size),
+                     ("vocab", "embed"), EMBED_INIT_STD)
+        w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size),
+                        ("embed", "vocab"))
+        x = constrain(wte[tokens], "batch", "seq", "embed")
         for i in range(cfg.num_hidden_layers):
             x = _block(cfg)(cfg, layer_idx=i, name=f"block_{i}")(x)
         h = RMSNorm(cfg, name="norm_f")(x)
         if targets is None:
-            return _constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
-        losses = _chunked_token_ce(
+            return constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
+        losses = chunked_token_ce(
             h, w_head, targets, cfg.ce_chunk or tokens.shape[1], vocab_first=False)
         self.sow("metrics", "trunk_loss", token_loss_mean(losses, targets))
         return losses
 
     @staticmethod
     def book_step_counters(metrics: dict) -> dict:
-        """``mla_moe.book_step_counters``: the same layer sows the same names."""
+        """``moe.book_step_counters`` (the contract: ``models/build.py``)."""
         return book_step_counters(metrics)

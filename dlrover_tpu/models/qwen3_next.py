@@ -32,7 +32,7 @@ mixer's gate norm has a plain weight.
 - **``MoE``**: ``p = softmax(x W_r)`` in float32 over all ``num_experts``;
   the top ``num_experts_per_tok``; gates ``p`` of the chosen over their sum;
   plus ``sigmoid(x w_s)`` times the shared expert. It is
-  ``mla_moe.MoeLayer`` given this model's sizes; ``experts_held`` /
+  ``moe.MoeLayer`` given this model's sizes; ``experts_held`` /
   ``expert_offset`` say which experts live here (the chip's share: what
   the absent ones would add is another chip's, counted and left out).
 
@@ -41,16 +41,16 @@ in the server drafts.
 
 **Decoding** (``decode=True``, the contract ``generation.decode_apply``
 spells). An attention layer keeps keys and values through
-``gpt.cached_decode_attention``. A delta layer keeps two *states with no
+``layers.cached_decode_attention``. A delta layer keeps two *states with no
 position axis*: ``delta_state [B, Hv, dk, dv]`` (float32) and
 ``conv_state [B, K - 1, 2 Hk dk + Hv dv]`` (the ``[q ; k ; v]`` of the
 request's last ``K - 1`` real tokens). The rule model and engine keep
 together: **a padded token leaves both states alone and is invisible to
 every real token after it.** For the recurrence that is ``g = 0`` and
 ``beta = 0`` at a padded token, exactly (decay ``exp(0) = 1``, nothing
-written); for the convolution it is ``lfm2_moe.real_neighbours``, three
+written); for the convolution it is ``layers.real_neighbours``, three
 deep. Which tokens of a call are real is read from ``kv_valid`` at the
-slots the call writes (``lfm2_moe.token_valid_at``).
+slots the call writes (``layers.token_valid_at``).
 """
 
 import math
@@ -60,32 +60,18 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from flax.linen import partitioning as nn_partitioning
 
 from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
-from .gpt import _chunked_token_ce, cached_decode_attention, dtypes_read_by_name
-from .lfm2_moe import real_neighbours, token_valid_at
-from .llama import _constrain, apply_rope, apply_rope_at, rope_tables
-from .mla_moe import MoeLayer, MoeSizes, _weight, decode_step_counters
-
-param_with_axes = nn_partitioning.param_with_axes
+from .layers import (
+    CONV_INIT_STD, a_log_init, apply_rope, apply_rope_at, cached_decode_attention,
+    chunked_token_ce, constrain, dt_bias_init, dtypes_read_by_name, param_with_axes,
+    real_neighbours, rope_tables, state_leaves_by_name, token_valid_at, weight)
+from .moe import MoeLayer, MoeSizes, decode_step_counters
 
 DELTA_CHUNK = 64
-
-# The init the config does not state. The reference model's own draw
-# (``A ~ U(0, 16)``, ``dt_bias = 1``) gives a per-token log-decay of -1 to
-# -20: the state forgets within a token or two and no comparison of
-# outputs sees it. Here ``softplus(dt_bias)`` is log-uniform in DT_RANGE and
-# ``A`` log-spaced over the heads in A_RANGE (the draw of
-# ``granite_hybrid.py``), so the slowest heads' decay leaves the delta
-# rule's own overwriting as what forgets; the share of a layer's output
-# that state older than one chunk carries is measured by the reference
-# (``benchmark/reference/qwen3_next.py: old_state_share``; the
-# configuration's file has the numbers). The 4-tap filters at torch
-# ``Conv1d``'s default spread (uniform in +-1/sqrt(4): std 0.2887).
-DT_RANGE = (0.001, 0.1)
-A_RANGE = (0.0625, 1.0)
-CONV_INIT_STD = 0.5 / math.sqrt(3.0)
+# The init the config does not state (``dt_bias``, ``A_log``, the taps) is
+# ``layers.dt_bias_init`` and its neighbours, which say why: the reference
+# model's own draw forgets within a token or two.
 
 
 @dataclass(frozen=True)
@@ -215,16 +201,6 @@ class Qwen3NextConfig:
         return Qwen3NextConfig(**base)
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """``softplus(dt_bias)`` log-uniform in ``DT_RANGE``."""
-    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, *(math.log(v) for v in DT_RANGE)))
-    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)  # softplus's inverse
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    return jnp.linspace(*(math.log(v) for v in A_RANGE), shape[0]).astype(dtype)
-
-
 class ZeroCentredRMSNorm(nn.Module):
     """``x / rms(x) * (1 + w)`` over the last axis, in float32."""
 
@@ -258,13 +234,13 @@ class GatedDeltaMixer(nn.Module):
         dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim
         keys, values, width = cfg.delta_key_width, cfg.delta_value_width, cfg.delta_conv_width
         f32 = jnp.float32
-        w_qkvz = _weight("w_qkvz", cfg, (D, width + values), ("embed", "delta_proj"))
-        w_ba = _weight("w_ba", cfg, (D, 2 * Hv), ("embed", "delta_heads"))
-        w_out = _weight("w_out", cfg, (values, D), ("delta_inner", "embed"), cfg.residual_init_std)
+        w_qkvz = weight("w_qkvz", cfg, (D, width + values), ("embed", "delta_proj"))
+        w_ba = weight("w_ba", cfg, (D, 2 * Hv), ("embed", "delta_heads"))
+        w_out = weight("w_out", cfg, (values, D), ("delta_inner", "embed"), cfg.residual_init_std)
         taps = param_with_axes("conv_kernel", nn.initializers.normal(CONV_INIT_STD),
                                (K, width), f32, axes=("conv_taps", "delta_channels"))
-        dt_bias = param_with_axes("dt_bias", _dt_bias_init, (Hv,), f32, axes=("delta_heads",))
-        a_log = param_with_axes("A_log", _a_log_init, (Hv,), f32, axes=("delta_heads",))
+        dt_bias = param_with_axes("dt_bias", dt_bias_init, (Hv,), f32, axes=("delta_heads",))
+        a_log = param_with_axes("A_log", a_log_init, (Hv,), f32, axes=("delta_heads",))
         gate_w = param_with_axes("gate_norm", nn.initializers.ones, (dv,), f32, axes=("norm",))
 
         with jax.named_scope("gdn.in_proj"):
@@ -308,7 +284,7 @@ class GatedDeltaMixer(nn.Module):
             y = (o.reshape(B, T, values) * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
         with jax.named_scope("gdn.out_proj"):
             out = jnp.dot(y, w_out)
-        return _constrain(out, "batch", "seq", "embed")
+        return constrain(out, "batch", "seq", "embed")
 
 
 class GatedAttention(nn.Module):
@@ -323,10 +299,10 @@ class GatedAttention(nn.Module):
         cfg = self.config
         B, T, D = x.shape
         H, G, d, rot = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.rotary_dim
-        wq = _weight("wq", cfg, (D, H, 2 * d), ("embed", "heads", "kv"))  # [q_h ; gate_h] a head
-        wk = _weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wv = _weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
-        wo = _weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"), cfg.residual_init_std)
+        wq = weight("wq", cfg, (D, H, 2 * d), ("embed", "heads", "kv"))  # [q_h ; gate_h] a head
+        wk = weight("wk", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wv = weight("wv", cfg, (D, G, d), ("embed", "kv_heads", "kv"))
+        wo = weight("wo", cfg, (H, d, D), ("heads", "kv", "embed"), cfg.residual_init_std)
         q_gate = jnp.einsum("btd,dhk->bthk", x, wq)
         q, gate = q_gate[..., :d], q_gate[..., d:]
         q = ZeroCentredRMSNorm(cfg, name="q_norm")(q)
@@ -343,7 +319,7 @@ class GatedAttention(nn.Module):
                 cos_t, sin_t = rope_tables(cfg.max_seq_len, rot, cfg.rope_theta)
                 q = rotated(q, lambda a: apply_rope_at(a, cos_t, sin_t, positions))
                 k = rotated(k, lambda a: apply_rope_at(a, cos_t, sin_t, positions))
-                # the narrow cache and the grouped contraction are gpt.py's;
+                # the narrow cache and the grouped contraction are ``layers``';
                 # without ``wo`` it returns the heads, for the gate
                 out = cached_decode_attention(
                     self, cfg.max_seq_len, q, k, v, kv_valid, cache_slots, None, cfg)
@@ -357,8 +333,8 @@ class GatedAttention(nn.Module):
                 probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(cfg.dtype)
                 out = jnp.einsum("bhqs,bshk->bqhk", probs, v)
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
-        out = _constrain(out, "batch", "seq", "heads", "kv")
-        return _constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
+        out = constrain(out, "batch", "seq", "heads", "kv")
+        return constrain(jnp.einsum("bqhk,hkd->bqd", out, wo), "batch", "seq", "embed")
 
 
 class Block(nn.Module):
@@ -377,7 +353,7 @@ class Block(nn.Module):
         else:
             x = x + GatedDeltaMixer(cfg, name="gdn")(u, decode=decode, token_valid=token_valid)
         y = MoeLayer(cfg.moe_sizes, name="moe")(ZeroCentredRMSNorm(cfg, name="post_norm")(x))
-        return _constrain(x + y, "batch", "seq", "embed")
+        return constrain(x + y, "batch", "seq", "embed")
 
 
 # Every use of these is ``leaf.astype(cfg.dtype)``. The norms' weights, the
@@ -391,31 +367,22 @@ _STATE_LEAVES = frozenset({"conv_state", "delta_state"})
 
 class Qwen3NextLM(nn.Module):
     """``__call__(tokens[B, T]) -> logits[B, T, V]`` (float32); with
-    ``targets`` the per-token losses ``[B, T]`` (``gpt.py``'s fused-CE
-    contract); with ``decode=True`` through the ``"cache"`` collection."""
+    ``targets`` the per-token losses ``[B, T]``; with ``decode=True``
+    through the ``"cache"`` collection. The three optional methods are
+    the contract's (``models/build.py``)."""
 
     config: Qwen3NextConfig
 
     @nn.nowrap
     def consumed_param_dtypes(self, params):
-        """The dtype ``__call__`` reads each leaf of ``params`` in (the
-        contract of ``GPT.consumed_param_dtypes``)."""
         return dtypes_read_by_name(params, _READ_IN_COMPUTE_DTYPE, self.config.dtype)
 
     @nn.nowrap
     def cache_state_leaves(self, cache):
-        """True where a leaf of ``cache`` is a per-request *state* with no
-        position axis (``Lfm2MoeLM.cache_state_leaves``'s contract): by
-        the leaf's name."""
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: getattr(path[-1], "key", None) in _STATE_LEAVES, cache)
+        return state_leaves_by_name(cache, _STATE_LEAVES)
 
     @nn.nowrap
-    def decode_step_counters(self, metrics):
-        """What one decode step sowed under ``"metrics"``, as the named
-        device scalars a server books: ``mla_moe.decode_step_counters``
-        and, for a chip that holds a share of the experts, the assignments
-        that landed here and those routed elsewhere."""
+    def decode_step_counters(self, metrics):  # a share: also what landed here and elsewhere
         return decode_step_counters(metrics, share=True)
 
     @nn.compact
@@ -423,9 +390,9 @@ class Qwen3NextLM(nn.Module):
                  kv_valid=None, cache_slots=None):
         cfg = self.config
         B, T = tokens.shape
-        wte = _weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), cfg.embed_init_std)
-        w_head = _weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size), ("embed", "vocab"))
-        x = _constrain(wte[tokens], "batch", "seq", "embed")
+        wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), cfg.embed_init_std)
+        w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size), ("embed", "vocab"))
+        x = constrain(wte[tokens], "batch", "seq", "embed")
         token_valid = token_valid_at(self, B, T, kv_valid, cache_slots) if decode else None
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, layer_idx=i, name=f"block_{i}")(
@@ -433,6 +400,6 @@ class Qwen3NextLM(nn.Module):
                 cache_slots=cache_slots, token_valid=token_valid)
         h = ZeroCentredRMSNorm(cfg, name="final_norm")(x)
         if targets is not None:
-            return _chunked_token_ce(h, w_head, targets, cfg.ce_chunk or T, vocab_first=False)
+            return chunked_token_ce(h, w_head, targets, cfg.ce_chunk or T, vocab_first=False)
         logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
-        return _constrain(logits, "batch", "seq", "vocab")
+        return constrain(logits, "batch", "seq", "vocab")

@@ -20,7 +20,7 @@ TPU shape — every device program is static-shape and compiled once:
   fetch per chunk, not per token.
 - **One write rule, two cache layouts** (``cache_layout=``): every row
   writes at its OWN next slot (a B-row scatter —
-  gpt._update_decode_cache ``cache_slots`` mode), so there are no holes
+  layers._update_decode_cache ``cache_slots`` mode), so there are no holes
   past a prompt's bucket and a freed slot is reused in place. Liveness
   is per-request: ``prompt_width + max_new_tokens <= max_seq_len``.
 
@@ -85,12 +85,6 @@ TPU shape — every device program is static-shape and compiled once:
   onto the profiler's clock (``serve.round`` > ``serve.admission``,
   ``serve.prefill``, ``serve.decode_dispatch``, ``serve.host_sync``,
   ``serve.retirement``) and books it under its phase for ``/healthz``.
-- **decode_chunk auto-tuning** (``auto_chunk=True``): the measured
-  ``serving_host_frac`` drives the chunk length between dispatches —
-  host-bound streams grow the chunk (amortize per-round host cost over
-  more tokens), device-bound streams shrink it back (less wasted tail
-  decode and faster admission). One compiled program per candidate
-  length, all liveness-checked.
 """
 
 import contextlib
@@ -171,66 +165,6 @@ class _Slot:
     first_tok_t: float = 0.0
 
 
-class _ChunkAutoTuner:
-    """Retunes ``decode_chunk`` between dispatches from the measured
-    ``serving_host_frac`` (attribution.phases): when the host fraction
-    of a window of rounds runs high, per-round host cost dominates —
-    grow the chunk so one dispatch/readback amortizes over more
-    tokens; when it runs low, shrink back — small chunks waste fewer
-    tail steps on finished rows and admit queued requests sooner.
-    Candidates are fixed at construction (one compiled program each);
-    liveness is per request and does not depend on the chunk length, so
-    a retune can never strand the stream."""
-
-    WINDOW = 8  # rounds per decision — enough samples to smooth noise
-    HIGH = 0.35
-    LOW = 0.10
-
-    def __init__(self, engine):
-        s = engine.s
-        cands = {engine.d} | {4, 8, 16, 32}
-        cands = {
-            c for c in cands
-            if c == engine.d or 1 <= c <= s.max_new_tokens
-        }
-        self.candidates = sorted(cands)
-        self.engine = engine
-        self.retunes = 0
-        self._mark = self._snapshot()
-
-    def _snapshot(self):
-        split = self.engine.phases.split()
-        return (split.host_s, split.total_s, split.rounds)
-
-    def maybe_retune(self) -> Optional[int]:
-        """Called once per scheduler round; returns the new chunk
-        length when a retune happened, else None. The off-decision
-        rounds pay one integer compare — a full split() only builds
-        on decision rounds."""
-        h0, t0, r0 = self._mark
-        rounds = self.engine.phases.rounds
-        if rounds < r0:  # accumulator was reset
-            self._mark = self._snapshot()
-            return None
-        if rounds - r0 < self.WINDOW:
-            return None
-        split = self.engine.phases.split()
-        dh, dt = split.host_s - h0, split.total_s - t0
-        self._mark = (split.host_s, split.total_s, split.rounds)
-        if dt <= 0 or dh < 0:  # accumulator was reset mid-window
-            return None
-        frac = dh / dt
-        idx = self.candidates.index(self.engine.d)
-        if frac > self.HIGH and idx + 1 < len(self.candidates):
-            self.engine.d = self.candidates[idx + 1]
-        elif frac < self.LOW and idx > 0:
-            self.engine.d = self.candidates[idx - 1]
-        else:
-            return None
-        self.retunes += 1
-        return self.engine.d
-
-
 class ContinuousBatchingEngine:
     """Serve a stream of prompts through ``batch_size`` decode slots.
 
@@ -253,7 +187,6 @@ class ContinuousBatchingEngine:
         rules=None,
         cache_layout: str = "per_row",
         overlap: bool = True,
-        auto_chunk: bool = False,
         kv_block_size: int = 16,
         kv_pool_blocks: int = 0,
     ):
@@ -281,7 +214,7 @@ class ContinuousBatchingEngine:
 
         - ``"per_row"`` (default): every row writes at its OWN next
           slot of a dense ``[B, L]`` cache via a B-row scatter
-          (``gpt._update_decode_cache`` ``cache_slots`` mode). No holes
+          (``layers._update_decode_cache`` ``cache_slots`` mode). No holes
           past a request's prompt bucket; slots are reused in place, so
           a request's lifetime is bounded by its own prompt+budget, not
           by the stream's. Liveness is simply prompt_width +
@@ -307,8 +240,6 @@ class ContinuousBatchingEngine:
         the device executes. ``overlap=False`` keeps the host-serial
         round (the reference the bit-identity tests hold the overlapped
         round to).
-        ``auto_chunk`` lets the engine retune ``decode_chunk`` between
-        dispatches from the measured host fraction.
         """
         cfg = model.config
         L = cfg.max_seq_len
@@ -436,7 +367,6 @@ class ContinuousBatchingEngine:
             )
         self._build_programs()
         self._reset_device_state()
-        self._tuner = _ChunkAutoTuner(self) if auto_chunk else None
 
     # -- device programs (compiled once each; the decode contract and
     # sampling live in generation.py — token-exactness with the
@@ -658,8 +588,7 @@ class ContinuousBatchingEngine:
         else:
             self._admit_fn = jax.jit(admit)
             self._admit_many_fn = jax.jit(admit_many)
-        # chunk programs are cached per d: the auto-tuner changes d
-        # between dispatches and each length is one compile
+        # chunk programs are cached per d: each length is one compile
         self._chunk_src = make_decode_chunk
         self._chunk_fns: Dict[int, Callable] = {}
 
@@ -1590,8 +1519,6 @@ class ContinuousBatchingEngine:
         self._drained_uncounted = 0
         if emitted:
             self.phases.count("tokens_emitted", emitted)
-        if self._tuner is not None:
-            self._tuner.maybe_retune()
         return emitted
 
     # tpulint: hotpath
@@ -1744,9 +1671,6 @@ class ContinuousBatchingEngine:
             "overlap": self.overlap,
             "inflight_chunks": len(self._inflight),
             "decode_chunk": self.d,
-            "auto_chunk_retunes": (
-                self._tuner.retunes if self._tuner is not None else None
-            ),
             "busy_slots": sum(1 for st in self._slots if st.uid >= 0),
             "queue_depth": len(self._queue),
             "registered_prefixes": len(self._prefixes),

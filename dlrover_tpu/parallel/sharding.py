@@ -142,8 +142,11 @@ def data_sharding_for(
     return logical_to_sharding(PartitionSpec(*axes), mesh, rules)
 
 
-def with_logical_constraint(x, *logical_axes: Optional[str], rules=None):
-    """Annotate an activation with logical axes inside a jitted fn."""
+def with_logical_constraint(x, *logical_axes: Optional[str]):
+    """Annotate an activation with logical axes inside a jitted fn
+    (``models/layers.py: constrain``). Without a mesh flax returns ``x``
+    untouched, so on a one-chip cell the ~50 annotations in ``models/``
+    constrain nothing (ROADMAP S1 / D17)."""
     return flax_spmd.with_logical_constraint(
         x, PartitionSpec(*logical_axes), fallback=flax_spmd.RulesFallback.NO_CONSTRAINT
     )

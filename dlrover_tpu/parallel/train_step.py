@@ -263,7 +263,7 @@ def build_train_step(
     replicated = NamedSharding(mesh, PartitionSpec())
     accum = max(1, int(grad_accum_steps))
 
-    # Fused-CE contract (models/gpt.py): a model with ce_chunk > 0
+    # Fused-CE contract (models/build.py): a model with ce_chunk > 0
     # computes per-token losses internally when handed targets — the
     # full logits never materialize. loss_fn then receives [B, T] token
     # losses (pair with token_loss_mean), not [B, T, V] logits.

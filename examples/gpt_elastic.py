@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu.checkpoint.engine import CheckpointEngine
-from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.parallel.mesh import build_mesh, choose_mesh_shape
 from dlrover_tpu.parallel.train_step import (
     build_train_step,

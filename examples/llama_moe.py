@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dlrover_tpu.models.gpt import cross_entropy_loss
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (

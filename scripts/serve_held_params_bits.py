@@ -47,6 +47,7 @@ def main() -> int:
     from dlrover_tpu.models import gpt as gpt_mod
     from dlrover_tpu.models import llama as llama_mod
     from dlrover_tpu.models.generation import SamplingConfig, left_pad_prompts, prefill_prompt
+    from dlrover_tpu.models.layers import dtypes_read_by_name
     from dlrover_tpu.models.serving import ContinuousBatchingEngine
 
     families = {
@@ -77,7 +78,7 @@ def main() -> int:
         def proxy(held_names):
             m = types.SimpleNamespace(config=cfg, apply=model.apply, init=model.init)
             if held_names is not None:
-                m.consumed_param_dtypes = lambda p: gpt_mod.dtypes_read_by_name(p, held_names, cfg.dtype)
+                m.consumed_param_dtypes = lambda p: dtypes_read_by_name(p, held_names, cfg.dtype)
             return m
 
         def serve(m):

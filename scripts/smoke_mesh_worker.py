@@ -59,7 +59,8 @@ def main() -> int:
 
     from dlrover_tpu.checkpoint.engine import CheckpointEngine
     from dlrover_tpu.checkpoint.shm_handler import _path_str
-    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.models.layers import cross_entropy_loss
     from dlrover_tpu.parallel.mesh import (
         MeshConfig,
         build_mesh,

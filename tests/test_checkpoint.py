@@ -14,7 +14,8 @@ from dlrover_tpu.checkpoint.meta import CheckpointMeta
 from dlrover_tpu.checkpoint.saver import AsyncCheckpointSaver
 from dlrover_tpu.checkpoint.shm_handler import SharedMemoryHandler
 from dlrover_tpu.checkpoint.storage import PosixCheckpointStorage
-from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import default_optimizer, init_train_state
 

@@ -12,12 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.models.gpt import (
-    GPT,
-    GPTConfig,
-    cross_entropy_loss,
-    token_loss_mean,
-)
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss, token_loss_mean
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
     build_train_step,

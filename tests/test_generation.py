@@ -417,7 +417,7 @@ class TestInt8KvCache:
             assert (cos > 0.999).all(), cos
 
     def test_quant_roundtrip_error_bounded(self):
-        from dlrover_tpu.models.gpt import _dequant_kv, _quant_kv
+        from dlrover_tpu.models.layers import _dequant_kv, _quant_kv
 
         x = jax.random.normal(
             jax.random.PRNGKey(0), (2, 5, 3, 16), jnp.bfloat16

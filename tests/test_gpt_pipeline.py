@@ -12,7 +12,8 @@ import numpy as np
 import optax
 import pytest
 
-from dlrover_tpu.models.gpt import GPTConfig, cross_entropy_loss
+from dlrover_tpu.models.gpt import GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.models.gpt_pipeline import (
     build_gpt_pipeline_train_step,
     gpt_pipeline_forward,

@@ -25,7 +25,7 @@ from dlrover_tpu.models.granite_hybrid import (
     GraniteHybridLM,
     MambaMixer,
 )
-from dlrover_tpu.models.lfm2_moe import real_neighbours
+from dlrover_tpu.models.layers import real_neighbours
 from dlrover_tpu.ops.ssd_scan import ssd_scan, ssd_step
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
@@ -337,7 +337,7 @@ def test_cache_leaves_and_their_kinds():
 def test_attention_keeps_the_grouped_cache_leaf_and_contraction():
     """Queries over fewer kv heads: the keys and values stay ``[B, L, KVH,
     Hd]`` and a decode step contracts them group by group, as
-    ``gpt._masked_attention`` writes it (the folded ``[B, L, lanes]`` leaf
+    ``layers._masked_attention`` writes it (the folded ``[B, L, lanes]`` leaf
     is the ungrouped models'; this cell's programs are to stay as measured
     until the grouped body is folded too: ``docs/generation.md``)."""
     cfg = GraniteHybridConfig.tiny()

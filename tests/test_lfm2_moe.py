@@ -15,11 +15,12 @@ import numpy as np
 import pytest
 
 from benchmark.reference import lfm2_moe as ref
-from dlrover_tpu.models import mla_moe
+from dlrover_tpu.models import moe
 from dlrover_tpu.models.build import build_model, init_params_as_consumed
 from dlrover_tpu.models.generation import decode_apply, init_cache
 from dlrover_tpu.models.lfm2_moe import Attention, Lfm2MoeConfig, Lfm2MoeLM, ShortConv
-from dlrover_tpu.models.mla_moe import MoeLayer, SwiGlu
+from dlrover_tpu.models.layers import SwiGlu
+from dlrover_tpu.models.moe import MoeLayer
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
     build_train_step,
@@ -266,7 +267,7 @@ def test_cache_leaves_and_their_kinds():
 def test_attention_keeps_the_grouped_cache_leaf_and_contraction():
     """Queries over fewer kv heads: the keys and values stay ``[B, L, KVH,
     Hd]`` and a decode step contracts them group by group, as
-    ``gpt._masked_attention`` writes it (the folded ``[B, L, lanes]`` leaf
+    ``layers._masked_attention`` writes it (the folded ``[B, L, lanes]`` leaf
     is the ungrouped models'; this cell's programs are to stay as measured
     until the grouped body is folded too: ``docs/generation.md``)."""
     cfg = Lfm2MoeConfig.tiny()
@@ -302,7 +303,7 @@ def test_decode_counters_are_the_layers_sums():
     assert int(c["moe.layer_steps"]) == 3 and int(c["moe.assignments"]) == 3 * B * 2
     assert 3 <= int(c["moe.experts_touched"]) <= 3 * B * 2
     assert float(c["moe.load_max_over_mean"]) >= 3.0
-    assert mla_moe.decode_step_counters({}) == {}
+    assert moe.decode_step_counters({}) == {}
 
 
 # -- the dtypes a server holds ----------------------------------------------

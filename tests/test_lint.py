@@ -909,15 +909,18 @@ class TestMeshAxesMachinery:
         r = self._lint(root)
         assert not r.violations, [v.render() for v in r.violations]
 
-    def test_mesh_axis_in_logical_annotation_flagged(self, tmp_path):
-        """A mesh axis in param_with_axes is the silent-no-constraint
-        drift even though the name is registered."""
+    @pytest.mark.parametrize("call", [
+        'param_with_axes("w", init, (4,), axes=("dp",))',
+        'constrain(init, "dp")',  # models/layers.py's name for the constraint
+    ])
+    def test_mesh_axis_in_logical_annotation_flagged(self, tmp_path, call):
+        """A mesh axis in param_with_axes, or in a constraint, is the
+        silent-no-constraint drift even though the name is registered."""
         root = self._tree(
             tmp_path,
             self._REGISTRY,
             self._RULES,
-            "def f(init):\n"
-            '    return param_with_axes("w", init, (4,), axes=("dp",))\n',
+            f"def f(init):\n    return {call}\n",
         )
         r = self._lint(root)
         assert len(r.violations) == 1

@@ -22,9 +22,9 @@ import pytest
 from benchmark.reference import mellum as ref
 from dlrover_tpu.models import mellum
 from dlrover_tpu.models.build import FAMILIES, build_model
-from dlrover_tpu.models.gpt import token_loss_mean
+from dlrover_tpu.models.layers import token_loss_mean
 from dlrover_tpu.models.mellum import MellumConfig, MellumLM, rope_table
-from dlrover_tpu.models.mla_moe import step_counters
+from dlrover_tpu.models.moe import MoeLayer, step_counters
 
 B, T = 2, 32
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -315,7 +315,7 @@ def test_a_shares_buffer_of_twice_the_mean_load_takes_every_row(load, extra):
     here = np.random.default_rng(6).permutation(n) < tokens_here
     h[:, 0] = np.where(here, 1.0, -1.0)  # the channel the router is skewed along
     h = jnp.asarray(h.reshape(B, T, cfg.hidden_size))
-    layer = mellum.MoeLayer(sizes)
+    layer = MoeLayer(sizes)
     params = jax.tree.map(np.array, layer.init(jax.random.PRNGKey(7), h)["params"])
     params["w_router"][0] = 0.0
     params["w_router"][0, 4:6] = 6.0

@@ -16,10 +16,11 @@ import numpy as np
 import pytest
 
 from benchmark.reference import mla_moe as ref
-from dlrover_tpu.models import mla_moe
+from dlrover_tpu.models import layers, mla_moe, moe
 from dlrover_tpu.models.build import FAMILIES, build_model
-from dlrover_tpu.models.gpt import token_loss_mean
-from dlrover_tpu.models.mla_moe import MlaMoeConfig, MlaMoeLM, MoeLayer
+from dlrover_tpu.models.layers import token_loss_mean
+from dlrover_tpu.models.mla_moe import MlaMoeConfig, MlaMoeLM
+from dlrover_tpu.models.moe import MoeLayer
 from dlrover_tpu.ops.grouped_matmul import collect_rows, grouped_matmul, spread_rows
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
@@ -61,7 +62,7 @@ def objective(model, x, y):
 def model_losses_and_grads(model, params, x, y):
     """(total, trunk, mtp, landed-by-layer), grads of ``objective``."""
     (loss, metrics), grads = jax.value_and_grad(objective(model, x, y), has_aux=True)(params)
-    c = mla_moe.step_counters(metrics)
+    c = moe.step_counters(metrics)
     return (float(loss), c["train.trunk_loss"], c["train.mtp_loss"],
             c["moe.assignments_here_by_layer"]), grads
 
@@ -115,7 +116,7 @@ def _rehearsal_model(compute, monkeypatch, policy):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", "joyai-llm-flash-ep16.json")) as f:
         config = json.load(f)["rehearsal"]["model"]["config"]
-    monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", policy)
+    monkeypatch.setattr(mla_moe, "KEEP_FLASH_RESULTS", policy)
     model, _ = build_model({"family": "mla_moe", "config": dict(config, use_remat=True, dtype=compute)})
     return model
 
@@ -146,7 +147,7 @@ def test_keeping_the_flash_kernels_results_moves_no_bit(compute, monkeypatch):
         x, y = batch(model.config, b=2, t=32)
         return model_losses_and_grads(model, params, x, y)
 
-    kept, kept_grads = run(mla_moe._KEEP_FLASH_RESULTS)
+    kept, kept_grads = run(layers.KEEP_FLASH_RESULTS)
     again, again_grads = run(jax.checkpoint_policies.nothing_saveable)
     assert kept == again
     flat, again_flat = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (kept_grads, again_grads))
@@ -159,7 +160,7 @@ def test_keeping_the_flash_kernels_results_moves_no_bit(compute, monkeypatch):
 @pytest.mark.parametrize("policy,kernels", [
     # a block: forward, forward again, dk/dv + dq
     pytest.param(jax.checkpoint_policies.nothing_saveable, 12, id="nothing_saveable"),
-    pytest.param(mla_moe._KEEP_FLASH_RESULTS, 9, id="kept"),
+    pytest.param(layers.KEEP_FLASH_RESULTS, 9, id="kept"),
 ])
 def test_a_blocks_backward_pass_holds_no_second_forward_kernel(policy, kernels, monkeypatch):
     """The jaxpr of losses and gradients over three blocks (two layers and
@@ -201,7 +202,7 @@ def test_the_kept_names_are_seen_through_the_sharded_kernels_shard_map(devices):
                 return jnp.sum(flash_attention_sharded(q * 2.0, k, v, mesh) ** 2)
         return jax.grad(jax.checkpoint(f, policy=policy), argnums=(0, 1, 2))
 
-    kept = grads(mla_moe._KEEP_FLASH_RESULTS)
+    kept = grads(layers.KEEP_FLASH_RESULTS)
     again = grads(jax.checkpoint_policies.nothing_saveable)
     kept_jaxpr = jax.make_jaxpr(kept)(q, k, v).jaxpr
     assert _count_primitive(kept_jaxpr, "shard_map") == (3 if devices > 1 else 0)
@@ -280,7 +281,7 @@ def test_no_token_is_dropped_when_all_choose_the_held_experts(experts, held, ext
 ])
 def test_the_row_buffer_is_twice_the_mean_load_only_where_nothing_steers_it(
         train_gates, bias_name, multiple):
-    sizes = mla_moe.MoeSizes(n_experts=64, top_k=8, width=16, experts_held=16,
+    sizes = moe.MoeSizes(n_experts=64, top_k=8, width=16, experts_held=16,
                              train_gates=train_gates, bias_name=bias_name)
     assert sizes.buffer_over_mean == multiple
 
@@ -304,7 +305,7 @@ def test_only_the_smaller_buffers_program_gains_the_overflow_branch(train_gates,
     assignment, one pass, and no ``cond`` is in the program (a served
     share's chunk and prefill are the parent's); at 2x it is half of them,
     and what is past it goes through the overflow pass under a ``cond``."""
-    sizes = mla_moe.MoeSizes(n_experts=8, top_k=2, width=16, experts_held=2, expert_offset=2,
+    sizes = moe.MoeSizes(n_experts=8, top_k=2, width=16, experts_held=2, expert_offset=2,
                              train_gates=train_gates, bias_name=bias_name, dtype=jnp.float32)
     h = jnp.zeros((B, T, 32))
     layer = MoeLayer(sizes)
@@ -407,7 +408,7 @@ def test_step_returns_its_counters_with_the_loss(tiny_step, accum):
                             return_metrics=True, grad_accum_steps=accum)
     x, y = batch(model.config, seed=4)
     state, (loss, metrics) = step(state, x, y)
-    c = mla_moe.step_counters(metrics)
+    c = moe.step_counters(metrics)
     assignments = B * T * model.config.num_experts_per_tok
     assert c["moe.layer_steps"] == 2
     assert c["moe.assignments_here"] + c["moe.assignments_absent"] == 2 * assignments

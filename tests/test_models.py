@@ -11,13 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.models.gpt import cross_entropy_loss
-from dlrover_tpu.models.llama import (
-    Llama,
-    LlamaConfig,
-    apply_rope,
-    rope_tables,
-)
+from dlrover_tpu.models.layers import apply_rope, cross_entropy_loss, rope_tables
+from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh, choose_mesh_shape
 from dlrover_tpu.parallel.sharding import apply_rules
 from dlrover_tpu.parallel.train_step import (

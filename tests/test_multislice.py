@@ -64,11 +64,8 @@ class TestMultisliceMesh:
     def test_slice_loss_remesh_trains(self):
         """Losing a whole slice re-meshes as a pure dp shrink: the
         per-slice layout is unchanged and the survivor world trains."""
-        from dlrover_tpu.models.gpt import (
-            GPT,
-            GPTConfig,
-            cross_entropy_loss,
-        )
+        from dlrover_tpu.models.gpt import GPT, GPTConfig
+        from dlrover_tpu.models.layers import cross_entropy_loss
         from dlrover_tpu.parallel.train_step import (
             build_train_step,
             default_optimizer,

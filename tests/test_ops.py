@@ -504,7 +504,8 @@ class TestRingAttentionInModel:
     def test_sp_train_step_matches_dense(self):
         """A full sharded train step with ring attention (sp=4) produces
         the same loss as the dense-attention step on identical weights."""
-        from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+        from dlrover_tpu.models.gpt import GPT, GPTConfig
+        from dlrover_tpu.models.layers import cross_entropy_loss
         from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
         from dlrover_tpu.parallel.train_step import (
             build_train_step,

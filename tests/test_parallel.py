@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.models.mnist import MlpConfig, MnistMlp, classification_loss
 from dlrover_tpu.parallel.mesh import (
     MeshConfig,

@@ -19,7 +19,7 @@ import pytest
 from benchmark.reference import qwen3_next as ref
 from dlrover_tpu.models.build import FAMILIES, build_model, init_params_as_consumed
 from dlrover_tpu.models.generation import SamplingConfig, decode_apply, init_cache
-from dlrover_tpu.models.mla_moe import MoeLayer, MoeSizes, route
+from dlrover_tpu.models.moe import MoeLayer, MoeSizes, route
 from dlrover_tpu.models.qwen3_next import (
     GatedAttention,
     GatedDeltaMixer,

@@ -399,6 +399,113 @@ class TestStreaming:
         assert len(results[2]["tokens"]) == 6
 
 
+class TestStreamedDeadline:
+    """A streamed request has ONE deadline, its own ``timeout``, and the
+    wait for the serving loop to take it off the inbox counts against it
+    (ROADMAP S0 (iv): a loop that compiles two prefill widths inside one
+    round is away for over a minute, and the fixed 60 s of that wait
+    answered 500 to fourteen requests that had asked for 300). The loop is
+    held where it picks an item up, by an event the test sets."""
+
+    @staticmethod
+    def _hold_the_loop(daemon, monkeypatch):
+        gate, handle = threading.Event(), daemon._handle_inbox
+
+        def held(item):
+            assert gate.wait(30), "the test never let the loop go"
+            handle(item)
+
+        monkeypatch.setattr(daemon, "_handle_inbox", held)
+        return gate
+
+    @staticmethod
+    def _stream(base, **body):
+        req = urllib.request.Request(
+            base + "/v1/completions",
+            data=json.dumps({"prompt": [5, 9], "stream": True, **body}).encode(),
+            headers={"Content-Type": "application/json"},
+            method="POST",
+        )
+        with urllib.request.urlopen(req, timeout=60) as r:
+            return r.status, [json.loads(x) for x in r if x.strip()]
+
+    def test_a_held_loop_costs_the_request_its_own_timeout_and_no_more(
+        self, server, monkeypatch
+    ):
+        """``"timeout": 1`` against a held loop: the error answer comes
+        while the loop is still held, at about 1 s (not a 200 once it is let
+        go, from a clock started after the wait: the timer that lets it go
+        after 6 s is only there for that case, and the test lets it go itself
+        as soon as it has its answer), the request cancelled on the engine
+        once the loop is back, and the refusal named in the server's log."""
+        import logging
+
+        base, daemon = server[0], server[-1]
+        gate = self._hold_the_loop(daemon, monkeypatch)
+        cancels, cancel = [], daemon.eng.cancel
+
+        def cancel_and_note(uid):
+            cancels.append(cancel(uid))
+            return cancels[-1]
+
+        monkeypatch.setattr(daemon.eng, "cancel", cancel_and_note)
+        records = []
+        handler = logging.Handler()
+        handler.emit = records.append
+        log = logging.getLogger("dlrover_tpu")
+        log.addHandler(handler)
+        let_go = threading.Timer(6.0, gate.set)
+        let_go.start()
+        try:
+            t0 = time.monotonic()
+            with pytest.raises(urllib.error.HTTPError) as e:
+                self._stream(base, timeout=1)
+            waited, still_held = time.monotonic() - t0, not gate.is_set()
+            assert e.value.code == 500 and "TimeoutError" in json.loads(e.value.read())["error"]
+            assert still_held and waited >= 0.9, (still_held, waited)
+            gate.set()
+            # the loop is back: it takes the request, then the cancel behind it
+            until = time.monotonic() + 10
+            while not cancels and time.monotonic() < until:
+                time.sleep(0.02)
+        finally:
+            gate.set()
+            let_go.cancel()
+            log.removeHandler(handler)
+        assert cancels == [True]
+        stats = daemon.eng.stats()
+        assert stats["busy_slots"] == 0 and stats["queue_depth"] == 0
+        assert daemon.served == 0 and not daemon._stream_uids
+        refused = [r.getMessage() for r in records if "refused with 500" in r.getMessage()]
+        assert len(refused) == 1 and refused[0].endswith("(2 prompt tokens)"), refused
+        assert refused[0].startswith("streamed completion refused with 500: TimeoutError")
+
+    def test_submit_streaming_has_no_clock_of_its_own(self, server):
+        daemon = server[-1]
+        with pytest.raises(TypeError, match="timeout"):
+            daemon.submit_streaming([5, 9])
+
+    def test_a_long_timeout_outlasts_a_held_loop(self, server, monkeypatch):
+        """The guard of the other direction: the loop held for a fraction
+        of the request's timeout, then let go: 200 and the tokens asked."""
+        base, daemon = server[0], server[-1]
+        gate = self._hold_the_loop(daemon, monkeypatch)
+        let_go = threading.Timer(1.0, gate.set)
+        let_go.start()
+        try:
+            t0 = time.monotonic()
+            status, lines = self._stream(base, timeout=30)
+            waited = time.monotonic() - t0
+        finally:
+            gate.set()
+            let_go.cancel()
+        assert status == 200 and lines[-1]["done"] is True
+        assert len(lines[-1]["tokens"]) == 6
+        assert waited >= 0.9, waited
+        _, plain = _post(base, "/v1/completions", {"prompt": [5, 9]})
+        assert lines[-1]["tokens"] == plain["tokens"]
+
+
 class TestConstrainedHttp:
     def test_allowed_tokens_over_http(self, server):
         """allowed_tokens forwards through the daemon payload on both

@@ -334,7 +334,7 @@ class TestWeightSwap:
 
 class TestPerRowLayout:
     """cache_layout='per_row' (the default): every row writes at its
-    own next slot (gpt._update_decode_cache cache_slots scatter) — no
+    own next slot (layers._update_decode_cache cache_slots scatter) — no
     admission holes past the prompt bucket. The paged-KV property vLLM
     gets from block tables, here from per-row slot reuse in a static
     [B, L] cache."""
@@ -917,47 +917,6 @@ class TestOverlappedPipeline:
         )
         assert eng.stats()["swap_pending"] is False
         assert eng.swap_latency_s is not None and eng.swap_latency_s > 0
-
-    def test_auto_chunk_tuner_retunes_and_stays_exact(self):
-        """auto_chunk: the tuner moves decode_chunk with the measured
-        host fraction — and a retuned stream stays token-exact."""
-        model = _model(seq=256)
-        params = _params(model)
-        sampling = SamplingConfig(max_new_tokens=16, temperature=0.0)
-        eng = ContinuousBatchingEngine(
-            model, params, sampling, batch_size=2, prompt_width=16,
-            decode_chunk=4, cache_layout="per_row", auto_chunk=True,
-        )
-        tuner = eng._tuner
-        assert tuner is not None
-        assert eng.d in tuner.candidates
-        assert all(c <= 16 for c in tuner.candidates)  # <= max_new
-
-        # drive the decision with synthetic phase windows: host-bound
-        # rounds must grow the chunk...
-        for _ in range(tuner.WINDOW):
-            eng.phases.add_round(
-                [("decode_dispatch", 0.02), ("host_sync", 0.01)]
-            )
-            tuner.maybe_retune()
-        assert eng.d > 4
-        # ...and device-bound rounds shrink it back
-        grown = eng.d
-        for _ in range(tuner.WINDOW):
-            eng.phases.add_round(
-                [("decode_dispatch", 0.0001), ("host_sync", 0.05)]
-            )
-            tuner.maybe_retune()
-        assert eng.d < grown
-        assert tuner.retunes >= 2
-
-        # a real stream after retunes stays exact
-        eng.phases.reset()
-        prompts = _mixed_prompts(4, rng_seed=13, lo=4, hi=9)
-        got = eng.run(prompts)
-        want = _reference_completions(model, params, prompts, sampling)
-        for c, w in zip(got, want):
-            assert c.tokens == w, f"uid {c.uid}: {c.tokens} != {w}"
 
 
 class TestConstrainedDecoding:

@@ -610,7 +610,8 @@ def test_tracing_the_gpt2_small_step_books_every_flash_kernel(tmp_path, monkeypa
     (10 of its 16 sub-squares), the forward keeps the general kernel."""
     import dataclasses
 
-    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.models.layers import cross_entropy_loss
     from dlrover_tpu.ops import flash_attention as fa
     from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
     from dlrover_tpu.parallel.train_step import (

@@ -185,7 +185,7 @@ def test_routed_experts_in_a_serving_program_compile_for_v5e(
     the non-empty groups only; the expert weights are read where they
     lie (no copy of them among the program's temporaries)."""
     from dlrover_tpu.models.lfm2_moe import Lfm2MoeConfig
-    from dlrover_tpu.models.mla_moe import MoeLayer
+    from dlrover_tpu.models.moe import MoeLayer
     from dlrover_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
@@ -233,7 +233,8 @@ def _lowered_step(model, loss_fn, tx, mesh, tokens, **step_options):
 def _gpt2_small_step(devices, mesh_config, batch=32):
     """(lowered step, state) the GPT-2-small train step exactly as
     ``chip_smoke.py``'s worker builds it, over described devices."""
-    from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_tpu.models.layers import cross_entropy_loss
     from dlrover_tpu.parallel.mesh import build_mesh
     from dlrover_tpu.parallel.train_step import default_optimizer
 
@@ -584,9 +585,10 @@ def test_served_state_families_build_no_flash_kernel(
     kernel, and their prefill and chunk programs hold none: a server runs
     the model's decode pass, which attends over the cache and returns before
     ``attention_impl`` is read (the full-size entries leave it at its
-    default, ``flash``; ``dense`` is the rehearsal's). The *module* is
-    imported all the same: ``models/mla_moe.py`` imports it at its top, and
-    both families take their SwiGLU and ``MoeLayer`` from there."""
+    default, ``flash``; ``dense`` is the rehearsal's). Since PR 53 neither
+    family imports ``models/mla_moe.py`` (their SwiGLU and ``MoeLayer`` are
+    ``models/layers.py``'s and ``models/moe.py``'s): the kernels' module is
+    imported only where a non-decode pass is traced, as the init's is."""
     import json
     import os
     import re
@@ -847,7 +849,7 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     if kept == "nothing":  # the parent's blocks, and no names in ``_fa_fwd``
-        monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
+        monkeypatch.setattr(mla_moe, "KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
         monkeypatch.setattr(fa, "checkpoint_name", lambda value, name: value)
     entry = _benchmark_model_entry("joyai-llm-flash-ep16")
     model, loss_fn = build_model(
@@ -918,7 +920,7 @@ def test_joyai_cells_whole_step_fits_beside_the_kept_flash_results(
     if request.config.getoption("capture") != "no":
         return
     print(f"\njoyai step, described v5e, b4 x 4096, out and lse kept: {line}")
-    monkeypatch.setattr(mla_moe, "_KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
+    monkeypatch.setattr(mla_moe, "KEEP_FLASH_RESULTS", jax.checkpoint_policies.nothing_saveable)
     held_parent, _, line = sizes()
     print(f"nothing kept (the parent's blocks): {line}")
     print(f"the kept results cost {(held - held_parent) / 1e6:.1f} MB")

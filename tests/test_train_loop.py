@@ -12,7 +12,8 @@ import pytest
 from dlrover_tpu.checkpoint.engine import CheckpointEngine
 from dlrover_tpu.checkpoint.saver import AsyncCheckpointSaver
 from dlrover_tpu.checkpoint.shm_handler import SharedMemoryHandler
-from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import cross_entropy_loss
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
     build_train_step,

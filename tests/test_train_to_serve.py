@@ -20,7 +20,8 @@ from dlrover_tpu.models.generation import (
     generate,
     left_pad_prompts,
 )
-from dlrover_tpu.models.gpt import GPT, GPTConfig, token_loss_mean
+from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.layers import token_loss_mean
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
     build_train_step,

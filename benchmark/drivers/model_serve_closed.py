@@ -252,7 +252,7 @@ def run(run):
             _, trace_open = http_json("GET", base + "/healthz", timeout=30)
             time.sleep(p["trace_seconds"])
             _, trace_close = http_json("GET", base + "/healthz", timeout=30)
-            ctl.ask(cmd="trace_stop", timeout=240.0)
+            ctl.trace_stop(log, checks)
             around_trace = [trace_open.get("phase_split"), trace_close.get("phase_split")]
         with lock:
             state["stop"] = True

@@ -121,6 +121,15 @@ def stream_completion(port: int, prompt, max_tokens: int, holder: dict = None):
     return rec
 
 
+# How long either serving driver waits for the launcher to answer
+# ``trace_stop``: the one place it is written (``run.deadline_s`` is 1,100).
+# A backstop: at ``trace_seconds`` 1.5, with ``reduce_trace.stop_trace``
+# writing the ``xplane.pb`` alone, no cell's stop comes within a factor of
+# three of it (``PERF.md`` section 6, PR 55); at 4 s and with JAX's own export
+# GPT-2 XL's took 222-237 s of the 240 s that both drivers then allowed.
+TRACE_STOP_WAIT_S = 600.0
+
+
 class Control:
     """The launcher's control directory, from the parent's side."""
 
@@ -141,7 +150,30 @@ class Control:
             if self.proc.poll() is not None:
                 raise RunFailed(f"the server exited rc={self.proc.returncode}")
             time.sleep(0.02)
-        raise RunFailed(f"the server's launcher did not answer {cmd}")
+        raise RunFailed(f"the server's launcher did not answer {cmd} in the {timeout:.0f} s waited for it; "
+                        f"its process was {'still alive' if self.proc.poll() is None else 'gone'}")
+
+    def trace_stop(self, log: str, checks: dict) -> None:
+        """Ask the launcher to stop the profiler and wait ``TRACE_STOP_WAIT_S``
+        for its answer, whose timings go to ``checks["trace_stop"]`` with the
+        seconds waited. A wait that runs out says what the launcher's log
+        last said of the stop, and a stop that fell back on JAX's own export
+        (another JAX: the slow way, ``collect_s`` unknown) says so on stderr."""
+        t0 = time.monotonic()
+        try:
+            got = self.ask(cmd="trace_stop", timeout=TRACE_STOP_WAIT_S)
+        except RunFailed as e:
+            said = [line.strip() for line in harness.tail(log, 400).splitlines() if "trace_stop:" in line]
+            raise RunFailed(f"{e}; the launcher's log on this stop: {said[-3:] or 'nothing'}") from None
+        if not got.get("ok"):
+            raise RunFailed(f"the launcher could not stop the trace: {got.get('error')}")
+        checks["trace_stop"] = dict(
+            {k: got.get(k) for k in ("collect_s", "export_s", "xplane_bytes", "wrote")},
+            waited_s=time.monotonic() - t0, wait_limit_s=TRACE_STOP_WAIT_S)
+        if got.get("wrote") != "xplane.pb":
+            sys.stderr.write(f"benchmark: WARNING: the trace was stopped by {got.get('wrote')}, not written as "
+                             f"reduce_trace.stop_trace writes it: {checks['trace_stop']['waited_s']:.0f} s of a "
+                             f"{TRACE_STOP_WAIT_S:.0f} s wait; see reduce_trace._held_session\n")
 
 
 def check_canary(port: int, run, checks: dict) -> bool:
@@ -298,7 +330,7 @@ def run(run):
             time.sleep(p["trace_after_s"])
             ctl.ask(cmd="trace_start", dir=trace_dir)
             time.sleep(p["trace_seconds"])
-            ctl.ask(cmd="trace_stop", timeout=240.0)
+            ctl.trace_stop(log, checks)
         with lock:
             state["stop"] = True
         for h in holders:  # abandon what is in flight: the server cancels it

@@ -117,6 +117,28 @@ def dump_logs(log_dir: str) -> None:
             sys.stderr.write(f"--- {path}\n{tail(path)}\n")
 
 
+LOG_ENDINGS = (".log", ".jsonl")  # the drivers' own: tpurun.log, serve.log, events.jsonl, requests.jsonl
+
+
+def failure_report(work: str, reason: str) -> str:
+    """``<work>/FAILED.txt``: why the run gave no result, then the tail of
+    every log under its work directory (a file with a log's ending, or
+    anything under a ``logs`` directory): what ``keep`` takes to where the
+    chip tool brings it back, since the work directory is removed."""
+    parts = [f"FAILED: {reason}\n"]
+    for top, dirs, files in os.walk(work):
+        dirs.sort()
+        for name in sorted(files):
+            path = os.path.join(top, name)
+            in_logs = "logs" in os.path.relpath(path, work).split(os.sep)[:-1]
+            if name != "FAILED.txt" and (in_logs or name.endswith(LOG_ENDINGS)):
+                parts.append(f"--- {os.path.relpath(path, work)}\n{tail(path)}\n")
+    out = os.path.join(work, "FAILED.txt")
+    with open(out, "w") as f:
+        f.write("".join(parts))
+    return out
+
+
 def keep(paths, sub: str) -> None:
     """Copy small records of a run where the chip tool brings them back."""
     dst = os.path.join(KEEP, sub)

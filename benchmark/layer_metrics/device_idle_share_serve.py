@@ -1,5 +1,12 @@
 """Idle share of the server's chip over the traced seconds of the window,
-in percent (the server's launcher brackets them with the profiler)."""
+in percent (the server's launcher brackets them with the profiler). Since
+PR 55 the window is ``live`` (``reduce_trace.Trace``): from the first
+program begun a quarter second after the opening marker to the end of the
+device's record, not from marker to marker. The program in flight at the
+stop left up to a whole chunk unrecorded, and that one gap was most of
+what this read before; the first launches after the profiler's start
+stall, which was most of the rest (``PERF.md`` section 6, PR 55: no reading of PR 53 or
+earlier compares with one of PR 55 or later)."""
 
 
 def read(ctx):

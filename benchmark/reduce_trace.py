@@ -13,6 +13,15 @@ one clock (nanoseconds):
   two markers ``bench_trace_start`` / ``bench_trace_stop`` that the traced
   process writes right after the profiler starts and right before it
   stops: the traced window runs from the first's start to the second's end.
+  A window cut out of a program that goes on running (a server's) carries
+  ``bench_trace_live`` too, and runs from the first program the device
+  began ``LIVE_SETTLE_S`` after the opening marker to the end of the
+  device's record: of the program in flight at the stop the profiler keeps
+  only what had finished, up to a whole chunk short of the marker, and the
+  first launches under a profiler just started stall in PJRT's ``Execute``
+  (4-71 ms of a dry device, within some 120 ms of the marker, in five runs
+  of fourteen); both read as idleness of the server (``PERF.md`` section 6,
+  PR 55).
 
 The device planes' clocks are brought onto the host's first
 (``clock_offset_ns``). Busy time is the union of a device's operation
@@ -25,12 +34,15 @@ that covers most of it.
 import glob
 import os
 import re
+import socket
 import statistics
 import sys
+import time
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-MARK_START, MARK_STOP = "bench_trace_start", "bench_trace_stop"
+MARK_START, MARK_STOP, MARK_LIVE = "bench_trace_start", "bench_trace_stop", "bench_trace_live"
+LIVE_SETTLE_S = 0.25  # of a live window's head: twice the longest stall seen after the profiler's start
 
 
 def start_trace(trace_dir: str) -> None:
@@ -45,12 +57,64 @@ def start_trace(trace_dir: str) -> None:
         pass
 
 
-def stop_trace() -> None:
+def stop_trace(log=None, live: bool = False) -> dict:
+    """Write the window's closing marker (and, where the traced program goes
+    on running, ``bench_trace_live``), stop the profiler and write the one
+    file ``load`` opens: ``<dir>/plugins/profile/<stamp>/<host>.xplane.pb``.
+
+    ``jax.profiler.stop_trace`` (JAX 0.9.0) is ``session.stop_and_export``,
+    which beside that file converts every event to a ``trace.json.gz`` that
+    no reader here opens: 154 of 229 s at 2.5 M events (``PERF.md`` section
+    7 (14)). ``session.stop()`` returns the same serialized ``XSpace``, and
+    its bytes are written as they are. Where JAX keeps its session elsewhere
+    the fallback is ``jax.profiler.stop_trace()``, and ``wrote`` says which
+    it was. Returns ``collect_s``, ``export_s``, ``xplane_bytes``, ``wrote``;
+    ``log(text)`` is told each half as it ends, so that a stop nobody waits
+    out has still said how far it came."""
     import jax
 
+    say = log or (lambda text: None)
     with jax.profiler.TraceAnnotation(MARK_STOP):
         pass
-    jax.profiler.stop_trace()
+    if live:
+        with jax.profiler.TraceAnnotation(MARK_LIVE):
+            pass
+    t0 = time.time()
+    held = _held_session()
+    if held is None:
+        say("trace_stop: no session handle, jax.profiler.stop_trace() collects and exports")
+        jax.profiler.stop_trace()
+        return dict(collect_s=None, export_s=time.time() - t0, xplane_bytes=None, wrote="jax.profiler.stop_trace")
+    state, log_dir = held
+    with state.lock:
+        xspace = state.profile_session.stop()
+        t1 = time.time()
+        say(f"trace_stop: collect_s={t1 - t0:.2f} xplane_bytes={len(xspace)}")
+        path = os.path.join(log_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S"),
+                            socket.gethostname() + ".xplane.pb")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as f:
+            f.write(xspace)
+        state.reset()
+    out = dict(collect_s=t1 - t0, export_s=time.time() - t1, xplane_bytes=len(xspace), wrote="xplane.pb")
+    say(f"trace_stop: export_s={out['export_s']:.2f} wrote={path}")
+    return out
+
+
+def _held_session():
+    """(JAX's profile state, its log directory) where a session runs and the
+    state is shaped as in JAX 0.9.0; None where it is not, and the caller
+    falls back on JAX's own stop."""
+    try:
+        from jax._src import profiler as jax_profiler
+
+        state = jax_profiler._profile_state
+        state.lock, state.reset, state.profile_session.stop  # noqa: B018 — what stop_trace will use
+        if not state.log_dir or state.create_perfetto_trace:
+            return None
+        return state, str(state.log_dir)
+    except (ImportError, AttributeError):  # another JAX, or no session: its own stop says so
+        return None
 
 
 def union(intervals):
@@ -104,11 +168,21 @@ class Trace:
     def __init__(self, devices: dict, host: dict):
         self.devices = devices  # plane -> {"ops": [(s, e, name)], "modules": [...]}
         self.host = host  # span name -> [(s, e)]
+        self.events = {}  # ``load`` counts what it read of the file
         self._busy = {}
+        self.head_cut_s = self.tail_cut_s = 0.0  # a live window: what went at either end
         starts = self.host.get(MARK_START) or []
         stops = self.host.get(MARK_STOP) or []
         if starts and stops:
-            self.window = (starts[0][0], stops[-1][1])
+            lo, hi = starts[0][0], stops[-1][1]
+            if self.host.get(MARK_LIVE):  # whole programs under a running profiler, and nothing else
+                settled = lo + LIVE_SETTLE_S * 1e9
+                begun = [s for d in devices.values() for s, _, _ in d["modules"] if settled <= s < hi]
+                ends = [e for d in devices.values() for s, e, _ in d["ops"] if lo < e and s < hi]
+                first, last = min(begun, default=lo), min(hi, max(ends, default=hi))
+                if first < last:
+                    self.head_cut_s, self.tail_cut_s, lo, hi = (first - lo) / 1e9, (hi - last) / 1e9, first, last
+            self.window = (lo, hi)
         else:  # no markers: from the first device operation to the last
             ops = [o for d in devices.values() for o in d["ops"]]
             self.window = (min(o[0] for o in ops), max(o[1] for o in ops)) if ops else (0, 0)
@@ -243,6 +317,7 @@ def load(trace_dir: str, path: str = None):
     data = ProfileData.from_file(path)
     devices, host, runs = {}, {}, {}
     enqueued, completed = {}, {}  # device ordinal -> run_id -> host ns
+    n_host = 0  # every event of the host's lines, kept or not
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             entry = {"ops": [], "modules": []}
@@ -261,6 +336,7 @@ def load(trace_dir: str, path: str = None):
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 for e in line.events:
+                    n_host += 1
                     name = e.name
                     if name in ("DoEnqueueProgram", "CompleteCallbacks"):
                         stats = dict(e.stats)
@@ -279,6 +355,9 @@ def load(trace_dir: str, path: str = None):
         spans.sort()
     trace = Trace(devices, host)
     trace.clock_offsets_ns = offsets
+    n_device = sum(len(d["ops"]) + len(d["modules"]) for d in devices.values())  # the two lines read
+    trace.events = dict(device=n_device, host=n_host, file_bytes=os.path.getsize(path),
+                        head_cut_s=trace.head_cut_s, tail_cut_s=trace.tail_cut_s)
     return trace
 
 

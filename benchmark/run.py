@@ -13,7 +13,9 @@ The last line of stdout is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
 ``--trace 0`` the metrics are the cell's end-to-end ones, with ``--trace 1``
 its per-layer ones. A run that does not find the chips its cell asks for
-prints no result and exits non-zero; nothing falls back to the CPU.
+prints no result and exits non-zero; nothing falls back to the CPU. A run
+that fails leaves ``FAILED.txt`` (the reason, then the tail of each of its
+logs) under ``chiprun_out/benchmark/<cell>.seed<n>.trace<t>/``.
 
 ``--rehearse`` (tests only) runs the same path on the host at the tiny size
 the configuration and the traffic file carry under ``rehearsal``; its line
@@ -28,6 +30,7 @@ import os
 import shutil
 import sys
 import time
+import traceback
 import types
 
 T_START = time.time()
@@ -117,6 +120,7 @@ def main(argv=None) -> int:
             ctx.trace = reduce_trace.load(result["trace_dir"])
             if ctx.trace is not None:
                 device.update(ctx.trace.busy_and_window(run.chips))
+                result.setdefault("checks", {})["trace_events"] = ctx.trace.events  # what the stop had to write
         kind = "layer_metrics" if run.trace else "end_to_end"
         metrics = metrics_of(run.cell, bench["per_layer" if run.trace else "end_to_end"], kind, ctx)
         line = dict(correct=bool(result["correct"]), attempted=result["attempted"],
@@ -133,7 +137,11 @@ def main(argv=None) -> int:
         harness.keep(records, f"{run.cell}.seed{run.seed}.trace{ns.trace}")
     except RunFailed as e:
         sys.stderr.write(f"benchmark: {run.cell}: FAILED: {e}\n")
+        keep_failure(run, str(e), ns.trace)
         return EXIT_NO_ACCELERATOR if "platform" in str(e) else EXIT_FAILED
+    except Exception:
+        keep_failure(run, traceback.format_exc(), ns.trace)
+        raise
     finally:
         shutil.rmtree(harness.WORK, ignore_errors=True)
     if "jax" in sys.modules and not run.trace:
@@ -143,6 +151,15 @@ def main(argv=None) -> int:
     else:
         print(json.dumps(line), flush=True)
     return 0
+
+
+def keep_failure(run, reason: str, trace: int) -> None:
+    """A failed run prints no result and its work directory goes: its reason
+    and its logs' tails stay, where a run's records do."""
+    try:
+        harness.keep([harness.failure_report(run.work, reason)], f"{run.cell}.seed{run.seed}.trace{trace}")
+    except OSError as e:  # the reason is on stderr already
+        sys.stderr.write(f"benchmark: {run.cell}: FAILED.txt was not kept: {e}\n")
 
 
 def peaks_for(device_kind: str) -> dict:

@@ -83,12 +83,29 @@ def test_flash_calls_with_nothing_to_read_is_none():
     assert reader("layer_metrics", NAME)(gpt) is None
 
 
-def test_benchmark_lists_it_for_the_cell_that_has_the_kernel():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    entry = bench["per_layer"][-1]
+def _listed(entry, bench):
     assert entry == dict(name=NAME, unit="calls", better="lower", source="device_trace",
                          layer="models / kernels", moves="train_tokens_per_s",
                          workloads=["joyai-flash-train-ep16share"])
     beside = next(m for m in bench["per_layer"] if m["name"] == "mla_flash_roofline")
     assert {k: beside[k] for k in ("layer", "moves", "workloads")} == {
         k: entry[k] for k in ("layer", "moves", "workloads")}
+
+
+def test_benchmark_lists_it_by_name_for_the_cell_that_has_the_kernel():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    _listed(next(m for m in bench["per_layer"] if m["name"] == NAME), bench)
+
+
+@pytest.mark.xfail(strict=True, reason="retired at PR 55: it read the metric as the LAST entry of per_layer, and "
+                   "new entries go at the end; the test above finds it by name")
+def test_benchmark_lists_it_for_the_cell_that_has_the_kernel():
+    """Kept, failing and marked so, for one reason: ``tests/test_benchmark_suite.py``
+    calls this function by this name under a strict ``xfail`` of its own, and
+    a ``benchmark`` PR may edit no file outside ``benchmark/``. Mended in
+    place it would turn that ``xfail`` into a tier-1 failure; deleted, the
+    suite's module would not import. Once a PR that may touch ``tests/`` has
+    taken the suite's alias and copy out, the next ``benchmark`` PR deletes
+    this one (``PERF.md`` section 7)."""
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    _listed(bench["per_layer"][-1], bench)

@@ -9,7 +9,11 @@ The parent writes ``<dir>/req_<n>.json`` ``{"cmd": ...}`` and reads
 ``<dir>/resp_<n>.json``. Commands: ``stats`` (device memory, and the
 programs compiled or read from the cache so far, so that the parent can
 see whether anything compiled inside its window), ``trace_start`` (with a
-``dir``) and ``trace_stop``.
+``dir``) and ``trace_stop`` (a ``live`` stop: the server decodes on, so the
+window ends where the device's record does), whose answer carries
+``reduce_trace.stop_trace``'s timings (``collect_s``, ``export_s``, ``xplane_bytes``, ``wrote``); the same
+numbers go to this process's log as each half ends, so that a parent that
+stops waiting finds in the log's tail how far the stop had come.
 """
 
 import glob
@@ -37,6 +41,10 @@ def on_duration(name, *_, **__):
         COUNTS["compiles"] += 1
 
 
+def say(text: str) -> None:
+    print(f"bench-control: {text}", file=sys.stderr, flush=True)
+
+
 def answer(cmd: dict) -> dict:
     import jax
 
@@ -47,8 +55,8 @@ def answer(cmd: dict) -> dict:
         reduce_trace.start_trace(cmd["dir"])
         return {"ok": True}
     if cmd["cmd"] == "trace_stop":
-        reduce_trace.stop_trace()
-        return {"ok": True}
+        say("trace_stop: begun")
+        return dict(reduce_trace.stop_trace(log=say, live=True), ok=True)  # the server decodes on
     devices = jax.devices()[:1]
     return dict(COUNTS, ok=True, memory_peak_bytes=memory_peak_bytes(devices),
                 memory_stats=devices[0].memory_stats() or {})

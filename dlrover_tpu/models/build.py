@@ -4,7 +4,10 @@ class's fields>}}``. Callers (the benchmark's workers, a training script,
 ``tpurun-serve``) name no model class; a new family is one more line in
 ``FAMILIES``, the one registry trainer and server share, and one file that
 imports ``layers``, ``moe``, ``ops/`` and ``parallel/`` and no other family
-(``tests/test_models_layering.py``).
+(``tests/test_models_layering.py``). What two families share lives in
+``layers.py``: the gated delta-rule mixer (``layers.GatedDeltaMixer``, built
+by ``qwen3_next`` and ``olmo_hybrid`` from their own sizes), the decode cache
+and its three attention products.
 
 **The contract**, written here once: what a family's model may give, and
 who reads it. No base class and no protocol type: a holder probes with
@@ -22,7 +25,9 @@ who reads it. No base class and no protocol type: a holder probes with
   that call is spelt): ``positions [B, T]`` absolute, ``kv_valid [B, L]``
   the cache slots that hold real tokens, ``cache_slots [B]`` a one-token
   step's per-row write slots (without it the call's tokens go to the
-  shared write offset).
+  shared write offset). Every holder of a multi-token decode call reads
+  ``logits[:, -1]`` alone, and a model may return just that position
+  (``olmo_hybrid``: ``[B, 1, V]``).
 - On the config: ``ce_chunk`` (> 0: the train step hands the targets in;
   the size of a loss chunk) and ``takes_targets`` (hand them in whatever
   ``ce_chunk`` says: the model sows terms or counters on the way),
@@ -68,6 +73,7 @@ FAMILIES = {
     "granite_hybrid": ("granite_hybrid", "GraniteHybridLM", "GraniteHybridConfig"),
     "qwen3_next": ("qwen3_next", "Qwen3NextLM", "Qwen3NextConfig"),
     "mellum": ("mellum", "MellumLM", "MellumConfig"),
+    "olmo_hybrid": ("olmo_hybrid", "OlmoHybridLM", "OlmoHybridConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")

@@ -7,7 +7,9 @@ module, ``moe.py``, ``ops/`` and ``parallel/``, and never another family;
 this module imports no ``models`` module
 (``tests/test_models_layering.py``). A part moves here when a second family
 needs it: ``mla_moe.rope_interleaved`` and ``mellum.rope_table`` (YaRN) have
-one user each and stay with it.
+one user each and stay with it; the gated delta-rule mixer
+(:class:`GatedDeltaMixer`) came when ``olmo_hybrid.py`` became the second
+family to build it.
 """
 
 import math
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
 
+from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
 from ..parallel.sharding import with_logical_constraint as constrain
 
 param_with_axes = nn_partitioning.param_with_axes
@@ -34,13 +37,14 @@ def weight(name, cfg, shape, axes, std: float = 0.0):
 
 class RMSNorm(nn.Module):
     config: Any  # reads ``rms_eps``, ``param_dtype``, ``dtype``
+    init: float = 1.0  # the weight's start: a norm on a sublayer's *output* sets the layer's step by it
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         scale = param_with_axes(
             "scale",
-            nn.initializers.ones,
+            nn.initializers.ones if self.init == 1.0 else nn.initializers.constant(self.init),
             (x.shape[-1],),
             cfg.param_dtype,
             axes=("norm",),
@@ -307,6 +311,22 @@ def _update_decode_cache(
     return _read(mask)
 
 
+# The most positions squared (a call's width x the cache's length) that a
+# call's scores are held whole for, a head a row: 32 MiB of float32. Like
+# ``flash_attention._sub_block`` the one place that decides, from the call's
+# static shapes alone. Every served program up to a 2,048-wide prefill over
+# 2,560 positions (5.2 M) stays under it, text for text; an 8,192-wide one
+# over 8,704 (71 M: 8.6 GB of scores at 30 heads) does not.
+_WHOLE_SCORES_MAX = 8 * 1024 * 1024
+
+
+def prefill_is_tiled(width: int, max_len: int) -> bool:
+    """Whether a ``width``-token call over a cache of ``max_len`` positions
+    attends in tiles (:func:`_tiled_prefill_attention`) and not by the
+    masked product. A one-token step never does."""
+    return width > 1 and width * max_len > _WHOLE_SCORES_MAX
+
+
 def cached_decode_attention(
     module, max_len, q, k, v, kv_valid, cache_slots, wo, cfg
 ):
@@ -324,6 +344,10 @@ def cached_decode_attention(
     the int8 cache. Two for now: ``docs/generation.md`` says why.
     ``wo`` None (the bf16 GQA-narrow cache only) returns the heads'
     outputs ``[B, T, H, Hd]`` unprojected, for a model that gates them.
+
+    A multi-token call over a bf16 cache whose scores would be too many
+    to hold (:func:`prefill_is_tiled`) writes its keys and values the same
+    way and attends through :func:`_tiled_prefill_attention`.
     """
     res = _update_decode_cache(
         module, max_len, k, v, kv_valid, cache_slots,
@@ -331,11 +355,74 @@ def cached_decode_attention(
     )
     if len(res) == 3:
         k_full, v_full, mask = res
+        if cache_slots is None and prefill_is_tiled(q.shape[1], max_len):
+            offset = module.get_variable("cache", "index") - q.shape[1]
+            return _tiled_prefill_attention(
+                q, k, v, k_full, v_full, kv_valid, offset, wo, cfg)
         folded = k_full.ndim == 3
         attend = _masked_attention_folded if folded else _masked_attention
         return attend(q, k_full, v_full, mask, wo, cfg)
     k8, ks, v8, vs, mask = res
     return _masked_attention_int8(q, k8, ks, v8, vs, mask, wo, cfg)
+
+
+def _tiled_prefill_attention(q, k, v, k_full, v_full, kv_valid, offset, wo, cfg):
+    """A multi-token call's attention without its ``[T, L]`` scores or its
+    ``[B, T, L]`` mask: ``q``, ``k``, ``v`` are this call's own ``[B, T, ..]``,
+    ``k_full`` / ``v_full`` the row's leaves with them written at ``offset``
+    (a traced scalar: the cache's write index before the call).
+
+    **A fresh row** (``offset == 0``: a prompt's prefill, nothing in the row
+    before it) has no key but its own ``T``: the flash forward kernel
+    (``ops/flash_attention.py``, causal, unchanged) walks them in tiles. The
+    kernel knows no left pad, so each row is turned until its real tokens
+    stand first (prompts are LEFT-padded: a row's real tokens are its last
+    ``n``): under the causal mask a real token then sees real tokens only,
+    the padding behind them sees anything and is seen by nobody, and the
+    output is turned back. Whatever depends on position (RoPE) was applied
+    before. **A continued row** (a prefix's continuation) attends over the
+    whole row as the masked product does, a block of queries at a time."""
+    B, T, H, Hd = q.shape
+    L, KVH = k_full.shape[1], k.shape[2]
+    if kv_valid is None:
+        kv_valid = jnp.broadcast_to(jnp.arange(L)[None, :] < offset + T, (B, L))
+
+    def fresh():
+        from ..ops.flash_attention import flash_attention
+
+        pad = T - jnp.sum(kv_valid[:, :T], axis=1, dtype=jnp.int32)  # [B]: the call lies at slots [0, T)
+
+        def turned(a, by):
+            return jax.vmap(lambda row, n: jnp.roll(row, n, axis=0))(a, by)
+
+        def as_the_kernel_takes(a):  # turned, then a grouped call's keys repeated to the query heads
+            a = turned(a, -pad)
+            return a if H == KVH else jnp.repeat(a, H // KVH, axis=2)
+
+        out = flash_attention(turned(q, -pad), as_the_kernel_takes(k), as_the_kernel_takes(v), causal=True)
+        return turned(out, pad)
+
+    def continued():
+        block = T
+        while block * L > _WHOLE_SCORES_MAX and block % 2 == 0 and block > 2:
+            block //= 2
+        attend = _masked_attention_folded if k_full.ndim == 3 else _masked_attention
+
+        def one(i):
+            rows = jax.lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+            slot = offset + i * block + jnp.arange(block)
+            mask = kv_valid[:, None, :] & (
+                jnp.arange(L)[None, None, :] <= slot[None, :, None])
+            return attend(rows, k_full, v_full, mask, None, cfg)
+
+        out = jax.lax.map(one, jnp.arange(T // block))  # [T / block, B, block, H, Hd]
+        return jnp.moveaxis(out, 0, 1).reshape(B, T, H, Hd)
+
+    out = jax.lax.cond(offset == 0, fresh, continued)
+    if wo is None:
+        return out
+    y = jnp.einsum("bqhk,hkd->bqd", out, wo.astype(cfg.dtype))
+    return constrain(y, "batch", "seq", "embed")
 
 
 def _masked_attention_int8(q, k8, ks, v8, vs, mask, wo, cfg):
@@ -518,6 +605,102 @@ def token_valid_at(module, B, T, kv_valid, cache_slots):
     if kv_valid is None:
         return jnp.ones((B, T), bool)
     return jax.lax.dynamic_slice(kv_valid, (0, offset), (B, T))
+
+
+# -- the gated delta-rule mixer -------------------------------------------------
+
+DELTA_CHUNK = 64  # the chunked form's tile (``ops/gated_delta.py``)
+
+
+def _l2_normalised(x, eps: float = 1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+class GatedDeltaMixer(nn.Module):
+    """The gated delta-rule mixer of ``qwen3_next.py`` and
+    ``olmo_hybrid.py`` (their docstrings have the equations), built from
+    the config's own sizes by their published names: ``linear_num_key_heads``
+    (``Hk``), ``linear_num_value_heads`` (``Hv``, a multiple of ``Hk``),
+    ``linear_key_head_dim``, ``linear_value_head_dim``,
+    ``linear_conv_kernel_dim``; ``linear_allow_neg_eigval`` (absent: False)
+    makes the write strength ``beta = 2 sigmoid(b)``, in (0, 2), so that a
+    write can reflect the state along ``k`` (eigenvalue ``1 - beta`` in
+    (-1, 1)); beside them ``rms_norm_eps``, ``residual_init_std`` (of
+    ``w_out``), ``init_std``, ``dtype``, ``param_dtype``. ``token_valid``
+    ``[B, T]`` (decode only) says which of this call's tokens are real:
+    at a padded token ``g = 0`` and ``beta = 0`` exactly, and the
+    convolution's neighbours are the real ones (:func:`real_neighbours`).
+    ``inverse`` is ``ops/gated_delta.py``'s: a family whose programs are
+    pinned to the squaring names it. In decode mode it keeps ``delta_state
+    [B, Hv, dk, dv]`` (float32) and ``conv_state [B, K - 1, 2 Hk dk + Hv
+    dv]`` in the ``"cache"`` collection."""
+
+    config: Any
+    inverse: str = "blocks"
+
+    @nn.compact
+    def __call__(self, u, *, decode: bool = False, token_valid=None):
+        cfg = self.config
+        B, T, D = u.shape
+        Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim
+        keys, values = Hk * dk, Hv * dv
+        width = 2 * keys + values  # the channels of ``[q ; k ; v]``
+        f32 = jnp.float32
+        w_qkvz = weight("w_qkvz", cfg, (D, width + values), ("embed", "delta_proj"))
+        w_ba = weight("w_ba", cfg, (D, 2 * Hv), ("embed", "delta_heads"))
+        w_out = weight("w_out", cfg, (values, D), ("delta_inner", "embed"), cfg.residual_init_std)
+        taps = param_with_axes("conv_kernel", nn.initializers.normal(CONV_INIT_STD),
+                               (K, width), f32, axes=("conv_taps", "delta_channels"))
+        dt_bias = param_with_axes("dt_bias", dt_bias_init, (Hv,), f32, axes=("delta_heads",))
+        a_log = param_with_axes("A_log", a_log_init, (Hv,), f32, axes=("delta_heads",))
+        gate_w = param_with_axes("gate_norm", nn.initializers.ones, (dv,), f32, axes=("norm",))
+
+        with jax.named_scope("gdn.in_proj"):
+            qkvz = jnp.dot(u, w_qkvz)
+            qkv, z = qkvz[..., :width], qkvz[..., width:]
+            ba = jnp.dot(u, w_ba, preferred_element_type=f32)  # the decays stay float32
+        with jax.named_scope("gdn.conv"):
+            if not decode:
+                padded = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+                earlier = [padded[:, j:j + T] for j in range(K - 1)]
+            else:
+                state = self.variable("cache", "conv_state", jnp.zeros, (B, K - 1, width), qkv.dtype)
+                earlier, state.value = real_neighbours(state.value, qkv, token_valid)
+            conv = taps[K - 1] * qkv.astype(f32)
+            for j in range(K - 1):
+                conv = conv + taps[j] * earlier[j].astype(f32)
+            qkv = jax.nn.silu(conv)
+        q = _l2_normalised(qkv[..., :keys].reshape(B, T, Hk, dk)) * dk ** -0.5
+        k = _l2_normalised(qkv[..., keys:2 * keys].reshape(B, T, Hk, dk))
+        v = qkv[..., 2 * keys:].reshape(B, T, Hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        if getattr(cfg, "linear_allow_neg_eigval", False):
+            beta = 2.0 * beta
+        g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
+        if decode:
+            # the padding rule: at a padded token no decay and nothing written,
+            # exactly, so it leaves the state alone
+            beta = jnp.where(token_valid[:, :, None], beta, 0.0)
+            g = jnp.where(token_valid[:, :, None], g, 0.0)
+        held = self.variable("cache", "delta_state", jnp.zeros, (B, Hv, dk, dv), f32) if decode else None
+        if decode and T == 1:
+            with jax.named_scope("gdn.step"):
+                o, held.value = gated_delta_step(held.value, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                o = o[:, None]
+        else:  # from the row's state, or from zeros where nothing is cached
+            with jax.named_scope("gdn.chunk"):
+                o, last = gated_delta_chunked(
+                    q, k, v, g, beta, DELTA_CHUNK, held.value if decode else None, inverse=self.inverse)
+            if decode:
+                held.value = last
+        with jax.named_scope("gdn.gate_norm"):
+            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            o = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * gate_w
+            y = (o.reshape(B, T, values) * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
+        with jax.named_scope("gdn.out_proj"):
+            out = jnp.dot(y, w_out)
+        return constrain(out, "batch", "seq", "embed")
 
 
 # -- a model's side of the contract (``models/build.py``) ---------------------

@@ -61,17 +61,21 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
 from .layers import (
-    CONV_INIT_STD, a_log_init, apply_rope, apply_rope_at, cached_decode_attention,
-    chunked_token_ce, constrain, dt_bias_init, dtypes_read_by_name, param_with_axes,
-    real_neighbours, rope_tables, state_leaves_by_name, token_valid_at, weight)
+    GatedDeltaMixer, apply_rope, apply_rope_at, cached_decode_attention, chunked_token_ce,
+    constrain, dtypes_read_by_name, param_with_axes, rope_tables, state_leaves_by_name,
+    token_valid_at, weight)
 from .moe import MoeLayer, MoeSizes, decode_step_counters
 
-DELTA_CHUNK = 64
-# The init the config does not state (``dt_bias``, ``A_log``, the taps) is
-# ``layers.dt_bias_init`` and its neighbours, which say why: the reference
-# model's own draw forgets within a token or two.
+# The delta mixer is ``layers.GatedDeltaMixer``, built from this config's
+# sizes (``olmo_hybrid.py`` builds it from its own). The init the config does
+# not state (``dt_bias``, ``A_log``, the taps) is ``layers.dt_bias_init`` and
+# its neighbours, which say why: the reference model's own draw forgets
+# within a token or two. This family names the legacy ``inverse="squaring"``
+# (its ``beta`` stays under 1, and its served programs are pinned to that
+# text: ``tests/test_tpu_compile.py: PARENT_FAMILY_PROGRAMS``); the PR that
+# may next move ``qwen3next-serve-rag-16`` re-bases the pins, drops the
+# argument here and deletes the squaring from ``ops/gated_delta.py``.
 
 
 @dataclass(frozen=True)
@@ -216,77 +220,6 @@ class ZeroCentredRMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + cfg.rms_norm_eps) * (1.0 + w)).astype(cfg.dtype)
 
 
-def _l2_normalised(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
-
-
-class GatedDeltaMixer(nn.Module):
-    """The gated delta-rule mixer. ``token_valid`` ``[B, T]`` (decode
-    only) says which of this call's tokens are real."""
-
-    config: Qwen3NextConfig
-
-    @nn.compact
-    def __call__(self, u, *, decode: bool = False, token_valid=None):
-        cfg = self.config
-        B, T, D = u.shape
-        Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim
-        keys, values, width = cfg.delta_key_width, cfg.delta_value_width, cfg.delta_conv_width
-        f32 = jnp.float32
-        w_qkvz = weight("w_qkvz", cfg, (D, width + values), ("embed", "delta_proj"))
-        w_ba = weight("w_ba", cfg, (D, 2 * Hv), ("embed", "delta_heads"))
-        w_out = weight("w_out", cfg, (values, D), ("delta_inner", "embed"), cfg.residual_init_std)
-        taps = param_with_axes("conv_kernel", nn.initializers.normal(CONV_INIT_STD),
-                               (K, width), f32, axes=("conv_taps", "delta_channels"))
-        dt_bias = param_with_axes("dt_bias", dt_bias_init, (Hv,), f32, axes=("delta_heads",))
-        a_log = param_with_axes("A_log", a_log_init, (Hv,), f32, axes=("delta_heads",))
-        gate_w = param_with_axes("gate_norm", nn.initializers.ones, (dv,), f32, axes=("norm",))
-
-        with jax.named_scope("gdn.in_proj"):
-            qkvz = jnp.dot(u, w_qkvz)
-            qkv, z = qkvz[..., :width], qkvz[..., width:]
-            ba = jnp.dot(u, w_ba, preferred_element_type=f32)  # the decays stay float32
-        with jax.named_scope("gdn.conv"):
-            if not decode:
-                padded = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
-                earlier = [padded[:, j:j + T] for j in range(K - 1)]
-            else:
-                state = self.variable("cache", "conv_state", jnp.zeros, (B, K - 1, width), qkv.dtype)
-                earlier, state.value = real_neighbours(state.value, qkv, token_valid)
-            conv = taps[K - 1] * qkv.astype(f32)
-            for j in range(K - 1):
-                conv = conv + taps[j] * earlier[j].astype(f32)
-            qkv = jax.nn.silu(conv)
-        q = _l2_normalised(qkv[..., :keys].reshape(B, T, Hk, dk)) * dk ** -0.5
-        k = _l2_normalised(qkv[..., keys:2 * keys].reshape(B, T, Hk, dk))
-        v = qkv[..., 2 * keys:].reshape(B, T, Hv, dv)
-        beta = jax.nn.sigmoid(ba[..., :Hv])
-        g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
-        if decode:
-            # the padding rule: at a padded token no decay and nothing written,
-            # exactly, so it leaves the state alone
-            beta = jnp.where(token_valid[:, :, None], beta, 0.0)
-            g = jnp.where(token_valid[:, :, None], g, 0.0)
-        held = self.variable("cache", "delta_state", jnp.zeros, (B, Hv, dk, dv), f32) if decode else None
-        if decode and T == 1:
-            with jax.named_scope("gdn.step"):
-                o, held.value = gated_delta_step(held.value, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-                o = o[:, None]
-        else:  # from the row's state, or from zeros where nothing is cached
-            with jax.named_scope("gdn.chunk"):
-                o, last = gated_delta_chunked(q, k, v, g, beta, DELTA_CHUNK, held.value if decode else None)
-            if decode:
-                held.value = last
-        with jax.named_scope("gdn.gate_norm"):
-            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
-            o = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * gate_w
-            y = (o.reshape(B, T, values) * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
-        with jax.named_scope("gdn.out_proj"):
-            out = jnp.dot(y, w_out)
-        return constrain(out, "batch", "seq", "embed")
-
-
 class GatedAttention(nn.Module):
     """Grouped-query attention with per-head q/k norms, RoPE on part of a
     head and a per-head sigmoid gate on the output."""
@@ -351,7 +284,8 @@ class Block(nn.Module):
                 u, decode=decode, positions=positions, kv_valid=kv_valid,
                 cache_slots=cache_slots)
         else:
-            x = x + GatedDeltaMixer(cfg, name="gdn")(u, decode=decode, token_valid=token_valid)
+            x = x + GatedDeltaMixer(cfg, inverse="squaring", name="gdn")(
+                u, decode=decode, token_valid=token_valid)
         y = MoeLayer(cfg.moe_sizes, name="moe")(ZeroCentredRMSNorm(cfg, name="post_norm")(x))
         return constrain(x + y, "batch", "seq", "embed")
 

@@ -54,7 +54,13 @@ TPU shape — every device program is static-shape and compiled once:
   that every token rewrites whole, ``models/granite_hybrid.py``: a step
   size of exactly 0 at a padded token). ``stats()`` reports
   ``cache_bytes_positional`` and ``cache_bytes_state``, and counts
-  ``prefill_tokens_real`` against ``prefill_tokens_padded`` at admission.
+  ``prefill_tokens_real`` against ``prefill_tokens_padded`` at admission,
+  ``prefill_tiled_calls`` beside them (admissions whose call was too wide
+  for an attention layer to hold its scores: ``layers.prefill_is_tiled``),
+  and at each chunk's read-back ``kv_positions_valid`` (the rows' real
+  lengths at the steps that emitted their tokens) against
+  ``kv_positions_held`` (``slots x max_seq_len`` a step: what a step's
+  attention reads against what it needs).
 - **Weight hot-swap between chunks**: ``set_params`` replaces the
   parameter argument of the jitted programs (same shapes — no
   recompile), so a WeightBus push lands at the next chunk boundary;
@@ -102,6 +108,7 @@ from ..attribution.recovery import startup_summary
 from ..chaos import faults
 from ..observability.spans import span
 from . import kv_blocks
+from .layers import prefill_is_tiled
 from .generation import (
     SamplingConfig,
     decode_apply,
@@ -1086,9 +1093,13 @@ class ContinuousBatchingEngine:
         # (a stored prefix's are not computed again) against the width of
         # the bucket they were padded to. A scan, unlike attention, pays
         # for every padded position.
+        own_width = self._bucket_width(len(prompt))
         self.phases.count("prefill_tokens_real", len(prompt))
+        self.phases.count("prefill_tokens_padded", own_width)
+        # ... and whether that call was too wide for an attention layer to
+        # hold its scores whole (``layers.prefill_is_tiled``)
         self.phases.count(
-            "prefill_tokens_padded", self._bucket_width(len(prompt))
+            "prefill_tiled_calls", int(prefill_is_tiled(own_width, self.L))
         )
         row = (row_cache, row_logits, row_pos, row_kv, row_allow)
         return row, width, full_prompt
@@ -1146,9 +1157,22 @@ class ContinuousBatchingEngine:
 
     def _count_chunk(self, row_steps: int) -> None:
         """One dispatched chunk: ``row_steps`` = slots x positions it
-        decodes, whether or not a live row fills them."""
+        decodes, whether or not a live row fills them; each of those
+        steps' attention reads its row whole, ``max_seq_len`` positions."""
         self.phases.count("chunks")
         self.phases.count("row_steps", row_steps)
+        self.phases.count("kv_positions_held", row_steps * self.L)
+
+    def _count_kv_valid(self, st: _Slot, new: int) -> None:
+        """What the decode steps that emitted a row's last ``new`` tokens
+        needed of its keys and values: the row's real length at each (the
+        prompt, what it had emitted, the step's own token), against the
+        ``kv_positions_held`` a step reads. Booked at the chunk's read-back,
+        after the tokens are credited."""
+        before = len(st.prompt) + len(st.emitted) - new
+        self.phases.count(
+            "kv_positions_valid", new * before + new * (new + 1) // 2
+        )
 
     # -- paged block planning (host side of admission) ------------------
 
@@ -1419,6 +1443,7 @@ class ContinuousBatchingEngine:
                         float(x)
                         for x in logps[sel, slot][: len(new)]
                     )
+                    self._count_kv_valid(st, len(new))
                     emitted += len(new)
             st.finished = bool(done[slot])
             if st.finished or len(st.emitted) >= st.cap:
@@ -1556,6 +1581,7 @@ class ContinuousBatchingEngine:
         for slot, st in enumerate(self._slots):
             if st.uid < 0:
                 continue
+            had = len(st.emitted)
             for t in range(toks.shape[0]):
                 if len(st.emitted) >= st.cap:
                     break
@@ -1565,6 +1591,7 @@ class ContinuousBatchingEngine:
                     st.emitted.append(int(toks[t, slot]))
                     st.logprobs.append(float(logps[t, slot]))
                     emitted += 1
+            self._count_kv_valid(st, len(st.emitted) - had)
             st.finished = bool(done[slot])
             if st.finished or len(st.emitted) >= st.cap:
                 self._retire(slot)

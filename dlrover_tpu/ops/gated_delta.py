@@ -1,8 +1,10 @@
 """The gated delta-rule recurrence of a linear-attention mixer, two ways.
 
 Per value head (``K`` key channels, ``V`` value channels, a state ``S``
-of ``[K, V]``), with ``g_t <= 0`` a log-decay and ``beta_t`` in ``[0, 1]``
-a write strength:
+of ``[K, V]``), with ``g_t <= 0`` a log-decay and ``beta_t`` in ``[0, 2]``
+a write strength (in ``[0, 1]`` the write moves the state's answer at
+``k_t`` towards ``v_t``; past 1 it overshoots, and the state's eigenvalue
+along ``k_t``, ``1 - beta_t``, is negative: a reflection):
 
     S   <- exp(g_t) S
     u_t  = beta_t (v_t - S^T k_t)        what the state does not yet say of k_t
@@ -25,8 +27,18 @@ live), and how :func:`gated_delta_chunked` fills ``T`` up to whole chunks.
 exp(c_i - c_j) (k_i . k_j)`` for ``j < i``; ``A`` is strictly lower
 triangular, so nilpotent, and ``(I + A)^-1 = (I - A)(I + A^2)(I + A^4)
 ...``: ``log2 Q - 1`` squarings and as many products on the matrix unit
-instead of a ``Q``-row substitution. Then ``O = exp(c) Q S_0 + (M o Q
-K^T) U`` with ``M[i, j] = exp(c_i - c_j)`` for ``j <= i``, and ``S_Q =
+instead of a ``Q``-row substitution (``inverse="squaring"``, the legacy
+path, kept only for the caller whose programs are pinned to it). That
+product is exact in exact arithmetic and no better than its largest term in
+float32: where a chunk's keys share a direction (``k_i . k_j`` about ``c``
+for every pair) the entries of ``A^n`` grow like ``(beta c)^n C(Q, n)`` and
+cancel to an inverse of size 1, so the rounding of the powers is what is
+left (at ``beta c`` 0.3 they reach 1e6, at 0.8 1e15). ``beta`` up to 2
+doubles every entry of ``A``. ``inverse="blocks"``, the default, forms no
+power: rows of 16 by substitution (each row from the rows before it:
+nothing larger than the inverse itself is ever formed), and two blocks'
+inverses joined by ``X_21 = -X_22 A_21 X_11``, two levels for 64. Then
+``O = exp(c) Q S_0 + (M o Q K^T) U`` with ``M[i, j] = exp(c_i - c_j)`` for ``j <= i``, and ``S_Q =
 exp(c_Q) S_0 + (exp(c_Q - c) K)^T U``. Everything that does not read
 ``S_0`` is computed for all chunks at once; only three products a chunk
 are carried sequentially. :func:`gated_delta_step` is one token of the
@@ -56,12 +68,38 @@ def _inverse_of_unit_lower(a):
     return inv
 
 
-def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, initial_state=None):
+_SUBSTITUTED_ROWS = 16
+
+
+def _inverse_of_unit_lower_in_blocks(a):
+    """``(I + a)^-1`` for ``a [..., Q, Q]`` strictly lower triangular, with
+    no power of ``a``: up to ``_SUBSTITUTED_ROWS`` rows by substitution (row
+    ``i`` of the inverse is ``e_i - a[i, :i] X[:i]``), larger ones from their
+    two halves' inverses."""
+    q = a.shape[-1]
+    if q <= _SUBSTITUTED_ROWS:
+        x = jnp.broadcast_to(jnp.eye(q, dtype=a.dtype), a.shape)
+        for i in range(1, q):  # the rows from ``i`` on are still the identity's, and ``a[i, i:]`` is 0
+            row = jnp.einsum("...j,...jk->...k", a[..., i, :], x, precision=_EXACT)
+            x = x.at[..., i, :].add(-row)
+        return x
+    h = q // 2
+    x11 = _inverse_of_unit_lower_in_blocks(a[..., :h, :h])
+    x22 = _inverse_of_unit_lower_in_blocks(a[..., h:, h:])
+    x21 = -jnp.matmul(jnp.matmul(x22, a[..., h:, :h], precision=_EXACT), x11, precision=_EXACT)
+    top = jnp.concatenate([x11, jnp.zeros_like(a[..., :h, h:])], axis=-1)
+    return jnp.concatenate([top, jnp.concatenate([x21, x22], axis=-1)], axis=-2)
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, initial_state=None, inverse: str = "blocks"):
     """``q`` and ``k`` ``[b, T, Hk, K]`` (``k`` of unit length where the
     caller wants the delta rule's contraction), ``v [b, T, Hv, V]``, ``g``
     and ``beta`` ``[b, T, Hv]`` (float32; both 0 at a padded token),
     ``initial_state [b, Hv, K, V]`` (zeros if None) -> (``o [b, T, Hv, V]``
-    float32, the state after the last token ``[b, Hv, K, V]`` float32)."""
+    float32, the state after the last token ``[b, Hv, K, V]`` float32).
+    ``inverse``: ``"blocks"`` or the legacy ``"squaring"`` (the module's
+    docstring says what each loses)."""
+    invert = {"squaring": _inverse_of_unit_lower, "blocks": _inverse_of_unit_lower_in_blocks}[inverse]
     bsz, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r = hv // hk
@@ -90,7 +128,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64, initial_state=None):
     kk = jnp.einsum("bchik,bchjk->bchij", kc, kc)[:, :, :, None]  # shared by a key head's r value heads
     qk = jnp.einsum("bchik,bchjk->bchij", qc, kc)[:, :, :, None]
     a = jnp.where(jnp.tril(jnp.ones((n, n), bool), -1), bc[..., :, None] * decay * kk, 0.0)
-    solve = _inverse_of_unit_lower(a) * bc[..., None, :]  # (I + A)^-1 diag(beta)
+    solve = invert(a) * bc[..., None, :]  # (I + A)^-1 diag(beta)
     u_own = jnp.einsum("bchrij,bchrjv->bchriv", solve, vc)  # U where S_0 = 0
     w = jnp.einsum("bchrij,bchjk->bchrik", solve * jnp.exp(cum)[..., None, :], kc)  # U = u_own - w S_0
     attend = decay * qk  # M o Q K^T

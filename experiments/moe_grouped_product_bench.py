@@ -40,10 +40,10 @@ def product(impl, lhs, rhs, sizes):
 
 
 def experts(impl, x, w_gate, w_up, w_down, token_of, sizes):
-    n_valid = jnp.sum(sizes)
-    xs = gm.spread_rows(x, token_of, n_valid)
+    moves = gm.row_order(token_of, jnp.sum(sizes), TOKENS)
+    xs = gm.spread_rows(x, moves)
     h = jax.nn.silu(product(impl, xs, w_gate, sizes)) * product(impl, xs, w_up, sizes)
-    return gm.collect_rows(product(impl, h, w_down, sizes), token_of, n_valid, TOKENS)
+    return gm.collect_rows(product(impl, h, w_down, sizes), moves)
 
 
 def timed(fn, *args, n=10):
@@ -85,8 +85,9 @@ def main():
         token_of, sizes = routing(1, rows_n)
         n_valid = jnp.sum(sizes)
         rows = jax.random.normal(key, (rows_n, D), jnp.bfloat16)
-        out[f"spread_ms.rows{rows_n}"] = 1e3 * timed(jax.jit(gm.spread_rows), x, token_of, n_valid)
-        collect = jax.jit(lambda r, t, n: gm.collect_rows(r, t, n, TOKENS))
+        spread = jax.jit(lambda x, t, n: gm.spread_rows(x, gm.row_order(t, n, TOKENS)))
+        out[f"spread_ms.rows{rows_n}"] = 1e3 * timed(spread, x, token_of, n_valid)
+        collect = jax.jit(lambda r, t, n: gm.collect_rows(r, gm.row_order(t, n, TOKENS)))
         out[f"collect_ms.rows{rows_n}"] = 1e3 * timed(collect, rows, token_of, n_valid)
         masked = jnp.where((jnp.arange(rows_n) < n_valid)[:, None], rows, 0).astype(jnp.float32)
         want = jax.ops.segment_sum(masked, token_of, num_segments=TOKENS)

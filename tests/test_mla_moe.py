@@ -21,7 +21,9 @@ from dlrover_tpu.models.build import FAMILIES, build_model
 from dlrover_tpu.models.layers import token_loss_mean
 from dlrover_tpu.models.mla_moe import MlaMoeConfig, MlaMoeLM
 from dlrover_tpu.models.moe import MoeLayer
-from dlrover_tpu.ops.grouped_matmul import collect_rows, grouped_matmul, spread_rows
+from dlrover_tpu.ops import grouped_matmul as gm
+from dlrover_tpu.ops.grouped_matmul import (
+    collect_rows, grouped_matmul, row_order, sort_carrying, spread_rows)
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.train_step import (
     build_train_step,
@@ -336,23 +338,295 @@ def test_grouped_matmul_and_the_row_movements():
     np.testing.assert_allclose(got[:5], lhs[:5] @ rhs[0], atol=1e-5)
     np.testing.assert_allclose(got[5:14], lhs[5:14] @ rhs[2], atol=1e-5)
 
-    # 6 tokens x 2 choices; 5 assignments have a row, in this order
-    # 6 tokens; 5 of the buffer's 8 rows belong to a token, in this order
+    # 6 tokens; 5 of the buffer's 8 rows belong to a token, in this order.
+    # The rows past ``n_valid`` are copies of some token too and the caller's
+    # to ignore: nothing zeroes them on the way in (the grouped products never
+    # visit them), and whatever they hold on the way out, NaN included, is
+    # selected away. The transpose identity holds over the valid rows.
     token_of, n_valid = jnp.asarray([3, 0, 5, 3, 1, 0, 0, 0]), jnp.asarray(5)
+    moves = row_order(token_of, n_valid, 6)
+    np.testing.assert_array_equal(moves.token_sorted, [0, 1, 3, 3, 5, 6, 6, 6])
+    np.testing.assert_array_equal(moves.by_token, [1, 4, 0, 3, 2, 5, 6, 7])
     x = jax.random.normal(key, (6, 4))
     r = jax.random.normal(jax.random.fold_in(key, 2), (8, 4))
-    spread = spread_rows(x, token_of, n_valid)
-    np.testing.assert_allclose(spread[:5], x[token_of[:5]])
-    assert not np.any(np.asarray(spread[5:]))
-    collected = collect_rows(r, token_of, n_valid, 6)
+    spread = spread_rows(x, moves)
+    np.testing.assert_allclose(spread, x[token_of])
+    collected = collect_rows(r, moves)
     np.testing.assert_allclose(collected[3], r[0] + r[3], atol=1e-6)
     assert not np.any(np.asarray(collected[jnp.asarray([2, 4])]))
-    # each is the other's transpose: <spread(x), r> == <x, collect(r)>
-    np.testing.assert_allclose(jnp.sum(spread * r), jnp.sum(x * collected), rtol=1e-5)
-    gx = jax.grad(lambda x: jnp.sum(spread_rows(x, token_of, n_valid) * r))(x)
+    np.testing.assert_array_equal(collect_rows(r.at[5:].set(jnp.nan), moves), collected)
+    # each is the other's transpose: <spread(x)[:5], r[:5]> == <x, collect(r)>
+    np.testing.assert_allclose(jnp.sum(spread[:5] * r[:5]), jnp.sum(x * collected), rtol=1e-5)
+    gx = jax.grad(lambda x: jnp.sum(spread_rows(x, moves) * r.at[5:].set(jnp.nan)))(x)
     np.testing.assert_allclose(gx, collected, atol=1e-6)
-    gr = jax.grad(lambda r: jnp.sum(collect_rows(r, token_of, n_valid, 6) * x))(r)
+    gr = jax.grad(lambda r: jnp.sum(collect_rows(r, moves) * x))(r)
     np.testing.assert_allclose(gr, spread, atol=1e-6)
+
+    # one sort gives the permutation and the values along it; their cotangents
+    # come home in the order the values came in
+    sort_key = jnp.asarray([2, 0, 2, 1, 0, 3], jnp.int32)
+    values = jnp.arange(6.0) + 10.0
+    order, carried = sort_carrying(sort_key, values)
+    np.testing.assert_array_equal(order, jnp.argsort(sort_key, stable=True))
+    np.testing.assert_array_equal(carried, values[order])
+    w = jax.random.normal(key, (6,))
+    np.testing.assert_allclose(
+        jax.grad(lambda v: jnp.sum(sort_carrying(sort_key, v)[1] * w))(values),
+        jax.grad(lambda v: jnp.sum(v[order] * w))(values))
+
+
+def _interpret_the_collecting_kernel(monkeypatch):
+    """The chip's way back on this CPU: ``_on_tpu`` says yes and
+    ``megablox.tgmm`` is interpreted; returns the list its calls are counted in."""
+    megablox = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+    calls = []
+
+    def tgmm(*args, **kwargs):
+        calls.append(args[0].shape)
+        return megablox_tgmm(*args, interpret=True, **kwargs)
+
+    megablox_tgmm = megablox.tgmm
+    monkeypatch.setattr(megablox, "tgmm", tgmm)
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    return calls
+
+
+def test_the_collecting_kernel_adds_nothing_from_rows_of_no_token(monkeypatch):
+    """The chip's way back (``megablox.tgmm`` over tiles of 128 tokens,
+    interpreted here) with NaN in every row of no token, forward and behind
+    ``spread_rows`` in the backward pass: those rows are sorted past every
+    tile's group and selected to zero before the one-hot product, which
+    would make a NaN of the sum out of a NaN it multiplies by zero; the
+    rest is the plain segment sum."""
+    calls = _interpret_the_collecting_kernel(monkeypatch)
+    key = jax.random.PRNGKey(10)
+    n_tokens, n_rows, n_valid = 256, 384, 300
+    token_of = jax.random.randint(key, (n_rows,), 0, n_tokens)
+    moves = row_order(token_of, jnp.asarray(n_valid), n_tokens)
+    assert int(jnp.sum(moves.tile_sizes)) == n_valid
+    r = jax.random.normal(key, (n_rows, 128)).at[n_valid:].set(jnp.nan)
+    want = jax.ops.segment_sum(r[:n_valid], token_of[:n_valid], num_segments=n_tokens)
+    np.testing.assert_allclose(collect_rows(r, moves), want, atol=1e-5)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (n_tokens, 128))
+    np.testing.assert_allclose(
+        jax.grad(lambda x: jnp.sum(spread_rows(x, moves) * r))(x), want, atol=1e-5)
+    assert len(calls) == 2
+
+
+@pytest.mark.parametrize("way_back,n_tokens,gate_rounded", [
+    pytest.param("cpu", 256, True, id="off-the-chip-rounds"),
+    pytest.param("kernel", 256, True, id="kernel-rounds"),
+    pytest.param("chips_segment_sum", 250, False, id="chips-segment-sum-keeps-float32"),
+])
+def test_which_gate_a_row_meets_on_its_way_back(way_back, n_tokens, gate_rounded, monkeypatch):
+    """``collect_rows(rows, order, gate)`` with one row a token, so that a
+    sum has one term and the product can be held to its bits: bf16 rows meet
+    the gate rounded to bf16 off the chip and in front of the chip's kernel,
+    and the float32 gate in a segment sum on the chip (whose tokens are no
+    whole tiles), the product rounded once either way; NaN in the rows of no
+    token reaches neither the sum nor the gate's gradient."""
+    calls = _interpret_the_collecting_kernel(monkeypatch) if way_back != "cpu" else []
+    key = jax.random.PRNGKey(15)
+    n_rows = 384
+    token_of = jnp.concatenate([jax.random.permutation(key, n_tokens), jnp.zeros(n_rows - n_tokens, jnp.int32)])
+    moves = row_order(token_of, jnp.asarray(n_tokens), n_tokens)
+    rows = jax.random.normal(jax.random.fold_in(key, 1), (n_rows, 128), jnp.bfloat16).at[n_tokens:].set(jnp.nan)
+    gate = jax.random.uniform(jax.random.fold_in(key, 2), (n_rows,), jnp.float32, 0.05, 1.0)
+    rounded = gate.astype(jnp.bfloat16).astype(jnp.float32)
+    assert int(jnp.sum(rounded != gate)) > n_rows // 2  # (so the case can tell the two products apart)
+    met = rounded if gate_rounded else gate
+    product = (rows.astype(jnp.float32) * met[:, None]).astype(jnp.bfloat16)
+    got = collect_rows(rows, moves, gate)
+    assert got.dtype == jnp.bfloat16 and len(calls) == (way_back == "kernel")
+    np.testing.assert_array_equal(got[token_of[:n_tokens]], product[:n_tokens])
+
+    w = jax.random.normal(jax.random.fold_in(key, 3), (n_tokens, 128), jnp.bfloat16)
+    d_rows, d_gate = jax.grad(
+        lambda r, g: jnp.sum((collect_rows(r, moves, g) * w).astype(jnp.float32)), argnums=(0, 1))(rows, gate)
+    assert np.all(np.isfinite(d_gate.astype(np.float32))) and not np.any(np.asarray(d_gate[n_tokens:]))
+    np.testing.assert_allclose(
+        d_gate[:n_tokens], jnp.sum(rows[:n_tokens].astype(jnp.float32) * w[token_of[:n_tokens]], axis=1),
+        rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(
+        d_rows[:n_tokens], (w[token_of[:n_tokens]].astype(jnp.float32) * met[:n_tokens, None]).astype(jnp.bfloat16))
+    assert not np.any(np.asarray(d_rows[n_tokens:].astype(jnp.float32)))
+
+
+def _layer_of_every_familys_kind(bias_name, train_gates, held, score_fn, dtype=jnp.float32, tokens=24):
+    """(sizes, input, parameters): 16 experts top-4 over 2 x ``tokens``
+    tokens, a shared expert, the selection bias (where there is one) drawn
+    wider than the scores are apart."""
+    sizes = moe.MoeSizes(
+        n_experts=16, top_k=4, width=16, experts_held=held, expert_offset=4 if held else 0,
+        n_shared=1, score_fn=score_fn, bias_name=bias_name, train_gates=train_gates,
+        scale=2.5, norm_eps=1e-20 if score_fn == "sigmoid" else 0.0,
+        init_std=0.3, bias_init_std=0.3, dtype=dtype)
+    h = jax.random.normal(jax.random.PRNGKey(11), (2, tokens, 32), dtype)
+    return sizes, h, MoeLayer(sizes).init(jax.random.PRNGKey(12), h)["params"]
+
+
+def _token_by_token(sizes, p, h, gate_dtype=jnp.float32):
+    """The layer as a loop over each token's chosen experts, the held ones
+    alone, plus the shared expert; ``train_gates`` False holds the gates
+    constant in the backward pass; a row meets its gate rounded to
+    ``gate_dtype``."""
+    xf = h.reshape(-1, h.shape[-1])
+    score_fn = {"sigmoid": jax.nn.sigmoid, "softmax": lambda a: jax.nn.softmax(a, axis=-1)}[sizes.score_fn]
+    scores = score_fn(jnp.dot(xf, p["w_router"], precision="highest"))
+    biased = scores + p[sizes.bias_name] if sizes.bias_name else scores
+    idx = jnp.argsort(-biased, axis=-1, stable=True)[:, :sizes.top_k]
+    lo = sizes.expert_offset
+
+    def swiglu(x, w):
+        return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+
+    rows = []
+    for n in range(xf.shape[0]):
+        chosen = jnp.stack([scores[n, e] for e in idx[n]])
+        gates = (chosen / (jnp.sum(chosen) + sizes.norm_eps) * sizes.scale).astype(gate_dtype).astype(jnp.float32)
+        if not sizes.train_gates:
+            gates = jax.lax.stop_gradient(gates)
+        rows.append(sum(
+            (g * swiglu(xf[n], {name: p[name][e - lo] for name in ("w_gate", "w_up", "w_down")})
+             for g, e in zip(gates, (int(e) for e in idx[n])) if lo <= e < lo + sizes.experts_here),
+            jnp.zeros_like(xf[n])))
+    return (jnp.stack(rows) + swiglu(xf, p["shared"])).reshape(h.shape)
+
+
+FAMILY_FLAGS = [  # bias_name, train_gates, experts held (0: all), score_fn
+    pytest.param("e_score_correction_bias", True, 4, "sigmoid", id="mla_moe-share"),
+    pytest.param("expert_bias", True, 0, "sigmoid", id="lfm2_moe-whole"),
+    pytest.param("", True, 4, "softmax", id="qwen3_next-share"),
+    pytest.param("", False, 4, "softmax", id="mellum-share"),
+    pytest.param("", True, 0, "softmax", id="mellum-whole"),
+    pytest.param("e_score_correction_bias", False, 4, "softmax", id="frozen-bias-share"),
+]
+
+
+@pytest.mark.parametrize("bias_name,train_gates,held,score_fn", FAMILY_FLAGS)
+def test_the_layer_is_a_loop_over_each_tokens_chosen_experts(bias_name, train_gates, held, score_fn):
+    """Every family's flags: the layer's output and every gradient against
+    the loop written above, and ``route``'s chosen gates bit for bit the
+    gates of every expert read at the chosen ones, as the parent computed
+    them (normalise all ``E`` scores, then gather by choice)."""
+    sizes, h, p = _layer_of_every_familys_kind(bias_name, train_gates, held, score_fn)
+    tol = TOLERANCES["float32"]
+
+    def run(p, h):
+        return MoeLayer(sizes).apply({"params": p}, h, mutable=("metrics",))[0]
+
+    np.testing.assert_allclose(run(p, h), _token_by_token(sizes, p, h), atol=tol["loss"])
+    w = jax.random.normal(jax.random.PRNGKey(13), h.shape)
+    got = jax.grad(lambda p, h: jnp.sum(run(p, h) * w), argnums=(0, 1))(p, h)
+    want = jax.grad(lambda p, h: jnp.sum(_token_by_token(sizes, p, h) * w), argnums=(0, 1))(p, h)
+    flat, r_flat = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (got, want))
+    for (path, g), (_, r) in zip(flat, r_flat):
+        err = float(jnp.max(jnp.abs(g - r))) / max(float(jnp.max(jnp.abs(r))), 1e-6)
+        assert err < tol["grad"], f"{jax.tree_util.keystr(path)}: {err}"
+    router = float(jnp.max(jnp.abs(got[0]["w_router"])))
+    assert router > 0 if train_gates else router == 0  # (the shared expert reads no score)
+
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(14), (512, 64)) * 3)
+    bias = p[bias_name][:1] * jnp.linspace(-1, 1, 64) if bias_name else None
+    idx, gates = jax.jit(lambda s: moe.route(s, bias, 4, True, sizes.scale, sizes.norm_eps))(scores)
+    chosen = jnp.sum(jnp.take_along_axis(scores, idx, axis=-1), axis=-1, keepdims=True)
+    of_every_expert = scores / (chosen + sizes.norm_eps if sizes.norm_eps else chosen) * sizes.scale
+    np.testing.assert_array_equal(gates, jnp.take_along_axis(of_every_expert, idx, axis=-1))
+    np.testing.assert_array_equal(idx, jax.lax.top_k(scores if bias is None else scores + bias, 4)[1])
+
+
+def _products_that_leave_nan_past_their_groups():
+    """(a ``grouped_matmul`` that writes NaN into every row past
+    ``sum(group_sizes)``, forward and in the backward product that writes
+    rows; the list its backward passes are counted in)."""
+    poisoned = []
+
+    def plain(lhs, rhs, group_sizes):
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32), preferred_element_type=lhs.dtype)
+
+    @jax.custom_vjp
+    def nan_past_the_groups(lhs, rhs, group_sizes):
+        past = (jnp.arange(lhs.shape[0]) >= jnp.sum(group_sizes))[:, None]
+        return jnp.where(past, jnp.nan, plain(lhs, rhs, group_sizes))
+
+    def fwd(lhs, rhs, group_sizes):
+        return nan_past_the_groups(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, g):
+        lhs, rhs, group_sizes = res
+        past = (jnp.arange(lhs.shape[0]) >= jnp.sum(group_sizes))[:, None]
+        poisoned.append(past.shape[0])
+        # the kernel's two backward products read the groups' rows alone ...
+        d_lhs, d_rhs = jax.vjp(lambda a, b: plain(a, b, group_sizes), lhs, rhs)[1](jnp.where(past, 0, g))
+        return jnp.where(past, jnp.nan, d_lhs), d_rhs, None  # ... and write them alone
+
+    nan_past_the_groups.defvjp(fwd, bwd)
+    return nan_past_the_groups, poisoned
+
+
+@pytest.mark.parametrize("bias_name,train_gates,held,score_fn", FAMILY_FLAGS)
+def test_what_the_products_leave_in_rows_of_no_token_reaches_nothing(
+        bias_name, train_gates, held, score_fn, monkeypatch):
+    """``grouped_matmul`` says nothing of the rows past ``sum(group_sizes)``
+    (the kernel never visits them). With NaN written into every one of
+    them, forward and in the backward products, the layer's output and
+    gradients are finite and what they were: the rows are selected away,
+    never multiplied by zero."""
+    sizes, h, p = _layer_of_every_familys_kind(bias_name, train_gates, held, score_fn)
+    w = jax.random.normal(jax.random.PRNGKey(13), h.shape)
+
+    def out_and_grads():
+        def run(p, h):
+            return MoeLayer(sizes).apply({"params": p}, h, mutable=("metrics",))[0]
+
+        return run(p, h), jax.grad(lambda p, h: jnp.sum(run(p, h) * w), argnums=(0, 1))(p, h)
+
+    want = out_and_grads()
+    nan_past_the_groups, poisoned = _products_that_leave_nan_past_their_groups()
+    monkeypatch.setattr(moe, "grouped_matmul", nan_past_the_groups)
+    got = out_and_grads()
+    assert len(poisoned) >= 3  # (the buffer has rows of no token: 4x the mean of a share, or absent experts)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.all(np.isfinite(a))
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("bias_name,train_gates,held,score_fn", [
+    pytest.param("e_score_correction_bias", True, 4, "sigmoid", id="mla_moe-share-one-pass"),
+    pytest.param("", False, 4, "softmax", id="mellum-share-two-passes"),
+])
+def test_the_layer_on_the_chips_way_back_is_the_loop_with_its_gates_rounded(
+        bias_name, train_gates, held, score_fn, monkeypatch):
+    """What every training and prefill program runs on the chip and no
+    other case here does: bf16 rows that go back through the collecting
+    kernel (interpreted), behind gates rounded to bf16, with NaN in every
+    row the products do not visit. Output and every gradient against the
+    loop, whose rows meet their gates rounded to bf16."""
+    sizes, h, p = _layer_of_every_familys_kind(bias_name, train_gates, held, score_fn, jnp.bfloat16, tokens=64)
+    calls = _interpret_the_collecting_kernel(monkeypatch)
+    nan_past_the_groups, poisoned = _products_that_leave_nan_past_their_groups()
+    monkeypatch.setattr(moe, "grouped_matmul", nan_past_the_groups)
+    w = jax.random.normal(jax.random.PRNGKey(13), h.shape)
+
+    def run(p, h):
+        return MoeLayer(sizes).apply({"params": p}, h, mutable=("metrics",))[0].astype(jnp.float32)
+
+    def loop(p, h):  # in float32, over what the layer's products read: matrices and rows rounded to bf16
+        matrices = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32) if a.ndim >= 2 else a, p)
+        return _token_by_token(sizes, {**matrices, "w_router": p["w_router"]}, h.astype(jnp.float32), jnp.bfloat16)
+
+    tol = TOLERANCES["bfloat16"]
+    got, want = run(p, h), loop(p, h)
+    assert np.all(np.isfinite(got))
+    assert float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want))) < tol["loss"]
+    grads = jax.grad(lambda p, h: jnp.sum(run(p, h) * w), argnums=(0, 1))(p, h)
+    r_grads = jax.grad(lambda p, h: jnp.sum(loop(p, h) * w), argnums=(0, 1))(p, h)
+    assert len(calls) >= 2 and len(poisoned) >= 3  # (the kernel took the rows back, forward and backward)
+    flat, r_flat = (jax.tree_util.tree_flatten_with_path(g)[0] for g in (grads, r_grads))
+    for (path, g), (_, r) in zip(flat, r_flat):
+        assert np.all(np.isfinite(g.astype(np.float32))), jax.tree_util.keystr(path)
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - r))) / max(float(jnp.max(jnp.abs(r))), 1e-6)
+        assert err < tol["grad"], f"{jax.tree_util.keystr(path)}: {err}"
 
 
 @pytest.fixture()

@@ -271,10 +271,10 @@ def test_score_functions_and_shared_gate_against_routes_contract(score_fn, share
     got = layer.apply({"params": p}, x, mutable=("metrics",))[0]
     xf = x.reshape(-1, 32)
     scores = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score_fn](xf @ p["w_router"])
-    idx, gate_of_expert = route(scores, 0.0, sizes.top_k, True, 1.0)
-    chosen = jnp.zeros_like(scores).at[jnp.arange(xf.shape[0])[:, None], idx].set(1.0)
-    np.testing.assert_allclose(jnp.sum(gate_of_expert * chosen, axis=-1), 1.0, atol=1e-6)
-    want = sum((gate_of_expert * chosen)[:, e:e + 1] * ref.swiglu(xf, p["w_gate"][e], p["w_up"][e], p["w_down"][e])
+    idx, gates = route(scores, None, sizes.top_k, True, 1.0)  # the chosen experts' alone, [N, k]
+    np.testing.assert_allclose(jnp.sum(gates, axis=-1), 1.0, atol=1e-6)
+    gate_of_expert = jnp.zeros_like(scores).at[jnp.arange(xf.shape[0])[:, None], idx].set(gates)
+    want = sum(gate_of_expert[:, e:e + 1] * ref.swiglu(xf, p["w_gate"][e], p["w_up"][e], p["w_down"][e])
                for e in range(sizes.n_experts))
     s = p["shared"]
     shared = ref.swiglu(xf, s["w_gate"], s["w_up"], s["w_down"])

@@ -151,12 +151,12 @@ def test_grouped_expert_products_compile_for_v5e(
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def experts(x, w_gate, w_up, w_down, token_of, sizes):
-        n_valid = jnp.sum(sizes)
-        xs = gm.spread_rows(x, token_of, n_valid)
+        moves = gm.row_order(token_of, jnp.sum(sizes), tokens)
+        xs = gm.spread_rows(x, moves)
         h = jax.nn.silu(gm.grouped_matmul(xs, w_gate, sizes)) * (
             gm.grouped_matmul(xs, w_up, sizes))
         ys = gm.grouped_matmul(h, w_down, sizes)
-        return gm.collect_rows(ys, token_of, n_valid, tokens)
+        return gm.collect_rows(ys, moves)
 
     def loss_grads(*args):
         return jax.grad(
@@ -747,28 +747,34 @@ def test_qwen3_next_served_programs_compile_for_v5e(
             _described(engine._state, one_chip), (row,) * k, (i32,) * k, (i32,) * k, (i32,) * k)))
 
 
-# sha256 of the parent's lowered text (commit 452bf4c, before ``MoeSizes``
-# grew a score function and a shared gate and ``_masked_attention`` an
-# unprojected return for the ``qwen3_next`` family): the served families that
-# share ``MoeLayer``, ``cached_decode_attention``, ``real_neighbours`` and the
-# engine keep the parent's programs, text for text, and so did the trained
-# ``mla_moe`` model's forward and backward until PR 44 changed it on purpose.
+# sha256 of the lowered text of the programs of the families that share
+# ``MoeLayer``, ``cached_decode_attention``, ``real_neighbours`` and the
+# engine: a PR that does not mean to change them finds them here, text for
+# text. The granite pair is commit 452bf4c's (before ``MoeSizes`` grew a score
+# function and a shared gate and ``_masked_attention`` an unprojected return
+# for the ``qwen3_next`` family) and has held since. **Every program with a
+# ``MoeLayer`` in it was taken anew in PR 57, on purpose**: the layer moves
+# its rows without the mask pass on the way in, reads the chosen scores from
+# the top-k and carries gates and index vectors along its sorts
+# (``test_mellum_cells_expert_layer_touches_its_row_buffer_once_a_move`` says
+# what the new text must and must not hold; the values are the parent's:
+# ``tests/test_serving_state_cache.py: PARENT_STREAMS``, ``tests/test_mla_moe.py``).
+# Their parents' (57f0678): lfm2 prefill 71d26a6e..., chunk 4790e7e8...; joyai
+# 9402bb6e... and, nothing kept, 3f49fce6... (623ba44's own); qwen3-next prefill
+# f9c8a75c..., chunk 44ebf5c3... (623ba44's, held again in PR 48: a served share
+# sets no ``train_gates``, so its buffer stays 4x the mean load, ``N x K`` rows,
+# and no overflow branch enters its programs: still so).
 PARENT_FAMILY_PROGRAMS = {
-    "lfm2-24b-a2b-l10.prefill": "71d26a6e5345e4ae386cf7c9def6e19867488806242331808febac189b826037",
-    "lfm2-24b-a2b-l10.chunk": "4790e7e85494130771257fb9f5d72c39fb01b3ecc0e8b93bad133b55518fa381",
+    "lfm2-24b-a2b-l10.prefill": "3c9a96d28de81445a0d41c7777400d42cd670c6523e989f917159a707eaa6ce1",
+    "lfm2-24b-a2b-l10.chunk": "3c1902029dfdc270ad6a8f1e4776176b65ff7ae3861f7d1601c99488ab568d5c",
     "granite-4.0-h-micro.prefill": "d7b20c70771f80c106e7e7b5c264e7c5967ccbc440143b6479609c3e11e8fa38",
     "granite-4.0-h-micro.chunk": "53441fb221dd45af451d27b8513c5653328d326284c25b0f283ef47bc9ac2ce1",
-    # taken anew in PR 44, whose blocks keep their flash kernel's two results
-    # (the parent's, 623ba44: 3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294,
-    # which the same model still lowers to with the block's policy set back)
-    "joyai-llm-flash-ep16.loss_and_grads": "9402bb6e3df9dfc63616451b28715df93e0301a20b27d2b63763baaeb00e1789",
-    "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "3f49fce6d7726508724858a58593b5f6834079fb22fb7e9d9496fde1cdffc294",
-    # taken on commit 623ba44 (PR 44, before any program file was edited): the
-    # cell PR 43's check lost a run of, one PR old then and without a pin. Held
-    # again in PR 48: a served share sets no ``train_gates``, so its buffer stays
-    # 4x the mean load, ``N x K`` rows, and no overflow branch enters its programs
-    "qwen3-next-80b-a3b-ep4-l12.prefill": "f9c8a75c113d4e719823fa099aa71facd0149de68aae8b83e1dcd4c170be3915",
-    "qwen3-next-80b-a3b-ep4-l12.chunk": "44ebf5c33ce41eb26bf15dc39579325b109c321d2417a67d811693f1036d8034",
+    # the blocks keep their flash kernel's two results (PR 44); the second is
+    # the same model with the block's policy set back to keeping nothing
+    "joyai-llm-flash-ep16.loss_and_grads": "5294ed1a7af7f44801fff16f42ad2403a3412b3e01fcada75209e87512620ab5",
+    "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "5844f3eed7061b9dc01a3be268faebda56595716638a19098e278faad1c9927a",
+    "qwen3-next-80b-a3b-ep4-l12.prefill": "35056d2c1a442ee1ebb6aa16f6259ea0c6b24d9d0b66eb501dd1292c832d22e3",
+    "qwen3-next-80b-a3b-ep4-l12.chunk": "7ae41b1230ae0b24733d230497ee8c6a964e0fcca34d76e14ad8146f864bd913",
 }
 
 
@@ -794,7 +800,8 @@ def test_served_families_programs_are_the_parents(
     """``lfm2-moe-serve-rollout-16`` and ``granite-h-micro-serve-chat``: the
     widest prefill and the chunk of the servers built from the benchmark's
     configurations (the depth cut as in the test above), lowered for the
-    described chip: the parent's text."""
+    described chip: the pinned text (granite's the parent's still; the two
+    with a ``MoeLayer`` taken anew in PR 57)."""
     from dlrover_tpu.models.build import build_model
     from dlrover_tpu.models.generation import SamplingConfig
     from dlrover_tpu.models.serving import ContinuousBatchingEngine
@@ -839,7 +846,8 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
     ``reduce_precision`` on the kept ``out``. With the block's policy set
     back to ``nothing_saveable`` and the two names taken out of ``_fa_fwd``
     (they emit no operation, but number later private functions one higher)
-    the text is the parent's to the character."""
+    the text was PR 42's to the character until PR 57 changed ``MoeLayer``'s
+    part of both on purpose; both pins are PR 57's."""
     import collections
     import re
 
@@ -867,9 +875,13 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
 
     text = jax.jit(loss_and_grads).lower(_described(params, one_chip), tokens, tokens).as_text()
     names = collections.Counter(re.findall(r'kernel_name = "([^"]+)"', text))
-    assert names.pop("kernel") == 13  # megablox's, the same on both sides
+    # megablox's, the same on both sides: 13 until PR 57, whose gates go to their
+    # rows along the layer's sort and come back along it (the collecting kernel
+    # over ``f32[rows, 256]`` behind the spread gates is gone)
+    assert names.pop("kernel") == 12
     assert names == flash_kernels
-    assert text.count("stablehlo.reduce_precision") == (3 if kept == "flash_results" else 0)
+    # (on the kept ``out``, bf16; the expert layers' float32 gates are rounded by the same operation since PR 57)
+    assert len(re.findall(r"stablehlo\.reduce_precision.*xbf16>", text)) == (3 if kept == "flash_results" else 0)
     assert _sha256(text) == PARENT_FAMILY_PROGRAMS[pin], _sha256(text)
 
 
@@ -949,6 +961,84 @@ def test_windowed_flash_kernels_compile_at_the_mellum_cells_shape(
     plan = fa._kernel_plan(True, 8192, 8192, 1024, 1024, 256, window)
     assert (plan["path"], plan["tiles_run"], plan["tiles_visited"]) == (path, tiles_run, 64)
     assert spans.process_accumulator().stats()["flash.kernel_built"].count >= 3
+
+
+def test_mellum_cells_expert_layer_touches_its_row_buffer_once_a_move(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """``mellum2-train-ep4share``'s ``MoeLayer`` alone, forward and backward,
+    at the cell's sizes (16,384 tokens x top-8 = 131,072 assignments, a buffer
+    of 65,536 rows x 2,304, 16 of 64 experts held, two passes of which the
+    second stands under a ``cond``), compiled for the described chip. What
+    the compiled program holds, counted by instruction (PR 57):
+
+    - nine gathers over ``bf16[65536,2304]`` (a pass moves its rows in twice
+      and out twice, forward and backward, and the overflow pass's backward
+      spreads once more to run its products again) and **four** selects over
+      the buffer: one for each collecting kernel, on the way out, each inside
+      the fusion that makes the rows it masks (the product with the gates, the
+      sum of the two cotangents), so none is a pass of its own. The way in has
+      none; the parent had one behind every gather, nine, each its own pass.
+    - no gather whose result is ``s32[65536]`` (the parent: six, ``key[taken]``
+      and ``token[by_token]``, 0.31 ms each on the chip) and none that takes
+      the chosen scores ``f32[16384,8]`` out of ``f32[16384,64]``, nor a row
+      of ``[rows, 64]`` gates: the index vectors come out of the sorts that
+      made them, the chosen scores out of the top-k that chose them, a row's
+      gate along the layer's one sort.
+    - the program as traced sorts once a layer and once a pass: four ``sort``
+      equations (the layer's, the first pass's, the overflow pass's in the
+      forward pass and where its backward runs it again); the parent's
+      ``_collect`` sorted anew in each direction, five."""
+    import re
+
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.moe import MoeLayer
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    model, _ = build_model(_benchmark_model_entry("mellum2-12b-a2.5b-ep4-l4"))
+    sizes = model.config.moe_sizes
+    assert (sizes.n_experts, sizes.experts_here, sizes.top_k, sizes.bias_name) == (64, 16, 8, "")
+    layer = MoeLayer(sizes)
+    shape = (2, 8192, model.config.hidden_size)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    params = _described(jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(shape, jnp.bfloat16))["params"]), one_chip)
+
+    def loss_grads(p, x):
+        def loss(p, x):
+            out = layer.apply({"params": p}, x, mutable=("metrics",))[0]
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.grad(loss, argnums=(0, 1))(p, x)
+
+    def sorts(jaxpr):
+        """``sort`` equations in a jaxpr and every jaxpr its equations carry."""
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += eqn.primitive.name == "sort"
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    n += sorts(sub) if hasattr(sub, "eqns") else 0
+        return n
+
+    assert sorts(jax.make_jaxpr(loss_grads)(params, x).jaxpr) == 4
+    text = jax.jit(loss_grads).lower(params, x).compile().as_text()
+
+    def count(instruction, result):
+        return len(re.findall(rf"= {re.escape(result)}\S* {instruction}\(", text))
+
+    assert count("gather", "bf16[65536,2304]") == 9
+    assert count("custom-call", "bf16[128,128,2304]") == 4  # the collecting kernel, 128 tiles of 128 tokens
+    assert count("select", "bf16[65536,2304]") == 4
+    selecting = [c for c in text.split("\n\n") if re.search(r"= bf16\[65536,2304\]\S* select\(", c)]
+    assert len(selecting) == 4 and all(re.search(r" (multiply|add)\(", c) for c in selecting)
+    assert "[131072,2304]" not in text
+    assert count("gather", "s32[65536]") == 0
+    assert count("gather", "f32[16384,8]") == 0  # (the parent: one, ``take_along_axis(scores, idx)``)
+    assert count("gather", "f32[65536,64]") == 0  # (the parent: three, a row of gates behind every ``token_of``)
+    assert len(re.findall(r" conditional\(", text)) == 2
 
 
 def test_mellum_cells_whole_step_fits_one_v5e(topo, on_chip_kernels, monkeypatch, request):

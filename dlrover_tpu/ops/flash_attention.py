@@ -42,11 +42,16 @@ Two sets of kernels, chosen from the call's static shapes in one place
 number of blocks, the walked kernels run only the band: the diagonal tile
 as above, the tile ``w / block`` tiles before it in the same sub-blocks
 with the complementary mask (``key_local > row_local``), the tiles between
-the two unmasked, and no tile further back is run or fetched (the index
-maps repeat the nearest tile that runs). Any other windowed call takes the
-general kernels with the window as one more term of their mask. Without a
-window, or with one that cuts nothing off, every program is what it was
-before the band existed, text for text.
+the two unmasked, and no tile further back is run or fetched: the grid
+itself is the band, ``(heads, tiles, w / block + 1)``, whose inner step
+``j`` names the band's ``j``-th tile (:func:`_band_step`). Only where the
+band hangs over the sequence's edge (the first ``w / block`` row tiles, the
+last ``w / block`` key tiles) does a step name a tile that does not exist:
+its index maps repeat the nearest tile that does, which comes next or is
+there already, and its body does not run. Any other windowed call takes
+the general kernels with the window as one more term of their mask. Without
+a window, or with one that cuts nothing off, every program is what it was
+before the band existed, text for text: the grid ``(heads, tiles, tiles)``.
 
 Two head sizes: q and k share ``d`` (the score's contraction), v and the
 output share ``d_v``, and the two may differ (latent attention:
@@ -309,7 +314,7 @@ def _walk_causal(
     ``band``: a window of ``band`` whole tiles. Tile ``iq - band`` is the
     band's far end and is walked like the diagonal's with the complementary
     mask (:func:`_diagonal_pieces`); the tiles between the two carry no
-    mask; a tile further back is neither run nor fetched."""
+    mask; a tile further back is no grid step (:func:`_band_step`)."""
 
     def masked_tile(far: bool = False):
         key = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
@@ -356,11 +361,16 @@ def _kernel_plan(
     """What one head's grid does, for the ``flash.kernel_built`` record:
     grid steps, steps whose body runs, and the scores computed as a share
     of the ``t_q x t_kv`` square (the mask itself keeps 0.5 of it, or with
-    a ``window`` (:func:`_window_of`) ``window / t`` less half its square)."""
+    a ``window`` (:func:`_window_of`) ``window / t`` less half its square).
+    ``tiles_visited`` is the grid as built: under a band ``band + 1`` steps
+    a tile, so visited less run is the steps that hang over the edge."""
     n_q = _round_up(t_q, block_q) // block_q
     n_k = _round_up(t_kv, block_k) // block_k
+    visited = n_q * n_k
     if sub:
         band = window // block_q if window else n_q  # tiles back to the far one
+        if window:
+            visited = n_q * (band + 1)
         masked = n_q + max(n_q - band, 0)  # the diagonal's, and the far ones
         tiles_run = sum(min(i, band) + 1 for i in range(n_q))
         scores = (tiles_run - masked) * block_q * block_k + masked * sum(
@@ -378,7 +388,7 @@ def _kernel_plan(
         scores = tiles_run * block_q * block_k
     plan = {
         "path": ("window_tiled" if window else "causal_tiled") if sub else "general",
-        "tiles_visited": n_q * n_k,
+        "tiles_visited": visited,
         "tiles_run": tiles_run,
         "score_share": scores / (t_q * t_kv),
     }
@@ -399,14 +409,53 @@ def _as_row(ref):
     return ref[0].T[:1]
 
 
+def _inner_steps(n_tiles: int, band: int) -> int:
+    """The inner extent of a walked kernel's grid: the band's steps, or
+    every tile where no window cuts the walk short."""
+    return band + 1 if band else n_tiles
+
+
+def _band_step(n_tiles: int, band: int, by_keys: bool):
+    """What grid step ``(head, it, inner)`` of a walked kernel is: ``(iq, ik,
+    inner, last, live)``, the tile pair it names, the inner step, the inner
+    axis' last step, and whether the pair exists (None: every one does).
+
+    Without a band the inner axis is the streamed tiles themselves, ``n_tiles``
+    of them. Under a window of ``band`` tiles it is the band, ``band + 1``
+    steps: rows streamed over keys (forward, dq) meet key tile ``iq - band +
+    inner``, far tile first and diagonal last; keys streamed over rows
+    (dk/dv) meet row tile ``ik + inner``, diagonal first and far tile last.
+    The first ``band`` row tiles' bands start before key tile 0 and the last
+    ``band`` key tiles' end past row tile ``n_tiles - 1``: those steps are
+    not ``live``, and :func:`_keys_at` / :func:`_rows_at` clamp them."""
+    it, inner = pl.program_id(1), pl.program_id(2)
+    if not band:
+        streamed, live = inner, None
+    elif by_keys:
+        streamed = it + inner
+        live = streamed <= n_tiles - 1
+    else:
+        streamed = it - band + inner
+        live = streamed >= 0
+    iq, ik = (streamed, it) if by_keys else (it, streamed)
+    return iq, ik, inner, _inner_steps(n_tiles, band) - 1, live
+
+
+def _walk_live(live, walk):
+    """Run ``walk`` where the step's tile pair exists."""
+    if live is None:
+        walk()
+    else:
+        pl.when(live)(walk)
+
+
 def _walk_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, sm_scale: float, block: int, sub: int, n_tiles: int, band: int = 0,
 ):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    iq, ik, inner, last, live = _band_step(n_tiles, band, False)
 
-    @pl.when(ik == 0)
+    @pl.when(inner == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -435,9 +484,9 @@ def _walk_fwd_kernel(
         )
         acc_ref[:, rows] = acc_ref[:, rows] * alpha + pv
 
-    _walk_causal(body, iq, ik, block, sub, n_tiles, False, band)
+    _walk_live(live, lambda: _walk_causal(body, iq, ik, block, sub, n_tiles, False, band))
 
-    @pl.when(ik == n_tiles - 1)
+    @pl.when(inner == last)
     def _finish():
         l = l_ref[:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -452,11 +501,11 @@ def _walk_bwd_kernel(
 ):
     """dk/dv (``by_keys``: grid ``(head, key tile, q tile)``, outputs and
     accumulators ``[keys, d]`` and ``[keys, d_v]``) or dq (grid ``(head, q
-    tile, key tile)``, its accumulator held transposed, ``[d, rows]``)."""
+    tile, key tile)``, its accumulator held transposed, ``[d, rows]``); under
+    a band the inner axis is the band's steps (:func:`_band_step`)."""
     n_out = len(outs_and_scratch) // 2
     outs, accs = outs_and_scratch[:n_out], outs_and_scratch[n_out:]
-    it, inner = pl.program_id(1), pl.program_id(2)
-    iq, ik = (inner, it) if by_keys else (it, inner)
+    iq, ik, inner, last, live = _band_step(n_tiles, band, by_keys)
     # One tile a head (T <= the block): each sub-block's result is whole
     # after its one piece and is written where it goes; no accumulator,
     # nothing to start or finish.
@@ -505,11 +554,11 @@ def _walk_bwd_kernel(
             else:
                 accs[0][:, rows] += dq
 
-    _walk_causal(body, iq, ik, block, sub, n_tiles, by_keys, band)
+    _walk_live(live, lambda: _walk_causal(body, iq, ik, block, sub, n_tiles, by_keys, band))
     if one_pass:
         return
 
-    @pl.when(inner == n_tiles - 1)
+    @pl.when(inner == last)
     def _finish():
         if by_keys:
             for out, acc in zip(outs, accs):
@@ -535,12 +584,25 @@ _walk_jit = functools.partial(
 
 def _keys_at(band: int):
     """The key tile grid step ``(b, i, j)`` names, rows streamed over keys
-    (forward, dq). A tile above the diagonal names the diagonal's blocks:
-    no copy. Under a window of ``band`` tiles, a tile before the band names
-    the band's first, which comes next and is fetched once."""
+    (forward, dq). With no band ``j`` is the key tile, and a tile above the
+    diagonal names the diagonal's blocks: no copy. Under a window of ``band``
+    tiles ``j`` is a step of the band (:func:`_band_step`) and names key tile
+    ``i - band + j``; a step before key tile 0 names tile 0, which comes
+    next and is fetched once."""
     if not band:
         return lambda b, i, j: (b, jnp.minimum(j, i), 0)
-    return lambda b, i, j: (b, jnp.clip(j, jnp.maximum(i - band, 0), i), 0)
+    return lambda b, i, j: (b, jnp.maximum(i - band + j, 0), 0)
+
+
+def _rows_at(band: int, n_tiles: int):
+    """The row tile grid step ``(b, j, i)`` names, keys streamed over rows
+    (dk/dv: q, do, lse and delta alike). With no band ``i`` is the row tile,
+    and a tile above the diagonal names the diagonal's blocks, which come
+    next: no copy. Under a band ``i`` is a step of it and names row tile ``j
+    + i``; a step past the last row tile names the last, which is there."""
+    if not band:
+        return lambda b, j, i: (b, jnp.maximum(i, j), 0)
+    return lambda b, j, i: (b, jnp.minimum(j + i, n_tiles - 1), 0)
 
 
 @_walk_jit
@@ -557,7 +619,7 @@ def _walk_fwd(
             _walk_fwd_kernel, sm_scale=sm_scale, block=block, sub=sub, n_tiles=n,
             band=band,
         ),
-        grid=(bh, n, n),
+        grid=(bh, n, _inner_steps(n, band)),
         in_specs=[
             _vmem_spec((1, block, d), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block, d), at_k),
@@ -588,17 +650,13 @@ def _walk_dkdv(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret, band
     d_v = v.shape[2]
     n = t // block
     per_row = (1, block, _LSE_LANES)
-    # the streamed axis: a tile above the diagonal names the diagonal's
-    # blocks, which come next: no copy
-    rows_at = lambda b, j, i: (b, jnp.maximum(i, j), 0)
-    if band:  # a row tile past the band names the band's last, which is there
-        rows_at = lambda b, j, i: (b, jnp.clip(i, j, jnp.minimum(j + band, n - 1)), 0)
+    rows_at = _rows_at(band, n)
     return pl.pallas_call(
         functools.partial(
             _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
             n_tiles=n, by_keys=True, band=band,
         ),
-        grid=(bh, n, n),
+        grid=(bh, n, _inner_steps(n, band)),
         in_specs=[
             _vmem_spec((1, block, d), rows_at),
             _vmem_spec((1, block, d), lambda b, j, i: (b, j, 0)),
@@ -636,7 +694,7 @@ def _walk_dq(q, k, v, do, lse, delta, *, sm_scale, block, sub, interpret, band=0
             _walk_bwd_kernel, sm_scale=sm_scale, block=block, sub=sub,
             n_tiles=n, by_keys=False, band=band,
         ),
-        grid=(bh, n, n),
+        grid=(bh, n, _inner_steps(n, band)),
         in_specs=[
             _vmem_spec((1, block, d), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block, d), keys_at),

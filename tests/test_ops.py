@@ -319,9 +319,13 @@ class TestFlashAttentionWindow:
     # blocks of 256 (one sub-block a tile) and 512 (a tile walked in 2 x 2
     # sub-squares, so both masked pieces and an unmasked one): T of 2, 4 and
     # 8 blocks, a window of one block and of two
+    # ... and T of 3 blocks under a window of 2 (``band`` 2 of ``n`` 3): row
+    # tile 0's band hangs two steps over the sequence's edge and row tile 1's
+    # one, key tiles 2 and 1 the same at their other end
     @pytest.mark.parametrize("blocks_back", [1, 2])
     @pytest.mark.parametrize("t,block", [(512, 256), (1024, 256), (2048, 256),
-                                         (1024, 512), (2048, 512), (4096, 512)])
+                                         (1024, 512), (2048, 512), (4096, 512),
+                                         (768, 256)])
     def test_band_matches_reference(self, t, block, blocks_back, monkeypatch):
         window = blocks_back * block
         keys = jax.random.split(jax.random.PRNGKey(t + window), 4)
@@ -337,6 +341,41 @@ class TestFlashAttentionWindow:
         for a, b, atol in zip(got, want, (2e-5, 5e-5, 5e-5, 5e-5)):
             assert a.shape == b.shape
             np.testing.assert_allclose(a, b, atol=atol)
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkdv"])
+    @pytest.mark.parametrize("window,inner", [
+        (256, 2), (512, 3),  # a band of 1 and of 2 tiles: ``band + 1`` steps
+        (None, 4), (1024, 4),  # no window, and one that cuts nothing off: ``n``
+    ])
+    def test_the_grid_is_the_band(self, kernel, window, inner):
+        """The walked kernels' grids as ``jax.make_jaxpr`` shows them, T 1,024
+        in blocks of 256 (``n`` 4) for 2 heads: ``(bh, n, band + 1)`` under a
+        band, the parent's ``(bh, n, n)`` where there is none."""
+        t, block, d, d_v = 1024, 256, 16, 32
+        qk = jax.ShapeDtypeStruct((1, t, 2, d), jnp.float32)
+        v = jax.ShapeDtypeStruct((1, t, 2, d_v), jnp.float32)
+        attend = lambda q, k, v: flash_attention(q, k, v, True, None, block, block, window).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(attend, (0, 1, 2)))(qk, qk, v).jaxpr
+        grids = {}
+        for eqn in jaxpr.eqns:  # the walk's jitted wrappers are inlined
+            if eqn.primitive.name != "pallas_call":
+                continue
+            outs = [a.shape for a in eqn.params["out_avals"]]
+            # dq is one result; dk/dv are two ``[bh, t, .]``; the forward's
+            # second is its lse as rows, ``[bh, 8, t]``
+            name = "dq" if len(outs) == 1 else "dkdv" if outs[1] == (2, t, d_v) else "fwd"
+            assert name not in grids
+            grids[name] = eqn.params["grid_mapping"].grid
+        assert sorted(grids) == ["dkdv", "dq", "fwd"]
+        n = t // block
+        assert grids[kernel] == (2, n, inner)
+        band = window // block if window and window < t else 0
+        plan = fa._kernel_plan(True, t, t, block, block, 256, window if band else None)
+        assert plan["tiles_visited"] == n * inner
+        # what the grid visits and does not run: the band's steps over the
+        # sequence's edge, or with no band the tiles above the diagonal
+        assert plan["tiles_visited"] - plan["tiles_run"] == (
+            band * (band + 1) // 2 if band else n * (n - 1) // 2)
 
     @pytest.mark.parametrize("t,block,window", [
         (1000, 512, 300),  # a ragged T

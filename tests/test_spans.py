@@ -658,15 +658,18 @@ def test_tracing_the_gpt2_small_step_books_every_flash_kernel(tmp_path, monkeypa
 def test_tracing_a_windowed_layer_books_the_band(tmp_path, kernel):
     """``flash.kernel_built`` under a window, at the shape of the
     ``mellum2-train-ep4share`` cell's window layers (T 8,192 in tiles of
-    1,024, a window of one tile): a row tile runs 2 key tiles and not 8, so a
-    head's grid runs 15 of its 64 steps and computes 0.146 of the square
-    (the mask itself leaves 0.117; the causal walk computes 0.516, beside the
-    0.625 / 0.53125 of T 1,024 / 4,096 held above). The span carries the
-    plan and the stat ``window``; a full layer's carries none."""
+    1,024, a window of one tile): a row tile runs 2 key tiles and not 8, and
+    ``tiles_visited`` is the grid as built (PR 58: ``band + 1`` = 2 steps a
+    row tile, 16 a head, where the square grid had 64), so a head's grid runs
+    15 of its 16 steps, the one left hanging over the sequence's edge, and
+    computes 0.146 of the square (the mask itself leaves 0.117; the causal
+    walk computes 0.516 in 36 of 64 steps, beside the 0.625 / 0.53125 of T
+    1,024 / 4,096 held above). The span carries the plan and the stat
+    ``window``; a full layer's carries none."""
     from dlrover_tpu.ops import flash_attention as fa
 
     plan = fa._kernel_plan(True, 8192, 8192, 1024, 1024, 256, 1024)
-    assert plan == {"path": "window_tiled", "tiles_visited": 64, "tiles_run": 15,
+    assert plan == {"path": "window_tiled", "tiles_visited": 16, "tiles_run": 15,
                     "score_share": (8 * 10 + 7 * 10) / 16 / 64, "window": 1024}
     assert plan["score_share"] == pytest.approx(0.146, abs=5e-4)
     mask_share = (1024 * 1025 // 2 + 7168 * 1024) / 8192**2
@@ -685,10 +688,11 @@ def test_tracing_a_windowed_layer_books_the_band(tmp_path, kernel):
     built = [st for _, _, _, st in found["flash.kernel_built"] if st["kernel"] == kernel]
     banded = [st for st in built if st["path"] == "window_tiled"]
     assert banded and all(
-        (int(st["window"]), st["tiles_run"], st["tiles_visited"]) == (1024, 15, 64)
+        (int(st["window"]), st["tiles_run"], st["tiles_visited"]) == (1024, 15, 16)
         and float(st["score_share"]) == plan["score_share"] for st in banded)
     causal = [st for st in built if st["path"] == "causal_tiled"]
-    assert causal and all("window" not in st and st["tiles_run"] == 36 for st in causal)
+    assert causal and all(
+        "window" not in st and (st["tiles_run"], st["tiles_visited"]) == (36, 64) for st in causal)
 
 
 @pytest.mark.parametrize("overlap", [False, True])

@@ -473,6 +473,14 @@ PARENT_PROGRAMS = {
     # the XL server's 512-wide ``prefill_row`` (855,523 characters and no
     # kernel: a prefill runs in decode mode over the cache, dense)
     "xl_prefill_row_512": "0bf1b673e1f4f51d2ef0e410dc97ce4f1e3f46286f1ef95a44217fa4ba6e12ad",
+    # the causal walk with no window, forward and backward, where a head is
+    # several tiles (taken from commit 7399eb8 in PR 58, before a window's
+    # band became the walked kernels' grid: with ``band == 0`` the grid stays
+    # ``(heads, tiles, tiles)`` and these stay): ``joyai``'s call as
+    # ``mla.attend`` makes it (4 tiles a head, q/k 192 wide, v 128) and
+    # ``mellum2``'s full layer (8 tiles a head)
+    "walk_fwd_bwd_b4_t4096_h32_d192_dv128": "8c3c93eeef99f28e3b3ebaae7e201557d4cde91ecb6e8161b48e2ef889248d18",
+    "walk_fwd_bwd_b2_t8192_h32_d128": "e5d9528a7560a771b2eef4b229181c18d96cb33accbeb25a8757299d90cabff2",
 }
 
 
@@ -515,6 +523,37 @@ def test_calls_outside_the_walk_lower_to_the_parents_program(
     fn = fwd_bwd if program.startswith("fwd_bwd") else fwd
     text = jax.jit(fn).lower(q, kv, kv).as_text()
     assert "_walk_" not in text
+    assert _sha256(text) == PARENT_PROGRAMS[program]
+
+
+@pytest.mark.parametrize("window", [None, 8192], ids=["no_window", "window_cuts_nothing"])
+@pytest.mark.parametrize("program", [n for n in PARENT_PROGRAMS if n.startswith("walk")])
+def test_the_causal_walk_with_no_band_lowers_to_the_parents_program(
+    program, window, one_chip, on_chip_kernels, no_locations
+):
+    """The walked kernels take their grid from the window's band (PR 58).
+    With no window, and with a window that cuts nothing off (T or more keys),
+    the band is 0 and the three kernels of each call are the parent's, text
+    for text, Mosaic bodies included."""
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, k, v = {
+        "walk_fwd_bwd_b4_t4096_h32_d192_dv128": (
+            shaped(4, 4096, 32, 192), shaped(4, 4096, 32, 192), shaped(4, 4096, 32, 128)),
+        "walk_fwd_bwd_b2_t8192_h32_d128": (shaped(2, 8192, 32, 128),) * 3,
+    }[program]
+
+    def fwd(q, k, v):  # the text carries the functions' names: the pinned ones'
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    text = jax.jit(fwd_bwd).lower(q, k, v).as_text()
+    assert text.count("_walk_") >= 3 and text.count("tpu_custom_call") == 3
     assert _sha256(text) == PARENT_PROGRAMS[program]
 
 
@@ -941,14 +980,17 @@ def test_joyai_cells_whole_step_fits_beside_the_kept_flash_results(
 # -- the window band (PR 47) ---------------------------------------------------
 
 
-@pytest.mark.parametrize("window,path,tiles_run", [(1024, "window_tiled", 15), (None, "causal_tiled", 36)])
+@pytest.mark.parametrize("window,path,tiles_run,tiles_visited", [
+    (1024, "window_tiled", 15, 16), (None, "causal_tiled", 36, 64)])
 def test_windowed_flash_kernels_compile_at_the_mellum_cells_shape(
-    window, path, tiles_run, one_chip, on_chip_kernels
+    window, path, tiles_run, tiles_visited, one_chip, on_chip_kernels
 ):
     """``mellum2-train-ep4share``'s attention as its layers call it, b2 x
     8192, 32 heads of 128, forward and backward, for the described chip: the
-    window layers' band (2 key tiles a row tile, 15 of 64 a head) and the
-    full layer's causal walk at 8 tiles a head, a shape no other cell runs."""
+    window layers' band (2 key tiles a row tile; ``tiles_visited`` is the
+    grid as built, ``band + 1`` steps a row tile: 15 of 16 a head run) and
+    the full layer's causal walk at 8 tiles a head (36 of 64), a shape no
+    other cell runs."""
     x = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
 
     def attend(q, k, v):
@@ -959,7 +1001,8 @@ def test_windowed_flash_kernels_compile_at_the_mellum_cells_shape(
     compiled = jax.jit(jax.grad(attend, (0, 1, 2))).lower(x, x, x).compile()
     assert _kernel_text(compiled).count("tpu_custom_call") >= 3
     plan = fa._kernel_plan(True, 8192, 8192, 1024, 1024, 256, window)
-    assert (plan["path"], plan["tiles_run"], plan["tiles_visited"]) == (path, tiles_run, 64)
+    assert (plan["path"], plan["tiles_run"], plan["tiles_visited"]) == (
+        path, tiles_run, tiles_visited)
     assert spans.process_accumulator().stats()["flash.kernel_built"].count >= 3
 
 

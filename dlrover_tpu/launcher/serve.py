@@ -490,6 +490,25 @@ def _restore_params(model, mesh, ckpt_dir: str):
 # ---------------------------------------------------------------------------
 
 
+def _passes_of(c) -> dict:
+    """The done-line's ``passes`` of a model decoded by blocks (the pass of
+    its block at which each token was fixed); nothing for any other."""
+    return {} if c.passes is None else {"passes": c.passes}
+
+
+def _lines_of(new, position: int, blocks) -> list:
+    """What a streamed completion writes a line each of the tokens ``new``,
+    the first of which stands at ``position`` of its sequence: all of them
+    in one, or for a model decoded by blocks (``engine.blocks``) one line a
+    final block: a first block the prompt began and a last one the cap cut
+    are shorter lines."""
+    if blocks is None:
+        return [new]
+    Bl = blocks.block_length
+    cuts = [0] + list(range(Bl - position % Bl, len(new), Bl)) + [len(new)]
+    return [new[a:b] for a, b in zip(cuts, cuts[1:]) if b > a]
+
+
 def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                   role="decode"):
     from ..common.http import JsonRequestHandler
@@ -582,7 +601,11 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                 while time.monotonic() < deadline:
                     toks, finished = daemon.partial(uid)
                     if len(toks) > sent:
-                        chunk({"uid": uid, "tokens": toks[sent:]})
+                        for new in _lines_of(
+                            toks[sent:], len(prompt) + sent,
+                            daemon.eng.blocks,
+                        ):
+                            chunk({"uid": uid, "tokens": new})
                         sent = len(toks)
                     if finished:
                         c = daemon.result(uid, timeout=5.0)
@@ -591,6 +614,7 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                             "done": True,
                             "tokens": c.tokens,
                             "logprobs": c.logprobs,
+                            **_passes_of(c),
                             "queue_s": round(c.queue_s, 4),
                             "ttft_s": round(c.ttft_s, 4),
                             "total_s": round(c.total_s, 4),
@@ -757,6 +781,7 @@ def _make_handler(daemon: ServingDaemon, reload_fn, replica_id=None,
                         "uid": c.uid,
                         "tokens": c.tokens,
                         "logprobs": c.logprobs,
+                        **_passes_of(c),
                         "queue_s": round(c.queue_s, 4),
                         "ttft_s": round(c.ttft_s, 4),
                         "total_s": round(c.total_s, 4),
@@ -887,7 +912,12 @@ def main(argv=None) -> int:
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--top-p", type=float, default=1.0)
     ap.add_argument("--eos-id", type=int, default=-1)
-    ap.add_argument("--decode-chunk", type=int, default=8)
+    ap.add_argument(
+        "--decode-chunk", type=int, default=None,
+        help="steps a decode chunk (passes, for a model decoded by "
+        "blocks), taken as given. Default: the engine's, 8, and for a "
+        "model decoded by blocks the next whole number of blocks (9 at "
+        "2 denoising steps)")
     ap.add_argument(
         "--sync-round", action="store_true",
         help="serve with the host-serialized scheduler round (the "

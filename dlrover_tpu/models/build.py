@@ -7,6 +7,7 @@ imports ``layers``, ``moe``, ``ops/`` and ``parallel/`` and no other family
 (``tests/test_models_layering.py``). What two families share lives in
 ``layers.py``: the gated delta-rule mixer (``layers.GatedDeltaMixer``, built
 by ``qwen3_next`` and ``olmo_hybrid`` from their own sizes), the decode cache
+with its two masks (causal by slot; by blocks of positions for ``sdar_moe``)
 and its three attention products.
 
 **The contract**, written here once: what a family's model may give, and
@@ -23,11 +24,16 @@ who reads it. No base class and no protocol type: a holder probes with
   takes ``targets`` alone. With ``decode=True`` the model reads and writes
   the ``"cache"`` collection (``generation.decode_apply`` is the one place
   that call is spelt): ``positions [B, T]`` absolute, ``kv_valid [B, L]``
-  the cache slots that hold real tokens, ``cache_slots [B]`` a one-token
-  step's per-row write slots (without it the call's tokens go to the
-  shared write offset). Every holder of a multi-token decode call reads
-  ``logits[:, -1]`` alone, and a model may return just that position
-  (``olmo_hybrid``: ``[B, 1, V]``).
+  the cache slots that hold real tokens, ``cache_slots [B]`` per-row write
+  slots: row ``b``'s ``T`` tokens go to slots ``[s_b, s_b + T)`` (without it
+  the call's tokens go to the shared write offset). An autoregressive
+  model's step is such a call of one token; a multi-token decode call may
+  carry ``cache_slots`` too (PR 59: a block's pass,
+  ``layers._update_decode_cache``), and then every position's logits are
+  its holder's to read. Every holder of a multi-token call *without*
+  ``cache_slots`` (a prefill) reads ``logits[:, -1]`` alone, and a model
+  may return just that position (``olmo_hybrid``, ``sdar_moe``:
+  ``[B, 1, V]``).
 - On the config: ``ce_chunk`` (> 0: the train step hands the targets in;
   the size of a loss chunk) and ``takes_targets`` (hand them in whatever
   ``ce_chunk`` says: the model sows terms or counters on the way),
@@ -54,6 +60,19 @@ who reads it. No base class and no protocol type: a holder probes with
   traced inside the decode chunk and read back with its tokens
   (``_build_programs``, ``serving.py:438``). Without it the chunk returns
   no counters.
+- ``decode_blocks()`` -> ``layers.BlockDecoding(block_length,
+  denoising_steps, mask_token_id)``: the model is *decoded a block at a
+  time* (generation by diffusion over blocks, ``models/sdar_moe.py``), its
+  logits at position ``i`` scoring the token at ``i``. The one probe by
+  which ``ContinuousBatchingEngine`` builds the block chunk in the decode
+  chunk's place (``serving.py: make_block_chunk``: a row's block goes
+  through the model at the row's next ``block_length`` slots with
+  ``mask_token_id`` where a position is undecided, and only a block with no
+  undecided position leaves valid keys and values), refuses the paged
+  layout, stored prefixes and the prefill hand-off, and streams a block a
+  line (``launcher/serve.py``). Such a model attends under the mask by
+  blocks of positions (``layers.cached_decode_attention(block_length=)``).
+  Without it the model is decoded a token a step.
 - ``book_step_counters(metrics)`` -> one train step's returned ``metrics``
   booked into the process accumulator (``moe.book_step_counters``), called
   by the holder of the step at a sync
@@ -74,6 +93,7 @@ FAMILIES = {
     "qwen3_next": ("qwen3_next", "Qwen3NextLM", "Qwen3NextConfig"),
     "mellum": ("mellum", "MellumLM", "MellumConfig"),
     "olmo_hybrid": ("olmo_hybrid", "OlmoHybridLM", "OlmoHybridConfig"),
+    "sdar_moe": ("sdar_moe", "SdarMoeLM", "SdarMoeConfig"),
 }
 
 _DTYPE_FIELDS = ("dtype", "param_dtype")

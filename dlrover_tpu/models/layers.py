@@ -13,7 +13,7 @@ family to build it.
 """
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -185,7 +185,8 @@ def _fold_heads(x):
 
 
 def _update_decode_cache(
-    module, max_len, k, v, kv_valid, cache_slots=None, *, fold=False
+    module, max_len, k, v, kv_valid, cache_slots=None, *, fold=False,
+    block_length=0,
 ):
     """Write this call's K/V into the module's decode cache; return the
     full cache plus the attention mask for the queries of this call.
@@ -207,14 +208,24 @@ def _update_decode_cache(
     left-pad); queries at local position i attend valid slots s with
     s <= offset + i.
 
-    ``cache_slots`` int32 ``[B]`` switches to PER-ROW write slots for
-    single-token decode (the continuous-batching engine's per-row
-    cache layout: every request advances its own write position, so
-    admissions leave no holes past a prompt's bucket). The write is a
-    B-row scatter — tiny next to the attention pass that reads the
-    whole cache anyway — and the causal mask keys on each query's own
-    slot (returned mask is [B, 1, max_len]). Requires an explicit
-    ``kv_valid``.
+    ``cache_slots`` int32 ``[B]`` switches to PER-ROW write slots (the
+    continuous-batching engine's per-row cache layout: every request
+    advances its own write position, so admissions leave no holes past a
+    prompt's bucket): row ``b``'s ``T`` tokens go to slots ``[s_b, s_b +
+    T)``, one token a step for an autoregressive model, a block a pass
+    for one decoded by blocks. The write is a B-row scatter — tiny next
+    to the attention pass that reads the whole cache anyway — and the
+    causal mask keys on each query's own slot (returned mask is
+    [B, T, max_len]). Requires an explicit ``kv_valid``.
+
+    ``block_length`` > 0 (a model decoded a block at a time:
+    ``models/sdar_moe.py``) replaces the causal rule by **blocks of
+    positions**, in both write modes: a slot's position is its rank among
+    the row's valid slots (prompts are left-padded, so a slot says nothing
+    of a position), and the query at position ``i`` sees the valid key at
+    position ``j`` iff ``j // block_length <= i // block_length``: causal
+    between blocks, both ways inside one. The query's own slot has to be
+    valid. It needs an explicit ``kv_valid`` too.
 
     Reference RL rollouts lean on vLLM for this
     (examples/unified/rl/openrlhf/ppo/main.py:26-60); here generation is
@@ -268,12 +279,14 @@ def _update_decode_cache(
     if cache_slots is not None:
         if kv_valid is None:
             raise ValueError("cache_slots mode needs explicit kv_valid")
-        slots_bt = cache_slots[:, None]  # one token a row: T is 1
-        if slots_bt.shape != (B, T):
+        if cache_slots.shape != (B,):
             raise ValueError(
                 f"cache_slots {cache_slots.shape} incompatible with "
                 f"tokens [B={B}, T={T}]"
             )
+        slots_bt = cache_slots[:, None]  # [B, T]: a row's slots [s_b, s_b + T)
+        if T > 1:
+            slots_bt = slots_bt + jnp.arange(T, dtype=slots_bt.dtype)[None, :]
         rows = jnp.arange(B)[:, None]
         ck.value = ck.value.at[rows, slots_bt].set(k_store)
         cv.value = cv.value.at[rows, slots_bt].set(v_store)
@@ -283,6 +296,8 @@ def _update_decode_cache(
         # cidx (the shared frontier) is meaningless per-row; leave it.
         # causal per (row, query): query written at slot slots_bt[b, t]
         # sees valid slots <= its own
+        if block_length:
+            return _read(_block_mask(kv_valid, slots_bt, block_length))
         causal = (
             jnp.arange(max_len)[None, None, :] <= slots_bt[:, :, None]
         )  # [B, T, max_len]
@@ -300,6 +315,11 @@ def _update_decode_cache(
             csv.value, v_scale, (0, offset, 0)
         )
     cidx.value = offset + T
+    if block_length:
+        if kv_valid is None:
+            raise ValueError("a mask by blocks of positions needs explicit kv_valid")
+        slots_bt = jnp.broadcast_to(offset + jnp.arange(T)[None, :], (B, T))
+        return _read(_block_mask(kv_valid, slots_bt, block_length))
     if kv_valid is None:
         # all slots up to the write frontier are real tokens
         kv_valid = jnp.arange(max_len)[None, :] < (offset + T)
@@ -309,6 +329,27 @@ def _update_decode_cache(
     causal = jnp.arange(max_len)[None, :] <= slot_q[:, None]  # [T, max_len]
     mask = kv_valid[:, None, :] & causal[None, :, :]  # [B, T, max_len]
     return _read(mask)
+
+
+class BlockDecoding(NamedTuple):
+    """What a model that is decoded a block at a time tells its holder
+    (``model.decode_blocks()``, ``models/build.py``'s contract): a block of
+    ``block_length`` positions is fed with ``mask_token_id`` where a position
+    is undecided, and ``denoising_steps`` passes fix all of them."""
+
+    block_length: int
+    denoising_steps: int
+    mask_token_id: int
+
+
+def _block_mask(kv_valid, slots_bt, block_length):
+    """``[B, T, L]``: the query written at slot ``slots_bt[b, t]`` sees the
+    valid slots whose position lies in its own block of ``block_length``
+    positions or in an earlier one; a slot's position is its rank among
+    the row's valid slots (``kv_valid [B, L]``)."""
+    block_of = (jnp.cumsum(kv_valid, axis=1, dtype=jnp.int32) - 1) // block_length  # [B, L]
+    own = jnp.take_along_axis(block_of, slots_bt, axis=1)  # [B, T]
+    return kv_valid[:, None, :] & (block_of[:, None, :] <= own[:, :, None])
 
 
 # The most positions squared (a call's width x the cache's length) that a
@@ -328,7 +369,8 @@ def prefill_is_tiled(width: int, max_len: int) -> bool:
 
 
 def cached_decode_attention(
-    module, max_len, q, k, v, kv_valid, cache_slots, wo, cfg
+    module, max_len, q, k, v, kv_valid, cache_slots, wo, cfg, *,
+    block_length=0,
 ):
     """Update the module's decode cache with this call's K/V, then run
     attention in the cache's STORAGE precision: the bf16 cache feeds
@@ -347,11 +389,17 @@ def cached_decode_attention(
 
     A multi-token call over a bf16 cache whose scores would be too many
     to hold (:func:`prefill_is_tiled`) writes its keys and values the same
-    way and attends through :func:`_tiled_prefill_attention`.
+    way and attends through :func:`_tiled_prefill_attention`, which knows
+    the causal rule alone: with ``block_length`` (the mask by blocks of
+    positions, :func:`_update_decode_cache`) such a call is refused.
     """
+    if block_length and cache_slots is None and prefill_is_tiled(q.shape[1], max_len):
+        raise ValueError(
+            f"a {q.shape[1]}-token call over {max_len} positions attends in tiles, "
+            f"and the tiled prefill has no mask by blocks of positions")
     res = _update_decode_cache(
         module, max_len, k, v, kv_valid, cache_slots,
-        fold=q.shape[2] == k.shape[2],
+        fold=q.shape[2] == k.shape[2], block_length=block_length,
     )
     if len(res) == 3:
         k_full, v_full, mask = res

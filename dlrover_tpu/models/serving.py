@@ -61,6 +61,18 @@ TPU shape — every device program is static-shape and compiled once:
   lengths at the steps that emitted their tokens) against
   ``kv_positions_held`` (``slots x max_seq_len`` a step: what a step's
   attention reads against what it needs).
+- **A model decoded a block at a time** (``model.decode_blocks()``:
+  generation by diffusion over blocks, ``models/sdar_moe.py``) gets the
+  *block chunk* in the decode chunk's place (``make_block_chunk``): a row
+  holds a block of ``block_length`` positions, some decided; a pass runs
+  the model over the whole block at the row's next ``block_length`` slots
+  and fixes the most confident of the undecided positions, or, where none
+  is undecided, makes the block final: only then do its slots become
+  valid, its tokens leave, the row's budget and write slot move. A pass
+  yields several tokens of a row or none, and rows stand at different
+  passes of their blocks. ``docs/generation.md`` has the carry and what is
+  refused for such a model (the paged layout, prefixes, the hand-off, a
+  tiled prefill).
 - **Weight hot-swap between chunks**: ``set_params`` replaces the
   parameter argument of the jitted programs (same shapes — no
   recompile), so a WeightBus push lands at the next chunk boundary;
@@ -97,7 +109,7 @@ import contextlib
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +127,7 @@ from .generation import (
     init_cache,
     left_pad_prompts,
     prefill_prompt,
+    sample_logits,
     sample_step,
 )
 
@@ -129,6 +142,9 @@ class Completion:
     uid: int
     tokens: List[int]
     logprobs: List[float]
+    # a model decoded by blocks: the pass of its block at which each token
+    # was fixed (0 .. denoising_steps - 1); None for every other model
+    passes: Optional[List[int]] = None
     # per-request service metrics (host wall-clock)
     queue_s: float = 0.0  # submit → slot admission
     ttft_s: float = 0.0  # admission → first emitted token
@@ -159,12 +175,78 @@ def _device_put_like(tree, like):
     return jax.device_put(tree, spec)
 
 
+class _Block(NamedTuple):
+    """A row's block in the block chunk's carry (``make_block_chunk``):
+    ``[B, Bl]`` the tokens, which positions are decided (a flag, never
+    ``tok == mask_token_id``: a prompt may hold that id), each decided
+    one's log-probability and the pass that fixed it; ``[B]`` the passes
+    left to the block and the position its emission starts from (what a
+    prompt's tail brought into a first block is not emitted)."""
+
+    tok: Any
+    decided: Any
+    logp: Any
+    at_pass: Any
+    left: Any
+    emit_from: Any
+
+
+def _fresh_block(blocks, rows: tuple) -> _Block:
+    """Blocks with every position undecided, for ``rows`` (a shape) rows."""
+    wide = rows + (blocks.block_length,)
+    return _Block(
+        jnp.zeros(wide, jnp.int32), jnp.zeros(wide, bool),
+        jnp.zeros(wide, jnp.float32), jnp.zeros(wide, jnp.int32),
+        jnp.full(rows, blocks.denoising_steps, jnp.int32),
+        jnp.zeros(rows, jnp.int32),
+    )
+
+
+def _choose(logits, rng, s: SamplingConfig):
+    """A token for every position of ``logits [B, Bl, V]`` (float32, as
+    given: a row's disallowed tokens at -inf) and its log-probability under
+    them, which is the position's confidence: the argmax at temperature 0,
+    else a sample of the filtered logits (``generation.sample_logits``)."""
+    B, Bl, V = logits.shape
+    choice = sample_logits(
+        logits.reshape(B * Bl, V), rng, s.temperature, s.top_k, s.top_p
+    ).reshape(B, Bl)
+    # (the chosen logit less the log-sum: no [B, Bl, V] of log-probabilities)
+    chosen = jnp.take_along_axis(logits, choice[..., None], axis=-1)[..., 0]
+    return choice, chosen - jax.nn.logsumexp(logits, axis=-1)
+
+
+def _rank_by_confidence(logp, among):
+    """``[B, Bl]``: each position's rank among the positions ``among`` of its
+    row by ``logp``, the most confident 0; of two equally confident ones the
+    lower position comes first."""
+    at = jnp.arange(logp.shape[1])
+    ahead = among[:, None, :] & (
+        (logp[:, None, :] > logp[:, :, None])
+        | ((logp[:, None, :] == logp[:, :, None])
+           & (at[None, None, :] < at[None, :, None]))
+    )  # [B, i, j]: j comes before i
+    return jnp.sum(ahead, axis=2, dtype=jnp.int32)
+
+
+def _block_final(undecided_in, undecided_out):
+    """``[B]``: the blocks that this pass made final. A block is final at a
+    pass that was *given* no undecided position, because only that pass
+    wrote every position's keys and values from its final token; the pass
+    that decides the last position saw ``mask_token_id`` there (and
+    ``undecided_out``, what is left after it, is not what decides). The one
+    place that says so: the benchmark's ``scratch-kept`` control swaps it."""
+    del undecided_out
+    return ~jnp.any(undecided_in, axis=1)
+
+
 @dataclass
 class _Slot:
     uid: int = -1  # -1 = empty
     prompt: List[int] = field(default_factory=list)
     emitted: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)
+    passes: List[int] = field(default_factory=list)  # block decoding only
     finished: bool = False  # EOS seen (device done flag)
     cap: int = 0  # this request's max_new_tokens (<= engine budget)
     submit_t: float = 0.0
@@ -189,7 +271,7 @@ class ContinuousBatchingEngine:
         sampling: SamplingConfig,
         batch_size: int,
         prompt_width: int,
-        decode_chunk: int = 8,
+        decode_chunk: Optional[int] = None,
         mesh=None,
         rules=None,
         cache_layout: str = "per_row",
@@ -274,6 +356,28 @@ class ContinuousBatchingEngine:
                 "holds only leaves shaped [row, position, ...]; serve it "
                 "with cache_layout 'per_row'"
             )
+        # a model decoded a block at a time says so (``decode_blocks``,
+        # ``models/build.py``); None for every autoregressive one
+        blocks_of = getattr(model, "decode_blocks", None)
+        self.blocks = blocks_of() if blocks_of is not None else None
+        if self.blocks is not None:
+            Bl = self.blocks.block_length
+            if cache_layout == "paged":
+                raise ValueError(
+                    "cache_layout 'paged': this model is decoded a block at "
+                    "a time (model.decode_blocks), and the block pool's "
+                    "chunk gathers and scatters a row for one token a "
+                    "step; serve it with cache_layout 'per_row'"
+                )
+            # a first block may begin up to Bl - 1 tokens before the
+            # prompt's end, and a last one end as many past the cap
+            if prompt_width + sampling.max_new_tokens + 2 * (Bl - 1) > L:
+                raise ValueError(
+                    f"per_row liveness by blocks: prompt_width + "
+                    f"max_new_tokens + 2 x (block_length - 1) = "
+                    f"{prompt_width + sampling.max_new_tokens + 2 * (Bl - 1)}"
+                    f" > max_seq_len {L}"
+                )
         # liveness: each request lives in its own slots
         if prompt_width + sampling.max_new_tokens > L:
             raise ValueError(
@@ -282,6 +386,19 @@ class ContinuousBatchingEngine:
                 f"{prompt_width + sampling.max_new_tokens} > "
                 f"max_seq_len {L}"
             )
+        if decode_chunk is None:
+            # the default: 8 steps, and for a model decoded by blocks the
+            # next whole number of blocks (S passes and one that makes the
+            # block final, so 9 at S = 2). Rows admitted at a chunk's edge
+            # then stand at the same pass of their blocks in every pass;
+            # the benchmark's cell reads one rate either way and spreads
+            # less over seeds at 9 (0.65% and 0.66% against 1.76% and
+            # 1.05%: PERF.md section 6, PR 59). A length that is given is
+            # taken as given: any is right.
+            decode_chunk = 8
+            if self.blocks is not None:
+                per_block = self.blocks.denoising_steps + 1
+                decode_chunk = -(-decode_chunk // per_block) * per_block
         self.model = model
         self.s = sampling
         self.mesh = mesh
@@ -416,6 +533,24 @@ class ContinuousBatchingEngine:
                 kvv[0],
             )
 
+        def prefill_block_row(params, toks, mask, tail, n_tail):
+            """A block model's admission: the prompt's WHOLE blocks
+            ``[1, W]`` (left-padded) through the model under the mask by
+            blocks, into a fresh row's slots ``[0, W)``; the ``n_tail``
+            tokens that begin a block the prompt does not fill (``tail
+            [Bl]``, padded) seat that block's first positions as decided.
+            -> (row cache, the row's first block, the position its first
+            block begins at, row kv_valid): a row as :func:`admit` takes
+            it, the block where an autoregressive row has its last logits."""
+            # (the prefill's logits are nobody's: the model returns one
+            # position's and the compiler drops what computes them)
+            cache, _, _, kv_valid = prefill_prompt(model, params, toks, mask)
+            first = _fresh_block(self.blocks, ())._replace(
+                tok=tail, decided=jnp.arange(tail.shape[0]) < n_tail,
+                emit_from=n_tail,
+            )
+            return cache, first, jnp.sum(mask, dtype=jnp.int32), kv_valid[0]
+
         def admit(state, row_cache, row_logits, row_pos, row_kv,
                   row_allow, slot, next_slot, cap):
             """Insert a prefilled row at ``slot`` (traced — one compile
@@ -424,7 +559,9 @@ class ContinuousBatchingEngine:
             width. ``cap`` arms the row's DEVICE-side emission budget:
             the chunk fn decrements it per emitted token and done-masks
             the row at zero, so cap enforcement cannot lag the device (the
-            overlapped round's one-chunk window)."""
+            overlapped round's one-chunk window). ``row_logits`` is what
+            the row's decoding starts from: its last logits, or for a
+            model decoded by blocks its first block (a tree, leaf by leaf)."""
             (cache, kv_valid, last_logits, cur_pos, allow, budget, done,
              row_f) = state
             cache = ContinuousBatchingEngine._insert_row(
@@ -433,7 +570,10 @@ class ContinuousBatchingEngine:
             return (
                 cache,
                 kv_valid.at[slot].set(row_kv),
-                last_logits.at[slot].set(row_logits),
+                jax.tree_util.tree_map(
+                    lambda rows, row: rows.at[slot].set(row),
+                    last_logits, row_logits,
+                ),
                 cur_pos.at[slot].set(row_pos),
                 allow.at[slot].set(row_allow),
                 budget.at[slot].set(cap),
@@ -540,6 +680,138 @@ class ContinuousBatchingEngine:
 
             return chunk
 
+        def make_block_chunk(d: int):
+            """The chunk of a model decoded a block at a time: ``d``
+            passes. The state is the decode chunk's with the row's block
+            (:class:`_Block`) where that has ``last_logits``, and
+            ``cur_pos`` the position the block begins at. **One pass**:
+            every row's block goes through the model at the row's next
+            ``Bl`` slots (``cache_slots``: keys and values written at
+            ``[row_f, row_f + Bl)``, valid for this row in this pass,
+            nothing past them), ``mask_token_id`` where a position is
+            undecided. Then by row: **no position was undecided** -> the
+            pass wrote the final tokens' keys and values and the block is
+            final: its slots stay valid, ``row_f`` and the position move on
+            by ``Bl``, its tokens are emitted (a first block: what the
+            prompt did not bring; a last one: up to the budget; up to an
+            EOS), a fresh block starts. **Else** the ``ceil(undecided /
+            passes left)`` most confident of the undecided positions are
+            fixed (a tie: the lowest position), each with its
+            log-probability and the pass's number; what this pass wrote is
+            scratch that the next pass overwrites. So a block takes
+            ``denoising_steps`` passes and one more (a final pass carried
+            with the next block's first is ``ROADMAP.md``'s). Returns
+            stacked (toks, emits, logps) ``[d x Bl, B]`` (a pass's
+            positions in order: what the host's emission reads a token a
+            step), the counters ``[d]`` (the model's, and ``block.*`` and
+            ``kv_positions_valid``, summed over live rows), the passes at
+            which the tokens were fixed ``[d x Bl, B]``, and the advanced
+            state. Done and empty rows keep stepping with their block
+            parked at the row's last slots, as the decode chunk's do."""
+            Bl, S, mask_id = self.blocks
+            at = jnp.arange(Bl)
+
+            def chunk(params, state, rng):
+                def one_pass(carry, _):
+                    (cache, kv_valid, blk, base_pos, allow, budget, done,
+                     row_f, rng) = carry
+                    rng, sub = jax.random.split(rng)
+                    live = ~done
+                    write_slots = jnp.minimum(row_f, L - Bl)
+                    own = jnp.arange(L)[None, :] - write_slots[:, None]
+                    kv_pass = kv_valid | ((own >= 0) & (own < Bl))
+                    logits, cache, sown = decode_apply(
+                        model, params, cache,
+                        jnp.where(blk.decided, blk.tok, mask_id),
+                        base_pos[:, None] + at[None, :], kv_pass,
+                        cache_slots=write_slots, metrics=True,
+                    )
+                    choice, choice_logp = _choose(
+                        jnp.where(
+                            allow[:, None, :], logits.astype(jnp.float32),
+                            -jnp.inf,
+                        ), sub, s,
+                    )
+                    undecided = ~blk.decided
+                    n_undecided = jnp.sum(undecided, axis=1, dtype=jnp.int32)
+                    n_fix = -(-n_undecided // jnp.maximum(blk.left, 1))
+                    newly = undecided & (
+                        _rank_by_confidence(choice_logp, undecided)
+                        < n_fix[:, None]
+                    )
+                    tok = jnp.where(newly, choice, blk.tok)
+                    logp = jnp.where(newly, choice_logp, blk.logp)
+                    at_pass = jnp.where(
+                        newly, (S - blk.left)[:, None], blk.at_pass
+                    )
+                    decided = blk.decided | newly
+                    final = live & _block_final(undecided, ~decided)
+                    # what a final block emits
+                    emit = (
+                        final[:, None]
+                        & (at[None, :] >= blk.emit_from[:, None])
+                        & (at[None, :] - blk.emit_from[:, None]
+                           < budget[:, None])
+                    )
+                    if s.eos_id >= 0:  # the EOS is kept, nothing after it
+                        eos = emit & (tok == s.eos_id)
+                        emit = emit & (
+                            jnp.cumsum(eos, axis=1) - eos.astype(jnp.int32)
+                            == 0
+                        )
+                        done = done | jnp.any(emit & eos, axis=1)
+                    budget = budget - jnp.sum(emit, axis=1, dtype=jnp.int32)
+                    done = done | (final & (budget <= 0))
+                    counters = dict(
+                        counters_of(sown) if counters_of else {},
+                        **{
+                            "block.row_passes": jnp.sum(live, dtype=jnp.int32),
+                            "block.commit_row_passes": jnp.sum(
+                                live & (n_undecided == 0), dtype=jnp.int32),
+                            "block.tokens_fixed": jnp.sum(
+                                newly & live[:, None], dtype=jnp.int32),
+                            "block.blocks_final": jnp.sum(
+                                final, dtype=jnp.int32),
+                            "block.positions_undecided_in": jnp.sum(
+                                undecided & live[:, None], dtype=jnp.int32),
+                            "kv_positions_valid": jnp.sum(
+                                kv_pass & live[:, None], dtype=jnp.int32),
+                        },
+                    )
+                    fresh = _fresh_block(self.blocks, (tok.shape[0],))
+                    blk = jax.tree_util.tree_map(
+                        lambda new, old: jnp.where(
+                            final.reshape((-1,) + (1,) * (old.ndim - 1)),
+                            new, old,
+                        ),
+                        fresh,
+                        _Block(tok, decided, logp, at_pass,
+                               blk.left - (n_undecided > 0), blk.emit_from),
+                    )
+                    step = jnp.where(final, Bl, 0)
+                    return (
+                        cache,
+                        jnp.where(final[:, None], kv_pass, kv_valid),
+                        blk,
+                        base_pos + step,
+                        allow,
+                        budget,
+                        done,
+                        row_f + step,
+                        rng,
+                    ), (tok.T, emit.T, logp.T, counters, at_pass.T)
+
+                carry, (toks, emits, logps, counters, passes) = jax.lax.scan(
+                    one_pass, (*state, rng), None, length=d
+                )
+                flat = lambda a: a.reshape((d * Bl,) + a.shape[2:])  # noqa: E731
+                return carry[:-1], (
+                    flat(toks), flat(emits), flat(logps), counters,
+                    flat(passes),
+                )
+
+            return chunk
+
         def admit_many(state, rows, slots, next_slots, caps):
             """Burst admission: K row inserts in ONE dispatch. A wave
             of slots tends to retire together (equal caps), so the
@@ -587,7 +859,9 @@ class ContinuousBatchingEngine:
                 state = paged_admit(state, *row, slot, nxt, cap, tr)
             return state
 
-        self._prefill_fn = jax.jit(prefill_row)
+        self._prefill_fn = jax.jit(
+            prefill_row if self.blocks is None else prefill_block_row
+        )
         self._continue_fn = jax.jit(continue_prefill_row, static_argnums=6)
         if paged:
             self._admit_fn = jax.jit(paged_admit)
@@ -596,7 +870,9 @@ class ContinuousBatchingEngine:
             self._admit_fn = jax.jit(admit)
             self._admit_many_fn = jax.jit(admit_many)
         # chunk programs are cached per d: each length is one compile
-        self._chunk_src = make_decode_chunk
+        self._chunk_src = (
+            make_decode_chunk if self.blocks is None else make_block_chunk
+        )
         self._chunk_fns: Dict[int, Callable] = {}
 
     _NULL_CTX = contextlib.nullcontext()
@@ -677,7 +953,9 @@ class ContinuousBatchingEngine:
         self._state = (
             cache,
             jnp.zeros((self.B, self.L), bool),
-            jnp.full((self.B, V), -1e9, jnp.float32),
+            # what a row decodes from: its last logits, or its block
+            jnp.full((self.B, V), -1e9, jnp.float32) if self.blocks is None
+            else _fresh_block(self.blocks, (self.B,)),
             jnp.zeros((self.B,), jnp.int32),
             jnp.ones((self.B, V), bool),  # per-row allowed-token mask
             jnp.zeros((self.B,), jnp.int32),  # per-row emission budget
@@ -694,6 +972,7 @@ class ContinuousBatchingEngine:
         every admission (vLLM's prefix-caching capability). The device
         state is built lazily on first use, so registration is cheap
         and weight swaps just invalidate."""
+        self._refuse_for_blocks("register_prefix")
         if not tokens:
             raise ValueError("empty prefix")
         # the STORED state occupies the prefix's bucket width — a
@@ -715,11 +994,7 @@ class ContinuousBatchingEngine:
         width) for a registered prefix at the CURRENT weights."""
         if pid not in self._prefix_states:
             prefix = self._prefixes[pid]
-            width = self._bucket_width(len(prefix))
-            toks, mask = self._pad_rows([prefix], width)
-            with self._ctx():
-                row = self._prefill_fn(self.params, toks, mask)
-            self._prefix_states[pid] = (*row, width)
+            self._prefix_states[pid] = self._prefill(prefix)
         return self._prefix_states[pid]
 
     def unregister_prefix(self, prefix_id: int) -> None:
@@ -755,18 +1030,15 @@ class ContinuousBatchingEngine:
         payload carries every leaf of the row's cache, per-request
         state leaves included (they are leaves like the others, checked
         by shape on arrival)."""
+        self._refuse_for_blocks("export_prefill")
         if not tokens:
             raise ValueError("empty prompt")
         if len(tokens) > self.Pw:
             raise ValueError(
                 f"prompt length {len(tokens)} > prompt_width {self.Pw}"
             )
-        width = self._bucket_width(len(tokens))
-        toks, mask = self._pad_rows([tokens], width)
-        with self._ctx():
-            row = self._prefill_fn(self.params, toks, mask)
-        row = jax.device_get(row)
-        return kv_blocks.pack_row_state(*row, width, tokens)
+        *row, width = self._prefill(tokens)
+        return kv_blocks.pack_row_state(*jax.device_get(row), width, tokens)
 
     def submit_prefilled(
         self,
@@ -782,6 +1054,7 @@ class ContinuousBatchingEngine:
         A weight swap between staging and admission clears the staged
         row and the request gracefully RE-prefills from its prompt
         tokens at the new weights (the payload carries them)."""
+        self._refuse_for_blocks("submit_prefilled")
         (row_cache, row_logits, row_pos, row_kv, width, prompt) = (
             kv_blocks.unpack_row_state(
                 payload, init_cache(self.model, 1)
@@ -839,6 +1112,14 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"prompt length {len(tokens)} > prompt_width {self.Pw}"
             )
+        if self.blocks is not None and prefill_is_tiled(
+            self._bucket_width(self._prefill_len(tokens)), self.L
+        ):
+            raise ValueError(
+                f"prompt length {len(tokens)}: its prefill would attend "
+                f"in tiles (layers.prefill_is_tiled), which know the "
+                f"causal mask alone and not this model's mask by blocks"
+            )
         cap = self.s.max_new_tokens
         if max_new_tokens is not None:
             if not 1 <= max_new_tokens <= cap:
@@ -863,6 +1144,39 @@ class ContinuousBatchingEngine:
              allowed_tokens)
         )
         return uid
+
+    def _refuse_for_blocks(self, what: str) -> None:
+        """A stored prefix and a hand-off payload carry an autoregressive
+        row's last logits; a row decoded by blocks starts from its first
+        block, and its prefix would have to end on a block's edge."""
+        if self.blocks is not None:
+            raise ValueError(
+                f"{what}: this model is decoded a block at a time "
+                f"(model.decode_blocks), and a stored or handed-off row "
+                f"carries the last logits of an autoregressive one"
+            )
+
+    def _prefill_len(self, prompt: List[int]) -> int:
+        """How many of a prompt's tokens its prefill covers: all, or for a
+        model decoded by blocks its whole blocks (the tail begins the first
+        block the chunk decodes)."""
+        if self.blocks is None:
+            return len(prompt)
+        return len(prompt) - len(prompt) % self.blocks.block_length
+
+    def _prefill(self, prompt: List[int]) -> tuple:
+        """(row cache, what the row decodes from, position, row kv_valid,
+        bucket width) of a prompt: one call of the prefill program."""
+        n = self._prefill_len(prompt)
+        width = self._bucket_width(n)
+        toks, mask = self._pad_rows([prompt[:n]], width)
+        extra = ()
+        if self.blocks is not None:
+            tail = np.zeros(self.blocks.block_length, np.int32)
+            tail[:len(prompt) - n] = prompt[n:]
+            extra = (jnp.asarray(tail), self._i32(len(prompt) - n))
+        with self._ctx():
+            return (*self._prefill_fn(self.params, toks, mask, *extra), width)
 
     def _as_consumed(self, params, like=None):
         """The tree the programs get, from ``params`` as a trainer, a
@@ -1083,18 +1397,16 @@ class ContinuousBatchingEngine:
                     # in-flight chunk): admission is only the insert
                     row_cache, row_logits, row_pos, row_kv, width = pre
                 else:
-                    width = self._bucket_width(len(prompt))
-                    toks, mask = self._pad_rows([prompt], width)
-                    row_cache, row_logits, row_pos, row_kv = (
-                        self._prefill_fn(self.params, toks, mask)
+                    row_cache, row_logits, row_pos, row_kv, width = (
+                        self._prefill(prompt)
                     )
                 full_prompt = prompt
         # what this admission's prefill covers: the request's own tokens
         # (a stored prefix's are not computed again) against the width of
         # the bucket they were padded to. A scan, unlike attention, pays
         # for every padded position.
-        own_width = self._bucket_width(len(prompt))
-        self.phases.count("prefill_tokens_real", len(prompt))
+        own_width = self._bucket_width(self._prefill_len(prompt))
+        self.phases.count("prefill_tokens_real", self._prefill_len(prompt))
         self.phases.count("prefill_tokens_padded", own_width)
         # ... and whether that call was too wide for an attention layer to
         # hold its scores whole (``layers.prefill_is_tiled``)
@@ -1169,6 +1481,8 @@ class ContinuousBatchingEngine:
         prompt, what it had emitted, the step's own token), against the
         ``kv_positions_held`` a step reads. Booked at the chunk's read-back,
         after the tokens are credited."""
+        if self.blocks is not None:
+            return  # a pass counts its live rows' valid slots on the device
         before = len(st.prompt) + len(st.emitted) - new
         self.phases.count(
             "kv_positions_valid", new * before + new * (new + 1) // 2
@@ -1290,6 +1604,7 @@ class ContinuousBatchingEngine:
             self._completions.append(
                 Completion(
                     st.uid, st.emitted, st.logprobs,
+                    passes=None if self.blocks is None else st.passes,
                     queue_s=max(st.admit_t - st.submit_t, 0.0),
                     ttft_s=max(
                         (st.first_tok_t or now) - st.admit_t, 0.0
@@ -1406,13 +1721,16 @@ class ContinuousBatchingEngine:
         in-flight record (output futures + done futures + the uid
         snapshot) without reading anything back."""
         with self._ctx():
-            self._state, (toks, emits, logps, counters) = self._chunk_for(
-                self.d
-            )(self.params, self._state, rng)
+            self._state, (toks, emits, logps, counters, *passes) = (
+                self._chunk_for(self.d)(self.params, self._state, rng)
+            )
         self._count_chunk(self.B * self.d)
         return (
             toks, emits, logps, self._state[-2],  # -2: the done flags
-            counters, [st.uid for st in self._slots],
+            counters,
+            # block decoding: the pass that fixed each token; else None
+            passes[0] if passes else None,
+            [st.uid for st in self._slots],
         )
 
     def _emit_outputs(self, fetched, uids) -> int:
@@ -1422,7 +1740,7 @@ class ContinuousBatchingEngine:
         or cancel + re-admit during the lag window) is skipped: the
         old row's emit mask is the device's own guarantee that a
         re-admitted request never sees a predecessor's tokens."""
-        toks, emits, logps, done, counters = fetched
+        toks, emits, logps, done, counters, passes = fetched
         self._book_counters(counters)
         emitted = 0
         now = time.perf_counter()
@@ -1443,6 +1761,10 @@ class ContinuousBatchingEngine:
                         float(x)
                         for x in logps[sel, slot][: len(new)]
                     )
+                    if passes is not None:
+                        st.passes.extend(
+                            int(x) for x in passes[sel, slot][: len(new)]
+                        )
                     self._count_kv_valid(st, len(new))
                     emitted += len(new)
             st.finished = bool(done[slot])
@@ -1492,11 +1814,7 @@ class ContinuousBatchingEngine:
             uid, prompt, _submit_t, _cap, prefix_id, _allowed = item
             if prefix_id is not None or uid in self._prefilled:
                 continue
-            width = self._bucket_width(len(prompt))
-            toks, mask = self._pad_rows([prompt], width)
-            with self._ctx():
-                row = self._prefill_fn(self.params, toks, mask)
-            self._prefilled[uid] = (*row, width)
+            self._prefilled[uid] = self._prefill(prompt)
 
     def _oldest_ready(self) -> bool:
         """Non-blocking: has the oldest in-flight chunk already
@@ -1575,7 +1893,7 @@ class ContinuousBatchingEngine:
         """The synchronous round's per-token host loop, kept verbatim
         as the reference for the overlapped round's fused emission
         (greedy equality between the two paths is under test)."""
-        toks, emits, logps, done, counters = fetched
+        toks, emits, logps, done, counters, passes = fetched
         self._book_counters(counters)
         emitted = 0
         for slot, st in enumerate(self._slots):
@@ -1590,6 +1908,8 @@ class ContinuousBatchingEngine:
                         self._first_token(st, time.perf_counter())
                     st.emitted.append(int(toks[t, slot]))
                     st.logprobs.append(float(logps[t, slot]))
+                    if passes is not None:
+                        st.passes.append(int(passes[t, slot]))
                     emitted += 1
             self._count_kv_valid(st, len(st.emitted) - had)
             st.finished = bool(done[slot])

@@ -335,7 +335,7 @@ def test_consumed_dtypes_and_the_held_init():
 def test_registry_builds_every_family():
     from dlrover_tpu.models.build import FAMILIES
 
-    assert sorted(FAMILIES) == ["gpt", "granite_hybrid", "lfm2_moe", "llama", "mellum", "mla_moe", "olmo_hybrid", "qwen3_next"]
+    assert sorted(FAMILIES) == ["gpt", "granite_hybrid", "lfm2_moe", "llama", "mellum", "mla_moe", "olmo_hybrid", "qwen3_next", "sdar_moe"]
     model, loss_fn = build_model({"family": "lfm2_moe", "config": {
         "num_hidden_layers": 2, "layer_types": ["conv", "full_attention"], "dtype": "float32"}})
     assert type(model).__name__ == "Lfm2MoeLM" and loss_fn.__name__ == "cross_entropy_loss"

@@ -59,7 +59,7 @@ ALLOWED = dict({family: {"layers", "moe"} for family in _families()},
 
 
 def test_every_family_is_held():
-    assert len(ALLOWED) == 10 and {"gpt", "llama", "mla_moe"} < set(ALLOWED)
+    assert len(ALLOWED) == 11 and {"gpt", "llama", "mla_moe"} < set(ALLOWED)
 
 
 @pytest.mark.parametrize("module", sorted(ALLOWED))

@@ -1456,3 +1456,65 @@ class TestTiledPrefill:
         lengths = [len(prefix) + len(s) for s in suffixes] + [28]
         assert counters["kv_positions_valid_n"] == sum(n * m + n * (n + 1) // 2 for m in lengths)
         assert counters["kv_positions_held_n"] == counters["row_steps_n"] * self.SEQ
+
+
+class TestSeveralTokensAtPerRowSlots:
+    """PR 59: ``layers._update_decode_cache(cache_slots=)`` takes ``T >= 1``
+    tokens a row at slots ``[s_b, s_b + T)`` (a block's pass of a model
+    decoded by blocks). Under the causal rule every other family has, such a
+    call is its tokens one step at a time; the autoregressive engine builds
+    the decode chunk it always built and its completions carry no passes."""
+
+    def test_a_call_of_three_tokens_is_three_one_token_steps(self):
+        from dlrover_tpu.models.generation import decode_apply, init_cache
+
+        cfg = GPTConfig.tiny()
+        model = GPT(cfg)
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))["params"]
+        L, slots = cfg.max_seq_len, jnp.asarray([5, 9], jnp.int32)  # rows at different write slots
+        kv = jnp.arange(L)[None, :] < slots[:, None]
+        toks = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 8 + 3)), jnp.int32)
+        _, cache = decode_apply(  # something in the rows to attend to: a prefill of 8
+            model, params, init_cache(model, 2), toks[:, :8], jnp.broadcast_to(jnp.arange(8), (2, 8)),
+            jnp.broadcast_to(jnp.arange(L) < 8, (2, L)))
+        kv3 = jnp.arange(L)[None, :] < slots[:, None] + 3
+        pos3 = slots[:, None] + jnp.arange(3)[None, :]
+        at_once, cache3 = decode_apply(model, params, cache, toks[:, 8:], pos3, kv3, cache_slots=slots)
+        stepped = []
+        for t in range(3):
+            kv = kv | (jnp.arange(L)[None, :] == (slots + t)[:, None])
+            logits, cache = decode_apply(model, params, cache, toks[:, 8 + t:9 + t], pos3[:, t:t + 1], kv,
+                                         cache_slots=slots + t)
+            stepped.append(logits[:, 0])
+        np.testing.assert_allclose(at_once, jnp.stack(stepped, axis=1), atol=2e-5, rtol=0)
+        for a, b in zip(jax.tree_util.tree_leaves(cache3), jax.tree_util.tree_leaves(cache)):
+            np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), atol=2e-5, rtol=0)
+        with pytest.raises(ValueError, match="incompatible with"):
+            decode_apply(model, params, cache, toks[:, 8:], pos3, kv3, cache_slots=slots[:1])
+
+    def test_an_autoregressive_engine_is_as_it_was(self):
+        cfg = GPTConfig.tiny()
+        model = GPT(cfg)
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        eng = ContinuousBatchingEngine(
+            model, params, SamplingConfig(max_new_tokens=6, temperature=0.0), batch_size=2, prompt_width=8,
+            decode_chunk=8)
+        assert eng.blocks is None and eng.d == 8  # no rounding of the chunk to whole blocks
+        eng.submit([3, 1, 4])
+        (c,) = eng.run()
+        assert len(c.tokens) == 6 and c.passes is None
+        assert not any(k.startswith("block.") for k in eng.phases.split().summary())
+
+    def test_a_streamed_line_a_final_block(self):
+        from dlrover_tpu.launcher.serve import _lines_of, _passes_of
+        from dlrover_tpu.models.layers import BlockDecoding
+        from dlrover_tpu.models.serving import Completion
+
+        blocks = BlockDecoding(4, 2, 99)
+        new = list(range(10))
+        assert _lines_of(new, 7, None) == [new]  # an autoregressive model: what a poll found, in one line
+        assert _lines_of(new, 8, blocks) == [[0, 1, 2, 3], [4, 5, 6, 7], [8, 9]]  # the cap cut the last block
+        assert _lines_of(new, 9, blocks) == [[0, 1, 2], [3, 4, 5, 6], [7, 8, 9]]  # the prompt began the first
+        assert _lines_of(new[:2], 11, blocks) == [[0], [1]] and _lines_of([], 3, blocks) == []
+        assert _passes_of(Completion(1, [5], [-1.0])) == {}
+        assert _passes_of(Completion(1, [5], [-1.0], passes=[0])) == {"passes": [0]}

@@ -205,7 +205,7 @@ def test_the_server_builds_from_the_shared_registry(capsys):
     with pytest.raises(SystemExit):
         serve.main(["--help"])
     out = capsys.readouterr().out
-    assert "{gpt,granite_hybrid,lfm2_moe,llama,mellum,mla_moe,olmo_hybrid,qwen3_next}" in out
+    assert "{gpt,granite_hybrid,lfm2_moe,llama,mellum,mla_moe,olmo_hybrid,qwen3_next,sdar_moe}" in out
     assert not hasattr(serve, "_build_model")
     with pytest.raises(SystemExit):  # a family with no decode path is refused, by name
         serve.main(["--cpu", "--family", "mla_moe", "--config", '{"num_hidden_layers": 1}'])

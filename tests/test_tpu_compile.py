@@ -1206,3 +1206,67 @@ def test_olmo_hybrid_served_programs_compile_for_v5e(
     for k in (1, 4):
         print(f"admit_many of {k}:", sizes(engine._admit_many_fn.lower(
             _described(engine._state, one_chip), (row,) * k, (i32,) * k, (i32,) * k, (i32,) * k)))
+
+
+def test_sdar_moe_cells_programs_compile_for_v5e(one_chip, monkeypatch, request, no_persistent_cache):
+    """``sdar-moe-serve-blockgen-16``: the server built from the benchmark's
+    configuration (every key and width as the file gives it, all 128 experts,
+    the whole vocabulary; two of its six layers, which is what a test can
+    hold in memory) lowers its 1,024-wide prefill and its block chunk (9
+    passes of 16 rows x 4 positions at per-row slots) for a described v5e,
+    each with the family's scopes and the grouped products' kernel in its
+    text, and both fit the chip. ``-s`` compiles all six layers and prints
+    the programs' sizes (8.7 GB of zeros on the host)."""
+    import re
+    import time
+
+    from dlrover_tpu.models.build import build_model
+    from dlrover_tpu.models.generation import SamplingConfig
+    from dlrover_tpu.models.serving import ContinuousBatchingEngine
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    entry = _benchmark_model_entry("sdar-30b-a3b-pp8-l6")
+    slots, width, new_tokens = 16, 1024, 256
+
+    def served(layers):
+        model, _ = build_model({"family": entry["family"],
+                                "config": dict(entry["config"], num_hidden_layers=layers)})
+        engine = ContinuousBatchingEngine(
+            model, _zeros_as_held(model), SamplingConfig(max_new_tokens=new_tokens, temperature=0.0),
+            batch_size=slots, prompt_width=width)
+        return engine, _described(engine.params, one_chip)
+
+    def prefill_of(engine, held, w):
+        i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        tail = jax.ShapeDtypeStruct((engine.blocks.block_length,), jnp.int32, sharding=one_chip)
+        return engine._prefill_fn.lower(held, *_prompt_row(w, one_chip), tail, i32)
+
+    def chunk_of(engine, held):
+        return engine._chunk_for(engine.d).lower(
+            held, _described(engine._state, one_chip), _described(jax.random.PRNGKey(0), one_chip))
+
+    engine, held = served(2)
+    assert engine.d == 9 and engine.blocks.block_length == 4  # the default for such a model: whole blocks of 3 passes
+    for lowered, scopes in ((prefill_of(engine, held, width), ("sdar.attend_prefill", "moe.route", "moe.experts")),
+                            (chunk_of(engine, held), ("sdar.attend_block", "moe.route", "moe.experts"))):
+        text = lowered.as_text(debug_info=True)
+        assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {"kernel"}
+        assert all(scope in text for scope in scopes) and "sdar.attend\"" not in text
+        assert _device_bytes(lowered.compile()) < V5E_HBM_BYTES
+
+    if request.config.getoption("capture") != "no":
+        return
+
+    def sizes(lowered):
+        t0 = time.time()
+        m = lowered.compile().memory_analysis()
+        return (f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB, output {m.output_size_in_bytes / 1e9:.3f}, "
+                f"temporaries {m.temp_size_in_bytes / 1e9:.3f}, aliased {m.alias_size_in_bytes / 1e9:.3f} "
+                f"(compiled in {time.time() - t0:.0f} s)")
+
+    engine, held = served(entry["config"]["num_hidden_layers"])
+    print(f"\nsdar-30b-a3b-pp8-l6, described v5e, {slots} slots")
+    for w in (width // 4, width // 2, width):
+        print(f"prefill_block_row {w}:", sizes(prefill_of(engine, held, w)))
+    print(f"chunk of {engine.d} passes:", sizes(chunk_of(engine, held)))

@@ -40,6 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..observability.spans import span
+
 TOKEN_TILE = 128  # tokens a group of the collecting kernel covers
 
 
@@ -47,18 +49,43 @@ def _largest_dividing(x: int, candidates) -> int:
     return next((c for c in candidates if x % c == 0), x)
 
 
-def megablox_tiling(m: int, k: int, n: int):
+ROW_TILES = (512, 256, 128)  # the row tiles the kernels are given, widest first
+
+
+def megablox_tiling(m: int, k: int, n: int, groups: int = 1):
     """(m, k, n) tiles of the Pallas kernels for one product's shapes; the
     kernels call this for the forward and for both backward products.
-    512 rows a tile keep an expert's weight block resident over four
-    times the rows of the kernel's 128 default; k and n tiles are the
-    largest multiples of 128 up to 1024 that divide the dimension."""
+
+    The row tile follows the rows a group can hold: the largest of
+    ``ROW_TILES`` that divides ``m`` and is at most ``max(128, m // groups)``
+    (``m`` itself where none divides it). The kernel multiplies one whole
+    ``[tm, tk] x [tk, tn]`` tile for every (group, row tile) it visits,
+    however few of the tile's rows are the group's. At 2,048 rows a group
+    (16 experts over 32,768 rows) 512 rows a tile keep an expert's weight
+    block resident over four times the rows of the kernel's 128 default
+    (PERF.md, PR 27). At ~6 rows a group (a block pass's 512 rows over 89 of
+    128 experts of 2048 x 768) a product took 0.86 ms on the v5e in 512-row
+    tiles, 0.49 in 256, 0.43 in 128 and 0.45 in 64, where the weights it
+    reads ask 0.34 ms, and every tile gave the same bits: the floor is 128
+    (PERF.md, PR 60, which has the served prefills' 40-64 rows a group too).
+    ``groups`` 1 (the collecting kernel over token tiles) is the rule by
+    ``m`` alone. k and n tiles are the largest multiples of 128 up to 1024
+    that divide the dimension."""
     wide = (1024, 768, 512, 384, 256, 128)
+    most = max(ROW_TILES[-1], m // groups)
     return (
-        _largest_dividing(m, (512, 256, 128)),
+        _largest_dividing(m, tuple(t for t in ROW_TILES if t <= most)),
         _largest_dividing(k, wide),
         _largest_dividing(n, wide),
     )
+
+
+@functools.cache
+def tiling_for(groups: int):
+    """:func:`megablox_tiling` for products over ``groups`` groups, the same
+    object for the same ``groups``: the kernels are jitted with their tiling
+    static, and a fresh ``partial`` a call would trace every call anew."""
+    return functools.partial(megablox_tiling, groups=groups)
 
 
 def _on_tpu() -> bool:
@@ -71,7 +98,14 @@ def grouped_matmul(lhs, rhs, group_sizes):
     if _on_tpu():
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(lhs, rhs, group_sizes, lhs.dtype, megablox_tiling)
+        m, groups = lhs.shape[0], rhs.shape[0]
+        tiling = tiling_for(groups)
+        row_tile = tiling(m, *rhs.shape[1:])[0]
+        # where a program is traced, never in a step: what the rule chose, and
+        # the most (group, row tile) visits the kernel's grid can make
+        with span("moe.gmm_built", m=m, groups=groups, row_tile=row_tile,
+                  visits_bound=m // row_tile + groups - 1):
+            return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype)
 
 

@@ -328,6 +328,50 @@ def test_interleaved_rope_matches_the_reference(shape):
         np.linalg.norm(pairs(got), axis=-1), np.linalg.norm(pairs(x), axis=-1), atol=1e-5)
 
 
+def test_the_tiling_is_one_object_a_group_count():
+    """The kernels are jitted with their tiling static: the rule's callable
+    for ``groups`` is made once, so a second call of a product traces
+    nothing anew; it is a function of ``(m, groups, k, n)`` alone."""
+    assert gm.tiling_for(128) is gm.tiling_for(128)
+    assert gm.tiling_for(128) is not gm.tiling_for(16) and hash(gm.tiling_for(16)) == hash(gm.tiling_for(16))
+    assert gm.tiling_for(128)(512, 2048, 768) == (128, 1024, 768)
+    assert gm.tiling_for(16)(32768, 2048, 768) == gm.megablox_tiling(512, 2048, 768) == (512, 1024, 768)
+    # the collecting kernel over token tiles keeps the rule by ``m`` alone
+    assert gm.megablox_tiling(65536, 128, 2304) == (512, 128, 768)
+
+
+@pytest.mark.parametrize("rows,groups,row_tile,visits_bound", [
+    (512, 128, 128, 131),  # a block pass: 4 rows a group
+    (512, 2, 256, 3), (1024, 2, 512, 3), (96, 4, 96, 4)])
+def test_a_built_kernel_call_says_what_the_rule_chose(monkeypatch, rows, groups, row_tile, visits_bound):
+    """``moe.gmm_built``: one span where ``grouped_matmul`` builds a kernel
+    call (a program's tracing, never a step), with the call's rows and groups,
+    the row tile the rule gave and the most (group, row tile) visits the
+    kernel's grid can make; off the TPU, where ``ragged_dot`` runs, none. The
+    kernel is traced here and not run."""
+    from dlrover_tpu.observability import spans
+
+    seen = []
+
+    def recorded(name, **stats):
+        seen.append((name, stats))
+        return spans.span(name, **stats)
+
+    monkeypatch.setattr(gm, "span", recorded)
+    lhs = jnp.zeros((rows, 256), jnp.bfloat16)
+    rhs = jnp.zeros((groups, 256, 128), jnp.bfloat16)
+    sizes = jnp.zeros((groups,), jnp.int32)
+    jax.make_jaxpr(grouped_matmul)(lhs, rhs, sizes)
+    assert not seen
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    count = lambda: getattr(spans.process_accumulator().stats().get("moe.gmm_built"), "count", 0)
+    before = count()
+    jaxpr = jax.make_jaxpr(lambda *a: grouped_matmul(*a) + grouped_matmul(*a))(lhs, rhs, sizes)
+    assert seen == [("moe.gmm_built", dict(m=rows, groups=groups, row_tile=row_tile, visits_bound=visits_bound))] * 2
+    assert count() == before + 2
+    assert "pallas_call" in str(jaxpr)
+
+
 def test_grouped_matmul_and_the_row_movements():
     key = jax.random.PRNGKey(9)
     rows, k, n, groups = 24, 8, 6, 3

@@ -174,6 +174,45 @@ def test_grouped_expert_products_compile_for_v5e(
     assert _kernel_text(compiled).count("tpu_custom_call") >= 9
 
 
+@pytest.mark.parametrize("m,groups,row_tile,was", [
+    (512, 128, 128, 512),  # sdar block pass: 16 rows x 4 positions x top-8
+    (8192, 128, 128, 512),  # sdar prefill 1,024 wide x top-8
+    (4096, 64, 128, 512),  # lfm2 prefill 1,024 wide x top-4
+    (20480, 128, 128, 512),  # qwen3next prefill 2,048 wide x top-10, 128 of 512 experts held
+    (64, 64, 64, 64),  # lfm2 decode: 16 rows x top-4
+    (160, 128, 160, 160),  # qwen3next decode: 16 rows x top-10
+    (65536, 16, 512, 512),  # mellum2 training buffer
+    (32768, 16, 512, 512),  # joyai training buffer
+])
+def test_the_row_tile_follows_the_rows_a_group_can_hold(m, groups, row_tile, was):
+    """``ops/grouped_matmul.py``'s rule at the cells' own shapes: the largest
+    of 512, 256, 128 that divides ``m`` and is at most ``max(128, m //
+    groups)``, ``m`` itself where none divides it; ``was`` is the tile by
+    ``m`` alone, the rule until PR 60. The served prefills and the block pass
+    leave 512 rows a tile; the two decode chunks (no tile divides their
+    ``m``) and the two training buffers (2,048-4,096 rows a group) keep what
+    they had, and with it their programs."""
+    from dlrover_tpu.ops import grouped_matmul as gm
+
+    for k, n in ((2048, 768), (768, 2048)):  # forward; the backward products call it again
+        k_n_tiles = gm.megablox_tiling(m, k, n)[1:]
+        assert gm.tiling_for(groups)(m, k, n) == (row_tile,) + k_n_tiles
+        assert gm.megablox_tiling(m, k, n) == (was,) + k_n_tiles
+
+
+def _gmm_row_tiles(text):
+    """{(m, groups, row tile)} of the megablox ``gmm`` calls in a lowered
+    text. The Mosaic body is serialized, but the call's operands say its
+    grid: the group and tile ids it is handed are ``m // tm + groups - 1``
+    long, the most (group, row tile) visits it can make."""
+    import re
+
+    calls = re.findall(
+        r"@tpu_custom_call\(.*\(tensor<i32>, tensor<\d+xi32>, tensor<(\d+)xi32>, tensor<\d+xi32>, tensor<1xi32>, "
+        r"tensor<(\d+)x\d+xbf16>, tensor<(\d+)x\d+x\d+xbf16>\) -> tensor<\d+x\d+xbf16>", text)
+    return {(int(m), int(groups), int(m) // (int(visits) - int(groups) + 1)) for visits, m, groups in calls}
+
+
 @pytest.mark.parametrize("tokens", [16, 1024], ids=["decode_step", "prefill"])
 def test_routed_experts_in_a_serving_program_compile_for_v5e(
     one_chip, no_persistent_cache, monkeypatch, tokens
@@ -803,16 +842,29 @@ def test_qwen3_next_served_programs_compile_for_v5e(
 # f9c8a75c..., chunk 44ebf5c3... (623ba44's, held again in PR 48: a served share
 # sets no ``train_gates``, so its buffer stays 4x the mean load, ``N x K`` rows,
 # and no overflow branch enters its programs: still so).
+# **PR 60 retook the two ``.prefill`` pins and joyai's two at b1 x 1024, on
+# purpose** (the grouped product's row tile follows the rows a group can hold,
+# ``ops/grouped_matmul.py: megablox_tiling``: 128 where it was 512 at the
+# prefills' 40-64 rows a group, and at the 2,048 rows over 16 experts, 128 a
+# group, of joyai's *test* shape; their parents' (9d0afa0): lfm2 prefill
+# 3c9a96d2..., qwen3-next prefill 35056d2c..., joyai 5294ed1a... and
+# 5844f3ee...). **Untouched, and the proof that these programs bypass the
+# change**: the two ``.chunk`` pins (row tiles 64 and 160: no tile divides
+# their ``m``), granite's two (no grouped product), and joyai's
+# ``.b4x4096``, new in PR 60: the same two layers at the *cell's* batch,
+# 32,768 rows over 16 experts, tile 512 as before, whose pin was taken on the
+# parent and holds on the change.
 PARENT_FAMILY_PROGRAMS = {
-    "lfm2-24b-a2b-l10.prefill": "3c9a96d28de81445a0d41c7777400d42cd670c6523e989f917159a707eaa6ce1",
+    "lfm2-24b-a2b-l10.prefill": "e75e4ad53c2254cf0381e4848dcb77956654441d01df7e41a99d0b802bceddac",
     "lfm2-24b-a2b-l10.chunk": "3c1902029dfdc270ad6a8f1e4776176b65ff7ae3861f7d1601c99488ab568d5c",
     "granite-4.0-h-micro.prefill": "d7b20c70771f80c106e7e7b5c264e7c5967ccbc440143b6479609c3e11e8fa38",
     "granite-4.0-h-micro.chunk": "53441fb221dd45af451d27b8513c5653328d326284c25b0f283ef47bc9ac2ce1",
     # the blocks keep their flash kernel's two results (PR 44); the second is
     # the same model with the block's policy set back to keeping nothing
-    "joyai-llm-flash-ep16.loss_and_grads": "5294ed1a7af7f44801fff16f42ad2403a3412b3e01fcada75209e87512620ab5",
-    "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "5844f3eed7061b9dc01a3be268faebda56595716638a19098e278faad1c9927a",
-    "qwen3-next-80b-a3b-ep4-l12.prefill": "35056d2c1a442ee1ebb6aa16f6259ea0c6b24d9d0b66eb501dd1292c832d22e3",
+    "joyai-llm-flash-ep16.loss_and_grads": "9adb5bee0f9b5fb15b004725083fb907dd86a9353542b491f619839172e87d0f",
+    "joyai-llm-flash-ep16.loss_and_grads.nothing_kept": "4615c3a9fae914a09843c16617ce8683c66b5a816f9c43a2ca4abba068b7ffa7",
+    "joyai-llm-flash-ep16.loss_and_grads.b4x4096": "e6592fbda63d7b2d7ddf0ea43f2ca31ad364e045f31eb80fd01385484fefe1f5",
+    "qwen3-next-80b-a3b-ep4-l12.prefill": "e410ccfe26f21edb9f22d6493e207583de1a75d8ef5d7473a0591c15d68d4115",
     "qwen3-next-80b-a3b-ep4-l12.chunk": "7ae41b1230ae0b24733d230497ee8c6a964e0fcca34d76e14ad8146f864bd913",
 }
 
@@ -826,21 +878,23 @@ def _benchmark_model_entry(config):
         return json.load(f)["model"]
 
 
-@pytest.mark.parametrize("config,layers,width,new_tokens", [
-    ("lfm2-24b-a2b-l10", 3, 1024, 512),
-    ("granite-4.0-h-micro", 6, 512, 256),
+@pytest.mark.parametrize("config,layers,width,new_tokens,gmm_tiles", [
+    ("lfm2-24b-a2b-l10", 3, 1024, 512, ({(4096, 64, 128)}, {(64, 64, 64)})),
+    ("granite-4.0-h-micro", 6, 512, 256, (set(), set())),
     # the first period of its pattern (delta, delta, delta, attention), as
     # ``test_qwen3_next_served_programs_compile_for_v5e`` cuts it
-    ("qwen3-next-80b-a3b-ep4-l12", 4, 2048, 512),
-])
+    ("qwen3-next-80b-a3b-ep4-l12", 4, 2048, 512, ({(20480, 128, 128)}, {(160, 128, 160)})),
+], ids=lambda v: "tiles" if isinstance(v, tuple) else str(v))
 def test_served_families_programs_are_the_parents(
-    config, layers, width, new_tokens, one_chip, no_persistent_cache, no_locations, monkeypatch
+    config, layers, width, new_tokens, gmm_tiles, one_chip, no_persistent_cache, no_locations, monkeypatch
 ):
     """``lfm2-moe-serve-rollout-16`` and ``granite-h-micro-serve-chat``: the
     widest prefill and the chunk of the servers built from the benchmark's
     configurations (the depth cut as in the test above), lowered for the
     described chip: the pinned text (granite's the parent's still; the two
-    with a ``MoeLayer`` taken anew in PR 57)."""
+    with a ``MoeLayer`` taken anew in PR 57, and their prefills again in PR
+    60, whose grouped products run 128 rows a tile where they ran 512; the
+    chunks, at a row tile of their whole ``m``, did not move)."""
     from dlrover_tpu.models.build import build_model
     from dlrover_tpu.models.generation import SamplingConfig
     from dlrover_tpu.models.serving import ContinuousBatchingEngine
@@ -863,22 +917,27 @@ def test_served_families_programs_are_the_parents(
         held, _described(engine._state, one_chip),
         _described(jax.random.PRNGKey(0), one_chip),
     ).as_text()
+    # (rows, experts held, row tile) of the grouped products, a prefill's and a chunk's
+    assert (_gmm_row_tiles(prefill), _gmm_row_tiles(chunk)) == gmm_tiles
     got = {f"{config}.prefill": _sha256(prefill), f"{config}.chunk": _sha256(chunk)}
     assert got == {name: PARENT_FAMILY_PROGRAMS.get(name) for name in got}, got
 
 
-@pytest.mark.parametrize("kept,flash_kernels,pin", [
+@pytest.mark.parametrize("kept,batch,row_tile,flash_kernels,pin", [
     # a block: forward, dk/dv, dq, and under the parent's policy the forward again
-    ("flash_results", {"_fwd_kernel": 3, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads"),
-    ("nothing", {"_fwd_kernel": 6, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads.nothing_kept"),
+    ("flash_results", (1, 1024), 128, {"_fwd_kernel": 3, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads"),
+    ("nothing", (1, 1024), 128, {"_fwd_kernel": 6, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads.nothing_kept"),
+    # the cell's own batch (PR 60): 32,768 rows over 16 experts keep 512 rows a tile, and the parent's text
+    # (beyond one tile a head the forward walks too: PR 41)
+    ("flash_results", (4, 4096), 512, {"_walk_fwd_kernel": 3, "_walk_bwd_kernel": 6}, "joyai-llm-flash-ep16.loss_and_grads.b4x4096"),
 ])
 def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
-    kept, flash_kernels, pin, one_chip, on_chip_kernels, no_locations, monkeypatch
+    kept, batch, row_tile, flash_kernels, pin, one_chip, on_chip_kernels, no_locations, monkeypatch
 ):
     """``joyai-flash-train-ep16share``: the model built from the benchmark's
     configuration (two layers: the dense one and the first expert layer,
     with its MTP module: three blocks), its losses and their gradients over
-    b1 x 1024, lowered for the described chip. Each rematerialised block
+    b1 x 1024 (and over the cell's b4 x 4096), lowered for the described chip. Each rematerialised block
     keeps its flash kernel's ``out`` and ``lse`` (PR 44), so the text holds
     one flash ``tpu_custom_call`` a block fewer than the parent's: 9, not 12,
     and nothing else but the kernel's four transposes a block and one
@@ -886,7 +945,9 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
     back to ``nothing_saveable`` and the two names taken out of ``_fa_fwd``
     (they emit no operation, but number later private functions one higher)
     the text was PR 42's to the character until PR 57 changed ``MoeLayer``'s
-    part of both on purpose; both pins are PR 57's."""
+    part of both on purpose; at b1 x 1024 an expert's group holds 128 rows
+    and the grouped products' row tile follows it since PR 60, whose pins
+    these two are; the third, at the cell's batch, is the parent's."""
     import collections
     import re
 
@@ -901,7 +962,7 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
     entry = _benchmark_model_entry("joyai-llm-flash-ep16")
     model, loss_fn = build_model(
         {"family": entry["family"], "config": dict(entry["config"], num_hidden_layers=2)})
-    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=one_chip)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
 
@@ -919,6 +980,8 @@ def test_trained_moe_models_blocks_keep_their_flash_kernels_results(
     # over ``f32[rows, 256]`` behind the spread gates is gone)
     assert names.pop("kernel") == 12
     assert names == flash_kernels
+    rows = batch[0] * batch[1] * 2  # 4 x the mean load of the 16 experts held: 8 of 256 a token
+    assert _gmm_row_tiles(text) == {(rows, 16, row_tile)}
     # (on the kept ``out``, bf16; the expert layers' float32 gates are rounded by the same operation since PR 57)
     assert len(re.findall(r"stablehlo\.reduce_precision.*xbf16>", text)) == (3 if kept == "flash_results" else 0)
     assert _sha256(text) == PARENT_FAMILY_PROGRAMS[pin], _sha256(text)
@@ -1248,12 +1311,16 @@ def test_sdar_moe_cells_programs_compile_for_v5e(one_chip, monkeypatch, request,
 
     engine, held = served(2)
     assert engine.d == 9 and engine.blocks.block_length == 4  # the default for such a model: whole blocks of 3 passes
-    for lowered, scopes in ((prefill_of(engine, held, width), ("sdar.attend_prefill", "moe.route", "moe.experts")),
-                            (chunk_of(engine, held), ("sdar.attend_block", "moe.route", "moe.experts"))):
+    # (program, its scopes, the rows of its grouped products: positions x top-8)
+    for lowered, scopes, rows in (
+            (prefill_of(engine, held, width), ("sdar.attend_prefill", "moe.route", "moe.experts"), width * 8),
+            (chunk_of(engine, held), ("sdar.attend_block", "moe.route", "moe.experts"), slots * 4 * 8)):
         text = lowered.as_text(debug_info=True)
         assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {"kernel"}
         assert all(scope in text for scope in scopes) and "sdar.attend\"" not in text
         assert _device_bytes(lowered.compile()) < V5E_HBM_BYTES
+        # 128 rows a tile since PR 60 (512 until then: a pass's 512 rows lie in groups of ~6)
+        assert _gmm_row_tiles(text) == {(rows, 128, 128)}
 
     if request.config.getoption("capture") != "no":
         return

@@ -1,0 +1,14 @@
+"""The head's and the engine's own share of the decode chunk's device time,
+in percent: the operations under ``<family>.head``, ``serve.sample``,
+``serve.cache_write`` (the cache's scatter inside an attention module too)
+and ``serve.counters``, over the self time of all operations inside the
+chunk program's executions. A row of the table goes to the innermost of its
+path's components that one of the four kinds accepts
+(``trace_scopes.DECODE_PARTS``)."""
+
+from benchmark import trace_scopes
+
+
+def read(ctx):
+    return trace_scopes.share(trace_scopes.decode_table(ctx),
+                              lambda tab: trace_scopes.decode_part_seconds(tab, "head_sample"))

@@ -1,0 +1,12 @@
+"""What of the decode chunk's device time the program can name, in
+percent: inside the chunk program's executions (``trace_names.decode_chunk``,
+else ``^jit_chunk``), 100 less the operations with no ``op_name`` or none
+that holds a scope or a flax module (``unscoped``, by opcode in
+``device_scopes.json``) and less those the trace's record of the compiled
+program does not hold (``benchmark/trace_scopes.py``)."""
+
+from benchmark import trace_scopes
+
+
+def read(ctx):
+    return trace_scopes.share(trace_scopes.decode_table(ctx), trace_scopes.scoped_seconds)

@@ -1,0 +1,14 @@
+"""The recurrent mixers' share of the decode chunk's device time, in
+percent: the operations under ``gdn.*``, ``mamba.*``, ``lfm2.conv_mixer``
+and ``lfm2.conv`` (projections, convolution, the state's update, the
+gate), over the self
+time of all operations inside the chunk program's executions. A row of the
+table goes to the innermost of its path's components that one of the four
+kinds accepts (``trace_scopes.DECODE_PARTS``)."""
+
+from benchmark import trace_scopes
+
+
+def read(ctx):
+    return trace_scopes.share(trace_scopes.decode_table(ctx),
+                              lambda tab: trace_scopes.decode_part_seconds(tab, "state"))

@@ -211,20 +211,22 @@ def prefill_prompt(model, params, tokens, mask):
     """
     B, W = tokens.shape
     L = model.config.max_seq_len
-    cache = init_cache(model, B)
-    positions = jnp.maximum(
-        jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1, 0
-    )
-    kv_valid = jnp.zeros((B, L), bool).at[:, :W].set(mask)
+    with jax.named_scope("serve.cache_write"):  # the fresh row: zeros, its valid bits
+        cache = init_cache(model, B)
+        positions = jnp.maximum(
+            jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1, 0
+        )
+        kv_valid = jnp.zeros((B, L), bool).at[:, :W].set(mask)
     logits, cache = decode_apply(
         model, params, cache, tokens, positions, kv_valid
     )
-    return (
-        cache,
-        logits[:, -1].astype(jnp.float32),
-        positions[:, -1],
-        kv_valid,
-    )
+    with jax.named_scope("serve.sample"):  # what the first sampling reads
+        return (
+            cache,
+            logits[:, -1].astype(jnp.float32),
+            positions[:, -1],
+            kv_valid,
+        )
 
 
 def build_generate_fn(

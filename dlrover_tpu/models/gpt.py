@@ -303,14 +303,15 @@ class GPT(nn.Module):
             cfg.param_dtype,
             axes=(None, "embed"),
         )
-        if positions is None:
-            if decode:
-                raise ValueError("decode=True needs absolute positions")
-            pos_emb = wpe.astype(cfg.dtype)[None, :T]
-        else:
-            pos_emb = wpe.astype(cfg.dtype)[positions]  # [B, T, D]
-        x = wte.astype(cfg.dtype)[tokens] + pos_emb
-        x = constrain(x, "batch", "seq", "embed")
+        if positions is None and decode:
+            raise ValueError("decode=True needs absolute positions")
+        with jax.named_scope("gpt.embed"):
+            if positions is None:
+                pos_emb = wpe.astype(cfg.dtype)[None, :T]
+            else:
+                pos_emb = wpe.astype(cfg.dtype)[positions]  # [B, T, D]
+            x = wte.astype(cfg.dtype)[tokens] + pos_emb
+            x = constrain(x, "batch", "seq", "embed")
 
         # remat trades FLOPs for HBM in training; during incremental
         # decode there is no backward pass and the cache collection must
@@ -345,32 +346,35 @@ class GPT(nn.Module):
                     kv_valid=kv_valid,
                     cache_slots=cache_slots,
                 )
-        x = LayerNorm(cfg, name="ln_f")(x)
+        # the last norm, the head and the loss: one device scope (the
+        # blocks' parts are named by their flax modules)
+        with jax.named_scope("gpt.head"):
+            x = LayerNorm(cfg, name="ln_f")(x)
 
-        if cfg.tie_embeddings:
-            w_head = wte.astype(cfg.dtype)  # [V, D]
-            vocab_first = True
-        else:
-            w_head = param_with_axes(
-                "lm_head",
-                nn.initializers.normal(0.02),
-                (cfg.embed_dim, cfg.vocab_size),
-                cfg.param_dtype,
-                axes=("embed", "vocab"),
-            ).astype(cfg.dtype)  # [D, V]
-            vocab_first = False
+            if cfg.tie_embeddings:
+                w_head = wte.astype(cfg.dtype)  # [V, D]
+                vocab_first = True
+            else:
+                w_head = param_with_axes(
+                    "lm_head",
+                    nn.initializers.normal(0.02),
+                    (cfg.embed_dim, cfg.vocab_size),
+                    cfg.param_dtype,
+                    axes=("embed", "vocab"),
+                ).astype(cfg.dtype)  # [D, V]
+                vocab_first = False
 
-        if targets is not None:
-            # uniform contract: targets given -> per-token losses.
-            # ce_chunk=0 degenerates to one whole-sequence chunk (the
-            # dense math, just routed through the fused path) so the
-            # pairing with token_loss_mean can never be silently wrong.
-            return chunked_token_ce(
-                x, w_head, targets, cfg.ce_chunk or T, vocab_first
-            )
+            if targets is not None:
+                # uniform contract: targets given -> per-token losses.
+                # ce_chunk=0 degenerates to one whole-sequence chunk (the
+                # dense math, just routed through the fused path) so the
+                # pairing with token_loss_mean can never be silently wrong.
+                return chunked_token_ce(
+                    x, w_head, targets, cfg.ce_chunk or T, vocab_first
+                )
 
-        if vocab_first:
-            logits = jnp.einsum("btd,vd->btv", x, w_head)
-        else:
-            logits = jnp.dot(x, w_head)
-        return constrain(logits, "batch", "seq", "vocab")
+            if vocab_first:
+                logits = jnp.einsum("btd,vd->btv", x, w_head)
+            else:
+                logits = jnp.dot(x, w_head)
+            return constrain(logits, "batch", "seq", "vocab")

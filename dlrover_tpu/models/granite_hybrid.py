@@ -291,10 +291,12 @@ class Block(nn.Module):
         if cfg.layer_types[self.layer_idx] == MAMBA:
             mix = MambaMixer(cfg, name="mamba")(u, decode=decode, token_valid=token_valid)
         else:
-            mix = Attention(cfg, name="attn")(
-                u, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots)
+            with jax.named_scope("granite.attn"):  # its projections, and granite.attend inside
+                mix = Attention(cfg, name="attn")(
+                    u, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots)
         x = x + scaled(mix)
-        y = SwiGlu(cfg, cfg.shared_intermediate_size, name="mlp")(RMSNorm(cfg, name="post_norm")(x))
+        with jax.named_scope("granite.mlp"):
+            y = SwiGlu(cfg, cfg.shared_intermediate_size, name="mlp")(RMSNorm(cfg, name="post_norm")(x))
         return constrain(x + scaled(y), "batch", "seq", "embed")
 
 
@@ -328,8 +330,9 @@ class GraniteHybridLM(nn.Module):
         cfg = self.config
         B, T = tokens.shape
         wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
-        x = (wte[tokens].astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
-        x = constrain(x, "batch", "seq", "embed")
+        with jax.named_scope("granite.embed"):
+            x = (wte[tokens].astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
+            x = constrain(x, "batch", "seq", "embed")
         if decode:  # ``positions`` is the contract's; nothing here encodes a position
             token_valid = token_valid_at(self, B, T, kv_valid, cache_slots)
             for i in range(cfg.num_hidden_layers):
@@ -343,10 +346,11 @@ class GraniteHybridLM(nn.Module):
                                  policy=jax.checkpoint_policies.nothing_saveable)
             for i in range(cfg.num_hidden_layers):
                 x = block(cfg, layer_idx=i, name=f"block_{i}")(x)
-        h = RMSNorm(cfg, name="final_norm")(x)
-        if targets is not None:
-            # the fused loss knows no divisor: it goes onto the hidden state
-            h = (h.astype(jnp.float32) / cfg.logits_scaling).astype(h.dtype)
-            return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
-        logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
-        return constrain(logits / cfg.logits_scaling, "batch", "seq", "vocab")
+        with jax.named_scope("granite.head"):
+            h = RMSNorm(cfg, name="final_norm")(x)
+            if targets is not None:
+                # the fused loss knows no divisor: it goes onto the hidden state
+                h = (h.astype(jnp.float32) / cfg.logits_scaling).astype(h.dtype)
+                return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
+            logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
+            return constrain(logits / cfg.logits_scaling, "batch", "seq", "vocab")

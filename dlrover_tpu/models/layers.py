@@ -288,11 +288,12 @@ def _update_decode_cache(
         if T > 1:
             slots_bt = slots_bt + jnp.arange(T, dtype=slots_bt.dtype)[None, :]
         rows = jnp.arange(B)[:, None]
-        ck.value = ck.value.at[rows, slots_bt].set(k_store)
-        cv.value = cv.value.at[rows, slots_bt].set(v_store)
-        if int8_cache:
-            csk.value = csk.value.at[rows, slots_bt].set(k_scale)
-            csv.value = csv.value.at[rows, slots_bt].set(v_scale)
+        with jax.named_scope("serve.cache_write"):
+            ck.value = ck.value.at[rows, slots_bt].set(k_store)
+            cv.value = cv.value.at[rows, slots_bt].set(v_store)
+            if int8_cache:
+                csk.value = csk.value.at[rows, slots_bt].set(k_scale)
+                csv.value = csv.value.at[rows, slots_bt].set(v_scale)
         # cidx (the shared frontier) is meaningless per-row; leave it.
         # causal per (row, query): query written at slot slots_bt[b, t]
         # sees valid slots <= its own
@@ -305,16 +306,17 @@ def _update_decode_cache(
         return _read(mask)
     offset = cidx.value
     at = (0, offset) + (0,) * (k_store.ndim - 2)
-    ck.value = jax.lax.dynamic_update_slice(ck.value, k_store, at)
-    cv.value = jax.lax.dynamic_update_slice(cv.value, v_store, at)
-    if int8_cache:
-        csk.value = jax.lax.dynamic_update_slice(
-            csk.value, k_scale, (0, offset, 0)
-        )
-        csv.value = jax.lax.dynamic_update_slice(
-            csv.value, v_scale, (0, offset, 0)
-        )
-    cidx.value = offset + T
+    with jax.named_scope("serve.cache_write"):
+        ck.value = jax.lax.dynamic_update_slice(ck.value, k_store, at)
+        cv.value = jax.lax.dynamic_update_slice(cv.value, v_store, at)
+        if int8_cache:
+            csk.value = jax.lax.dynamic_update_slice(
+                csk.value, k_scale, (0, offset, 0)
+            )
+            csv.value = jax.lax.dynamic_update_slice(
+                csv.value, v_scale, (0, offset, 0)
+            )
+        cidx.value = offset + T
     if block_length:
         if kv_valid is None:
             raise ValueError("a mask by blocks of positions needs explicit kv_valid")
@@ -819,8 +821,6 @@ def chunked_token_ce(
     if T % chunk:
         raise ValueError(f"seq len {T} not divisible by ce_chunk {chunk}")
     C = T // chunk
-    xc = jnp.swapaxes(x.reshape(B, C, chunk, D), 0, 1)  # [C, B, c, D]
-    tc = jnp.swapaxes(targets.reshape(B, C, chunk), 0, 1)  # [C, B, c]
 
     @jax.checkpoint
     def body(carry, xs):
@@ -831,8 +831,11 @@ def chunked_token_ce(
             logits = jnp.einsum("bcd,dv->bcv", xb, w_head)
         return carry, _token_ce(logits, tb, ignore_index)
 
-    _, tls = jax.lax.scan(body, (), (xc, tc))  # [C, B, c]
-    return jnp.swapaxes(tls, 0, 1).reshape(B, T)
+    with jax.named_scope("loss.chunk"):  # the device scope of the head's products and the CE
+        xc = jnp.swapaxes(x.reshape(B, C, chunk, D), 0, 1)  # [C, B, c, D]
+        tc = jnp.swapaxes(targets.reshape(B, C, chunk), 0, 1)  # [C, B, c]
+        _, tls = jax.lax.scan(body, (), (xc, tc))  # [C, B, c]
+        return jnp.swapaxes(tls, 0, 1).reshape(B, T)
 
 
 def token_loss_mean(token_losses, targets, ignore_index: int = -1):

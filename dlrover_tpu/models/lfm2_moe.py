@@ -257,16 +257,19 @@ class Block(nn.Module):
         cfg = self.config
         u = RMSNorm(cfg, name="operator_norm")(x)
         if cfg.layer_types[self.layer_idx] == CONV:
-            x = x + ShortConv(cfg, name="conv")(u, decode=decode, token_valid=token_valid)
+            with jax.named_scope("lfm2.conv_mixer"):  # its projections, and lfm2.conv inside
+                x = x + ShortConv(cfg, name="conv")(u, decode=decode, token_valid=token_valid)
         else:
-            x = x + Attention(cfg, name="attn")(
-                u, decode=decode, positions=positions, kv_valid=kv_valid,
-                cache_slots=cache_slots)
+            with jax.named_scope("lfm2.attn"):  # its projections, and lfm2.attend inside
+                x = x + Attention(cfg, name="attn")(
+                    u, decode=decode, positions=positions, kv_valid=kv_valid,
+                    cache_slots=cache_slots)
         h = RMSNorm(cfg, name="ffn_norm")(x)
         if cfg.is_expert_block(self.layer_idx):
             y = MoeLayer(cfg.moe_sizes, name="moe")(h)
         else:
-            y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
+            with jax.named_scope("lfm2.mlp"):
+                y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
         return constrain(x + y, "batch", "seq", "embed")
 
 
@@ -303,7 +306,8 @@ class Lfm2MoeLM(nn.Module):
         cfg = self.config
         B, T = tokens.shape
         wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("lfm2.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         if decode:
             token_valid = token_valid_at(self, B, T, kv_valid, cache_slots)
             for i in range(cfg.num_hidden_layers):
@@ -317,8 +321,9 @@ class Lfm2MoeLM(nn.Module):
                                  policy=jax.checkpoint_policies.nothing_saveable)
             for i in range(cfg.num_hidden_layers):
                 x = block(cfg, layer_idx=i, name=f"block_{i}")(x)
-        h = RMSNorm(cfg, name="embedding_norm")(x)  # the family's name; applied at the END
-        if targets is not None:
-            return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
-        logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
-        return constrain(logits, "batch", "seq", "vocab")
+        with jax.named_scope("lfm2.head"):
+            h = RMSNorm(cfg, name="embedding_norm")(x)  # the family's name; applied at the END
+            if targets is not None:
+                return chunked_token_ce(h, wte, targets, cfg.ce_chunk or T, vocab_first=True)
+            logits = jnp.einsum("btd,vd->btv", h, wte, preferred_element_type=jnp.float32)
+            return constrain(logits, "batch", "seq", "vocab")

@@ -405,8 +405,9 @@ class Llama(nn.Module):
             cfg.param_dtype,
             axes=("vocab", "embed"),
         )
-        x = wte.astype(cfg.dtype)[tokens]
-        x = constrain(x, "batch", "seq", "embed")
+        with jax.named_scope("llama.embed"):
+            x = wte.astype(cfg.dtype)[tokens]
+            x = constrain(x, "batch", "seq", "embed")
         # decode bypasses remat: no backward pass, and the decode kwargs
         # must not cross jax.checkpoint (it would trace the bool).
         if cfg.use_remat and not decode:
@@ -427,7 +428,6 @@ class Llama(nn.Module):
                     kv_valid=kv_valid,
                     cache_slots=cache_slots,
                 )
-        x = RMSNorm(cfg, name="norm_f")(x)
         w_lm = param_with_axes(
             "lm_head",
             nn.initializers.normal(0.02),
@@ -435,16 +435,18 @@ class Llama(nn.Module):
             cfg.param_dtype,
             axes=("embed", "vocab"),
         )
-        if targets is not None:
-            return chunked_token_ce(
-                x,
-                w_lm.astype(cfg.dtype),
-                targets,
-                cfg.ce_chunk or T,
-                vocab_first=False,
-            )
-        logits = jnp.dot(x, w_lm.astype(cfg.dtype))
-        return constrain(logits, "batch", "seq", "vocab")
+        with jax.named_scope("llama.head"):
+            x = RMSNorm(cfg, name="norm_f")(x)
+            if targets is not None:
+                return chunked_token_ce(
+                    x,
+                    w_lm.astype(cfg.dtype),
+                    targets,
+                    cfg.ce_chunk or T,
+                    vocab_first=False,
+                )
+            logits = jnp.dot(x, w_lm.astype(cfg.dtype))
+            return constrain(logits, "batch", "seq", "vocab")
 
 
 def llama_loss(model_vars_or_logits, targets=None, aux_weight: float = 0.01):

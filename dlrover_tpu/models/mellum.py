@@ -280,7 +280,8 @@ class Block(nn.Module):
     def __call__(self, x):
         cfg = self.config
         attn = Attention(cfg, cfg.layer_type(self.layer_idx), name="attn")
-        x = x + attn(RMSNorm(cfg, name="norm_attn")(x))
+        with jax.named_scope("swa.attn"):  # norm and projections, swa.attend_* inside
+            x = x + attn(RMSNorm(cfg, name="norm_attn")(x))
         y = MoeLayer(cfg.moe_sizes, name="moe")(RMSNorm(cfg, name="norm_mlp")(x))
         return constrain(x + y, "batch", "seq", "embed")
 
@@ -307,16 +308,18 @@ class MellumLM(nn.Module):
                      ("vocab", "embed"), EMBED_INIT_STD)
         w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size),
                         ("embed", "vocab"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("swa.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         for i in range(cfg.num_hidden_layers):
             x = _block(cfg)(cfg, layer_idx=i, name=f"block_{i}")(x)
-        h = RMSNorm(cfg, name="norm_f")(x)
-        if targets is None:
-            return constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
-        losses = chunked_token_ce(
-            h, w_head, targets, cfg.ce_chunk or tokens.shape[1], vocab_first=False)
-        self.sow("metrics", "trunk_loss", token_loss_mean(losses, targets))
-        return losses
+        with jax.named_scope("swa.head"):
+            h = RMSNorm(cfg, name="norm_f")(x)
+            if targets is None:
+                return constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
+            losses = chunked_token_ce(
+                h, w_head, targets, cfg.ce_chunk or tokens.shape[1], vocab_first=False)
+            self.sow("metrics", "trunk_loss", token_loss_mean(losses, targets))
+            return losses
 
     @staticmethod
     def book_step_counters(metrics: dict) -> dict:

@@ -215,12 +215,14 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        x = x + LatentAttention(cfg, name="attn")(RMSNorm(cfg, name="norm_attn")(x))
+        with jax.named_scope("mla.attn"):  # norm, projections, and mla.attend inside
+            x = x + LatentAttention(cfg, name="attn")(RMSNorm(cfg, name="norm_attn")(x))
         h = RMSNorm(cfg, name="norm_mlp")(x)
         if cfg.is_expert_block(self.layer_idx):
             y = MoeLayer(cfg.moe_sizes, name="moe")(h)
         else:
-            y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
+            with jax.named_scope("mla.mlp"):
+                y = SwiGlu(cfg, cfg.intermediate_size, name="mlp")(h)
         return constrain(x + y, "batch", "seq", "embed")
 
 
@@ -270,17 +272,19 @@ class MlaMoeLM(nn.Module):
             axes=("vocab", "embed")).astype(cfg.dtype)
         w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size),
                         ("embed", "vocab"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("mla.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         for i in range(cfg.num_hidden_layers):
             x = _block(cfg)(cfg, layer_idx=i, name=f"block_{i}")(x)
-        h = RMSNorm(cfg, name="norm_f")(x)
+        with jax.named_scope("mla.head"):
+            h = RMSNorm(cfg, name="norm_f")(x)
         chunk = cfg.ce_chunk or T
 
         mtp = cfg.num_nextn_predict_layers > 0
         if mtp and (targets is not None or self.is_initializing()):
             # init traces the module whatever the call, so that its leaves exist
             nxt = targets if targets is not None else tokens
-            with jax.named_scope("mtp"):
+            with jax.named_scope("mla.mtp"):
                 h_mtp = MtpModule(cfg, name="mtp_0")(h, wte[jnp.maximum(nxt, 0)])
                 if targets is not None:
                     # position i predicts t_{i+2} = targets[i + 1]; the last has none
@@ -291,11 +295,12 @@ class MlaMoeLM(nn.Module):
                     self.sow("objective", "mtp", cfg.mtp_loss_weight * mtp_loss)
                     self.sow("metrics", "mtp_loss", mtp_loss)
 
-        if targets is None:
-            return constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
-        losses = chunked_token_ce(h, w_head, targets, chunk, vocab_first=False)
-        self.sow("metrics", "trunk_loss", token_loss_mean(losses, targets))
-        return losses
+        with jax.named_scope("mla.head"):
+            if targets is None:
+                return constrain(jnp.dot(h, w_head), "batch", "seq", "vocab")
+            losses = chunked_token_ce(h, w_head, targets, chunk, vocab_first=False)
+            self.sow("metrics", "trunk_loss", token_loss_mean(losses, targets))
+            return losses
 
     @staticmethod
     def book_step_counters(metrics: dict) -> dict:

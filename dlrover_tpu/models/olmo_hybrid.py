@@ -188,8 +188,9 @@ class Block(nn.Module):
                  token_valid=None):
         cfg = self.config
         if cfg.is_attention(self.layer_idx):
-            mix = FullAttention(cfg, name="attn")(
-                x, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots)
+            with jax.named_scope("olmo.attn"):  # its projections, olmo.attend_* inside
+                mix = FullAttention(cfg, name="attn")(
+                    x, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots)
         else:
             mix = GatedDeltaMixer(cfg, name="gdn")(x, decode=decode, token_valid=token_valid)
         h = x + RMSNorm(cfg, cfg.sublayer_norm_init, name="post_mixer_norm")(mix)
@@ -229,14 +230,16 @@ class OlmoHybridLM(nn.Module):
         B, T = tokens.shape
         wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), cfg.embed_init_std)
         w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size), ("embed", "vocab"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("olmo.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         token_valid = token_valid_at(self, B, T, kv_valid, cache_slots) if decode else None
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, layer_idx=i, name=f"block_{i}")(
                 x, decode=decode, kv_valid=kv_valid, cache_slots=cache_slots,
                 token_valid=token_valid)
-        if decode and T > 1:
-            x = x[:, -1:]
-        h = RMSNorm(cfg, name="final_norm")(x)
-        logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
-        return constrain(logits, "batch", "seq", "vocab")
+        with jax.named_scope("olmo.head"):
+            if decode and T > 1:
+                x = x[:, -1:]
+            h = RMSNorm(cfg, name="final_norm")(x)
+            logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
+            return constrain(logits, "batch", "seq", "vocab")

@@ -280,9 +280,10 @@ class Block(nn.Module):
         cfg = self.config
         u = ZeroCentredRMSNorm(cfg, name="input_norm")(x)
         if cfg.is_attention(self.layer_idx):
-            x = x + GatedAttention(cfg, name="attn")(
-                u, decode=decode, positions=positions, kv_valid=kv_valid,
-                cache_slots=cache_slots)
+            with jax.named_scope("qwen3next.attn"):  # its projections and gate, qwen3next.attend inside
+                x = x + GatedAttention(cfg, name="attn")(
+                    u, decode=decode, positions=positions, kv_valid=kv_valid,
+                    cache_slots=cache_slots)
         else:
             x = x + GatedDeltaMixer(cfg, inverse="squaring", name="gdn")(
                 u, decode=decode, token_valid=token_valid)
@@ -326,14 +327,16 @@ class Qwen3NextLM(nn.Module):
         B, T = tokens.shape
         wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), cfg.embed_init_std)
         w_head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size), ("embed", "vocab"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("qwen3next.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         token_valid = token_valid_at(self, B, T, kv_valid, cache_slots) if decode else None
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, layer_idx=i, name=f"block_{i}")(
                 x, decode=decode, positions=positions, kv_valid=kv_valid,
                 cache_slots=cache_slots, token_valid=token_valid)
-        h = ZeroCentredRMSNorm(cfg, name="final_norm")(x)
-        if targets is not None:
-            return chunked_token_ce(h, w_head, targets, cfg.ce_chunk or T, vocab_first=False)
-        logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
-        return constrain(logits, "batch", "seq", "vocab")
+        with jax.named_scope("qwen3next.head"):
+            h = ZeroCentredRMSNorm(cfg, name="final_norm")(x)
+            if targets is not None:
+                return chunked_token_ce(h, w_head, targets, cfg.ce_chunk or T, vocab_first=False)
+            logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
+            return constrain(logits, "batch", "seq", "vocab")

@@ -196,9 +196,10 @@ class Block(nn.Module):
     def __call__(self, x, *, decode: bool = False, positions=None, kv_valid=None,
                  cache_slots=None):
         cfg = self.config
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg, name="input_norm")(x), decode=decode, positions=positions,
-            kv_valid=kv_valid, cache_slots=cache_slots)
+        with jax.named_scope("sdar.attn"):  # norm and projections, sdar.attend_* inside
+            x = x + Attention(cfg, name="attn")(
+                RMSNorm(cfg, name="input_norm")(x), decode=decode, positions=positions,
+                kv_valid=kv_valid, cache_slots=cache_slots)
         y = MoeLayer(cfg.moe_sizes, name="moe")(RMSNorm(cfg, name="post_attention_norm")(x))
         return constrain(x + y, "batch", "seq", "embed")
 
@@ -237,12 +238,14 @@ class SdarMoeLM(nn.Module):
         cfg = self.config
         wte = weight("wte", cfg, (cfg.vocab_size, cfg.hidden_size), ("vocab", "embed"), cfg.embed_init_std)
         head = weight("lm_head", cfg, (cfg.hidden_size, cfg.vocab_size), ("embed", "vocab"))
-        x = constrain(wte[tokens], "batch", "seq", "embed")
+        with jax.named_scope("sdar.embed"):
+            x = constrain(wte[tokens], "batch", "seq", "embed")
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, name=f"block_{i}")(
                 x, decode=decode, positions=positions, kv_valid=kv_valid, cache_slots=cache_slots)
-        if decode and cache_slots is None:
-            x = x[:, -1:]  # a prefill: no holder reads a prompt position's logits
-        h = RMSNorm(cfg, name="final_norm")(x)
-        logits = jnp.einsum("btd,dv->btv", h, head, preferred_element_type=jnp.float32)
-        return constrain(logits, "batch", "seq", "vocab")
+        with jax.named_scope("sdar.head"):
+            if decode and cache_slots is None:
+                x = x[:, -1:]  # a prefill: no holder reads a prompt position's logits
+            h = RMSNorm(cfg, name="final_norm")(x)
+            logits = jnp.einsum("btd,dv->btv", h, head, preferred_element_type=jnp.float32)
+            return constrain(logits, "batch", "seq", "vocab")

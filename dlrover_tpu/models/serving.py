@@ -519,19 +519,21 @@ class ContinuousBatchingEngine:
             The stored prefix cache is immutable — every admission
             derives a fresh row from it."""
             W = toks.shape[1]
-            positions = last_pos + jnp.cumsum(
-                mask.astype(jnp.int32), axis=1
-            )
-            kvv = row_kv[None, :].at[:, start:start + W].set(mask)
+            with jax.named_scope("serve.cache_write"):
+                positions = last_pos + jnp.cumsum(
+                    mask.astype(jnp.int32), axis=1
+                )
+                kvv = row_kv[None, :].at[:, start:start + W].set(mask)
             logits, cache = decode_apply(
                 model, params, row_cache, toks, positions, kvv
             )
-            return (
-                cache,
-                logits[0, -1].astype(jnp.float32),
-                positions[0, -1],
-                kvv[0],
-            )
+            with jax.named_scope("serve.sample"):
+                return (
+                    cache,
+                    logits[0, -1].astype(jnp.float32),
+                    positions[0, -1],
+                    kvv[0],
+                )
 
         def prefill_block_row(params, toks, mask, tail, n_tail):
             """A block model's admission: the prompt's WHOLE blocks
@@ -551,6 +553,7 @@ class ContinuousBatchingEngine:
             )
             return cache, first, jnp.sum(mask, dtype=jnp.int32), kv_valid[0]
 
+        @jax.named_scope("serve.admit")  # the device scope of every copy below
         def admit(state, row_cache, row_logits, row_pos, row_kv,
                   row_allow, slot, next_slot, cap):
             """Insert a prefilled row at ``slot`` (traced — one compile
@@ -613,35 +616,41 @@ class ContinuousBatchingEngine:
             def chunk(params, state, rng):
                 if paged:
                     (pool, tables) = state[0]
-                    state = (
-                        kv_blocks.gather_cache(pool, tables), *state[1:]
-                    )
+                    with jax.named_scope("serve.cache_write"):
+                        state = (
+                            kv_blocks.gather_cache(pool, tables), *state[1:]
+                        )
 
                 def step(carry, t):
                     (cache, kv_valid, last_logits, cur_pos, allow,
                      budget, done, row_f, rng) = carry
-                    rng, sub = jax.random.split(rng)
-                    # per-request constrained decoding (RL action
-                    # spaces): sampling AND behavior logprobs come from
-                    # the masked distribution — what the policy can
-                    # actually emit. An all-True row is a no-op.
-                    tok, emit, tok_logp, done = sample_step(
-                        jnp.where(allow, last_logits, -jnp.inf), done,
-                        sub, s,
-                    )
-                    # device-side cap: the token that exhausts the
-                    # budget is still emitted (host parity: emit while
-                    # count < cap), then the row is done
-                    emit = emit & (budget > 0)
-                    budget = budget - emit.astype(jnp.int32)
-                    done = done | (budget <= 0)
-                    write_slots = jnp.minimum(row_f, L - 1)
-                    slot_hits = (
-                        jnp.arange(L)[None, :] == write_slots[:, None]
-                    )
-                    row_f = row_f + 1
-                    kv_valid = kv_valid | slot_hits
-                    pos = cur_pos + 1
+                    # device scopes (``jax.named_scope``: HLO metadata
+                    # only) name the engine's share of a step; the
+                    # model's call stays under the model's own
+                    with jax.named_scope("serve.sample"):
+                        rng, sub = jax.random.split(rng)
+                        # per-request constrained decoding (RL action
+                        # spaces): sampling AND behavior logprobs come from
+                        # the masked distribution — what the policy can
+                        # actually emit. An all-True row is a no-op.
+                        tok, emit, tok_logp, done = sample_step(
+                            jnp.where(allow, last_logits, -jnp.inf), done,
+                            sub, s,
+                        )
+                        # device-side cap: the token that exhausts the
+                        # budget is still emitted (host parity: emit while
+                        # count < cap), then the row is done
+                        emit = emit & (budget > 0)
+                        budget = budget - emit.astype(jnp.int32)
+                        done = done | (budget <= 0)
+                    with jax.named_scope("serve.cache_write"):
+                        write_slots = jnp.minimum(row_f, L - 1)
+                        slot_hits = (
+                            jnp.arange(L)[None, :] == write_slots[:, None]
+                        )
+                        row_f = row_f + 1
+                        kv_valid = kv_valid | slot_hits
+                        pos = cur_pos + 1
                     logits, cache, sown = decode_apply(
                         model, params, cache, tok[:, None], pos[:, None],
                         kv_valid, cache_slots=write_slots, metrics=True,
@@ -649,11 +658,14 @@ class ContinuousBatchingEngine:
                     # what the model counted in this step (a routed
                     # layer's load), as device scalars: stacked by the
                     # scan, read back with the tokens, booked on the host
-                    counters = counters_of(sown) if counters_of else {}
+                    with jax.named_scope("serve.counters"):
+                        counters = counters_of(sown) if counters_of else {}
+                    with jax.named_scope("serve.sample"):  # what the next step samples from
+                        next_logits = logits[:, 0].astype(jnp.float32)
                     return (
                         cache,
                         kv_valid,
-                        logits[:, 0].astype(jnp.float32),
+                        next_logits,
                         pos,
                         allow,
                         budget,
@@ -667,15 +679,16 @@ class ContinuousBatchingEngine:
                 )
                 new_state = carry[:-1]
                 if paged:
-                    new_state = (
-                        (
-                            kv_blocks.scatter_cache(
-                                pool, tables, new_state[0]
+                    with jax.named_scope("serve.cache_write"):
+                        new_state = (
+                            (
+                                kv_blocks.scatter_cache(
+                                    pool, tables, new_state[0]
+                                ),
+                                tables,
                             ),
-                            tables,
-                        ),
-                        *new_state[1:],
-                    )
+                            *new_state[1:],
+                        )
                 return new_state, out
 
             return chunk
@@ -715,80 +728,84 @@ class ContinuousBatchingEngine:
                 def one_pass(carry, _):
                     (cache, kv_valid, blk, base_pos, allow, budget, done,
                      row_f, rng) = carry
-                    rng, sub = jax.random.split(rng)
-                    live = ~done
-                    write_slots = jnp.minimum(row_f, L - Bl)
-                    own = jnp.arange(L)[None, :] - write_slots[:, None]
-                    kv_pass = kv_valid | ((own >= 0) & (own < Bl))
+                    with jax.named_scope("serve.cache_write"):
+                        live = ~done
+                        write_slots = jnp.minimum(row_f, L - Bl)
+                        own = jnp.arange(L)[None, :] - write_slots[:, None]
+                        kv_pass = kv_valid | ((own >= 0) & (own < Bl))
                     logits, cache, sown = decode_apply(
                         model, params, cache,
                         jnp.where(blk.decided, blk.tok, mask_id),
                         base_pos[:, None] + at[None, :], kv_pass,
                         cache_slots=write_slots, metrics=True,
                     )
-                    choice, choice_logp = _choose(
-                        jnp.where(
-                            allow[:, None, :], logits.astype(jnp.float32),
-                            -jnp.inf,
-                        ), sub, s,
-                    )
-                    undecided = ~blk.decided
-                    n_undecided = jnp.sum(undecided, axis=1, dtype=jnp.int32)
-                    n_fix = -(-n_undecided // jnp.maximum(blk.left, 1))
-                    newly = undecided & (
-                        _rank_by_confidence(choice_logp, undecided)
-                        < n_fix[:, None]
-                    )
-                    tok = jnp.where(newly, choice, blk.tok)
-                    logp = jnp.where(newly, choice_logp, blk.logp)
-                    at_pass = jnp.where(
-                        newly, (S - blk.left)[:, None], blk.at_pass
-                    )
-                    decided = blk.decided | newly
-                    final = live & _block_final(undecided, ~decided)
-                    # what a final block emits
-                    emit = (
-                        final[:, None]
-                        & (at[None, :] >= blk.emit_from[:, None])
-                        & (at[None, :] - blk.emit_from[:, None]
-                           < budget[:, None])
-                    )
-                    if s.eos_id >= 0:  # the EOS is kept, nothing after it
-                        eos = emit & (tok == s.eos_id)
-                        emit = emit & (
-                            jnp.cumsum(eos, axis=1) - eos.astype(jnp.int32)
-                            == 0
+                    with jax.named_scope("serve.sample"):
+                        rng, sub = jax.random.split(rng)
+                        choice, choice_logp = _choose(
+                            jnp.where(
+                                allow[:, None, :], logits.astype(jnp.float32),
+                                -jnp.inf,
+                            ), sub, s,
                         )
-                        done = done | jnp.any(emit & eos, axis=1)
-                    budget = budget - jnp.sum(emit, axis=1, dtype=jnp.int32)
-                    done = done | (final & (budget <= 0))
-                    counters = dict(
-                        counters_of(sown) if counters_of else {},
-                        **{
-                            "block.row_passes": jnp.sum(live, dtype=jnp.int32),
-                            "block.commit_row_passes": jnp.sum(
-                                live & (n_undecided == 0), dtype=jnp.int32),
-                            "block.tokens_fixed": jnp.sum(
-                                newly & live[:, None], dtype=jnp.int32),
-                            "block.blocks_final": jnp.sum(
-                                final, dtype=jnp.int32),
-                            "block.positions_undecided_in": jnp.sum(
-                                undecided & live[:, None], dtype=jnp.int32),
-                            "kv_positions_valid": jnp.sum(
-                                kv_pass & live[:, None], dtype=jnp.int32),
-                        },
-                    )
-                    fresh = _fresh_block(self.blocks, (tok.shape[0],))
-                    blk = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(
-                            final.reshape((-1,) + (1,) * (old.ndim - 1)),
-                            new, old,
-                        ),
-                        fresh,
-                        _Block(tok, decided, logp, at_pass,
-                               blk.left - (n_undecided > 0), blk.emit_from),
-                    )
-                    step = jnp.where(final, Bl, 0)
+                        undecided = ~blk.decided
+                        n_undecided = jnp.sum(undecided, axis=1, dtype=jnp.int32)
+                        n_fix = -(-n_undecided // jnp.maximum(blk.left, 1))
+                        newly = undecided & (
+                            _rank_by_confidence(choice_logp, undecided)
+                            < n_fix[:, None]
+                        )
+                        tok = jnp.where(newly, choice, blk.tok)
+                        logp = jnp.where(newly, choice_logp, blk.logp)
+                        at_pass = jnp.where(
+                            newly, (S - blk.left)[:, None], blk.at_pass
+                        )
+                        decided = blk.decided | newly
+                        final = live & _block_final(undecided, ~decided)
+                        # what a final block emits
+                        emit = (
+                            final[:, None]
+                            & (at[None, :] >= blk.emit_from[:, None])
+                            & (at[None, :] - blk.emit_from[:, None]
+                               < budget[:, None])
+                        )
+                        if s.eos_id >= 0:  # the EOS is kept, nothing after it
+                            eos = emit & (tok == s.eos_id)
+                            emit = emit & (
+                                jnp.cumsum(eos, axis=1) - eos.astype(jnp.int32)
+                                == 0
+                            )
+                            done = done | jnp.any(emit & eos, axis=1)
+                        budget = budget - jnp.sum(emit, axis=1, dtype=jnp.int32)
+                        done = done | (final & (budget <= 0))
+                    with jax.named_scope("serve.counters"):
+                        counters = dict(
+                            counters_of(sown) if counters_of else {},
+                            **{
+                                "block.row_passes": jnp.sum(live, dtype=jnp.int32),
+                                "block.commit_row_passes": jnp.sum(
+                                    live & (n_undecided == 0), dtype=jnp.int32),
+                                "block.tokens_fixed": jnp.sum(
+                                    newly & live[:, None], dtype=jnp.int32),
+                                "block.blocks_final": jnp.sum(
+                                    final, dtype=jnp.int32),
+                                "block.positions_undecided_in": jnp.sum(
+                                    undecided & live[:, None], dtype=jnp.int32),
+                                "kv_positions_valid": jnp.sum(
+                                    kv_pass & live[:, None], dtype=jnp.int32),
+                            },
+                        )
+                    with jax.named_scope("serve.cache_write"):
+                        fresh = _fresh_block(self.blocks, (tok.shape[0],))
+                        blk = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(
+                                final.reshape((-1,) + (1,) * (old.ndim - 1)),
+                                new, old,
+                            ),
+                            fresh,
+                            _Block(tok, decided, logp, at_pass,
+                                   blk.left - (n_undecided > 0), blk.emit_from),
+                        )
+                        step = jnp.where(final, Bl, 0)
                     return (
                         cache,
                         jnp.where(final[:, None], kv_pass, kv_valid),
@@ -826,6 +843,7 @@ class ContinuousBatchingEngine:
                 state = admit(state, *row, slot, nxt, cap)
             return state
 
+        @jax.named_scope("serve.admit")
         def paged_admit(state, row_cache, row_logits, row_pos, row_kv,
                         row_allow, slot, next_slot, cap, table_row):
             """Paged-layout insert: scatter the prefilled [1, L] row

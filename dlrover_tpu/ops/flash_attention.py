@@ -399,7 +399,9 @@ def _kernel_plan(
 
 def _built(kernel: str, plan: dict):
     """The span around one kernel's tracing: ``flash.kernel_built`` with
-    the plan as stats."""
+    the plan as stats. No metric reads it: tests are its readers
+    (``tests/test_spans.py`` holds the stats of GPT-2-small's step), and the
+    kernels' device time is read from the trace."""
     return span("flash.kernel_built", kernel=kernel, **plan)
 
 

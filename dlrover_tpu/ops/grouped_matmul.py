@@ -102,7 +102,8 @@ def grouped_matmul(lhs, rhs, group_sizes):
         tiling = tiling_for(groups)
         row_tile = tiling(m, *rhs.shape[1:])[0]
         # where a program is traced, never in a step: what the rule chose, and
-        # the most (group, row tile) visits the kernel's grid can make
+        # the most (group, row tile) visits the kernel's grid can make. No
+        # metric reads the span: tests are its readers (tests/test_mla_moe.py)
         with span("moe.gmm_built", m=m, groups=groups, row_tile=row_tile,
                   visits_bound=m // row_tile + groups - 1):
             return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling)

@@ -300,14 +300,16 @@ def build_train_step(
                 logits, mutated = model.apply(
                     {"params": p}, inputs, mutable=collections
                 )
-            loss = loss_fn(logits, targets)
-            aux_leaves = jax.tree.leaves(mutated.get("losses", {}))
-            if aux_leaves and aux_loss_weight:
-                loss = loss + aux_loss_weight * sum(
-                    jnp.sum(a) for a in aux_leaves
-                )
-            for term in jax.tree.leaves(mutated.get("objective", {})):
-                loss = loss + jnp.sum(term)
+            with jax.named_scope("train.loss"):
+                loss = loss_fn(logits, targets)
+            with jax.named_scope("train.aux_loss"):
+                aux_leaves = jax.tree.leaves(mutated.get("losses", {}))
+                if aux_leaves and aux_loss_weight:
+                    loss = loss + aux_loss_weight * sum(
+                        jnp.sum(a) for a in aux_leaves
+                    )
+                for term in jax.tree.leaves(mutated.get("objective", {})):
+                    loss = loss + jnp.sum(term)
             return loss, unfreeze(mutated.get("metrics", {}))
 
         return jax.value_and_grad(compute_loss, has_aux=True)(params)
@@ -331,10 +333,12 @@ def build_train_step(
                 loss_acc, grads_acc = carry
                 mi, mt = xs
                 (loss, metrics), grads = grads_of(state.params, mi, mt)
-                grads = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
-                )
-                return (loss_acc + loss, grads), metrics
+                with jax.named_scope("train.accumulate"):
+                    grads = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
+                    )
+                    loss_acc = loss_acc + loss
+                return (loss_acc, grads), metrics
 
             zero_grads = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
@@ -343,22 +347,29 @@ def build_train_step(
                 one, (jnp.zeros((), jnp.float32), zero_grads),
                 (micro_in, micro_tgt),
             )
-            metrics = jax.tree.map(lambda m: jnp.sum(m, axis=0), per_slice)
-            loss = loss / accum
-            grads = jax.tree.map(
-                lambda g, p: (g / accum).astype(p.dtype),
-                grads,
-                state.params,
+            with jax.named_scope("train.accumulate"):
+                metrics = jax.tree.map(lambda m: jnp.sum(m, axis=0), per_slice)
+                loss = loss / accum
+                grads = jax.tree.map(
+                    lambda g, p: (g / accum).astype(p.dtype),
+                    grads,
+                    state.params,
+                )
+        # Device scopes (``jax.named_scope``: HLO metadata, nothing at run
+        # time): what follows is the update; an op_name holding
+        # ``transpose(`` is the backward pass, the rest the forward
+        # (benchmark/trace_scopes.py reads a step's device time by them).
+        with jax.named_scope("train.optimizer"):
+            grads = zero_frozen(grads)
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, zero_frozen(updates))
+            new_state = TrainState(
+                step=state.step + 1, params=new_params, opt_state=new_opt
             )
-        grads = zero_frozen(grads)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, zero_frozen(updates))
-        new_state = TrainState(
-            step=state.step + 1, params=new_params, opt_state=new_opt
-        )
         if not return_metrics:
             return new_state, loss
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("train.grad_norm"):
+            metrics["grad_norm"] = optax.global_norm(grads)
         return new_state, (loss, metrics)
 
     jitted = jax.jit(

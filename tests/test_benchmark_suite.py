@@ -36,6 +36,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_metrics_mellum",
     "benchmark.tests.test_metrics_olmo_hybrid",
     "benchmark.tests.test_metrics_sdar_moe",
+    "benchmark.tests.test_trace_scopes",
 )
 
 from benchmark.tests.test_metrics import *  # noqa: E402,F401,F403
@@ -51,14 +52,17 @@ from benchmark.tests.test_metrics_mla_flash_calls import *  # noqa: E402,F401,F4
 from benchmark.tests.test_metrics_mellum import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_olmo_hybrid import *  # noqa: E402,F401,F403
 from benchmark.tests.test_metrics_sdar_moe import *  # noqa: E402,F401,F403
+from benchmark.tests.test_trace_scopes import *  # noqa: E402,F401,F403
 
 
 def benchmark_as_it_stood_at(bench_file, cell_name):
     """``BENCHMARK.json`` with the entries of later PRs taken out: no cell
-    behind ``cell_name``, no configuration behind its own, the later cells'
-    names out of every ``workloads`` list, and no metric that listed only
-    them. New entries go at the end of their lists, so this is the file as
-    the PR that added ``cell_name`` left it, whatever came after."""
+    behind ``cell_name``, no configuration behind its own, no metric from the
+    first one on that lists only later cells (new entries go at the end of
+    their lists, so every metric behind it is a later PR's too, also one that
+    reads earlier cells as well: PR 61's eleven), and the later cells' names
+    out of the ``workloads`` lists that stay. So this is the file as the PR
+    that added ``cell_name`` left it, whatever came after."""
     cells = [w["name"] for w in bench_file["workloads"]]
     later = set(cells[cells.index(cell_name) + 1:])
     out = dict(bench_file, workloads=[w for w in bench_file["workloads"] if w["name"] not in later])
@@ -69,9 +73,9 @@ def benchmark_as_it_stood_at(bench_file, cell_name):
         kept = []
         for m in bench_file[key]:
             if "workloads" in m:
+                if later.issuperset(m["workloads"]):
+                    break
                 m = dict(m, workloads=[c for c in m["workloads"] if c not in later])
-                if not m["workloads"]:
-                    continue
             kept.append(m)
         out[key] = kept
     return out
